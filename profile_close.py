@@ -663,11 +663,9 @@ def apply_report(n_txs=5000, n_ledgers=3, workers=4, both=True):
 
 def assert_budget(budget_ms=2000.0, n_txs=5000, n_ledgers=3):
     """Close-regression gate: clean (unprofiled) p50 of the standard
-    close drive, exit nonzero when it exceeds the budget.  relay_watch.py
-    queues this each green window so a regression shows up next to the
-    measurement that would otherwise mask it.  The default budget is the
-    quiet-window round-7 p50 plus this host's ±0.4 s window noise — a
-    REGRESSION gate, not the ≤1.0 s target itself."""
+    close drive, exit nonzero when it exceeds the budget.  The default
+    budget is the quiet-window round-7 p50 plus this host's ±0.4 s window
+    noise — a REGRESSION gate, not the ≤1.0 s target itself."""
     p50, _h = _timed_close_run(92, n_txs, n_ledgers)
     ok = p50 * 1e3 <= budget_ms
     print(

@@ -20,9 +20,8 @@ representative two-bucket merge, then the catchup-from-archive leg
 (full-tree re-hash from disk) and per-backend bit-identity on every
 bucket the rung produced.  Writes STATE_LADDER_r22.json.
 hash_ab: one framed buffer through the host backend and the device
-kernel; exits 1 when the device leg is below 2x host throughput (the
-relay_watch bucket_hash_r22 acceptance gate — expected to fail on a
-CPU-only host, where "device" is XLA-CPU).
+kernel; exits 1 when the device leg is below 2x host throughput
+(expected to fail on a CPU-only host, where "device" is XLA-CPU).
 """
 
 import json
@@ -346,11 +345,10 @@ def ladder(max_rung: int = 1_000_000):
 
 
 def hash_ab(mb: int = 64):
-    """Device-vs-host bucket-hash A/B on one framed buffer (the
-    relay_watch bucket_hash_r22 gate): exits 1 below 2x host
-    throughput.  On a real TPU window the device leg is the Pallas
-    kernel; on a CPU-only host it is XLA-CPU and the gate is expected
-    to fail — the exit code IS the verdict."""
+    """Device-vs-host bucket-hash A/B on one framed buffer: exits 1
+    below 2x host throughput.  On a real TPU the device leg is the
+    Pallas kernel; on a CPU-only host it is XLA-CPU and the gate is
+    expected to fail — the exit code IS the verdict."""
     import struct
 
     from stellar_tpu.bucket import hashplane

@@ -290,9 +290,8 @@ def _try_native_merge(
         paths[0], paths[1], paths[2:], keep_dead_entries, tmp
     )
     if res is None:
-        # engine unavailable, merge failed, or the .so predates the v2
-        # hash symbol: the Python merge below produces the identical
-        # record stream AND the identical v2 hash
+        # engine unavailable or merge failed: the Python merge below
+        # produces the identical record stream AND the identical v2 hash
         return None
     h, count = res
     if count == 0:

@@ -31,10 +31,6 @@ tests/test_hashplane.py):
   pool.  The default whenever the extension builds.
 - ``hashlib`` — the always-available last resort (and the differential
   oracle), forced by ``STELLAR_TPU_NO_NATIVE_HASH=1``.
-
-A stale prebuilt native .so that predates the v2 entry points simply
-lacks the symbols; the loaders report None and resolution falls through
-to hashlib — never to a silently different hash.
 """
 
 from __future__ import annotations
@@ -250,7 +246,7 @@ def backend_by_name(
         from .. import native
 
         mod = native.load_sighash()
-        if mod is None or not hasattr(mod, "sha256_batch"):
+        if mod is None:
             return None
         return NativeBackend(mod)
     if name in ("device", "device-xla", "device-pallas"):
@@ -269,8 +265,8 @@ def backend_by_name(
 
 def get_backend(config=None) -> BucketHashBackend:
     """Resolve the active backend: device when Config.DEVICE_BUCKET_HASH
-    (and jax imports), else native (when the extension builds AND has
-    the v2 entries — a stale .so falls through), else hashlib."""
+    (and jax imports), else native (when the extension builds), else
+    hashlib."""
     want_device = bool(config is not None and getattr(
         config, "DEVICE_BUCKET_HASH", False
     ))

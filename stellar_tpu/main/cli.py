@@ -210,13 +210,9 @@ def _load_xdr(cfg, bucket_file: str) -> int:
 
     def load(app):
         # a default-constructed Bucket(path) has the zero hash, which means
-        # "empty" — hash the file (streamed; hashlib.file_digest is 3.11+
-        # but we support 3.10) so apply actually replays it
-        h = hashlib.sha256()
+        # "empty" — hash the file so apply actually replays it
         with open(bucket_file, "rb") as f:
-            for chunk in iter(lambda: f.read(1 << 20), b""):
-                h.update(chunk)
-        digest = h.digest()
+            digest = hashlib.file_digest(f, "sha256").digest()
         Bucket(bucket_file, hash=digest).apply(app.database)
         print(f"applied {bucket_file}")
         return 0
@@ -256,32 +252,8 @@ def _run_node(cfg, new_db: bool, metrics) -> int:
     return 0
 
 
-def _honor_jax_platforms_env() -> None:
-    """Make ``JAX_PLATFORMS=cpu stellar-tpu ...`` actually run jax on CPU.
-
-    Deployment images may register an accelerator platform from
-    sitecustomize at interpreter start, which LATCHES jax's platform choice
-    before the env var is consulted — a node configured with
-    SIGNATURE_BACKEND=tpu would then hang in backend init whenever the
-    accelerator transport is down, even though the operator explicitly
-    asked for CPU.  Re-assert the operator's intent via jax.config (a
-    no-op when jax is absent or the platform already matches)."""
-    import os
-
-    want = os.environ.get("JAX_PLATFORMS")
-    if not want:
-        return
-    try:
-        import jax
-
-        jax.config.update("jax_platforms", want)
-    except Exception:
-        pass  # jax not installed / unknown platform: surfaces at first use
-
-
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
-    _honor_jax_platforms_env()
     from .config import Config
 
     conf_path = "stellar-tpu.cfg"

@@ -220,6 +220,10 @@ class CommandHandler:
             ),
             "network": app.config.NETWORK_PASSPHRASE,
             "build": app.config.VERSION_STR,
+            # what verifies signatures on this node: for the tpu backend
+            # the device as JAX reports it, the kernel lowering, and the
+            # dispatch / cutover / wedge-latch counters
+            "sig_backend": app.sig_backend.stats(),
         }
         return {"info": info}
 

@@ -8,96 +8,8 @@ libsodium; we route every verify through the chosen SigBackend).
 from __future__ import annotations
 
 import os
-
-try:
-    import tomllib
-except ModuleNotFoundError:  # Python < 3.11: stdlib tomllib missing
-    try:
-        import tomli as tomllib  # the identical pre-3.11 backport, if present
-    except ModuleNotFoundError:
-        tomllib = None  # Config.load falls back to _parse_minimal_toml
+import tomllib
 from typing import Dict, List, Optional
-
-
-def _strip_toml_comment(line: str) -> str:
-    """Drop a trailing # comment, respecting quoted strings."""
-    in_str = False
-    for i, c in enumerate(line):
-        if c == '"' and (i == 0 or line[i - 1] != "\\"):
-            in_str = not in_str
-        elif c == "#" and not in_str:
-            return line[:i].strip()
-    return line.strip()
-
-
-def _split_toml_array(inner: str) -> List[str]:
-    """Split array elements on commas, respecting quoted strings."""
-    parts: List[str] = []
-    buf: List[str] = []
-    in_str = False
-    for i, c in enumerate(inner):
-        if c == '"' and (i == 0 or inner[i - 1] != "\\"):
-            in_str = not in_str
-            buf.append(c)
-        elif c == "," and not in_str:
-            parts.append("".join(buf).strip())
-            buf = []
-        else:
-            buf.append(c)
-    tail = "".join(buf).strip()
-    if tail:
-        parts.append(tail)
-    return [p for p in parts if p]
-
-
-def _toml_value(v: str, ln: int):
-    if v.startswith('"'):
-        end = v.find('"', 1)
-        while end > 0 and v[end - 1] == "\\":
-            end = v.find('"', end + 1)
-        if end < 1:
-            raise ValueError(f"unterminated string on config line {ln}")
-        return v[1:end].replace('\\"', '"')
-    if v.startswith("[") and v.endswith("]"):
-        return [_toml_value(p, ln) for p in _split_toml_array(v[1:-1])]
-    if v in ("true", "false"):
-        return v == "true"
-    try:
-        return int(v)
-    except ValueError:
-        pass
-    try:
-        return float(v)
-    except ValueError:
-        raise ValueError(f"unparseable config value on line {ln}: {v!r}")
-
-
-def _parse_minimal_toml(text: str) -> dict:
-    """Fallback parser for Python < 3.11 hosts: the flat subset our node
-    configs use — `KEY = value` lines, [SECTION] / [SECTION.SUB] tables,
-    quoted strings (incl. embedded # and ,), ints, floats, booleans, and
-    single-line arrays.  Not a general TOML implementation (no multiline
-    arrays/strings, no inline tables) — enough to boot a validator from
-    the documented config shape."""
-    root: dict = {}
-    cur = root
-    for ln, raw in enumerate(text.splitlines(), 1):
-        line = _strip_toml_comment(raw)
-        if not line:
-            continue
-        if line.startswith("[") and line.endswith("]"):
-            cur = root
-            for part in line[1:-1].strip().split("."):
-                nxt = cur.setdefault(part.strip(), {})
-                if not isinstance(nxt, dict):
-                    raise ValueError(f"table name collides with a key: {line}")
-                cur = nxt
-            continue
-        if "=" not in line:
-            raise ValueError(f"bad config line {ln}: {raw!r}")
-        key, _, val = line.partition("=")
-        cur[key.strip()] = _toml_value(val.strip(), ln)
-    return root
 
 from ..crypto.keys import PubKeyUtils, SecretKey
 from ..xdr.scp import SCPQuorumSet
@@ -203,9 +115,9 @@ class Config:
         # batches, level-spill merges, selfcheck's full-tree re-hash —
         # run on the batched multi-block SHA-256 kernel instead of the
         # pooled C host stage.  Off by default like DEVICE_HASH: an
-        # opt-in certified by the paired bucket_hash bench legs and the
-        # relay bucket_hash_r22 A/B gate; hashes are bit-exact across
-        # device/native/hashlib backends (tests/test_hashplane.py).
+        # opt-in whose paired bucket_hash bench legs have no chip number
+        # yet; hashes are bit-exact across device/native/hashlib backends
+        # (tests/test_hashplane.py).
         self.DEVICE_BUCKET_HASH = False
         # level-spill merges run on the dedicated background workers
         # (bucket/mergeworker.py) so the close boundary that commits a
@@ -226,9 +138,9 @@ class Config:
         # (tests/test_halfagg.py differential suite).
         self.SCP_SIG_SCHEME = "ed25519"
         # dispatch streams for multi-chunk verify batches: 2 overlaps one
-        # chunk's transport upload with another's execution — worth it
-        # only when the accelerator transport pipelines (probe_overlap.py
-        # measures; ops/ed25519.py BatchVerifier docs).  The TOML knob
+        # chunk's upload with another's execution — worth it only when
+        # the transfer pipelines with the kernel (bench.py A/Bs it;
+        # ops/ed25519.py BatchVerifier docs).  The TOML knob
         # wins; its default honors the STELLAR_TPU_VERIFY_STREAMS env var
         # so the documented operator override keeps working on the node
         # path too
@@ -367,12 +279,8 @@ class Config:
     # -- loading -----------------------------------------------------------
     @classmethod
     def load(cls, path: str) -> "Config":
-        if tomllib is None:
-            with open(path, "r", encoding="utf-8") as f:
-                data = _parse_minimal_toml(f.read())
-        else:
-            with open(path, "rb") as f:
-                data = tomllib.load(f)
+        with open(path, "rb") as f:
+            data = tomllib.load(f)
         return cls.from_dict(data)
 
     @classmethod

@@ -7,14 +7,19 @@ Python in the loop.  ctypes releases the GIL for the duration of the call,
 so merges running on the worker pool never stall the main crank — the
 property the reference gets from real C++ threads.
 
-The shared object is built on first use with the system compiler and
-cached next to the source; if no toolchain is available everything falls
-back to the pure-Python implementations in bucket/bucket.py.
+Each shared object is built on first use with the system compiler and
+cached next to its source, together with a ``<name>.so.srchash`` stamp of
+the source content and flags it was built from; a copied or checked-out
+tree has arbitrary mtimes, so staleness is decided by that content hash
+and nothing else.  If no toolchain is available everything falls back to
+the pure-Python implementations (``loaded()`` says which extensions are
+live, so a harness can refuse the slow path).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import re
 import subprocess
@@ -53,9 +58,8 @@ def _san_flags() -> tuple:
 
 
 def _san_so(so: str) -> str:
-    """Artifact name encodes the EXACT sanitize set (mtime-based staleness
-    alone would silently reuse an address-only build for an
-    address,undefined run)."""
+    """Artifact name encodes the EXACT sanitize set, so an address-only
+    build is never reused for an address,undefined run."""
     mode = sanitize_mode()
     if not mode:
         return so
@@ -90,15 +94,26 @@ def sanitizer_preload_libs(kinds: Sequence[str] = ("asan", "ubsan")) -> Optional
     return out
 
 
+def _source_digest(src: str, flags: Sequence[str]) -> str:
+    """Content hash of what a build is made from: the source bytes and
+    the compiler flags."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(flags).encode())
+    return h.hexdigest()
+
+
 def _compile_so(src: str, so: str, extra_flags: Sequence[str] = ()) -> bool:
+    flags = (*_san_flags(), *extra_flags)
+    digest = _source_digest(src, flags)
     # per-process temp name: concurrent first-use builds in sibling
     # processes must not interleave writes into one file
     tmp = f"{so}.{os.getpid()}.tmp"
     for cc in ("cc", "gcc", "clang"):
         try:
             r = subprocess.run(
-                [cc, "-O2", "-shared", "-fPIC", *_san_flags(), *extra_flags,
-                 "-o", tmp, src],
+                [cc, "-O2", "-shared", "-fPIC", *flags, "-o", tmp, src],
                 capture_output=True,
                 timeout=120,
             )
@@ -106,6 +121,10 @@ def _compile_so(src: str, so: str, extra_flags: Sequence[str] = ()) -> bool:
             continue
         if r.returncode == 0:
             os.replace(tmp, so)
+            stamp_tmp = f"{so}.srchash.{os.getpid()}.tmp"
+            with open(stamp_tmp, "w") as f:
+                f.write(digest)
+            os.replace(stamp_tmp, so + ".srchash")
             return True
     try:
         os.unlink(tmp)
@@ -114,15 +133,19 @@ def _compile_so(src: str, so: str, extra_flags: Sequence[str] = ()) -> bool:
     return False
 
 
-def _needs_build(src: str, so: str) -> bool:
-    """True when the .so must be (re)built.  A prebuilt .so with no source
-    next to it (source-stripped deployment) is used as-is."""
+def _needs_build(src: str, so: str, extra_flags: Sequence[str] = ()) -> bool:
+    """True when the .so must be (re)built: it is missing, or the stamp
+    beside it does not match the source and flags now on disk.  The .so
+    files are git-ignored and only ever built from the checkout's own
+    source, so a loaded library always has every symbol the source has."""
     if not os.path.exists(so):
         return True
     try:
-        return os.path.getmtime(so) < os.path.getmtime(src)
+        with open(so + ".srchash") as f:
+            built_from = f.read().strip()
     except OSError:
-        return False
+        return True
+    return built_from != _source_digest(src, (*_san_flags(), *extra_flags))
 
 
 def _build() -> bool:
@@ -156,21 +179,14 @@ def _load():
         ]
         lib.sha256_file.restype = ctypes.c_int
         lib.sha256_file.argtypes = [ctypes.c_char_p, ctypes.c_char * 32]
-        # v2 bucket-hash symbols (ISSUE r22) are OPTIONAL: a stale
-        # prebuilt .so (source-stripped deployment, _needs_build says
-        # use-as-is) simply lacks them — the wrappers below return None
-        # and the callers fall back to the Python v2 paths, never to a
-        # silently-wrong v1 hash (pinned by tests/test_hashplane.py)
-        if hasattr(lib, "bucket_merge_v2"):
-            lib.bucket_merge_v2.restype = ctypes.c_int
-            lib.bucket_merge_v2.argtypes = lib.bucket_merge.argtypes
-        if hasattr(lib, "bucket_hash_v2_file"):
-            lib.bucket_hash_v2_file.restype = ctypes.c_int
-            lib.bucket_hash_v2_file.argtypes = [
-                ctypes.c_char_p,
-                ctypes.c_char * 32,
-                ctypes.POINTER(ctypes.c_longlong),
-            ]
+        lib.bucket_merge_v2.restype = ctypes.c_int
+        lib.bucket_merge_v2.argtypes = lib.bucket_merge.argtypes
+        lib.bucket_hash_v2_file.restype = ctypes.c_int
+        lib.bucket_hash_v2_file.argtypes = [
+            ctypes.c_char_p,
+            ctypes.c_char * 32,
+            ctypes.POINTER(ctypes.c_longlong),
+        ]
         _lib = lib
         return _lib
 
@@ -235,15 +251,10 @@ def merge_files_v2(
 ) -> Optional[Tuple[bytes, int]]:
     """merge_files with the v2 per-record-digest bucket hash (ISSUE r22,
     bucket/hashplane.py).  Same record stream as merge_files; only the
-    content hash differs.  None when the engine (or the v2 symbol, on a
-    stale prebuilt .so) is unavailable — the caller's Python fallback
-    produces the identical v2 hash."""
+    content hash differs.  None when the engine is unavailable — the
+    caller's Python fallback produces the identical v2 hash."""
     lib = _load()
-    if (
-        lib is None
-        or not hasattr(lib, "bucket_merge_v2")
-        or len(shadow_paths) > 32
-    ):
+    if lib is None or len(shadow_paths) > 32:
         return None
     shadows = (ctypes.c_char_p * max(1, len(shadow_paths)))()
     for i, p in enumerate(shadow_paths):
@@ -271,7 +282,7 @@ def bucket_hash_v2_file(path: str) -> Optional[Tuple[bytes, int]]:
     malformed/truncated frame also returns None (treated as corrupt by
     the verify layer, which re-checks in Python for the verdict)."""
     lib = _load()
-    if lib is None or not hasattr(lib, "bucket_hash_v2_file"):
+    if lib is None:
         return None
     out = (ctypes.c_char * 32)()
     count = ctypes.c_longlong(0)
@@ -291,15 +302,14 @@ _cxdr_tried = False
 
 
 def _load_extension(name: str, src: str, so: str, extra_flags=()):
-    """Build (if stale) and load a CPython extension .so by path.  The
-    unresolved CPython symbols bind into the running interpreter at
-    dlopen time, so no libpython link is needed."""
+    """Build (if missing or stale) and load a CPython extension .so by
+    path.  The unresolved CPython symbols bind into the running
+    interpreter at dlopen time, so no libpython link is needed."""
     import sysconfig
 
-    if _needs_build(src, so):
-        inc = sysconfig.get_paths()["include"]
-        if not _compile_so(src, so, (f"-I{inc}", *extra_flags)):
-            return None
+    flags = (f"-I{sysconfig.get_paths()['include']}", *extra_flags)
+    if _needs_build(src, so, flags) and not _compile_so(src, so, flags):
+        return None
     try:
         import importlib.machinery
         import importlib.util
@@ -408,3 +418,16 @@ def load_sighash():
             "_sighash", _SIGHASH_SRC, _san_so(_SIGHASH_SO), ("-pthread",)
         )
         return _sighash_mod
+
+
+def loaded() -> dict:
+    """Which of the five extensions are live in this process, building
+    each on first use — what a harness checks before it trusts that no
+    pure-Python fallback is standing in for native code."""
+    return {
+        "bucketmerge": _load() is not None,
+        "cxdrpack": load_cxdrpack() is not None,
+        "sighash": load_sighash() is not None,
+        "halfagg": load_halfagg() is not None,
+        "applycore": load_applycore() is not None,
+    }

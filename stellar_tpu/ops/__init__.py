@@ -1,24 +1,23 @@
 """JAX/TPU kernels: GF(2^255-19) field arithmetic and batched ed25519 verify.
 
-Importing this package enables JAX's persistent compilation cache (under the
-repo, so recompiles of the verify kernel are paid once per machine, not per
-process — the CPU fallback compile of the full kernel is ~70s).
+Importing this package enables JAX's persistent compilation cache, so a
+bucket of the verify kernel compiles once per machine, not once per
+process.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it
+and nothing is set here; otherwise the cache lives at the fixed path
+``<checkout>/.jax_cache`` (the path is part of the cache key, so it must
+not move between processes).
 """
 
 import os
 
-try:
-    import jax
+import jax
 
-    _cache_dir = os.environ.get(
-        "STELLAR_TPU_JAX_CACHE",
-        os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(__file__))),
-            ".jax_cache",
-        ),
-    )
-    os.makedirs(_cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:  # pragma: no cover - cache is best-effort
-    pass
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)

@@ -4,9 +4,9 @@ Same math and bit-exact semantics as ops/ed25519.verify_kernel (decompress +
 Straus double-scalar-mult + encode + compare; see that module for the
 host/device split and provenance), but tiled over the batch so the per-item
 dynamic niels table and the accumulator stay **VMEM-resident** for the whole
-64-window ladder.  PROFILE.md: the XLA version re-reads the (4·16·20·N)
-table from HBM on every window (~10.7 GB per 32k batch) — that traffic and
-the fusion-boundary spills are what this kernel removes.
+64-window ladder.  The XLA version re-reads the (4·16·20·N) table from HBM
+on every window (~10.7 GB per 32k batch) — that traffic and the
+fusion-boundary spills are what this kernel removes.
 
 Layout per grid step: a batch tile of ``NT`` lanes; field elements are
 (20, NT) int32 (radix-2^13 limbs on sublanes, items on lanes — ops/fe.py).
@@ -41,8 +41,7 @@ NT = 512  # batch tile (lanes); must divide the padded batch
 
 # Compress-stage lane-tree Montgomery inversion (round-4 optimization,
 # ~11% modeled).  Env-switchable so profile_kernel.py can A/B it against
-# the per-lane pow-chain inversion within ONE relay window — cross-window
-# absolute comparisons are confounded by window quality (PROFILE.md).
+# the per-lane pow-chain inversion inside one run.
 _BATCH_INV = os.environ.get("STELLAR_TPU_BATCH_INV", "1") != "0"
 
 # Signed-digit windows (round-5 experiment): recode the radix-16 scalar

@@ -4,8 +4,7 @@ Declarative chaos harness over the in-process Simulation: topology × load
 × scheduled fault program × consensus-liveness scoreboard.  See
 ``scenario.py`` for the runner, ``faults.py`` for the fault vocabulary,
 ``matrix.py`` for the named small/big shapes per fault class, and
-``python -m stellar_tpu.scenarios`` for the CI entry point
-(relay_watch ``scenario_liveness_r12``).
+``python -m stellar_tpu.scenarios`` for the CI entry point.
 """
 
 from .faults import (  # noqa: F401

@@ -5,10 +5,9 @@
 
 One line per scenario; exits nonzero when ANY scenario fails — invariant
 violation, chain disagreement, liveness-floor miss, unrecovered heal, or
-a polluted verify cache under flood.  This is the relay_watch
-``scenario_liveness_r12`` step's entry point.
+a polluted verify cache under flood.
 
-The storage plane's sweep (relay_watch ``crash_sweep_r18``):
+The storage plane's sweep:
 
     python -m stellar_tpu.scenarios --kill-sweep [--points P[,P]]
                                     [--modes exit|all] [--target N] [--json]

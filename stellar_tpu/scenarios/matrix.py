@@ -3,8 +3,8 @@
 SMALL shapes run in tier-1 (each ≥10 ledgers closed in the chaos window,
 invariants all-on, deterministic seeded replay for the virtual-clock
 classes); BIG shapes are the same programs at core-and-tier ring scale
-and longer fault windows, behind ``-m slow`` / the relay_watch
-``scenario_liveness_r12`` step's ``--matrix big`` mode.
+and longer fault windows, behind ``-m slow`` / the CLI's ``--matrix big``
+mode.
 
 Fault classes (ROADMAP #5 / ISSUE r12 acceptance):
 - ``partition_heal``    — majority/minority split, heal, lagging node

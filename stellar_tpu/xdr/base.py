@@ -773,9 +773,7 @@ def pack_many(values, cls_or_codec, frames: bool = False) -> bytes:
     if prog is None:
         prog = codec._compile_cprog()
     if prog is not False:
-        fn = getattr(_cxdr(), "pack_many", None)  # tolerate a stale .so
-        if fn is not None:
-            return fn(prog, vals, 1 if frames else 0)
+        return _cxdr().pack_many(prog, vals, 1 if frames else 0)
     out = bytearray()
     for v in vals:
         body = codec.pack(v)

@@ -48,8 +48,35 @@ def test_info_metrics_scp(app):
     info = ch.handle_info({})["info"]
     assert info["ledger"]["num"] == 1
     assert info["network"] == app.config.NETWORK_PASSPHRASE
+    assert info["sig_backend"] == {"backend": "cpu"}
     assert "metrics" in ch.handle_metrics({})
     assert isinstance(ch.handle_scp({}), dict)
+
+
+def test_info_reports_what_runs_the_tpu_backend():
+    """/info's sig_backend block names the device JAX found, the kernel
+    lowering and whether it is interpreted — a tpu-backend node on a CPU
+    must say so, not just "tpu"."""
+    import json
+
+    clock = VirtualClock(VIRTUAL_TIME)
+    cfg = T.get_test_config(83, backend="tpu")
+    cfg.HTTP_PORT = 0
+    a = Application.create(clock, cfg, new_db=True)
+    try:
+        sb = a.command_handler.handle_info({})["info"]["sig_backend"]
+        json.dumps(sb)  # the route serializes it
+        assert sb["backend"] == "tpu"
+        assert sb["platform"] == "cpu" and sb["device_count"] >= 1
+        assert sb["device_kind"]
+        assert sb["kernel"] == "xla" and sb["interpret"] is False
+        assert sb["native_host_stage"] in (True, False)
+        assert sb["device_calls"] == 0 and sb["cpu_cutover_items"] == 0
+        assert sb["wedge_fallback_items"] == 0
+        assert sb["wedge_latch_flips"] == {}
+    finally:
+        a.graceful_stop()
+        clock.shutdown()
 
 
 def test_testacc_root_and_missing(app):

@@ -743,21 +743,14 @@ class TestPackMany:
         )
 
     def test_python_fallback_matches_c(self, monkeypatch):
-        """A stale .so without the pack_many symbol drops pack_many to
-        its per-value Python loop — same octets, framed and unframed."""
+        """A codec the C side does not model (``_cprog is False``) drops
+        pack_many to its per-value Python loop — same octets, framed and
+        unframed."""
         import stellar_tpu.xdr.base as B
 
         codec, vals = self._entries(n=10, seed=914)
         want_plain = B.pack_many(vals, codec)
         want_framed = B.pack_many(vals, codec, frames=True)
-        real = B._cxdr()
-
-        class StaleSo:
-            def __getattr__(self, name):
-                if name == "pack_many":
-                    raise AttributeError(name)
-                return getattr(real, name)
-
-        monkeypatch.setattr(B, "_cxdr", lambda: StaleSo())
+        monkeypatch.setattr(codec, "_cprog", False)
         assert B.pack_many(vals, codec) == want_plain
         assert B.pack_many(vals, codec, frames=True) == want_framed

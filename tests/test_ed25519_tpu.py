@@ -373,7 +373,7 @@ class TestPipelineAbort:
             # no 60s cold-cache dependency); only the error path is real
             calls.append(1)
             if len(calls) == 3:
-                raise RuntimeError("relay dropped mid-stream")
+                raise RuntimeError("device dropped mid-stream")
             return np.ones(16, dtype=bool)
 
         bv._dispatch_staged = flaky

@@ -8,9 +8,8 @@ kernel).  This suite pins:
    bucket buffers including the empty bucket;
 2. the hostile surface — truncated/malformed frames raise ValueError on
    every path (the verify layer maps that to "corrupt");
-3. fallback honesty — knob off, STELLAR_TPU_NO_NATIVE_HASH, and a stale
-   pre-v2 .so all land on a backend that produces the SAME hash, never a
-   silently different one;
+3. fallback honesty — knob off and STELLAR_TPU_NO_NATIVE_HASH land on a
+   backend that produces the SAME hash, never a silently different one;
 4. the streaming ``BucketHasher`` (the bucket writers' ``hasher=`` slot)
    against the batch entry point, across its flush boundary;
 5. background-vs-inline spill merges (bucket/mergeworker.py vs
@@ -144,7 +143,7 @@ class TestBackendBitIdentity:
         from stellar_tpu import native
 
         mod = native.load_sighash()
-        if mod is None or not hasattr(mod, "sha256_batch"):
+        if mod is None:
             pytest.skip("native sha256_batch not built")
         frames = [frame(b) for b in BODIES]
         out = bytearray(32 * len(frames))
@@ -176,24 +175,6 @@ class TestResolutionAndFallback:
         monkeypatch.setenv("STELLAR_TPU_NO_NATIVE_HASH", "1")
         reset_backend_cache()
         assert get_backend().name == "hashlib"
-
-    def test_stale_so_without_v2_symbols_falls_through(self, monkeypatch):
-        """A prebuilt .so predating the v2 entry points lacks
-        sha256_batch: resolution must land on hashlib — same hash, never
-        a silently different one."""
-        from stellar_tpu import native
-
-        class _StaleSighash:
-            pass  # no sha256_batch, no bucket_hash_frames
-
-        monkeypatch.setattr(native, "load_sighash", lambda: _StaleSighash())
-        reset_backend_cache()
-        assert backend_by_name("native") is None
-        be = get_backend()
-        assert be.name == "hashlib"
-        assert be.hash_frames(framed(*BODIES)) == (
-            expected_v2(BODIES), len(BODIES),
-        )
 
     def test_hash_frames_notes_stats(self):
         before = hashplane.stats.snapshot()

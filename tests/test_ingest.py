@@ -216,8 +216,6 @@ def test_wedge_latch_isolation_caller_ingest():
     be.cpu_cutover = 0
     be.n_cutover_items = 0
     be.n_wedge_fallback_items = 0
-    be._verify_warm = True
-    be._torsion_warm = False
     be._wedged_until = {}
     be.n_latch_flips = {}
     be._wedge_lock = threading.Lock()
@@ -225,7 +223,9 @@ def test_wedge_latch_isolation_caller_ingest():
 
     class WedgedVerifier:
         calls = 0
-        n_device_calls = 1
+
+        def cold_buckets(self, n, host_assist=True):
+            return 0  # past warm-up: the short DEVICE_TIMEOUT applies
 
         def verify(self, items):
             WedgedVerifier.calls += 1

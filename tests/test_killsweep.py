@@ -5,7 +5,7 @@ and bit-exact recovery vs an unkilled control.
 The tier-1 leg sweeps a representative point per plane (SQL commit,
 bucket staging incl. the torn-write modes, publish commit) — ~12 child
 processes.  The FULL sweep (every point × mode, ~80 children, ~60 s)
-runs behind ``-m slow`` and in relay_watch ``crash_sweep_r18``.
+runs behind ``-m slow``.
 """
 
 from __future__ import annotations
@@ -70,7 +70,7 @@ def test_kill_sweep_cli_rejects_unknown_point():
 @pytest.mark.slow
 def test_kill_sweep_full(tmp_path):
     """Every registered point the window crosses, every applicable
-    fault mode — the relay_watch crash_sweep_r18 shape."""
+    fault mode."""
     report = run_kill_sweep(base_dir=str(tmp_path), log=lambda s: None)
     assert not report.get("error"), report
     assert report["ok"], [v for v in report["verdicts"] if not v["ok"]]

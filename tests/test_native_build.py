@@ -52,6 +52,31 @@ def test_bucketmerge_cold_build_and_sha256(cold_dir):
     assert bytes(out) == native.sha256_file(str(path))
 
 
+def test_staleness_follows_source_content_not_mtime(tmp_path):
+    """A copied tree has arbitrary mtimes: only a change of the source
+    bytes (or of the flags) may trigger a rebuild, and a .so whose stamp
+    is missing is never trusted."""
+    src = tmp_path / "probe.c"
+    so = str(tmp_path / "probe.so")
+    src.write_text("int probe(int x) { return x + 1; }\n")
+    assert native._needs_build(str(src), so)
+    assert native._compile_so(str(src), so)
+    assert not native._needs_build(str(src), so)
+    # source newer than the .so, and far older: neither is stale
+    os.utime(src, (2_000_000_000, 2_000_000_000))
+    assert not native._needs_build(str(src), so)
+    os.utime(src, (1, 1))
+    assert not native._needs_build(str(src), so)
+    assert native._needs_build(str(src), so, ("-O3",))
+    src.write_text("int probe(int x) { return x + 2; }\n")
+    os.utime(src, (1, 1))  # older than the .so, yet stale by content
+    assert native._needs_build(str(src), so)
+    assert native._compile_so(str(src), so)
+    assert ctypes.CDLL(so).probe(1) == 3
+    os.unlink(so + ".srchash")
+    assert native._needs_build(str(src), so)
+
+
 def test_cxdrpack_cold_build_pack_differential(cold_dir):
     # the module name must match the source's PyInit symbol; loading the
     # SAME name from a different path yields a distinct fresh module

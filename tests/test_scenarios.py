@@ -325,9 +325,9 @@ def test_targeted_flood_tier2_small():
 @pytest.mark.slow  # ~126 s of XLA-CPU compile on the tier-1 host (r21
 # budget sweep): the flood/shed/cache oracles run in tier-1 on the cpu
 # backend (test_byzantine_flood_small + the halfagg leg), the wedge-latch
-# isolation contract in test_ingest/test_backend units, and the REAL-chip
-# leg rides relay_watch chaos_asymmetry_r19 — this leg's marginal value
-# is the device-shaped compile, which is exactly what makes it slow here
+# isolation contract in test_ingest/test_backend units — this leg's
+# marginal value is the device-shaped compile, which is exactly what
+# makes it slow here
 def test_byzantine_flood_tpu_small():
     """The tpu-backend flood leg (ROADMAP 6(a) / ISSUE r19): the same
     byzantine flood with SIGNATURE_BACKEND="tpu" and cutover 0, so every
@@ -516,8 +516,8 @@ def test_core_and_tier_topology_externalizes():
 
 
 def test_scenarios_cli_exit_codes():
-    """`python -m stellar_tpu.scenarios` argument contract (relay_watch
-    scenario_liveness_r12 depends on the nonzero-on-unknown path)."""
+    """`python -m stellar_tpu.scenarios` argument contract (callers
+    depend on the nonzero-on-unknown path)."""
     from stellar_tpu.scenarios.__main__ import main
 
     assert main(["--only", "not_a_fault_class"]) == 2
@@ -525,8 +525,7 @@ def test_scenarios_cli_exit_codes():
 
 @pytest.mark.slow
 def test_big_matrix_partition_heal():
-    """Core-and-tier ring at the big shape — slow/relay_watch sessions
-    (`--matrix big` in scenario_liveness_r12)."""
+    """Core-and-tier ring at the big shape (`--matrix big`)."""
     verify_cache().clear()
     r = run_matrix(matrix="big", only=["partition_heal"])[0]
     assert r.ok, r.failures

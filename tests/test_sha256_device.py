@@ -10,7 +10,8 @@ one compiled graph).  Host-side staging (``blocks_for`` /
 Compile budget: the XLA legs share ONE batch per row-shape (mixed
 lengths by design), so the whole module adds two small compile shapes;
 the Pallas-interpret parity leg rides ``-m slow`` per the r10 budget
-policy (real-chip certification is relay_watch bucket_hash_r22).
+policy (the Mosaic-compiled kernel runs on the chip in chip_smoke.py's
+kernel leg).
 """
 
 from __future__ import annotations

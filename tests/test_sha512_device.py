@@ -8,7 +8,7 @@ Three layers, mirroring the PR:
    libsodium AND the host-hash path on every lane class: 95/96/111/112-
    byte preimages, the multi-block residual routing, hostile-s (s >= L),
    all-reject chunks skipping dispatch, mesh remainder chunks, and the
-   stale-.so / no-toolchain staging fallbacks;
+   no-toolchain staging fallback;
 3. the torsion-proof plane — verify(A:=P, h:=L, s:=0, R:=identity) on
    the device batch plane vs ref25519.is_torsion_free, plus the backend
    surface (cutover/wedge) and the aggregate scheme's fresh-R routing.
@@ -154,13 +154,13 @@ class TestDeviceSha512:
 
     def test_native_stage_raw_vs_python_fallback(self):
         """The C stage_raw buffer is byte-identical to _stage_py_raw on
-        valid, hostile, malformed-length and residual lanes (stale-.so
+        valid, hostile, malformed-length and residual lanes (toolchain-less
         hosts run the Python twin, so the layouts must agree exactly)."""
         from stellar_tpu import native
 
         mod = native.load_sighash()
-        if mod is None or not hasattr(mod, "stage_raw"):
-            pytest.skip("native stage_raw not built")
+        if mod is None:
+            pytest.skip("native sighash not built")
         items = _valid_items(24) + _hostile_items()
         n = len(items)
         from stellar_tpu.ops.ed25519 import _BLACKLIST
@@ -229,33 +229,6 @@ class TestDeviceHashVerifier:
             sodium.verify_detached(sig, msg, pk) for pk, msg, sig in items
         ]
         assert py.verify(items) == want
-
-    def test_stale_so_without_stage_raw_falls_back(self, bvs):
-        """A pre-r16 .so exposes stage() but not stage_raw(): the
-        device-hash path must ride the Python staging instead of
-        crashing — and stay bit-exact."""
-        _, dev = bvs
-
-        class _StaleSighash:
-            # stage() exists (the old surface), stage_raw does not
-            @staticmethod
-            def stage(*a, **k):  # pragma: no cover - must not be called
-                raise AssertionError(
-                    "device-hash staging must not use stage()"
-                )
-
-        stale = BatchVerifier(
-            max_batch=64, min_device_batch=64, device_hash=True
-        )
-        stale._kernel = dev._kernel
-        stale._sighash = _StaleSighash()
-        stale._has_stage_raw = hasattr(stale._sighash, "stage_raw")
-        assert stale._has_stage_raw is False
-        items = _valid_items(12, seed=94000) + _hostile_items()
-        want = [
-            sodium.verify_detached(sig, msg, pk) for pk, msg, sig in items
-        ]
-        assert stale.verify(items) == want
 
     def test_knob_off_keeps_128_row_layout(self, bvs):
         host, dev = bvs
@@ -362,7 +335,6 @@ class TestTorsionDevicePlane:
         tb.cpu_cutover = 10_000
         tb.n_cutover_items = tb.n_cutover_torsion = 0
         tb.n_wedge_fallback_items = 0
-        tb._verify_warm = tb._torsion_warm = False
         tb._wedged_until, tb.n_latch_flips = {}, {}
         import threading
 
@@ -483,8 +455,8 @@ class TestConfigAndWiring:
 class TestPallasParity:
     """The Pallas sha stage (interpret mode) against the XLA lowering —
     device-shaped compile cost on a CPU host, slow-marked per the r10
-    budget policy; real-chip certification is relay_watch
-    device_hash_r16."""
+    budget policy; the Mosaic-compiled stage runs on the chip in
+    chip_smoke.py's kernel leg."""
 
     def test_sha512_pallas_matches_xla(self):
         from stellar_tpu.ops.ed25519_pallas import NT

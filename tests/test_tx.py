@@ -738,8 +738,6 @@ def test_wedged_device_dispatch_falls_back_to_host_and_latches():
     be.cpu_cutover = 0
     be.n_cutover_items = 0
     be.n_wedge_fallback_items = 0
-    be._verify_warm = True  # past warm-up: the short DEVICE_TIMEOUT applies
-    be._torsion_warm = False
     be._wedged_until = {}
     be.n_latch_flips = {}
     be._wedge_lock = threading.Lock()
@@ -747,7 +745,9 @@ def test_wedged_device_dispatch_falls_back_to_host_and_latches():
 
     class WedgedVerifier:
         calls = 0
-        n_device_calls = 1
+
+        def cold_buckets(self, n, host_assist=True):
+            return 0  # past warm-up: the short DEVICE_TIMEOUT applies
 
         def verify(self, items):
             WedgedVerifier.calls += 1
@@ -781,6 +781,75 @@ def test_wedged_device_dispatch_falls_back_to_host_and_latches():
     assert be.verify_batch(items, caller=CALLER_PIPELINE) == [True]
     assert WedgedVerifier.calls == 3
     assert be.n_latch_flips[CALLER_PIPELINE] == 2
+
+
+def test_first_dispatch_of_each_bucket_gets_the_compile_budget():
+    """The compile-bearing budget follows the compiled SHAPE, not the
+    surface: every bucket's first dispatch traces+lowers+compiles inside
+    the call and outlasts the short budget.  A healthy device must never
+    be abandoned for that — no latch flip, no host items — including a
+    flush that spans two new buckets, and a later flush that meets a
+    further new bucket after the surface is long warm."""
+    import threading
+    import time as _time
+
+    from stellar_tpu.crypto.sigbackend import CALLER_CLOSE, TpuSigBackend
+    from stellar_tpu.ops.ed25519 import BatchVerifier
+
+    be = TpuSigBackend.__new__(TpuSigBackend)  # skip JAX verifier init
+    be.cpu_cutover = 0
+    be.n_cutover_items = 0
+    be.n_wedge_fallback_items = 0
+    be._wedged_until = {}
+    be.n_latch_flips = {}
+    be._wedge_lock = threading.Lock()
+    be.DEVICE_TIMEOUT = 0.2
+    be.DEVICE_FIRST_TIMEOUT = 1.5
+
+    class SlowFirstCompile(BatchVerifier):
+        """The real bucket arithmetic and warm-set bookkeeping over a
+        kernel stand-in whose first call per bucket takes 3x the short
+        budget."""
+
+        def __init__(self):  # no JAX: only the planning state
+            self.max_batch = 64
+            self.min_device_batch = 16
+            self._granule = 1
+            self.host_assist = 0.0
+            self._calls_lock = threading.Lock()
+            self._warm_buckets = set()
+            self.dispatched = []
+
+        def verify(self, items):
+            for _, count in self._chunks(len(items)):
+                bucket = self._bucket(count)
+                with self._calls_lock:
+                    cold = bucket not in self._warm_buckets
+                if cold:
+                    _time.sleep(0.6)
+                with self._calls_lock:
+                    self._warm_buckets.add(bucket)
+                self.dispatched.append(bucket)
+            return [True] * len(items)
+
+    bv = be._verifier = SlowFirstCompile()
+    item = (b"\x01" * 32, b"m", b"\x02" * 64)
+    # 64 + 16: one flush, two new buckets, 1.2 s against a 0.2 s budget
+    assert bv.cold_buckets(80) == 2
+    assert be.verify_batch([item] * 80, caller=CALLER_CLOSE) == [True] * 80
+    assert bv.dispatched == [64, 16]
+    assert bv.cold_buckets(80) == 0
+    # the surface is warm; a further NEW bucket still gets its budget
+    assert bv.cold_buckets(30) == 1
+    assert be.verify_batch([item] * 30, caller=CALLER_CLOSE) == [True] * 30
+    assert bv.dispatched == [64, 16, 32]
+    # warm buckets run under the short budget and make it
+    t0 = _time.perf_counter()
+    assert be.verify_batch([item] * 80, caller=CALLER_CLOSE) == [True] * 80
+    assert _time.perf_counter() - t0 < 0.2
+    assert be.n_latch_flips == {}
+    assert be.n_wedge_fallback_items == 0
+    assert be.n_cutover_items == 0
 
 
 def test_start_rejects_insane_quorum_set(clock):

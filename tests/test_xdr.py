@@ -6,7 +6,7 @@ bytes computed independently from the spec, not from our own packer).
 """
 
 import pytest
-from _hypothesis_compat import given, st
+from hypothesis import given, strategies as st
 
 import stellar_tpu.xdr as X
 from stellar_tpu.xdr.base import XdrError, uint32, int32, uint64, int64, var_opaque
