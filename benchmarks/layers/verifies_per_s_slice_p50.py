@@ -1,0 +1,6 @@
+"""closed loop (harness): the median of the window's slice rates of verdicts
+returned, beside the end-to-end rate (all verdicts over the window's
+seconds).  A stall hardly moves the median: where it reads above the rate,
+the window held one."""
+
+from benchmarks.layers.common import slice_rate_p50 as read  # noqa: F401
