@@ -996,7 +996,6 @@ def bench_ledger_close(n_txs=5000, n_ledgers=3):
         agg = app.tracer.aggregates()
         phase_names = (
             "ledger.close",
-            "close.txset_validate",
             "close.sig_flush",
             "close.fees",
             "close.apply",

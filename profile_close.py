@@ -496,9 +496,9 @@ def pipeline_report(n_txs=5000, n_ledgers=3, both=True):
                 f"  pipeline: dispatched {stats['dispatched']},"
                 f" joined {stats['joined']} (warm {stats['joined_warm']}),"
                 f" quarantined {stats['quarantined']},"
-                f" hidden {stats['overlap_hidden_ms']:.1f} ms,"
-                f" join wait {stats['join_wait_ms']:.1f} ms,"
-                f" dispatch {stats['dispatch_ms']:.1f} ms"
+                f" hidden {stats['overlap_hidden_ms']:.1f} ms"
+                " (join wait and dispatch: the close.pipeline.join and"
+                " close.pipeline.dispatch rows above)"
             )
 
     p50_on, ph_on, st_on, h_on, sql_on = leg(86, True)

@@ -134,99 +134,99 @@ class HalfAggScheme(ScpSigScheme):
         t0 = time.perf_counter()
         items = list(items)
         n = len(items)
-        sp = self._tracer.begin("scp.agg_flush")
-        keys = [
-            self.cache.key_for(pk, sig, msg) for pk, msg, sig in items
-        ]
-        cached = self.cache.peek_many(keys)
-        verdicts: List[Optional[bool]] = [
-            bool(c) if c is not None else None for c in cached
-        ]
-        # per-slot aggregation buckets over the cache misses — one slot's
-        # ballots are one jointly-verified statement list
-        buckets: Dict[int, List[int]] = {}
-        for i, v in enumerate(verdicts):
-            if v is None:
-                buckets.setdefault(slots[i], []).append(i)
-        fallback: List[int] = []
-        n_checks = n_passed = n_agg = n_gate = n_small = n_unagg = 0
-        for slot, idxs in buckets.items():
-            if len(idxs) < self.MIN_AGG:
-                n_small += len(idxs)
-                fallback.extend(idxs)
-                continue
-            gate_ok = self._gate([items[i] for i in idxs])
-            for i, ok in zip(idxs, gate_ok):
-                if not ok:
-                    # outside libsodium's accept set — same verdict the
-                    # reference path would return, at gate cost
-                    verdicts[i] = False
-                    n_gate += 1
-            eligible = [i for i, ok in zip(idxs, gate_ok) if ok]
-            # pubkeys negative-cached as permanently unusable (undecodable
-            # or torsioned) can never aggregate but CAN carry signatures
-            # libsodium accepts — per-item verdicts, without letting one
-            # such key poison this bucket every flush
-            a_vals = self.point_cache.get_many(
-                [items[i][0] for i in eligible]
+        with self._tracer.span("scp.agg_flush") as sp:
+            keys = [
+                self.cache.key_for(pk, sig, msg) for pk, msg, sig in items
+            ]
+            cached = self.cache.peek_many(keys)
+            verdicts: List[Optional[bool]] = [
+                bool(c) if c is not None else None for c in cached
+            ]
+            # per-slot aggregation buckets over the cache misses — one slot's
+            # ballots are one jointly-verified statement list
+            buckets: Dict[int, List[int]] = {}
+            for i, v in enumerate(verdicts):
+                if v is None:
+                    buckets.setdefault(slots[i], []).append(i)
+            fallback: List[int] = []
+            n_checks = n_passed = n_agg = n_gate = n_small = n_unagg = 0
+            for slot, idxs in buckets.items():
+                if len(idxs) < self.MIN_AGG:
+                    n_small += len(idxs)
+                    fallback.extend(idxs)
+                    continue
+                gate_ok = self._gate([items[i] for i in idxs])
+                for i, ok in zip(idxs, gate_ok):
+                    if not ok:
+                        # outside libsodium's accept set — same verdict the
+                        # reference path would return, at gate cost
+                        verdicts[i] = False
+                        n_gate += 1
+                eligible = [i for i, ok in zip(idxs, gate_ok) if ok]
+                # pubkeys negative-cached as permanently unusable (undecodable
+                # or torsioned) can never aggregate but CAN carry signatures
+                # libsodium accepts — per-item verdicts, without letting one
+                # such key poison this bucket every flush
+                a_vals = self.point_cache.get_many(
+                    [items[i][0] for i in eligible]
+                )
+                bad_a = [i for i, v in zip(eligible, a_vals) if v is None]
+                if bad_a:
+                    n_unagg += len(bad_a)
+                    fallback.extend(bad_a)
+                    eligible = [
+                        i for i, v in zip(eligible, a_vals) if v is not None
+                    ]
+                if len(eligible) < self.MIN_AGG:
+                    n_small += len(eligible)
+                    fallback.extend(eligible)
+                    continue
+                n_checks += 1
+                if halfagg.verify_batch_aggregated(
+                    [items[i] for i in eligible],
+                    point_cache=self.point_cache,
+                    gated=True,
+                    torsion_prover=self._torsion_prover,
+                ):
+                    n_passed += 1
+                    n_agg += len(eligible)
+                    for i in eligible:
+                        verdicts[i] = True
+                    # valid-only latch, synchronously on the caller's thread:
+                    # the aggregate check just proved every one of these
+                    # signatures libsodium-valid (completeness is exact, and
+                    # soundness is 2^-128 because every A and fresh R was
+                    # proven prime-order before the MSM verdict counts), so
+                    # invalid items can never reach this line — the bounded
+                    # LRU stays un-pollutable under flood exactly like the
+                    # reference path
+                    self.cache.put_many((keys[i], True) for i in eligible)
+                else:
+                    # poisoned bucket: per-item verdicts come from the
+                    # reference plane (the caching backend latches its own
+                    # valid-only results)
+                    fallback.extend(eligible)
+            if fallback:
+                self.n_fallback_envelopes += len(fallback)
+                fresh = self.backend.verify_batch(
+                    [items[i] for i in fallback], caller=CALLER_OVERLAY
+                )
+                for i, ok in zip(fallback, fresh):
+                    verdicts[i] = bool(ok)
+            self.n_agg_checks += n_checks
+            self.n_agg_passed += n_passed
+            self.n_agg_envelopes += n_agg
+            self.n_gate_rejects += n_gate
+            self.n_small_buckets += n_small
+            self.n_unaggregatable += n_unagg
+            self._tracer.end(
+                sp,
+                batch=n,
+                cache_hits=sum(1 for c in cached if c is not None),
+                agg_checks=n_checks,
+                aggregated=n_agg,
+                fallback=len(fallback),
             )
-            bad_a = [i for i, v in zip(eligible, a_vals) if v is None]
-            if bad_a:
-                n_unagg += len(bad_a)
-                fallback.extend(bad_a)
-                eligible = [
-                    i for i, v in zip(eligible, a_vals) if v is not None
-                ]
-            if len(eligible) < self.MIN_AGG:
-                n_small += len(eligible)
-                fallback.extend(eligible)
-                continue
-            n_checks += 1
-            if halfagg.verify_batch_aggregated(
-                [items[i] for i in eligible],
-                point_cache=self.point_cache,
-                gated=True,
-                torsion_prover=self._torsion_prover,
-            ):
-                n_passed += 1
-                n_agg += len(eligible)
-                for i in eligible:
-                    verdicts[i] = True
-                # valid-only latch, synchronously on the caller's thread:
-                # the aggregate check just proved every one of these
-                # signatures libsodium-valid (completeness is exact, and
-                # soundness is 2^-128 because every A and fresh R was
-                # proven prime-order before the MSM verdict counts), so
-                # invalid items can never reach this line — the bounded
-                # LRU stays un-pollutable under flood exactly like the
-                # reference path
-                self.cache.put_many((keys[i], True) for i in eligible)
-            else:
-                # poisoned bucket: per-item verdicts come from the
-                # reference plane (the caching backend latches its own
-                # valid-only results)
-                fallback.extend(eligible)
-        if fallback:
-            self.n_fallback_envelopes += len(fallback)
-            fresh = self.backend.verify_batch(
-                [items[i] for i in fallback], caller=CALLER_OVERLAY
-            )
-            for i, ok in zip(fallback, fresh):
-                verdicts[i] = bool(ok)
-        self.n_agg_checks += n_checks
-        self.n_agg_passed += n_passed
-        self.n_agg_envelopes += n_agg
-        self.n_gate_rejects += n_gate
-        self.n_small_buckets += n_small
-        self.n_unaggregatable += n_unagg
-        self._tracer.end(
-            sp,
-            batch=n,
-            cache_hits=sum(1 for c in cached if c is not None),
-            agg_checks=n_checks,
-            aggregated=n_agg,
-            fallback=len(fallback),
-        )
         self.verify_wall_ms += (time.perf_counter() - t0) * 1000.0
         self.n_flush_envelopes += n
         return [bool(v) for v in verdicts]

@@ -198,31 +198,31 @@ class CachingSigBackend(SigBackend):
     ) -> List[bool]:
         # one sig-flush span per batch (never per item): batch size and the
         # cache-hit/miss split are THE attribution the close trace needs
-        sp = self._tracer.begin("sig.flush")
-        keys = [self.cache.key_for(pk, sig, msg) for pk, msg, sig in items]
-        cached = self.cache.peek_many(keys)
-        miss_idx = [i for i, c in enumerate(cached) if c is None]
-        if miss_idx:
-            fresh = self.inner.verify_batch(
-                [items[i] for i in miss_idx], caller=caller
+        with self._tracer.span("sig.flush") as sp:
+            keys = [self.cache.key_for(pk, sig, msg) for pk, msg, sig in items]
+            cached = self.cache.peek_many(keys)
+            miss_idx = [i for i, c in enumerate(cached) if c is None]
+            if miss_idx:
+                fresh = self.inner.verify_batch(
+                    [items[i] for i in miss_idx], caller=caller
+                )
+                # latch VALID verdicts only: a byzantine flood of distinct
+                # invalid-sig items must not be able to evict honest entries
+                # from the bounded LRU (cache-pollution defense; re-verifying
+                # an invalid item is cheap and pure, so nothing is lost) —
+                # the chaos plane's flood scenarios pin this contract
+                self.cache.put_many(
+                    (keys[i], ok) for i, ok in zip(miss_idx, fresh) if ok
+                )
+                for i, ok in zip(miss_idx, fresh):
+                    cached[i] = ok
+            self._tracer.end(
+                sp,
+                batch=len(items),
+                cache_hits=len(items) - len(miss_idx),
+                misses=len(miss_idx),
+                backend=self.name,
             )
-            # latch VALID verdicts only: a byzantine flood of distinct
-            # invalid-sig items must not be able to evict honest entries
-            # from the bounded LRU (cache-pollution defense; re-verifying
-            # an invalid item is cheap and pure, so nothing is lost) —
-            # the chaos plane's flood scenarios pin this contract
-            self.cache.put_many(
-                (keys[i], ok) for i, ok in zip(miss_idx, fresh) if ok
-            )
-            for i, ok in zip(miss_idx, fresh):
-                cached[i] = ok
-        self._tracer.end(
-            sp,
-            batch=len(items),
-            cache_hits=len(items) - len(miss_idx),
-            misses=len(miss_idx),
-            backend=self.name,
-        )
         return [bool(c) for c in cached]
 
     def verify_batch_async(
@@ -237,36 +237,41 @@ class CachingSigBackend(SigBackend):
         (aborted-close) batch can never leave verdicts behind."""
         items = list(items)
         fut = SigFlushFuture(len(items))
+        # the worker's spans name the span open here as their cause (the
+        # close pipeline's dispatch, or the close's own sig flush)
+        tracer = self._tracer
+        parent = tracer.current()
 
         def work():
-            sp = self._tracer.begin("sig.flush_async")
             try:
-                keys = [
-                    self.cache.key_for(pk, sig, msg) for pk, msg, sig in items
-                ]
-                cached = self.cache.peek_many(keys)
-                miss_idx = [i for i, c in enumerate(cached) if c is None]
-                self._tracer.end(
-                    sp,
-                    batch=len(items),
-                    cache_hits=len(items) - len(miss_idx),
-                    misses=len(miss_idx),
-                    backend=self.name,
-                )
-                if not miss_idx:
-                    fut._complete(result=[bool(c) for c in cached])
-                    return
-                # plain attribute store is atomic; _complete reads it
-                # under fut._lock and skips the latch if a quarantine won
-                # analysis: off locked-field -- happens-before by program order on the worker: _latch is written before the inner verify_batch, and _complete (same thread, after it) is the only reader path — there is no concurrent writer to exclude
-                fut._latch = (self.cache, [(keys[i], i) for i in miss_idx])
-                fresh = self.inner.verify_batch(
-                    [items[i] for i in miss_idx], caller=caller
-                )
-                merged = list(cached)
-                for i, ok in zip(miss_idx, fresh):
-                    merged[i] = ok
-                fut._complete(result=[bool(c) for c in merged])
+                with tracer.under(parent):
+                    sp = tracer.begin("sig.flush_async")
+                    keys = [
+                        self.cache.key_for(pk, sig, msg) for pk, msg, sig in items
+                    ]
+                    cached = self.cache.peek_many(keys)
+                    miss_idx = [i for i, c in enumerate(cached) if c is None]
+                    tracer.end(
+                        sp,
+                        batch=len(items),
+                        cache_hits=len(items) - len(miss_idx),
+                        misses=len(miss_idx),
+                        backend=self.name,
+                    )
+                    if not miss_idx:
+                        fut._complete(result=[bool(c) for c in cached])
+                        return
+                    # plain attribute store is atomic; _complete reads it
+                    # under fut._lock and skips the latch if a quarantine won
+                    # analysis: off locked-field -- happens-before by program order on the worker: _latch is written before the inner verify_batch, and _complete (same thread, after it) is the only reader path — there is no concurrent writer to exclude
+                    fut._latch = (self.cache, [(keys[i], i) for i in miss_idx])
+                    fresh = self.inner.verify_batch(
+                        [items[i] for i in miss_idx], caller=caller
+                    )
+                    merged = list(cached)
+                    for i, ok in zip(miss_idx, fresh):
+                        merged[i] = ok
+                    fut._complete(result=[bool(c) for c in merged])
             except BaseException as e:  # re-raised at fut.result()
                 fut._complete(err=e)
 
@@ -392,9 +397,11 @@ class TpuSigBackend(SigBackend):
     differential test suite (tests/test_ed25519_tpu.py)."""
 
     name = "tpu"
-    # class-level default: harness code (and tests) that build the backend
+    # class-level defaults: harness code (and tests) that build the backend
     # via __new__ + hand-set attributes still get a working no-op tracer
+    # and a flush count of their own
     _tracer = NULL_TRACER
+    n_device_flushes = 0
 
     def __init__(
         self,
@@ -511,10 +518,14 @@ class TpuSigBackend(SigBackend):
         result: List[Any] = [None]
         err: List[BaseException] = []
         done = threading.Event()
+        # what the worker (and the verifier's stager threads under it)
+        # records names the span open on the caller's thread as its cause
+        parent = self._tracer.current()
 
         def work():
             try:
-                result[0] = device_fn()
+                with self._tracer.under(parent):
+                    result[0] = device_fn()
             except BaseException as e:
                 err.append(e)
             finally:
@@ -567,14 +578,24 @@ class TpuSigBackend(SigBackend):
                 "sig.host_verify", items=len(items), reason="cutover"
             ):
                 return _sodium_verify_loop(items)
-        return self._guarded(
-            "verify",
-            len(items),
-            caller,
-            self._verifier.cold_buckets(len(items)),
-            lambda: self._verifier.verify(items),
-            lambda: _sodium_verify_loop(items),
-        )
+        # the flush as its caller waits for it: the hop to the guarded
+        # worker, the stager pool, staging, dispatch and drain are its
+        # children.  req: this backend's flush ordinal, where the flush is
+        # not already part of a ledger's close
+        self.n_device_flushes += 1
+        with self._tracer.span(
+            "sig.device_flush", req=self.n_device_flushes, items=len(items)
+        ) as sp:
+            if sp is not None:
+                sp.attrs["chunks"] = self._verifier.chunk_count(len(items))
+            return self._guarded(
+                "verify",
+                len(items),
+                caller,
+                self._verifier.cold_buckets(len(items)),
+                lambda: self._verifier.verify(items),
+                lambda: _sodium_verify_loop(items),
+            )
 
     def torsion_check(
         self,

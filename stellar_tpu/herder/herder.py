@@ -585,6 +585,7 @@ class Herder(SCPDriver):
         tr.end(self._trace_nom_spans.pop(slot_index, None))
         self._trace_nom_spans[slot_index] = tr.begin(
             "scp.nominate_round",
+            detached=True,  # ends in a later callback
             slot=slot_index,
             round=round_number,
             timed_out=timed_out,
@@ -602,7 +603,7 @@ class Herder(SCPDriver):
         # counter bumps inside the same ballot phase
         if slot_index not in self._trace_ballot_spans:
             self._trace_ballot_spans[slot_index] = tr.begin(
-                "scp.ballot", slot=slot_index
+                "scp.ballot", detached=True, slot=slot_index
             )
 
     # ------------------------------------------------------------------
@@ -1035,15 +1036,25 @@ class Herder(SCPDriver):
             return
 
         lcl = self.ledger_manager.get_last_closed_ledger_header()
+        # req: the slot, for everything the trigger causes on this thread
+        # (txset.validate, and on a single-node network the whole of
+        # consensus and the close, which run inside nominate below)
+        with self.app.tracer.span("herder.trigger", req=lcl.header.ledgerSeq + 1):
+            self._trigger_next_ledger(ledger_seq_to_trigger, lcl)
+
+    def _trigger_next_ledger(self, ledger_seq_to_trigger: int, lcl) -> None:
+        tracer = self.app.tracer
         proposed = TxSetFrame(lcl.hash)
         for gen in self.received_transactions:
             for txmap in gen.values():
                 for tx in txmap.transactions.values():
                     proposed.add_transaction(tx)
 
-        removed = proposed.trim_invalid(self.app)
-        self._remove_received_txs(removed)
-        proposed.surge_pricing_filter(self.ledger_manager)
+        with tracer.span("herder.trim_invalid", txs=proposed.size()):
+            removed = proposed.trim_invalid(self.app)
+            self._remove_received_txs(removed)
+        with tracer.span("herder.surge"):
+            proposed.surge_pricing_filter(self.ledger_manager)
 
         if not proposed.check_valid(self.app):
             raise RuntimeError("wanting to emit an invalid txSet")
@@ -1089,8 +1100,8 @@ class Herder(SCPDriver):
         # whole-slot consensus span: nominate → value_externalized (must be
         # registered BEFORE nominate — a single-node network externalizes
         # synchronously inside this call)
-        self._trace_slot_spans[slot_index] = self.app.tracer.begin(
-            "scp.consensus", slot=slot_index, txs=proposed.size()
+        self._trace_slot_spans[slot_index] = tracer.begin(
+            "scp.consensus", detached=True, slot=slot_index, txs=proposed.size()
         )
         self.scp.nominate(slot_index, self.current_value, prev_value)
 

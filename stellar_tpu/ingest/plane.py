@@ -51,6 +51,7 @@ digests stay byte-identical (the ``determinism`` analysis rule scopes
 
 from __future__ import annotations
 
+import time
 from typing import Callable, Dict, List, Optional
 
 from ..crypto.keys import verify_cache
@@ -115,6 +116,8 @@ class IngestPlane:
 
         self._queue: List[_Entry] = []
         self._arrivals = 0
+        self.n_submitted = 0
+        self.submit_s = 0.0
         self._buckets: Dict[bytes, _TokenBucket] = {}
         self._timer = VirtualTimer(app.clock)
         self._timer_armed = False
@@ -158,14 +161,22 @@ class IngestPlane:
         """Queue + flush immediately (the ``/tx`` and LoadGenerator
         edges need a synchronous answer); everything already queued
         rides the same dispatch."""
-        if not self.enabled or self._shutting_down:
-            return self.app.herder.recv_transaction(tx)
-        entry = self._admit(tx, None)
-        if entry is None:
-            return INGEST_STATUS_TRY_AGAIN
-        if entry.status is None:
-            self.flush_now()
-        return entry.status if entry.status is not None else INGEST_STATUS_TRY_AGAIN
+        # the admission edge is counted, not spanned (a span a
+        # transaction would fill the tracer's ring): stats()["submit_s"]
+        # over ["submitted"] is the edge's cost per transaction
+        t0 = time.perf_counter()
+        try:
+            if not self.enabled or self._shutting_down:
+                return self.app.herder.recv_transaction(tx)
+            entry = self._admit(tx, None)
+            if entry is None:
+                return INGEST_STATUS_TRY_AGAIN
+            if entry.status is None:
+                self.flush_now()
+            return entry.status if entry.status is not None else INGEST_STATUS_TRY_AGAIN
+        finally:
+            self.n_submitted += 1
+            self.submit_s += time.perf_counter() - t0
 
     def submit_replay(self, txs) -> List[str]:
         """Catchup/downloaded-txset edge: batched verify, NO rate/surge
@@ -274,64 +285,63 @@ class IngestPlane:
         self.m_flush.mark()
         self.h_batch_size.update(len(batch))
         self.h_occupancy.update(len(batch) / float(max(1, self.batch_max)))
-        sp = self.app.tracer.begin("ingest.flush")
+        with self.app.tracer.span("ingest.flush") as sp:
+            db = self.app.database
+            cache = self._cache
+            # per-entry candidate triples; triple-less txs pass through (the
+            # herder's eager path stays the validity oracle for them)
+            slices = []  # (entry, start, end) into the concatenated triples
+            keys: List[bytes] = []
+            triples = []
+            for e in batch:
+                try:
+                    cand = e.tx.candidate_signature_pairs(db)
+                except Exception:
+                    cand = []
+                start = len(triples)
+                triples.extend(cand)
+                keys.extend(cache.key_for(pk, sig, msg) for pk, msg, sig in cand)
+                slices.append((e, start, len(triples)))
 
-        db = self.app.database
-        cache = self._cache
-        # per-entry candidate triples; triple-less txs pass through (the
-        # herder's eager path stays the validity oracle for them)
-        slices = []  # (entry, start, end) into the concatenated triples
-        keys: List[bytes] = []
-        triples = []
-        for e in batch:
-            try:
-                cand = e.tx.candidate_signature_pairs(db)
-            except Exception:
-                cand = []
-            start = len(triples)
-            triples.extend(cand)
-            keys.extend(cache.key_for(pk, sig, msg) for pk, msg, sig in cand)
-            slices.append((e, start, len(triples)))
+            cached = cache.peek_many(keys)
+            miss_idx = [i for i, c in enumerate(cached) if c is None]
+            self.c_cache_hits.inc(len(keys) - len(miss_idx))
+            self.c_verified.inc(len(miss_idx))
+            if miss_idx:
+                fresh = self._inner.verify_batch(
+                    [triples[i] for i in miss_idx], caller=CALLER_INGEST
+                )
+                # valid-only latch — the CachingSigBackend quarantine
+                # contract at ingest granularity: a flood of distinct
+                # invalid-sig txs must never evict honest cache entries, and
+                # re-verifying an invalid triple later is cheap and pure
+                cache.put_many(
+                    (keys[i], ok) for i, ok in zip(miss_idx, fresh) if ok
+                )
+                for i, ok in zip(miss_idx, fresh):
+                    cached[i] = ok
 
-        cached = cache.peek_many(keys)
-        miss_idx = [i for i, c in enumerate(cached) if c is None]
-        self.c_cache_hits.inc(len(keys) - len(miss_idx))
-        self.c_verified.inc(len(miss_idx))
-        if miss_idx:
-            fresh = self._inner.verify_batch(
-                [triples[i] for i in miss_idx], caller=CALLER_INGEST
+            n_shed = 0
+            herder = self.app.herder
+            for e, start, end in slices:
+                if end > start and not any(cached[start:end]):
+                    # every (key, sig) pair the eager check_signature loop
+                    # could try verifies invalid — shed at the edge
+                    e.tx.set_result_code(TransactionResultCode.txBAD_AUTH)
+                    e.status = "ERROR"
+                    n_shed += 1
+                    self.m_reject_badsig.mark()
+                else:
+                    if end == start:
+                        self.m_passthrough.mark()
+                    e.status = herder.recv_transaction(e.tx)
+                    if e.status == "PENDING":
+                        self.m_admit.mark()
+                if e.on_status is not None:
+                    e.on_status(e.status)
+            self.app.tracer.end(
+                sp, batch=len(batch), triples=len(keys), shed=n_shed
             )
-            # valid-only latch — the CachingSigBackend quarantine
-            # contract at ingest granularity: a flood of distinct
-            # invalid-sig txs must never evict honest cache entries, and
-            # re-verifying an invalid triple later is cheap and pure
-            cache.put_many(
-                (keys[i], ok) for i, ok in zip(miss_idx, fresh) if ok
-            )
-            for i, ok in zip(miss_idx, fresh):
-                cached[i] = ok
-
-        n_shed = 0
-        herder = self.app.herder
-        for e, start, end in slices:
-            if end > start and not any(cached[start:end]):
-                # every (key, sig) pair the eager check_signature loop
-                # could try verifies invalid — shed at the edge
-                e.tx.set_result_code(TransactionResultCode.txBAD_AUTH)
-                e.status = "ERROR"
-                n_shed += 1
-                self.m_reject_badsig.mark()
-            else:
-                if end == start:
-                    self.m_passthrough.mark()
-                e.status = herder.recv_transaction(e.tx)
-                if e.status == "PENDING":
-                    self.m_admit.mark()
-            if e.on_status is not None:
-                e.on_status(e.status)
-        self.app.tracer.end(
-            sp, batch=len(batch), triples=len(keys), shed=n_shed
-        )
 
     # ------------------------------------------------------------------
     # lifecycle / introspection
@@ -360,6 +370,9 @@ class IngestPlane:
             "batch_size_p95": self.h_batch_size.percentile(0.95),
             "occupancy_mean": self.h_occupancy.mean,
             "admitted": self.m_admit.count,
+            # submit_sync calls and the seconds spent inside them
+            "submitted": self.n_submitted,
+            "submit_s": self.submit_s,
             "passthrough": self.m_passthrough.count,
             "rejects": {
                 "badsig": self.m_reject_badsig.count,

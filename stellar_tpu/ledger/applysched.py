@@ -46,6 +46,7 @@ import threading
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
+from ..trace import NULL_TRACER
 from ..util import xlog
 from ..xdr.ledger import TransactionMeta
 from .delta import LedgerDelta
@@ -365,7 +366,7 @@ class ApplyScheduler:
         return [s for s in shards if s]
 
     # -- worker leg ------------------------------------------------------
-    def _run_shard(self, shard_db, shard_app, jobs, ledger_delta, seq, tx_timer, tracer, outcomes, rows_out, errors):  # analysis: shard-leg
+    def _run_shard(self, shard_db, shard_app, jobs, ledger_delta, seq, tx_timer, tracer, parent, outcomes, rows_out, errors):  # analysis: shard-leg
         """Apply this shard's groups against its shard planes.
 
         Receives every plane it may touch as an explicit parameter —
@@ -374,54 +375,61 @@ class ApplyScheduler:
         re-introduces a main-plane dependency fails analysis, not
         production.  Mirrors the serial loop body except that per-tx
         deltas are NOT committed here: they queue for the canonical-
-        order merge on the main thread."""
+        order merge on the main thread.  ``parent`` is the span open on
+        the main thread (``close.apply``): this thread's spans name it."""
+        from ..tx.frame import TX_SAMPLE_STRIDE
         from ..xdr.txs import TransactionResultCode
 
         try:
-            sp = tracer.begin(
+            with tracer.span(
                 "apply.group",
+                parent=parent,
                 groups=len(jobs),
                 txs=sum(len(g) for g in jobs),
-            )
-            done = []
-            for group in jobs:
-                for idx, tx in group:
-                    with tx_timer.time_scope():
-                        delta = LedgerDelta(outer=ledger_delta)
-                        # nested deltas inherit _db from their outer: point
-                        # the whole chain at the shard planes so rollbacks
-                        # erase shard cache lines, never main ones
-                        delta._db = shard_db
-                        meta = TransactionMeta(0, [])
-                        try:
-                            ok = tx.apply(delta, shard_app, meta)
-                            if not ok:
-                                assert not delta.get_changes()
-                        except FootprintEscape:
-                            raise
-                        except Exception as e:  # serial-loop parity
-                            log.error("exception during tx apply: %s", e)
-                            tx.set_result_code(
-                                TransactionResultCode.txINTERNAL_ERROR
-                            )
-                            ok = False
-                    outcomes[idx] = (ok, delta)
-                    done.append((idx, tx, meta))
-            # batch the history-row encode (native leg drops the GIL, so
-            # shards overlap here even under CPython)
-            blobs = [
-                (
-                    tx.get_contents_hash(),
-                    tx.env_xdr(),
-                    tx.get_result_pair().to_xdr(),
-                    meta.to_xdr(),
-                )
-                for _idx, tx, meta in done
-            ]
-            enc = _encode_rows(blobs)
-            for (idx, _tx, _meta), (h, b, r, m) in zip(done, enc):
-                rows_out[idx] = (h, seq, idx + 1, b, r, m)
-            tracer.end(sp)
+            ):
+                done = []
+                skip = TX_SAMPLE_STRIDE - 1
+                for group in jobs:
+                    for idx, tx in group:
+                        # one transaction in TX_SAMPLE_STRIDE records
+                        # tx.apply and its children; the others get the
+                        # no-op tracer
+                        tx_tracer = NULL_TRACER if idx & skip else tracer
+                        with tx_tracer.span("tx.apply", index=idx), tx_timer.time_scope():
+                            delta = LedgerDelta(outer=ledger_delta)
+                            # nested deltas inherit _db from their outer: point
+                            # the whole chain at the shard planes so rollbacks
+                            # erase shard cache lines, never main ones
+                            delta._db = shard_db
+                            meta = TransactionMeta(0, [])
+                            try:
+                                ok = tx.apply(delta, shard_app, meta, tx_tracer)
+                                if not ok:
+                                    assert not delta.get_changes()
+                            except FootprintEscape:
+                                raise
+                            except Exception as e:  # serial-loop parity
+                                log.error("exception during tx apply: %s", e)
+                                tx.set_result_code(
+                                    TransactionResultCode.txINTERNAL_ERROR
+                                )
+                                ok = False
+                        outcomes[idx] = (ok, delta)
+                        done.append((idx, tx, meta))
+                # batch the history-row encode (native leg drops the GIL, so
+                # shards overlap here even under CPython)
+                blobs = [
+                    (
+                        tx.get_contents_hash(),
+                        tx.env_xdr(),
+                        tx.get_result_pair().to_xdr(),
+                        meta.to_xdr(),
+                    )
+                    for _idx, tx, meta in done
+                ]
+                enc = _encode_rows(blobs)
+                for (idx, _tx, _meta), (h, b, r, m) in zip(done, enc):
+                    rows_out[idx] = (h, seq, idx + 1, b, r, m)
         except BaseException as e:
             errors.append(e)
 
@@ -468,17 +476,19 @@ class ApplyScheduler:
             self.last_close = {"mode": "serial", "reason": "single-group"}
             return False
         workers = min(workers, len(groups))
-        shard_groups = self._assign(groups, workers)
-
         seq = lm.current.header.ledgerSeq
         fees = [tx.result.feeCharged for tx in txs]
-        shard_views = [
-            ShardView(db, frozenset().union(*(
-                (kb for _i, tx in groups[g] for kb in tx.static_footprint())
-                for g in sg
-            )))
-            for sg in shard_groups
-        ]
+        # the set-up before any shard runs: as long as apply.partition at
+        # 5,000 tx (it walks every footprint again)
+        with tracer.span("apply.shards", groups=len(groups), workers=workers):
+            shard_groups = self._assign(groups, workers)
+            shard_views = [
+                ShardView(db, frozenset().union(*(
+                    (kb for _i, tx in groups[g] for kb in tx.static_footprint())
+                    for g in sg
+                )))
+                for sg in shard_groups
+            ]
         outcomes: dict = {}
         rows_out: dict = {}
         errors: list = []
@@ -495,6 +505,7 @@ class ApplyScheduler:
                     seq,
                     lm._tx_apply_timer,
                     tracer,
+                    tracer.current(),
                     outcomes,
                     rows_out,
                     errors,
@@ -563,7 +574,8 @@ class ApplyScheduler:
                 for kb, slot in sv._store_buffer._overlay.items():
                     main_buf.record(kb, slot[0], slot[1], slot[2])
                 sv.close_view()
-            tx_history.insert_transaction_rows(lm.database, rows)
+            with tracer.span("apply.rows", rows=len(rows)):
+                tx_history.insert_transaction_rows(lm.database, rows)
 
         self.stats["parallel_txs"] += len(txs)
         self.stats["groups"] += len(groups)

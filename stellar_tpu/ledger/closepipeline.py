@@ -1,8 +1,8 @@
 """ClosePipeline — the pipelined-ledger-close scheduler (ROADMAP #3;
 reference anchor LedgerManagerImpl.cpp:845-888).
 
-The close phases run serially per ledger (``txset_validate → sig_flush →
-fees → apply → commit``), so the host idles while the signature plane
+The close phases run serially per ledger (``sig_flush → fees → apply →
+commit``), so the host idles while the signature plane
 verifies and the verify plane idles while the host applies.  This
 scheduler overlaps them ACROSS ledgers: while txset N is in
 ``close.apply``, the signature prewarm for the already-externalized txset
@@ -84,8 +84,6 @@ class ClosePipeline:
         self.n_quarantined = 0
         self.n_fallback = 0  # joined future failed -> inline prewarm
         self.overlap_hidden_ms = 0.0
-        self.join_wait_ms = 0.0
-        self.dispatch_ms = 0.0
 
     # -- externalized-ledger queue ------------------------------------------
     def queued_count(self) -> int:
@@ -183,7 +181,6 @@ class ClosePipeline:
         if backend is None or not self._space():
             return
         sp = tracer.begin("close.pipeline.dispatch")
-        t0 = time.perf_counter()
         n_sets = n_items = n_scp = 0
         db = self.app.database
         while self._candidates and self._space():
@@ -223,7 +220,6 @@ class ClosePipeline:
                         )
                     )
                     n_scp = len(scp_triples)
-        self.dispatch_ms += (time.perf_counter() - t0) * 1000.0
         tracer.end(sp, sets=n_sets, items=n_items, scp_items=n_scp)
 
     def _space(self) -> bool:
@@ -270,7 +266,6 @@ class ClosePipeline:
         hidden_ms = max(0.0, total_ms - wait_ms)
         self.n_joined += 1
         self.n_joined_warm += 1 if warm else 0
-        self.join_wait_ms += wait_ms
         self.overlap_hidden_ms += hidden_ms
         tracer.end(
             sp,
@@ -310,6 +305,4 @@ class ClosePipeline:
             "quarantined": self.n_quarantined,
             "fallback": self.n_fallback,
             "overlap_hidden_ms": round(self.overlap_hidden_ms, 3),
-            "join_wait_ms": round(self.join_wait_ms, 3),
-            "dispatch_ms": round(self.dispatch_ms, 3),
         }

@@ -23,6 +23,7 @@ from ..xdr.ledger import (
 )
 from ..xdr.ledger import TransactionMeta
 from ..database.database import UnrollbackableWrite
+from ..trace import NULL_TRACER
 from .accountframe import AccountFrame
 from .delta import LedgerDelta
 from .headerframe import LedgerHeaderFrame
@@ -360,15 +361,18 @@ class LedgerManager:
     # -- THE close (LedgerManagerImpl.cpp:612-741) -------------------------
     def close_ledger(self, ledger_data) -> None:
         tracer = self.app.tracer
+        # req: everything the close records, on any thread, carries the
+        # ledger sequence
         close_sp = tracer.begin(
             "ledger.close",
+            req=ledger_data.ledger_seq,
             seq=ledger_data.ledger_seq,
             txs=ledger_data.tx_set.size(),
         )
-        # phase 1 of the close trace: the txset's linkage + contents-hash
-        # audit (the expensive signature validation traces separately as
-        # txset.validate / sig.flush wherever check_valid runs)
-        with tracer.span("close.txset_validate", txs=ledger_data.tx_set.size()):
+        try:
+            # the txset's linkage + contents-hash audit (the expensive
+            # signature validation traces as txset.validate / sig.flush
+            # wherever check_valid runs)
             if ledger_data.tx_set.previous_ledger_hash != self.last_closed.hash:
                 raise RuntimeError("txset mismatch: wrong previous ledger hash")
             if (
@@ -376,11 +380,12 @@ class LedgerManager:
                 != ledger_data.value.txSetHash
             ):
                 raise RuntimeError("corrupt transaction set")
-
-        try:
             self._close_ledger_txn(ledger_data)
             tracer.end(close_sp)
         except BaseException:
+            # the span leaves this thread's stack with whatever the failed
+            # close left open above it
+            tracer.end(close_sp, failed=True)
             # the enclosing SQL transaction rolled back, but the decoded
             # -entry cache may hold post-apply values from the aborted
             # close — drop it wholesale so any retry/catchup reloads
@@ -515,7 +520,7 @@ class LedgerManager:
                 # closes attribute that cost here, not to no phase)
                 commit_sp = tracer.begin("close.commit")
                 if buf is not None:
-                    with self._flush_timer.time_scope():
+                    with tracer.span("commit.flush"), self._flush_timer.time_scope():
                         buf.flush(self.database)
             finally:
                 # success: overlay already flushed (deactivate clears
@@ -541,9 +546,10 @@ class LedgerManager:
             # fail policy aborts the close (ROLLBACK + wholesale cache
             # clear in close_ledger) instead of persisting a forked ledger
             if invariants is not None:
-                invariants.check_close(
-                    ledger_delta, self.database, inv_baseline, txs
-                )
+                with tracer.span("commit.invariants"):
+                    invariants.check_close(
+                        ledger_delta, self.database, inv_baseline, txs
+                    )
 
             ledger_delta.commit()
             self.current.invalidate_hash()
@@ -552,6 +558,10 @@ class LedgerManager:
             # queue any checkpoint inside this SQL transaction (crash-safe)
             self.app.history_manager.maybe_queue_history_checkpoint()
             fs.kill_point(KP_CLOSE_PRE, ctx=self.database)
+            # the span closes after the with-block has left
+            # database.transaction(): the COMMIT
+            sql_sp = tracer.begin("commit.sql")
+        tracer.end(sql_sp)
         fs.kill_point(KP_CLOSE_POST, ctx=self.database)
         tracer.end(
             commit_sp,
@@ -568,6 +578,10 @@ class LedgerManager:
 
         rows = []
         seq = self.current.header.ledgerSeq
+        tracer = self.app.tracer
+        # fees.charge and fees.rows partition the pass: the first opens
+        # with the scope's savepoint, the second closes with its release
+        phase_sp = tracer.begin("fees.charge", txs=len(txs))
         with self.database.transaction():
             for index, tx in enumerate(txs, start=1):
                 this_tx_delta = LedgerDelta(outer=delta)
@@ -576,14 +590,18 @@ class LedgerManager:
                     tx.fee_history_row(seq, index, this_tx_delta.get_changes())
                 )
                 this_tx_delta.commit()
+            tracer.end(phase_sp)
+            phase_sp = tracer.begin("fees.rows", rows=len(rows))
             # direct SQL write inside a (possibly savepoint-less) buffered
             # scope: give the scope a real savepoint first so a failure
             # after this point can still unwind the rows
             self.database.materialize_savepoints()
             tx_history.insert_fee_rows(self.database, rows)
+        tracer.end(phase_sp)
 
     def _apply_transactions(self, txs, ledger_delta, tx_result_set) -> None:
         from ..tx import history as tx_history
+        from ..tx.frame import TX_SAMPLE_STRIDE
         from ..xdr.txs import TransactionResultCode
 
         if self.app.config.PARALLEL_APPLY:
@@ -597,28 +615,36 @@ class LedgerManager:
 
         rows = []
         seq = self.current.header.ledgerSeq
-        for index, tx in enumerate(txs, start=1):
-            with self._tx_apply_timer.time_scope():
-                delta = LedgerDelta(outer=ledger_delta)
-                meta = TransactionMeta(0, [])
-                try:
-                    if tx.apply(delta, self.app, meta):
-                        delta.commit()
-                    else:
-                        assert not delta.get_changes()
-                except UnrollbackableWrite:
-                    # the SQL plane could not be unwound for this tx — DB
-                    # state is unknown; the close MUST abort (close_ledger
-                    # clears the entry cache and re-raises), a
-                    # txINTERNAL_ERROR continue would commit corrupt rows
-                    raise
-                except Exception as e:  # tx must never take down the close
-                    log.error("exception during tx apply: %s", e)
-                    tx.set_result_code(TransactionResultCode.txINTERNAL_ERROR)
-            self._tx_count_meter.mark()
-            tx_result_set.results.append(tx.get_result_pair())
-            rows.append(tx.history_row(seq, index, meta))
-        tx_history.insert_transaction_rows(self.database, rows)
+        tracer = self.app.tracer
+        skip = TX_SAMPLE_STRIDE - 1
+        with tracer.span("apply.serial", txs=len(txs)):
+            for index, tx in enumerate(txs, start=1):
+                # one transaction in TX_SAMPLE_STRIDE records tx.apply and
+                # its children; the others get the no-op tracer
+                tx_tracer = NULL_TRACER if (index - 1) & skip else tracer
+                with tx_tracer.span("tx.apply", index=index - 1):
+                    with self._tx_apply_timer.time_scope():
+                        delta = LedgerDelta(outer=ledger_delta)
+                        meta = TransactionMeta(0, [])
+                        try:
+                            if tx.apply(delta, self.app, meta, tx_tracer):
+                                delta.commit()
+                            else:
+                                assert not delta.get_changes()
+                        except UnrollbackableWrite:
+                            # the SQL plane could not be unwound for this tx — DB
+                            # state is unknown; the close MUST abort (close_ledger
+                            # clears the entry cache and re-raises), a
+                            # txINTERNAL_ERROR continue would commit corrupt rows
+                            raise
+                        except Exception as e:  # tx must never take down the close
+                            log.error("exception during tx apply: %s", e)
+                            tx.set_result_code(TransactionResultCode.txINTERNAL_ERROR)
+                    self._tx_count_meter.mark()
+                    tx_result_set.results.append(tx.get_result_pair())
+                    rows.append(tx.history_row(seq, index, meta))
+        with tracer.span("apply.rows", rows=len(rows)):
+            tx_history.insert_transaction_rows(self.database, rows)
 
     def _close_ledger_helper(self, delta) -> None:
         """BucketList add + header store + LCL pointers
@@ -629,13 +655,14 @@ class LedgerManager:
             PersistentState,
         )
 
-        self.app.bucket_manager.add_batch(
-            self.current.header.ledgerSeq,
-            delta.get_live_entries(),
-            delta.get_dead_entries(),
-        )
-        # bucketListHash + skipList rotation (BucketManagerImpl.cpp:300-331)
-        self.app.bucket_manager.snapshot_ledger(self.current.header)
+        with self.app.tracer.span("commit.buckets"):
+            self.app.bucket_manager.add_batch(
+                self.current.header.ledgerSeq,
+                delta.get_live_entries(),
+                delta.get_dead_entries(),
+            )
+            # bucketListHash + skipList rotation (BucketManagerImpl.cpp:300-331)
+            self.app.bucket_manager.snapshot_ledger(self.current.header)
         self.current.invalidate_hash()
         self.current.store_insert(self.database)
         fs.kill_point(KP_CLOSE_HEADER, ctx=self.database)

@@ -14,6 +14,7 @@ from __future__ import annotations
 import json
 import selectors
 import socket
+import time
 from typing import Callable, Dict, Optional
 from urllib.parse import parse_qsl, urlparse
 
@@ -434,8 +435,18 @@ class CommandHandler:
         profiler around the TPU crypto plane (SURVEY.md §5.1: the TPU
         build's tracing hook; the reference's analogue is its medida
         timers, which we also keep).  Traces are written as a TensorBoard
-        trace directory."""
+        trace directory.  Right after the start and right before the stop
+        a ``trace.sync.<time.monotonic_ns()>`` annotation goes into the
+        profile's host plane: its timestamp there less the number in its
+        name is the offset that lays ``/trace`` (``"clock": "monotonic"``)
+        over the device's events."""
         import jax
+
+        def sync():
+            with jax.profiler.TraceAnnotation(
+                "trace.sync.%d" % time.monotonic_ns()
+            ):
+                pass
 
         action = q.get("action", "")
         if action == "start":
@@ -448,12 +459,14 @@ class CommandHandler:
                 jax.profiler.start_trace(trace_dir)
             except Exception as e:
                 return {"error": f"start_trace failed: {e}"}
+            sync()
             self._profiling_dir = trace_dir
             return {"status": "profiling", "dir": trace_dir}
         if action == "stop":
             if not self._profiling_dir:
                 return {"error": "profiler not running"}
             try:
+                sync()
                 jax.profiler.stop_trace()
             except Exception as e:
                 # keep state for ONE retry (transient export I/O failure);
@@ -477,15 +490,20 @@ class CommandHandler:
     def handle_trace(self, q: dict) -> dict:
         """Dump the span ring as Chrome trace_event JSON (stellar_tpu/trace/;
         load in chrome://tracing or ui.perfetto.dev).  The per-name latency
-        aggregates ride along as top-level metadata both viewers ignore;
+        aggregates ride along as top-level metadata both viewers ignore
+        (``self_p50_ms``: the median, over the spans dumped, of a span's
+        time outside its children); ``"clock"`` names the clock of ``ts``;
         ``/trace?clear=1`` drops the ring after dumping (fresh window)."""
-        from ..trace import chrome_trace_json
+        from ..trace import chrome_trace_json, self_p50_ms
 
         tracer = self.app.tracer
         spans, aggregates, dropped = tracer.snapshot(
             clear=q.get("clear") == "1"
         )
-        out = chrome_trace_json(spans)
+        out = chrome_trace_json(spans, clock=tracer.clock_name)
+        for name, ms in self_p50_ms(spans).items():
+            if name in aggregates:
+                aggregates[name]["self_p50_ms"] = ms
         out["aggregates"] = aggregates
         out["enabled"] = tracer.enabled
         out["dropped_spans"] = dropped

@@ -513,6 +513,7 @@ class BatchVerifier:
         # budget from
         self._warm_buckets: set = set()  # analysis: locked-by _calls_lock
         self.n_device_calls = 0
+        self.n_lanes = 0
         self.n_items = 0
         self.n_gate_rejects = 0
         self.n_host_assist_items = 0
@@ -644,6 +645,10 @@ class BatchVerifier:
             for s in range(0, n_dev, self.max_batch)
         ]
 
+    def chunk_count(self, n: int) -> int:
+        """How many device chunks a verify call over ``n`` items makes."""
+        return len(self._chunks(n - self._host_assist_count(n)))
+
     def cold_buckets(self, n: int, host_assist: bool = True) -> int:
         """How many distinct buckets a call over ``n`` items dispatches to
         whose program has not compiled in this process yet.  Each costs a
@@ -718,10 +723,7 @@ class BatchVerifier:
             (start, n), staged, fut = pending.pop(0)
             dsp = self._tracer.begin("ed25519.drain")
             if fut is not None:
-                res = np.logical_and(
-                    np.asarray(fut)[:n], staged.ok[:n]
-                ).tolist()
-                out[start : start + n] = res
+                out[start : start + n] = self._read_back(fut, staged, n)
             # fut None: every lane was gate-rejected — out[] rows stay
             # False without a device round-trip
             self._tracer.end(dsp, items=n)
@@ -779,10 +781,7 @@ class BatchVerifier:
             (start, n), staged, fut = pending.pop(0)
             dsp = self._tracer.begin("ed25519.torsion_drain")
             if fut is not None:
-                res = np.logical_and(
-                    np.asarray(fut)[:n], staged.ok[:n]
-                ).tolist()
-                out[start : start + n] = res
+                out[start : start + n] = self._read_back(fut, staged, n)
             self._tracer.end(dsp, items=n)
             if staged is not None:
                 self._pool.release(staged.bufs)
@@ -795,6 +794,19 @@ class BatchVerifier:
             stage_fn=self._stage_torsion,
         )
         return out
+
+    def _read_back(self, fut, staged: _Staged, n: int) -> List[bool]:
+        """The two halves of a drain, as children that partition its span:
+        the wait until the device's answer is ready, then the rest of the
+        device -> host copy, the gate mask and the list.  The wait first
+        queues the copy behind the kernel, as ``np.asarray`` on a pending
+        result does: waiting and only then copying costs a host round trip
+        a chunk (~120 us, my chip run, PR 24)."""
+        with self._tracer.span("ed25519.wait"):
+            jax.copy_to_host_async(fut)
+            jax.block_until_ready(fut)
+        with self._tracer.span("ed25519.readback"):
+            return np.logical_and(np.asarray(fut)[:n], staged.ok[:n]).tolist()
 
     def _stage_torsion(self, encs, start, n) -> Optional[_Staged]:
         """Stage a torsion-proof chunk: A column = the encodings, R =
@@ -878,10 +890,14 @@ class BatchVerifier:
             # streams each needs an in-flight slot plus one being
             # drained, or the second stream can never overlap.
             depth = max(PIPELINE_DEPTH, self.streams + 1)
+            # the stager threads' spans name the span open here (the
+            # caller's flush) as their cause
+            parent = self._tracer.current()
 
             def stage_and_dispatch(rng):
-                staged = stage(items, *rng)
-                return staged, self._dispatch_staged(staged)
+                with self._tracer.under(parent):
+                    staged = stage(items, *rng)
+                    return staged, self._dispatch_staged(staged)
 
             with ThreadPoolExecutor(max_workers=self.streams) as stager:
                 futs = []
@@ -1113,6 +1129,7 @@ class BatchVerifier:
         self._tracer.end(dsp, bucket=bucket, backend=self.backend)
         with self._calls_lock:
             self.n_device_calls += 1
+            self.n_lanes += bucket
             self._warm_buckets.add(bucket)
         return ok
 
@@ -1149,6 +1166,9 @@ class BatchVerifier:
             "interpret": self.interpret,
             "device_calls": self.n_device_calls,
             "items": self.n_items,
+            # sum of the bucket sizes dispatched: items / lanes is how full
+            # the device's lanes were (5,000 items ride 4096 + 1024)
+            "lanes": self.n_lanes,
             "gate_rejects": self.n_gate_rejects,
             "host_assist_items": self.n_host_assist_items,
             "native_host_stage": self._sighash is not None,

@@ -166,6 +166,12 @@ def _kernel(
         out_ref[:] = (match & ~fail)[None]
 
 
+# The kernel's name in a device trace, stated here and not inherited from
+# whatever a Python function happens to be called: the benchmark finds the
+# kernel's device events by it (benchmarks/layers/common.py).
+VERIFY_KERNEL_NAME = "verify_kernel_pallas"
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "signed"))
 def verify_kernel_pallas(
     a_bytes, r_bytes, s_bytes, h_bytes, interpret=False, signed=None
@@ -206,6 +212,7 @@ def verify_kernel_pallas(
     )  # static niels table of k*B, lane-replicated for Mosaic
     return pl.pallas_call(
         functools.partial(_kernel, signed=signed),
+        name=VERIFY_KERNEL_NAME,
         grid=(grid,),
         in_specs=[
             pl.BlockSpec(

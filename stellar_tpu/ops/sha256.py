@@ -180,6 +180,10 @@ def _sha256_kernel(k_ref, nb_ref, p_ref, out_ref):
     out_ref[:] = _digest_rows(rows, nb_ref[0], lambda t: k_ref[t])
 
 
+# the kernel's name in a device trace (stated, as ed25519_pallas's is)
+SHA256_KERNEL_NAME = "sha256_pallas"
+
+
 def sha256_pallas(p, nblocks, interpret: bool = False):
     """Pallas stage over the packed (max_blocks * 64, N) uint8 columns
     -> (32, N) int32 digest rows.  N must be a multiple of the verify
@@ -199,6 +203,7 @@ def sha256_pallas(p, nblocks, interpret: bool = False):
     nb = nblocks.astype(jnp.int32).reshape(1, n)
     return pl.pallas_call(
         _sha256_kernel,
+        name=SHA256_KERNEL_NAME,
         grid=(grid,),
         in_specs=[
             pl.BlockSpec(

@@ -530,6 +530,10 @@ def _sha_kernel(k_ref, p_ref, out_ref):
     out_ref[:] = _h_rows(rows, lambda t: (k_ref[0, t], k_ref[1, t]))
 
 
+# the kernel's name in a device trace (stated, as ed25519_pallas's is)
+SHA512_KERNEL_NAME = "sha512_pallas"
+
+
 def sha512_pallas(p, interpret: bool = False):
     """Pallas stage over the packed (160, N) uint8 device-hash layout ->
     (32, N) int32 h rows.  N must be a multiple of the verify kernel's
@@ -553,6 +557,7 @@ def sha512_pallas(p, interpret: bool = False):
         )  # (2, 80, NT) int32
         return pl.pallas_call(
             _sha_kernel,
+            name=SHA512_KERNEL_NAME,
             grid=(grid,),
             in_specs=[
                 pl.BlockSpec(

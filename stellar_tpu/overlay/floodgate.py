@@ -93,31 +93,31 @@ class Floodgate:
         if self._shutting_down:
             return
         tracer = tracer_of(self.app)
-        sp = tracer.begin("overlay.flood")
-        # pack-once fan-out: ONE serialization (the C pack_many path)
-        # serves the flood key and every peer's send queue — each queue
-        # entry holds a reference to this same immutable buffer, so a
-        # 100-peer flood never re-serializes and shedding is O(1)
-        body = pack_many([msg], StellarMessage)
-        key = self.message_key(msg, body)
-        rec = self.flood_map.get(key)
-        if rec is None or force:
-            lm = self.app.ledger_manager
-            seq = lm.get_ledger_num() if lm.last_closed is not None else 0
-            rec = FloodRecord(seq, msg)
-            self.flood_map[key] = rec
-            self.m_added.set_count(len(self.flood_map))
-        om = self.app.overlay_manager
-        sent = 0
-        for peer in list(om.authenticated_peers()):
-            if peer not in rec.peers_told:
-                rec.peers_told.add(peer)
-                peer.send_message(msg, body=body)
-                sent += 1
-        self.n_sent += sent
-        tracer.end(
-            sp, msg_type=getattr(msg.type, "name", str(msg.type)), sent=sent
-        )
+        with tracer.span("overlay.flood") as sp:
+            # pack-once fan-out: ONE serialization (the C pack_many path)
+            # serves the flood key and every peer's send queue — each queue
+            # entry holds a reference to this same immutable buffer, so a
+            # 100-peer flood never re-serializes and shedding is O(1)
+            body = pack_many([msg], StellarMessage)
+            key = self.message_key(msg, body)
+            rec = self.flood_map.get(key)
+            if rec is None or force:
+                lm = self.app.ledger_manager
+                seq = lm.get_ledger_num() if lm.last_closed is not None else 0
+                rec = FloodRecord(seq, msg)
+                self.flood_map[key] = rec
+                self.m_added.set_count(len(self.flood_map))
+            om = self.app.overlay_manager
+            sent = 0
+            for peer in list(om.authenticated_peers()):
+                if peer not in rec.peers_told:
+                    rec.peers_told.add(peer)
+                    peer.send_message(msg, body=body)
+                    sent += 1
+            self.n_sent += sent
+            tracer.end(
+                sp, msg_type=getattr(msg.type, "name", str(msg.type)), sent=sent
+            )
 
     def shutdown(self) -> None:
         self._shutting_down = True

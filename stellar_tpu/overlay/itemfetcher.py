@@ -67,7 +67,7 @@ class Tracker:
         self.num_empty_rounds = 0
         # fetch latency span: opens with the tracker, ends at finish()
         self._span = tracer_of(app).begin(
-            "overlay.fetch", item=item_hash.hex()[:8]
+            "overlay.fetch", detached=True, item=item_hash.hex()[:8]
         )
 
     def finish(self, outcome: str) -> None:

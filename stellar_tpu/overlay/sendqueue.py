@@ -445,22 +445,22 @@ class SendQueue:
             if stall_ms is not None and stall_ms > self._stats.max_stall_ms:
                 self._stats.max_stall_ms = stall_ms
         tracer = tracer_of(peer.app)
-        sp = tracer.begin("overlay.sendq.stall")
-        log.warning(
-            "straggler disconnect %r: %s (queued=%dB inflight=%dB)",
-            peer, reason, self.queued_bytes, self._inflight,
-        )
-        # the goodbye ERROR frame must not re-enter the caps it just
-        # tripped; everything after this is best-effort into a transport
-        # that is being torn down anyway
-        self.bypass()
-        peer.note_straggler_backoff()
-        peer.drop(ErrorCode.ERR_LOAD, "send queue " + reason)
-        tracer.end(
-            sp,
-            reason=reason,
-            stall_ms=round(stall_ms, 1) if stall_ms is not None else -1,
-        )
+        with tracer.span("overlay.sendq.stall") as sp:
+            log.warning(
+                "straggler disconnect %r: %s (queued=%dB inflight=%dB)",
+                peer, reason, self.queued_bytes, self._inflight,
+            )
+            # the goodbye ERROR frame must not re-enter the caps it just
+            # tripped; everything after this is best-effort into a transport
+            # that is being torn down anyway
+            self.bypass()
+            peer.note_straggler_backoff()
+            peer.drop(ErrorCode.ERR_LOAD, "send queue " + reason)
+            tracer.end(
+                sp,
+                reason=reason,
+                stall_ms=round(stall_ms, 1) if stall_ms is not None else -1,
+            )
 
     # -- teardown / views ----------------------------------------------------
     def close(self) -> None:

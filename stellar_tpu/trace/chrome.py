@@ -3,7 +3,10 @@
 Format reference: the Trace Event Format doc (catapult); each completed span
 becomes one complete-duration event (``"ph": "X"``) with microsecond
 timestamps.  Loadable in chrome://tracing and https://ui.perfetto.dev; extra
-top-level keys (``aggregates``) are legal metadata both viewers ignore.
+top-level keys (``aggregates``, ``clock``) are legal metadata both viewers
+ignore.  ``clock`` names the clock of ``ts``: ``monotonic`` is
+``time.monotonic``, the clock of the ``trace.sync.<ns>`` markers that
+``/profiler`` writes into the device trace.
 """
 
 from __future__ import annotations
@@ -21,7 +24,7 @@ def _json_safe(v):
     return str(v)
 
 
-def chrome_trace_json(spans: Iterable) -> dict:
+def chrome_trace_json(spans: Iterable, clock: str = "monotonic") -> dict:
     events: List[dict] = []
     for s in spans:
         if s.end is None:
@@ -35,7 +38,14 @@ def chrome_trace_json(spans: Iterable) -> dict:
             "pid": PID,
             "tid": s.tid,
         }
+        args = {"sid": s.sid}
+        if s.parent is not None:
+            args["parent"] = s.parent
+        if s.req is not None:
+            args["req"] = _json_safe(s.req)
         if s.attrs:
-            ev["args"] = {k: _json_safe(v) for k, v in s.attrs.items()}
+            for k, v in s.attrs.items():
+                args[k] = _json_safe(v)
+        ev["args"] = args
         events.append(ev)
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
+    return {"traceEvents": events, "displayTimeUnit": "ms", "clock": clock}

@@ -8,6 +8,26 @@ the missing attribution plane:
 - ``Tracer.span(name, **attrs)`` — context manager for synchronous phases;
   ``begin``/``end`` for phases that start and finish on different callbacks
   or threads (async prewarm joins, item fetches, SCP rounds).
+- every span names its cause: ``sid`` (an integer of this tracer),
+  ``parent`` (the ``sid`` of the span that was open on the same thread when
+  it began) and ``req`` (the request it belongs to: the ledger sequence
+  under ``ledger.close`` / ``herder.trigger``, a flush ordinal under a
+  signature flush).  Each thread keeps a stack of its open spans;
+  ``span()`` and ``begin``/``end`` push and pop it, and ``end`` unwinds
+  whatever was begun above the span and never ended (an exception between
+  a ``begin`` and its ``end``).  Work handed to another thread names its
+  parent itself: ``parent=sp`` (``span`` or ``begin``) for one span,
+  ``under(sp)`` around everything a worker records for the caller; ``current()`` is what
+  the caller hands over.  A span that outlives the scope that began it, or
+  that another thread or a later callback ends (``overlay.fetch``,
+  ``scp.*``), is begun ``detached=True``: it has a parent and is nobody's.
+  ``req`` is the parent's where the parent has one, else the ``req=`` the
+  span was begun with.  ``selftime.py`` computes self time from parents.
+  A span at the bottom of a long-lived thread's stack is a ``span()``
+  block, ended on every path: a ``begin`` that an exception skipped would
+  stay there, a stale parent of whatever the thread records next.
+  (``begin``/``end`` pairs run under such a block, under ``ledger.close``,
+  which ends on its failure path, or on a worker's thread under ``under``.)
 - a lock-protected fixed-size ring buffer of completed spans (old spans are
   overwritten, the tracer never grows without bound);
 - per-name latency aggregation: every completed span feeds a reservoir
@@ -29,6 +49,7 @@ real one in (keeps every call site unconditional).
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Dict, List, Optional
@@ -38,23 +59,39 @@ from ..util.metrics import Histogram
 
 class Span:
     """One completed (or in-flight) phase.  ``start``/``end`` are seconds on
-    the tracer's clock; ``attrs`` land in the Chrome export's ``args``."""
+    the tracer's clock; ``attrs`` land in the Chrome export's ``args``, with
+    ``sid``, ``parent`` (a ``sid`` or None) and ``req`` (or None)."""
 
-    __slots__ = ("name", "start", "end", "tid", "attrs")
+    __slots__ = ("name", "start", "end", "tid", "attrs", "sid", "parent", "req")
 
-    def __init__(self, name: str, start: float, tid: int, attrs: Optional[dict]):
+    def __init__(
+        self,
+        name: str,
+        start: float,
+        tid: int,
+        attrs: Optional[dict],
+        sid: int = 0,
+        parent: Optional[int] = None,
+        req=None,
+    ):
         self.name = name
         self.start = start
         self.end: Optional[float] = None
         self.tid = tid
         self.attrs = attrs
+        self.sid = sid
+        self.parent = parent
+        self.req = req
 
     @property
     def duration(self) -> float:
         return (self.end - self.start) if self.end is not None else 0.0
 
     def __repr__(self) -> str:  # debugging aid only
-        return f"Span({self.name!r}, {self.start:.6f}..{self.end}, {self.attrs})"
+        return (
+            f"Span({self.name!r}, {self.start:.6f}..{self.end}, {self.attrs},"
+            f" sid={self.sid}, parent={self.parent}, req={self.req})"
+        )
 
 
 class _NoopScope:
@@ -87,6 +124,37 @@ class _SpanScope:
         return False
 
 
+class _UnderScope:
+    """``Tracer.under``: a span of another thread at the bottom of this
+    thread's stack for the length of the block."""
+
+    __slots__ = ("_stack", "_parent")
+
+    def __init__(self, stack: list, parent: Span):
+        self._stack = stack
+        self._parent = parent
+
+    def __enter__(self) -> Span:
+        self._stack.append(self._parent)
+        return self._parent
+
+    def __exit__(self, *exc):
+        _unwind(self._stack, self._parent)
+        return False
+
+
+def _unwind(stack: list, span: Span) -> None:
+    """Take ``span`` off ``stack`` with everything begun above it."""
+    if stack:
+        if stack[-1] is span:
+            stack.pop()
+            return
+        for i in range(len(stack) - 2, -1, -1):
+            if stack[i] is span:
+                del stack[i:]
+                return
+
+
 class Tracer:
     """Per-Application span recorder (see module docstring)."""
 
@@ -107,36 +175,97 @@ class Tracer:
         self._lock = threading.Lock()
         self._metrics = metrics
         self._hists: Dict[str, Histogram] = {}
+        self._sids = itertools.count(1)  # next() is atomic in CPython
+        self._local = threading.local()  # .stack: this thread's open spans
         # deterministic-test clock: only a VIRTUAL clock's now() is used
         # directly; REAL mode falls back to time.monotonic (wall time can
         # step backwards across NTP slews — a trace must not)
         if clock is not None and getattr(clock, "mode", None) == "virtual":
             self._now = clock.now
+            self.clock_name = "virtual"
         else:
             self._now = time.monotonic
+            self.clock_name = "monotonic"
 
     # -- recording ----------------------------------------------------------
-    def span(self, name: str, **attrs):
-        """Context manager timing a synchronous phase."""
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
+
+    def _open(self, name, parent, req, detached, attrs) -> Span:
+        stack = self._stack()
+        if parent is None and stack:
+            parent = stack[-1]
+        if parent is not None:
+            psid = parent.sid
+            if parent.req is not None:
+                req = parent.req
+        else:
+            psid = None
+        span = Span(
+            name,
+            self._now(),
+            threading.get_ident(),
+            attrs or None,
+            next(self._sids),
+            psid,
+            req,
+        )
+        if not detached:
+            stack.append(span)
+        return span
+
+    def span(self, name: str, parent: Optional[Span] = None, req=None, **attrs):
+        """Context manager timing a synchronous phase (``parent`` as for
+        ``begin``).  ``end(span, **attrs)`` inside the block ends it with
+        what the body learned; leaving the block is then a no-op."""
         if not self.enabled:
             return _NOOP_SCOPE
-        return _SpanScope(
-            self, Span(name, self._now(), threading.get_ident(), attrs or None)
-        )
+        return _SpanScope(self, self._open(name, parent, req, False, attrs))
 
-    def begin(self, name: str, **attrs) -> Optional[Span]:
-        """Open a span explicitly (async phases; completes via ``end``).
-        Returns None when disabled — ``end(None)`` is a no-op, so call
-        sites never need their own enabled check."""
+    def begin(
+        self,
+        name: str,
+        parent: Optional[Span] = None,
+        req=None,
+        detached: bool = False,
+        **attrs,
+    ) -> Optional[Span]:
+        """Open a span explicitly (completes via ``end``).  ``parent`` names
+        the cause where it is not the span open on this thread (work handed
+        over from another thread); ``detached`` for a span that outlives
+        the scope that begins it or that another thread ends.  Returns
+        None when disabled — ``end(None)`` is a no-op, so call sites never
+        need their own enabled check."""
         if not self.enabled:
             return None
-        return Span(name, self._now(), threading.get_ident(), attrs or None)
+        return self._open(name, parent, req, detached, attrs)
+
+    def current(self) -> Optional[Span]:
+        """The innermost span open on this thread: what a caller hands to
+        the thread it starts, as ``parent=`` or to ``under``."""
+        if not self.enabled:
+            return None
+        stack = self._stack()
+        return stack[-1] if stack else None
+
+    def under(self, parent: Optional[Span]):
+        """Context manager for a worker thread: everything this thread
+        records inside the block has ``parent`` (a span open on the
+        caller's thread) as its cause."""
+        if not self.enabled or parent is None:
+            return _NOOP_SCOPE
+        return _UnderScope(self._stack(), parent)
 
     def end(self, span: Optional[Span], **attrs) -> None:
         """Complete a span from ``begin`` (None-safe, double-end-safe)."""
         if span is None or span.end is not None:
             return
         span.end = self._now()
+        _unwind(self._stack(), span)
         if attrs:
             if span.attrs:
                 span.attrs.update(attrs)
@@ -232,7 +361,7 @@ class Tracer:
         https://ui.perfetto.dev)."""
         from .chrome import chrome_trace_json
 
-        return chrome_trace_json(self.spans())
+        return chrome_trace_json(self.spans(), clock=self.clock_name)
 
 
 # Disabled tracer for components constructed without an Application (ops-level

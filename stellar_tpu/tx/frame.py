@@ -25,6 +25,7 @@ from ..crypto import PubKeyUtils, sha256
 from ..crypto.keys import SecretKey
 from ..ledger.accountframe import _ACCT_KEY_PREFIX, AccountFrame
 from ..ledger.delta import LedgerDelta
+from ..trace import NULL_TRACER
 from .opframe import OperationFrame
 from ..util.xmath import INT64_MAX
 from ..xdr.base import xdr_to_opaque
@@ -40,6 +41,15 @@ from ..xdr.txs import (
     TransactionResultResult,
 )
 from . import history as tx_history
+
+# Sampled transaction spans: the loops that apply a set (the serial loop of
+# ``LedgerManager._apply_transactions``, ``ApplyScheduler._run_shard``) hand
+# the close's tracer to one transaction in TX_SAMPLE_STRIDE, chosen by its
+# index in the set (no clock, no random number: the same indices on every
+# run), and the no-op tracer to the others; a sampled transaction records
+# ``tx.apply`` with ``tx.valid`` and ``tx.ops`` under it.  A power of two:
+# the test is ``index & (TX_SAMPLE_STRIDE - 1)``.
+TX_SAMPLE_STRIDE = 64
 
 
 def _acct_kb(pk: PublicKey) -> bytes:
@@ -412,11 +422,21 @@ class TransactionFrame:
         self.signing_account.store_change(delta, lm.database)
 
     # -- apply (TransactionFrame.cpp:439-495) ------------------------------
-    def apply(self, delta: LedgerDelta, app, meta: Optional[TransactionMeta] = None) -> bool:
+    def apply(
+        self,
+        delta: LedgerDelta,
+        app,
+        meta: Optional[TransactionMeta] = None,
+        tracer=NULL_TRACER,
+    ) -> bool:
+        """``tracer`` records ``tx.valid`` and ``tx.ops``: the close's own
+        for a sampled transaction (``TX_SAMPLE_STRIDE``), else the no-op."""
         if meta is None:
             meta = TransactionMeta(0, [])
         self.reset_signature_tracker()
-        if not self.common_valid(app, True, 0):
+        with tracer.span("tx.valid"):
+            valid = self.common_valid(app, True, 0)
+        if not valid:
             return False
 
         error_encountered = False
@@ -426,24 +446,25 @@ class TransactionFrame:
         this_tx_delta = LedgerDelta(outer=delta)
         try:
             with db.transaction():
-                for op in self.operations:
-                    with op_timer.time_scope():
-                        op_delta = LedgerDelta(outer=this_tx_delta)
-                        try:
-                            ok = op.apply(op_delta, app)
-                        except BaseException:
-                            # EntryFrame stores hit the shared decoded-entry
-                            # cache immediately, before op_delta.commit()
-                            # lifts the keys into this_tx_delta — if apply
-                            # dies mid-op only op_delta knows those keys, so
-                            # its rollback must flush them or the caller's
-                            # txINTERNAL_ERROR path leaves stale cache lines
-                            op_delta.rollback()
-                            raise
-                    if not ok:
-                        error_encountered = True
-                    meta.value.append(OperationMeta(op_delta.get_changes()))
-                    op_delta.commit()
+                with tracer.span("tx.ops", ops=len(self.operations)):
+                    for op in self.operations:
+                        with op_timer.time_scope():
+                            op_delta = LedgerDelta(outer=this_tx_delta)
+                            try:
+                                ok = op.apply(op_delta, app)
+                            except BaseException:
+                                # EntryFrame stores hit the shared decoded-entry
+                                # cache immediately, before op_delta.commit()
+                                # lifts the keys into this_tx_delta — if apply
+                                # dies mid-op only op_delta knows those keys, so
+                                # its rollback must flush them or the caller's
+                                # txINTERNAL_ERROR path leaves stale cache lines
+                                op_delta.rollback()
+                                raise
+                        if not ok:
+                            error_encountered = True
+                        meta.value.append(OperationMeta(op_delta.get_changes()))
+                        op_delta.commit()
                 if not error_encountered:
                     if not self.check_all_signatures_used():
                         # malformed tx slipped through validation: roll back

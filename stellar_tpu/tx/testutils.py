@@ -7,6 +7,8 @@ reference's TxTests helpers play across its suites.
 
 from __future__ import annotations
 
+import os
+import tempfile
 from typing import List, Optional
 
 import stellar_tpu.xdr as X
@@ -31,8 +33,17 @@ def get_test_config(instance: int = 0, backend: str = "cpu") -> Config:
     cfg.MANUAL_CLOSE = True
     cfg.HTTP_PORT = 39100 + instance * 2
     cfg.PEER_PORT = 39200 + instance * 2
-    cfg.TMP_DIR_PATH = f"/tmp/stellar-tpu-test-{instance}"
-    cfg.BUCKET_DIR_PATH = f"/tmp/stellar-tpu-test-buckets-{instance}"
+    # under the process's temporary directory (TMPDIR), one directory per
+    # instance AND per pytest-xdist worker: test files that share an
+    # instance number (most use 0) run at the same time in different
+    # workers, and a node's start-up sweep of its bucket directory deleted
+    # the other worker's half-written bucket files (FileNotFoundError in
+    # fs.durable_rename, a few tests a run).  Both variables are inherited,
+    # so a test's child processes see its paths.
+    base = os.path.join(tempfile.gettempdir(), "stellar-tpu-test")
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "")
+    cfg.TMP_DIR_PATH = f"{base}-{worker}{instance}"
+    cfg.BUCKET_DIR_PATH = f"{base}-buckets-{worker}{instance}"
     cfg.SIGNATURE_BACKEND = backend
     cfg.NODE_SEED = SecretKey.from_seed(
         bytes([instance % 256]) + b"test-node-seed".ljust(31, b"\x00")

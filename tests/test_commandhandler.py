@@ -190,6 +190,45 @@ def test_profiler_route(app, tmp_path):
     assert "error" in ch.handle_profiler({})  # bad action
 
 
+def test_profiler_writes_the_markers_that_join_it_to_trace(app, tmp_path):
+    """An operator can lay /trace over /profiler: the profile's host plane
+    holds a ``trace.sync.<time.monotonic_ns()>`` annotation from right
+    after the start and one from right before the stop; /trace names the
+    clock of its own timestamps."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    ch = app.command_handler
+    d = str(tmp_path / "trace")
+    t0 = time.monotonic_ns()
+    assert ch.handle_profiler({"action": "start", "dir": d}).get("status") == "profiling"
+    with app.tracer.span("demo.phase"):
+        pass
+    assert ch.handle_profiler({"action": "stop"}).get("status") == "stopped"
+    t1 = time.monotonic_ns()
+    (path,) = glob.glob(d + "/plugins/profile/*/*.xplane.pb")
+    marks = [
+        (int(e.name[len("trace.sync."):]), e.start_ns)
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name.startswith("trace.sync.")
+    ]
+    assert len(marks) == 2
+    assert all(t0 <= ns <= t1 for ns, _ in marks)
+    # one offset, both times: the profiler's clock less time.monotonic
+    (a_ns, a_at), (b_ns, b_at) = sorted(marks)
+    assert abs((a_at - a_ns) - (b_at - b_ns)) < 5e6  # within 5 ms of each other
+    # (this fixture's node runs on the virtual clock, and /trace says so;
+    # a node on the real clock says "monotonic": tests/test_trace.py)
+    out = ch.execute("/trace")
+    assert out["clock"] == app.tracer.clock_name == "virtual"
+    assert any(e["name"] == "demo.phase" for e in out["traceEvents"])
+
+
 def test_maintenance_queue_processing():
     """HerderTests.cpp:103-147 'Queue processing': pubsub cursors gate
     maintenance deletion of old ledger headers; the min across cursors

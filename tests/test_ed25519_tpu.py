@@ -247,6 +247,70 @@ class TestBatchVerifier:
         assert got == want
         assert ha.n_host_assist_items == 16  # 0.4 * 40 peeled to host
 
+    def test_flush_traced_from_inside(self, bv):
+        """A device flush through TpuSigBackend: ``sig.device_flush`` on
+        the caller's thread is the cause of everything the guarded worker
+        and the stager threads record; a drain has the wait and the
+        read-back as children; ``lanes`` counts the buckets dispatched."""
+        import threading
+
+        from stellar_tpu.crypto.sigbackend import TpuSigBackend
+        from stellar_tpu.trace import NULL_TRACER, Tracer
+
+        items = []
+        for i in range(100):  # two chunks of the fixture's 64: the stager pool runs
+            sk = SecretKey.pseudo_random_for_testing(500 + i)
+            msg = b"traced %d" % i
+            items.append((sk.public_raw, msg, sk.sign(msg)))
+        be = TpuSigBackend.__new__(TpuSigBackend)  # the fixture's compiled verifier
+        be._verifier = bv
+        be.cpu_cutover = 0
+        be.n_cutover_items = be.n_wedge_fallback_items = 0
+        be._wedged_until, be.n_latch_flips = {}, {}
+        be._wedge_lock = threading.Lock()
+        tr = Tracer()
+        be._tracer = bv._tracer = tr
+        before = bv.stats()
+        try:
+            assert be.verify_batch(items) == [True] * 100
+            with tr.span("ledger.close", req=41):
+                assert be.verify_batch(items[:40]) == [True] * 40
+        finally:
+            bv._tracer = NULL_TRACER
+        after = bv.stats()
+        d_items = after["items"] - before["items"]
+        d_lanes = after["lanes"] - before["lanes"]
+        assert d_items == 140
+        assert d_lanes == sum(
+            s.attrs["bucket"] for s in tr.spans() if s.name == "ed25519.device_dispatch"
+        )
+        assert d_lanes >= d_items
+        spans = tr.spans()
+        by = {s.sid: s for s in spans}
+        flushes = [s for s in spans if s.name == "sig.device_flush"]
+        assert [(s.attrs["items"], s.attrs["chunks"]) for s in flushes] == [(100, 2), (40, 1)]
+        # a flush of its own is its own request (the backend's own count,
+        # from 1); one inside a close is the close's
+        assert flushes[0].req == 1 and flushes[1].req == 41
+        assert be.n_device_flushes == 2 and TpuSigBackend.n_device_flushes == 0
+        first = [s for s in spans if s.req == flushes[0].req and s is not flushes[0]]
+        names = sorted(s.name for s in first)
+        assert names == sorted(
+            2 * ["ed25519.host_hash", "ed25519.device_dispatch", "ed25519.drain",
+                 "ed25519.wait", "ed25519.readback"]
+        )
+        # at most 1 + 2 * chunks new spans a flush
+        assert len([s for s in first if s.name in ("ed25519.wait", "ed25519.readback")]) + 1 == 5
+        for s in first:
+            if s.name in ("ed25519.wait", "ed25519.readback"):
+                drain = by[s.parent]
+                assert drain.name == "ed25519.drain"
+                assert drain.start <= s.start and s.end <= drain.end
+            else:
+                # across the worker hop (drain) and the stager pool
+                # (host_hash, device_dispatch): threads other than the caller's
+                assert s.parent == flushes[0].sid and s.tid != flushes[0].tid
+
     def test_empty_and_gate_only_batches(self, bv):
         assert bv.verify([]) == []
         # all items fail the host gate -> no device call needed
@@ -254,6 +318,67 @@ class TestBatchVerifier:
         bad = [(b"\x00" * 32, b"m", b"\x00" * 64)] * 3
         assert bv.verify(bad) == [False, False, False]
         assert bv.n_device_calls == calls_before
+
+
+class TestKernelNames:
+    """A kernel's name in a device trace is stated at its ``pallas_call``,
+    not inherited from whatever a Python function is called: the
+    benchmark finds the verify kernel's device events by it."""
+
+    @staticmethod
+    def _pallas_names(jaxpr):
+        names = []
+
+        def walk(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    names.append(eqn.params["name"])
+                for v in eqn.params.values():
+                    inner = getattr(v, "jaxpr", None)
+                    if inner is not None and hasattr(inner, "eqns"):
+                        walk(inner)
+                    elif hasattr(v, "eqns"):
+                        walk(v)
+                    elif isinstance(v, (tuple, list)):
+                        for b in v:
+                            inner = getattr(b, "jaxpr", b)
+                            if hasattr(inner, "eqns"):
+                                walk(inner)
+
+        walk(jaxpr.jaxpr)
+        return names
+
+    def test_verify_kernel_carries_the_stated_name(self):
+        from stellar_tpu.ops import ed25519_pallas as EP
+
+        assert EP.VERIFY_KERNEL_NAME == "verify_kernel_pallas"
+        col = jax.ShapeDtypeStruct((32, EP.NT), jnp.uint8)
+        jaxpr = jax.make_jaxpr(
+            lambda a, r, s, h: EP.verify_kernel_pallas(a, r, s, h, interpret=True)
+        )(col, col, col, col)
+        assert self._pallas_names(jaxpr) == [EP.VERIFY_KERNEL_NAME]
+
+    @pytest.mark.parametrize("which", ["sha512", "sha256"])
+    def test_hash_kernels_carry_their_stated_names(self, which):
+        from stellar_tpu.ops.ed25519_pallas import NT
+
+        if which == "sha512":
+            from stellar_tpu.ops import sha512 as M
+
+            want = M.SHA512_KERNEL_NAME
+            jaxpr = jax.make_jaxpr(lambda p: M.sha512_pallas(p, interpret=True))(
+                jax.ShapeDtypeStruct((M.DH_ROWS, NT), jnp.uint8)
+            )
+        else:
+            from stellar_tpu.ops import sha256 as M
+
+            want = M.SHA256_KERNEL_NAME
+            jaxpr = jax.make_jaxpr(lambda p, nb: M.sha256_pallas(p, nb, interpret=True))(
+                jax.ShapeDtypeStruct((64, NT), jnp.uint8),
+                jax.ShapeDtypeStruct((NT,), jnp.int32),
+            )
+        assert want == which + "_pallas"
+        assert self._pallas_names(jaxpr) == [want]
 
 
 class TestPallasKernel:

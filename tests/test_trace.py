@@ -83,7 +83,10 @@ class TestTracerCore:
             assert key in ev
         assert ev["ph"] == "X"
         assert ev["cat"] == "a"
-        assert ev["args"] == {"blob": "0102", "n": 3, "label": "x"}
+        # the span's own attributes, beside what names its cause
+        assert ev["args"] == {"sid": 1, "blob": "0102", "n": 3, "label": "x"}
+        assert payload["clock"] == "virtual"
+        assert Tracer().chrome_trace()["clock"] == "monotonic"
 
     def test_aggregator_percentiles(self, clock):
         tr = Tracer(clock=clock)
@@ -149,6 +152,506 @@ class TestTracerCore:
         assert tr.aggregates()["t"]["count"] == 800
 
 
+def _tree(spans):
+    """(name, parent's name, req, start, end) of every span, by sid."""
+    by = {s.sid: s for s in spans}
+    return [
+        (s.sid, s.name, by[s.parent].name if s.parent in by else s.parent, s.req, s.start, s.end)
+        for s in sorted(spans, key=lambda s: s.sid)
+    ]
+
+
+class TestCause:
+    """Every span names its cause: ``sid``, ``parent``, ``req``."""
+
+    def test_parent_sid_req_on_one_thread(self, clock):
+        tr = Tracer(clock=clock)
+        with tr.span("ledger.close", req=7, seq=7) as root:
+            assert tr.current() is root
+            a = tr.begin("close.fees")
+            with tr.span("fees.charge") as inner:
+                assert (inner.parent, inner.req) == (a.sid, 7)
+            tr.end(a, txs=3)
+            with tr.span("close.apply", req=99) as b:
+                # the parent's request wins: a span is part of the request
+                # that caused it
+                assert (b.parent, b.req) == (root.sid, 7)
+        assert tr.current() is None
+        with tr.span("alone") as lone:
+            assert (lone.parent, lone.req) == (None, None)
+        sids = [s.sid for s in sorted(tr.spans(), key=lambda s: s.sid)]
+        assert sids == [1, 2, 3, 4, 5]  # per tracer, from 1, in order of beginning
+        assert Tracer(clock=clock).begin("x").sid == 1
+        ev = {e["name"]: e["args"] for e in tr.chrome_trace()["traceEvents"]}
+        assert ev["fees.charge"] == {"sid": 3, "parent": 2, "req": 7}
+        assert ev["close.fees"] == {"sid": 2, "parent": 1, "req": 7, "txs": 3}
+        assert ev["alone"] == {"sid": 5}
+
+    def test_end_unwinds_what_an_exception_left_open(self, clock):
+        tr = Tracer(clock=clock)
+        with pytest.raises(RuntimeError):
+            with tr.span("outer"):
+                tr.begin("never.ended")  # its end is skipped by the raise
+                raise RuntimeError
+        assert tr.current() is None
+        assert [s.name for s in tr.spans()] == ["outer"]
+
+    def test_detached_span_has_a_parent_and_is_nobodys(self, clock):
+        tr = Tracer(clock=clock)
+        with tr.span("scp.envelope") as cause:
+            fetch = tr.begin("overlay.fetch", detached=True)
+            with tr.span("next") as nxt:
+                assert nxt.parent == cause.sid
+        assert fetch.parent == cause.sid
+        assert tr.current() is None  # the open fetch is on no stack
+        with tr.span("later") as later:
+            assert later.parent is None
+        tr.end(fetch)
+
+    def test_explicit_hand_off_across_threads(self, clock):
+        import threading
+
+        tr = Tracer(clock=clock)
+        seen = {}
+
+        def shard(parent):
+            sp = tr.begin("apply.group", parent=parent)
+            with tr.span("tx.apply") as t:
+                seen["tx"] = (t.parent, t.req)
+            tr.end(sp)
+            seen["group"] = (sp.parent, sp.req)
+            seen["after"] = tr.current()
+
+        def worker(parent):
+            with tr.under(parent):
+                with tr.span("ed25519.drain") as d:
+                    seen["drain"] = (d.parent, d.req)
+            seen["worker_after"] = tr.current()
+
+        with tr.span("close.apply", req=12) as ca:
+            for fn in (shard, worker):
+                t = threading.Thread(target=fn, args=(tr.current(),))
+                t.start()
+                t.join()
+            assert tr.current() is ca  # the other threads' stacks are theirs
+        group = next(s for s in tr.spans() if s.name == "apply.group")
+        assert seen["group"] == (ca.sid, 12)
+        assert seen["tx"] == (group.sid, 12)
+        assert seen["drain"] == (ca.sid, 12)
+        assert seen["after"] is None and seen["worker_after"] is None
+        # a thread with nothing handed to it starts from nothing
+        with tr.under(None):
+            assert tr.current() is None
+
+    def test_span_block_takes_a_parent_and_ends_with_what_the_body_learned(self, clock):
+        import threading
+
+        tr = Tracer(clock=clock)
+        seen = {}
+
+        def shard(parent):
+            with tr.span("apply.group", parent=parent, txs=2) as sp:
+                seen["group"] = (sp.parent, sp.req)
+                tr.end(sp, done=2)  # the block's exit is then a no-op
+            seen["after"] = tr.current()
+
+        with tr.span("close.apply", req=9) as ca:
+            t = threading.Thread(target=shard, args=(tr.current(),))
+            t.start()
+            t.join()
+        assert seen == {"group": (ca.sid, 9), "after": None}
+        (group,) = [s for s in tr.spans() if s.name == "apply.group"]
+        assert group.attrs == {"txs": 2, "done": 2}
+        assert tr.aggregates()["apply.group"]["count"] == 1  # ended once
+
+    @pytest.mark.parametrize("site", ["sig.flush", "ingest.flush", "apply.group"])
+    def test_a_raising_body_leaves_no_stale_parent(self, clock, site):
+        """The spans that sit at the bottom of a long-lived thread's stack
+        are ended on the failure path too: what the thread records next
+        has no parent."""
+        from stellar_tpu.crypto.sigbackend import CachingSigBackend
+        from stellar_tpu.crypto.sigcache import VerifySigCache
+
+        class Boom(Exception):
+            pass
+
+        class Raising:
+            name = "raising"
+
+            def verify_batch(self, items, caller=None):
+                raise Boom
+
+        item = (b"\x01" * 32, b"msg", b"\x02" * 64)
+        if site == "sig.flush":
+            tr = Tracer(clock=clock)
+            be = CachingSigBackend(Raising(), VerifySigCache(), tracer=tr)
+            with pytest.raises(Boom):
+                be.verify_batch([item])
+        elif site == "ingest.flush":
+            from stellar_tpu.ledger.accountframe import AccountFrame
+            from stellar_tpu.main.application import Application
+            from stellar_tpu.tx import testutils as T
+
+            cfg = T.get_test_config(179)
+            cfg.HTTP_PORT = 0
+            app = Application.create(clock, cfg, new_db=True)
+            try:
+                app.start()
+                tr = app.tracer
+                root = T.root_key_for(app)
+                seq = AccountFrame.load_account(root.get_public_key(), app.database).get_seq_num()
+                tx = T.tx_from_ops(app, root, seq + 1, [T.create_account_op(T.get_account(9900), 10**9)])
+                app.ingest._inner = Raising()
+                tr.clear()
+                with pytest.raises(Boom):
+                    app.ingest.submit_sync(tx)
+            finally:
+                app.graceful_stop()
+        else:
+            from stellar_tpu.ledger.applysched import ApplyScheduler
+
+            class ExplodingGroup:
+                def __len__(self):
+                    return 1
+
+                def __iter__(self):
+                    raise Boom
+
+            tr = Tracer(clock=clock)
+            errors = []
+            ApplyScheduler._run_shard(
+                None, None, None, [ExplodingGroup()], None, 2, None, tr, None, {}, {}, errors
+            )
+            assert [type(e) for e in errors] == [Boom]
+        assert tr.current() is None
+        assert site in [s.name for s in tr.spans()]  # ended, so recorded
+        with tr.span("next") as nxt:
+            assert nxt.parent is None
+
+    def test_identical_trees_under_the_virtual_clock(self):
+        def run():
+            c = VirtualClock(VIRTUAL_TIME)
+            try:
+                tr = Tracer(clock=c)
+                for seq in (2, 3):
+                    c.set_current_virtual_time(float(seq))
+                    with tr.span("ledger.close", req=seq):
+                        with tr.span("close.apply"):
+                            for i in range(130):
+                                if not i & 63:
+                                    sp = tr.begin("tx.apply", index=i)
+                                    c.set_current_virtual_time(seq + (i + 1) / 1000.0)
+                                    tr.end(sp)
+                        det = tr.begin("scp.ballot", detached=True)
+                        with tr.span("close.commit"):
+                            pass
+                        tr.end(det)
+                return _tree(tr.spans()), json.dumps(tr.chrome_trace(), sort_keys=True)
+            finally:
+                c.shutdown()
+
+        first, second = run(), run()
+        assert first == second
+        assert len(first[0]) == 2 * (3 + 3 + 1)
+
+    def test_disabled_tracer_hands_nothing_over(self, clock):
+        tr = Tracer(enabled=False, clock=clock)
+        assert tr.current() is None
+        with tr.under(None):
+            assert tr.begin("x", parent=None, req=1, detached=True) is None
+        assert tr.spans() == []
+
+
+class TestSelfTime:
+    def test_self_time_is_duration_less_the_union_of_children(self):
+        from stellar_tpu.trace import Span, self_p50_ms, self_times
+
+        def span(sid, name, t0, t1, parent=None, tid=1):
+            sp = Span(name, t0, tid, None, sid, parent)
+            sp.end = t1
+            return sp
+
+        # two shards overlapping in time (other threads), one child that
+        # outlives the parent, a grandchild that must not count twice,
+        # and a span still open (left out)
+        spans = [
+            span(1, "close.apply", 0.0, 10.0),
+            span(2, "apply.group", 1.0, 4.0, parent=1, tid=2),
+            span(3, "apply.group", 3.0, 6.0, parent=1, tid=3),
+            span(4, "tx.apply", 1.0, 2.0, parent=2, tid=2),
+            span(5, "apply.merge", 9.0, 12.0, parent=1),
+            Span("open", 0.0, 1, None, 6, 1),
+        ]
+        st = self_times(spans)
+        assert st == {
+            1: pytest.approx(10.0 - 5.0 - 1.0),  # children cover [1,6] and [9,10]
+            2: pytest.approx(2.0),
+            3: pytest.approx(3.0),
+            4: pytest.approx(1.0),
+            5: pytest.approx(3.0),
+        }
+        p50 = self_p50_ms(spans)
+        assert p50["apply.group"] == pytest.approx(2500.0)
+        assert p50["close.apply"] == pytest.approx(4000.0)
+        assert "open" not in p50
+
+    def test_trace_route_reports_self_p50_beside_p50(self, clock):
+        from stellar_tpu.main.application import Application
+        from stellar_tpu.tx import testutils as T
+
+        cfg = T.get_test_config(174)
+        cfg.HTTP_PORT = 0
+        app = Application.create(clock, cfg, new_db=True)
+        try:
+            clock.set_current_virtual_time(100.0)
+            with app.tracer.span("outer.phase"):
+                clock.set_current_virtual_time(101.0)
+                with app.tracer.span("inner.phase"):
+                    clock.set_current_virtual_time(103.0)
+                clock.set_current_virtual_time(104.0)
+            out = app.command_handler.execute("/trace")
+            assert out["clock"] == "virtual"
+            agg = out["aggregates"]
+            assert agg["outer.phase"]["p50_ms"] == pytest.approx(4000.0)
+            assert agg["outer.phase"]["self_p50_ms"] == pytest.approx(2000.0)
+            assert agg["inner.phase"]["self_p50_ms"] == pytest.approx(2000.0)
+        finally:
+            app.graceful_stop()
+
+
+NEW_CLOSE_SPANS = {
+    "commit.flush", "commit.invariants", "commit.buckets", "commit.sql",
+    "fees.charge", "fees.rows", "apply.serial", "apply.shards", "apply.rows",
+    "tx.apply", "tx.valid", "tx.ops",
+}
+CLOSE_TXS = 130
+
+
+@pytest.fixture(scope="module", params=["parallel", "serial"])
+def traced_closes(request):
+    """Two consecutive real-clock closes of 130 payments each (accounts in
+    groups of two), traced: -> the spans of each close."""
+    from stellar_tpu.ledger.accountframe import AccountFrame
+    from stellar_tpu.main.application import Application
+    from stellar_tpu.tx import testutils as T
+    from stellar_tpu.util.clock import REAL_TIME
+
+    c = VirtualClock(REAL_TIME)
+    cfg = T.get_test_config(175 if request.param == "parallel" else 176)
+    cfg.PARALLEL_APPLY = request.param == "parallel"
+    cfg.APPLY_WORKERS = 4  # a 1-core host would auto-size to the serial loop
+    app = Application(c, cfg, new_db=True)
+    try:
+        lm = app.ledger_manager
+        root = T.root_key_for(app)
+        keys = [T.get_account(7000 + i) for i in range(CLOSE_TXS)]
+        seq = AccountFrame.load_account(root.get_public_key(), app.database).get_seq_num()
+        fund = [
+            T.tx_from_ops(app, root, seq + 1 + j, [T.create_account_op(k, 10**9) for k in keys[i : i + 70]])
+            for j, i in enumerate(range(0, CLOSE_TXS, 70))
+        ]
+        T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5, fund)
+        first = lm.last_closed.header.ledgerSeq << 32
+        closes = []
+        for r in range(3):
+            app.tracer.clear()
+            pay = [
+                T.tx_from_ops(app, k, first + 1 + r, [T.payment_op(keys[i ^ 1], 100)])
+                for i, k in enumerate(keys)
+            ]
+            T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5, pay)
+            assert all(tx.get_result_code().name == "txSUCCESS" for tx in pay)
+            closes.append((lm.last_closed.header.ledgerSeq, app.tracer.spans()))
+        assert app.tracer.dropped == 0
+        mode = app.ledger_manager._apply_sched.last_close["mode"] if cfg.PARALLEL_APPLY else "serial"
+        assert mode == request.param
+        yield request.param, closes
+    finally:
+        app.database.close()
+        c.shutdown()
+
+
+class TestCloseFromInside:
+    """The three spans that held 70 % of a close have children."""
+
+    @pytest.mark.parametrize("phase", ["close.commit", "close.fees", "close.apply"])
+    def test_direct_children_cover_the_phase(self, traced_closes, phase):
+        mode, closes = traced_closes
+        shares = []
+        for _seq, spans in closes:
+            (sp,) = [s for s in spans if s.name == phase]
+            kids = [(s.start, s.end) for s in spans if s.parent == sp.sid]
+            assert kids, phase
+            if phase == "close.apply" and mode == "parallel":
+                # the shard threads' phase runs from the end of
+                # apply.shards to the start of apply.merge: what a loaded
+                # host adds between starting a thread and its first
+                # instruction is the phase's, not a hole in the tracing
+                (shards,) = [s for s in spans if s.name == "apply.shards"]
+                (merge,) = [s for s in spans if s.name == "apply.merge"]
+                groups = [s for s in spans if s.name == "apply.group"]
+                assert groups and all(
+                    shards.end <= g.start and g.end <= merge.start for g in groups
+                )
+                kids.append((shards.end, merge.start))
+            covered, cursor = 0.0, sp.start
+            for lo, hi in sorted(kids):
+                lo, hi = max(lo, cursor), min(hi, sp.end)
+                if hi > lo:
+                    covered += hi - lo
+                    cursor = hi
+            shares.append(covered / sp.duration)
+        # the calmest of the closes
+        assert max(shares) >= 0.95, (phase, shares)
+
+    def test_children_by_name(self, traced_closes):
+        mode, closes = traced_closes
+        _seq, spans = closes[1]
+        by = {s.sid: s for s in spans}
+        kids = {}
+        for s in spans:
+            if s.parent in by:
+                kids.setdefault(by[s.parent].name, set()).add(s.name)
+        assert kids["close.commit"] == {
+            "commit.flush", "commit.invariants", "commit.buckets", "commit.sql",
+        }
+        assert all(n.startswith("invariant.") for n in kids["commit.invariants"])
+        assert kids["close.fees"] == {"fees.charge", "fees.rows"}
+        assert "fees.charge" not in kids  # no span a transaction in the fee loop
+        if mode == "parallel":
+            assert kids["close.apply"] == {
+                "apply.partition", "apply.shards", "apply.group", "apply.merge",
+            }
+            assert kids["apply.merge"] == {"apply.rows"}
+            assert kids["apply.group"] == {"tx.apply"}
+        else:
+            assert kids["close.apply"] == {"apply.serial", "apply.rows"}
+            assert kids["apply.serial"] == {"tx.apply"}
+        assert kids["tx.apply"] == {"tx.valid", "tx.ops"}
+
+    def test_every_span_of_a_close_carries_its_ledger(self, traced_closes):
+        _mode, closes = traced_closes
+        for seq, spans in closes:
+            under = [s for s in spans if s.name != "ledger.close"]
+            assert under and all(s.req == seq for s in spans), {
+                (s.name, s.req) for s in spans if s.req != seq
+            }
+            # the shard threads' and the prewarm worker's spans included
+            (close,) = [s for s in spans if s.name == "ledger.close"]
+            assert {s.tid for s in spans} != {close.tid}
+
+    def test_sampling_picks_the_same_indices_on_every_run(self, traced_closes):
+        from stellar_tpu.tx.frame import TX_SAMPLE_STRIDE
+
+        _mode, closes = traced_closes
+        picks = [
+            sorted(s.attrs["index"] for s in spans if s.name == "tx.apply")
+            for _seq, spans in closes
+        ]
+        assert picks[0] == picks[1] == picks[2]
+        assert picks[0] == list(range(0, CLOSE_TXS, TX_SAMPLE_STRIDE)) == [0, 64, 128]
+
+    def test_sampled_children_lie_in_the_transaction_in_order(self, traced_closes):
+        # tx.apply's own time is what is left: the deltas' commits, the
+        # result pair, the history row
+        _mode, closes = traced_closes
+        for _seq, spans in closes:
+            for tx in (s for s in spans if s.name == "tx.apply"):
+                parts = sorted((s for s in spans if s.parent == tx.sid), key=lambda s: s.start)
+                assert [s.name for s in parts] == ["tx.valid", "tx.ops"]
+                assert tx.start <= parts[0].start and parts[-1].end <= tx.end
+                assert parts[0].end <= parts[1].start
+                assert parts[1].attrs == {"ops": 1}
+
+    def test_span_budget_of_a_close(self, traced_closes):
+        import math
+
+        from stellar_tpu.tx.frame import TX_SAMPLE_STRIDE
+
+        _mode, closes = traced_closes
+        budget = lambda txs: 64 + 4 * math.ceil(txs / 64)  # noqa: E731
+        for _seq, spans in closes:
+            new = [s for s in spans if s.name in NEW_CLOSE_SPANS]
+            assert {s.name for s in spans} - NEW_CLOSE_SPANS - {"apply.group"} <= {
+                "ledger.close", "close.sig_flush", "close.fees", "close.apply",
+                "close.commit", "close.pipeline.dispatch", "apply.partition",
+                "apply.merge", "sig.flush_async", "sig.flush",
+            } | {s.name for s in spans if s.name.startswith("invariant.")}
+            assert len(new) <= budget(CLOSE_TXS)
+            fixed = len([s for s in new if not s.name.startswith("tx.")])
+            assert fixed <= 8
+        # and at the widths the cells run: whole spans + 3 a sampled
+        # transaction
+        for txs in (1000, 5000):
+            worst = 8 + 3 * math.ceil(txs / TX_SAMPLE_STRIDE)
+            assert worst <= budget(txs), txs
+
+
+class TestFrontDoorCounters:
+    def test_submit_sync_counts_and_records_no_span_of_its_own(self, clock):
+        from stellar_tpu.crypto.keys import SecretKey
+        from stellar_tpu.ledger.accountframe import AccountFrame
+        from stellar_tpu.main.application import Application
+        from stellar_tpu.tx import testutils as T
+
+        cfg = T.get_test_config(177)
+        cfg.HTTP_PORT = 0
+        app = Application.create(clock, cfg, new_db=True)
+        try:
+            app.start()
+            assert app.ingest is not None and app.ingest.enabled
+            before = app.ingest.stats()
+            app.tracer.clear()
+            n = 5
+            root = T.root_key_for(app)
+            seq = AccountFrame.load_account(root.get_public_key(), app.database).get_seq_num()
+            for i in range(n):
+                tx = T.tx_from_ops(
+                    app, root, seq + 1 + i,
+                    [T.create_account_op(SecretKey.pseudo_random_for_testing(9700 + i), 10**9)],
+                )
+                assert app.ingest.submit_sync(tx) == "PENDING"
+            after = app.ingest.stats()
+            assert after["submitted"] - before["submitted"] == n
+            assert after["submit_s"] > before["submit_s"]
+            names = [s.name for s in app.tracer.spans()]
+            # what the edge recorded before this PR, and nothing else
+            assert set(names) <= {"ingest.flush", "sig.flush", "sig.host_verify"}, names
+            assert names.count("ingest.flush") == n
+        finally:
+            app.graceful_stop()
+
+    def test_herder_trigger_and_its_children(self, clock):
+        from test_herder import create_account_tx, load_or_none, make_scp_app
+        from stellar_tpu.crypto.keys import SecretKey
+
+        app = make_scp_app(clock, instance=178)
+        app.herder.bootstrap()
+        dest = SecretKey.pseudo_random_for_testing(9800)
+        app.herder.recv_transaction(create_account_tx(app, dest, 10**10))
+        assert clock.crank_until(lambda: load_or_none(app, dest) is not None, 60)
+        spans = app.tracer.spans()
+        by = {s.sid: s for s in spans}
+        trig = [s for s in spans if s.name == "herder.trigger"]
+        assert trig
+        slot = trig[0].req
+        assert slot == 2
+        kids = [s.name for s in spans if s.parent == trig[0].sid]
+        for name in ("herder.trim_invalid", "herder.surge", "txset.validate", "scp.consensus"):
+            assert name in kids, name
+        # at most a dozen new spans a ledger, none per transaction
+        new = [s for s in spans if s.name.startswith("herder.") and s.req == slot]
+        assert len(new) <= 12
+        # on a single-node network consensus and the close run inside the
+        # trigger: the whole ledger is one request
+        (close,) = [s for s in spans if s.name == "ledger.close"]
+        assert close.req == slot
+        last = max(s.sid for s in spans if s.parent == close.sid)
+        inside = [s for s in spans if trig[0].sid <= s.sid <= last]
+        assert len(inside) > 20
+        assert all(s.req == slot for s in inside), [(s.name, s.req, s.parent) for s in inside if s.req != slot]
+
+
 class TestCloseTrace:
     """A simulation ledger close must leave a Chrome-loadable trace with the
     close phases and an attribute-carrying sig-flush span."""
@@ -169,12 +672,30 @@ class TestCloseTrace:
         names = {s.name for s in app.tracer.spans()}
         for phase in (
             "ledger.close",
-            "close.txset_validate",
             "close.sig_flush",
+            "close.fees",
             "close.apply",
             "close.commit",
+            # the children of the three phases that had none
+            "commit.flush",
+            "commit.invariants",
+            "commit.buckets",
+            "commit.sql",
+            "fees.charge",
+            "fees.rows",
+            "apply.rows",
+            "tx.apply",
+            "tx.valid",
+            "tx.ops",
+            # and the herder's side of the ledger
+            "herder.trigger",
+            "herder.trim_invalid",
+            "herder.surge",
         ):
             assert phase in names, f"missing close phase {phase}"
+        # close.txset_validate (two hash compares a close, one dot away
+        # from txset.validate) is gone: nothing read it
+        assert "close.txset_validate" not in names
         # consensus attribution rides along
         assert "scp.consensus" in names
         assert "txset.validate" in names
