@@ -1,10 +1,18 @@
-"""Conflict-partitioned parallel transaction apply (ROADMAP open item #2).
+"""Conflict-partitioned transaction apply on worker threads, and the
+sizing that decides whether a close uses it.
 
-Serial Python apply is the last serial wall in the close (PROFILE.md
-round-20 split): fees, signatures, flush and hashing are all batched or
-native, but `_apply_transactions` still walks 5000 txs one at a time.
-This module breaks the wall for the statically-partitionable part of the
-txset:
+**What was read on the chip's host (PERF.md, Findings, PR 25; 13 cores,
+CPython 3.12):** the shard legs run Python op bodies, so under one
+interpreter lock they take turns instead of overlapping — at 5,000 tx 13
+shards made a sampled transaction 18x longer than the serial loop's, and
+two threads lose as well as thirteen.  ``sized_workers`` therefore
+resolves ``APPLY_WORKERS = 0`` (auto) to ONE thread wherever the
+interpreter serialises Python threads, and the close takes
+`LedgerManager._apply_transactions`' serial loop with no partition, no
+shard views, no threads and no merge replay.  The threaded plane below
+runs only under an explicit ``APPLY_WORKERS >= 2`` (the tests, the chaos
+scenarios and ``profile_close.py --apply-report`` pin 4) or on an
+interpreter without the lock:
 
 - **pre-pass** (`apply.partition` span): `TransactionFrame
   .static_footprint()` extracts each tx's account read/write footprint
@@ -28,8 +36,8 @@ txset:
 - **merge** (`apply.merge` span, main thread): per-tx deltas commit into
   the close's LedgerDelta in canonical apply order, shard cache/buffer
   slots replay into the main planes (disjoint by construction), history
-  rows — batch-encoded in the workers via the native `_applycore` leg,
-  which releases the GIL so shards genuinely overlap — insert in one
+  rows — batch-encoded in the workers (`tx/history.transaction_rows`, the
+  same call the serial loop makes once after its loop) — insert in one
   executemany, exactly like the serial loop.
 
 The escape hatch is total: on ANY worker error the scheduler restores
@@ -42,6 +50,7 @@ main planes were never touched, which is what makes the fallback safe.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
@@ -262,32 +271,23 @@ class _ShardApp:
         return getattr(self._app, name)
 
 
-# -- history-row encode (native leg) ------------------------------------
+# -- sizing -------------------------------------------------------------
 
 
-def _encode_rows(items: List[Tuple[bytes, bytes, bytes, bytes]]):
-    """[(txid, body, result, meta)] bytes -> [(hex, b64, b64, b64)] str.
+def sized_workers(cfg) -> int:
+    """How many interpreter threads apply a transaction set.
 
-    The native `_applycore` leg releases the GIL across the whole batch,
-    so worker threads overlap their row encoding — the dominant residual
-    Python cost of the per-tx apply tail.  Pure-Python fallback keeps
-    the path alive where the toolchain can't build the extension."""
-    from ..native import load_applycore
-
-    mod = load_applycore()
-    if mod is not None:
-        return mod.encode_history_rows(items)
-    import base64
-
-    return [
-        (
-            t.hex(),
-            base64.b64encode(b).decode(),
-            base64.b64encode(r).decode(),
-            base64.b64encode(m).decode(),
-        )
-        for t, b, r, m in items
-    ]
+    An explicit ``APPLY_WORKERS`` is taken as given.  Auto (0) is sized
+    from what the code can observe: worker legs run Python, so under an
+    interpreter lock (no ``sys._is_gil_enabled``, or it answers true) any
+    count above one only adds turn-taking — one thread; on an interpreter
+    that runs Python threads side by side, the core count."""
+    if cfg.APPLY_WORKERS:
+        return cfg.APPLY_WORKERS
+    gil_enabled = getattr(sys, "_is_gil_enabled", None)
+    if gil_enabled is None or gil_enabled():
+        return 1
+    return os.cpu_count() or 1
 
 
 # -- the scheduler -------------------------------------------------------
@@ -313,8 +313,25 @@ class ApplyScheduler:
             "closes_parallel": 0,
             "closes_serial": 0,
         }
-        # last-close detail for profile_close.py --apply-report
+        # last-close detail: /info, the apply.serial span's attributes and
+        # profile_close.py --apply-report read it
         self.last_close: Optional[dict] = None
+
+    def info(self) -> dict:
+        """The block /info shows beside the verifier's: how the node's
+        closes were applied."""
+        return {
+            "workers": sized_workers(self.lm.app.config),
+            "closes_parallel": self.stats["closes_parallel"],
+            "closes_serial": self.stats["closes_serial"],
+            "reason": (self.last_close or {}).get("reason"),
+        }
+
+    def _serial(self, reason: str) -> bool:
+        """Record that this set goes to the caller's serial loop."""
+        self.stats["closes_serial"] += 1
+        self.last_close = {"mode": "serial", "reason": reason}
+        return False
 
     # -- partition -------------------------------------------------------
     def _partition(self, txs) -> Optional[List[List[Tuple[int, object]]]]:
@@ -378,6 +395,7 @@ class ApplyScheduler:
         order merge on the main thread.  ``parent`` is the span open on
         the main thread (``close.apply``): this thread's spans name it."""
         from ..tx.frame import TX_SAMPLE_STRIDE
+        from ..tx.history import transaction_rows
         from ..xdr.txs import TransactionResultCode
 
         try:
@@ -416,20 +434,19 @@ class ApplyScheduler:
                                 ok = False
                         outcomes[idx] = (ok, delta)
                         done.append((idx, tx, meta))
-                # batch the history-row encode (native leg drops the GIL, so
-                # shards overlap here even under CPython)
+                # one encode call for the leg's rows, as the serial loop's
                 blobs = [
                     (
+                        idx + 1,
                         tx.get_contents_hash(),
                         tx.env_xdr(),
                         tx.get_result_pair().to_xdr(),
                         meta.to_xdr(),
                     )
-                    for _idx, tx, meta in done
+                    for idx, tx, meta in done
                 ]
-                enc = _encode_rows(blobs)
-                for (idx, _tx, _meta), (h, b, r, m) in zip(done, enc):
-                    rows_out[idx] = (h, seq, idx + 1, b, r, m)
+                for row in transaction_rows(seq, blobs):
+                    rows_out[row[2] - 1] = row
         except BaseException as e:
             errors.append(e)
 
@@ -453,28 +470,28 @@ class ApplyScheduler:
         lm = self.lm
         self.stats["total_txs"] += len(txs)
         cfg = lm.app.config
-        if not getattr(cfg, "PARALLEL_APPLY", False) or not txs:
-            return False
+        if not cfg.PARALLEL_APPLY:
+            return self._serial("parallel-apply-off")
+        workers = sized_workers(cfg)
+        if workers <= 1:
+            # the shipped default under an interpreter lock: nothing of
+            # the plane below runs, not even the partition
+            return self._serial("one-worker")
         db = lm.database
         if active_buffer(db) is None:
             # per-shard writes merge through the store buffer; without it
             # every store is a (single-threaded) SQL write — stay serial
-            return False
-        workers = cfg.APPLY_WORKERS or (os.cpu_count() or 1)
-        if workers <= 1:
-            return False
+            return self._serial("no-store-buffer")
+        if not txs:
+            return self._serial("empty-txset")
         tracer = lm.app.tracer
         with tracer.span("apply.partition", txs=len(txs)):
             groups = self._partition(txs)
         if groups is None:
             self.stats["conflict_fallbacks"] += 1
-            self.stats["closes_serial"] += 1
-            self.last_close = {"mode": "serial", "reason": "conflicting-txset"}
-            return False
+            return self._serial("conflicting-txset")
         if len(groups) < 2:
-            self.stats["closes_serial"] += 1
-            self.last_close = {"mode": "serial", "reason": "single-group"}
-            return False
+            return self._serial("single-group")
         workers = min(workers, len(groups))
         seq = lm.current.header.ledgerSeq
         fees = [tx.result.feeCharged for tx in txs]
@@ -528,9 +545,7 @@ class ApplyScheduler:
             self._restore_for_serial(txs, fees, shard_views)
             self.stats["escapes"] += 1
             self.stats["conflict_fallbacks"] += 1
-            self.stats["closes_serial"] += 1
-            self.last_close = {"mode": "serial", "reason": "escape"}
-            return False
+            return self._serial("escape")
 
         with tracer.span(
             "apply.merge", shards=len(shard_views), groups=len(groups)
@@ -548,9 +563,7 @@ class ApplyScheduler:
                 self._restore_for_serial(txs, fees, shard_views)
                 self.stats["escapes"] += 1
                 self.stats["conflict_fallbacks"] += 1
-                self.stats["closes_serial"] += 1
-                self.last_close = {"mode": "serial", "reason": "header-escape"}
-                return False
+                return self._serial("header-escape")
             rows = []
             for i, tx in enumerate(txs):
                 ok, delta = outcomes[i]
@@ -563,7 +576,8 @@ class ApplyScheduler:
             main_buf = active_buffer(db)
             main_fctx = active_frame_context(db)
             for sv in shard_views:
-                for kb, entry in sv._entry_cache._local.items():
+                local = sv._entry_cache._local
+                for kb, entry in local.items():
                     main_cache.put_owned(kb, entry)
                     if main_fctx is not None:
                         # the main context may still map a pre-apply frame
@@ -573,6 +587,15 @@ class ApplyScheduler:
                         main_fctx.evict(kb)
                 for kb, slot in sv._store_buffer._overlay.items():
                     main_buf.record(kb, slot[0], slot[1], slot[2])
+                    if kb not in local:
+                        # a later transaction of the shard failed and its
+                        # rollback erased the shard's line for an account an
+                        # earlier one had stored: the slot holds the truth
+                        # and the main line is the pre-apply one — drop it,
+                        # as the serial loop's rollback does
+                        main_cache.erase(kb)
+                        if main_fctx is not None:
+                            main_fctx.evict(kb)
                 sv.close_view()
             with tracer.span("apply.rows", rows=len(rows)):
                 tx_history.insert_transaction_rows(lm.database, rows)

@@ -603,26 +603,31 @@ class LedgerManager:
         from ..tx import history as tx_history
         from ..tx.frame import TX_SAMPLE_STRIDE
         from ..xdr.txs import TransactionResultCode
+        from .applysched import apply_scheduler_of, sized_workers
 
-        if self.app.config.PARALLEL_APPLY:
-            from .applysched import apply_scheduler_of
+        # the scheduler sizes the apply (applysched.sized_workers): under
+        # an interpreter lock one thread, and it answers False at once;
+        # False also after a CONFLICTING classification, too few groups
+        # or a footprint escape.  The serial loop below is then the truth
+        sched = apply_scheduler_of(self)
+        if sched.apply(txs, ledger_delta, tx_result_set):
+            return
 
-            # conflict-partitioned parallel apply; False means the set was
-            # not touched (CONFLICTING classification, too few groups, or
-            # a footprint escape) and the serial loop below is the truth
-            if apply_scheduler_of(self).apply(txs, ledger_delta, tx_result_set):
-                return
-
-        rows = []
+        blobs = []
         seq = self.current.header.ledgerSeq
         tracer = self.app.tracer
         skip = TX_SAMPLE_STRIDE - 1
-        with tracer.span("apply.serial", txs=len(txs)):
-            for index, tx in enumerate(txs, start=1):
+        with tracer.span(
+            "apply.serial",
+            txs=len(txs),
+            workers=sized_workers(self.app.config),
+            reason=sched.last_close["reason"],
+        ):
+            for index, tx in enumerate(txs):
                 # one transaction in TX_SAMPLE_STRIDE records tx.apply and
                 # its children; the others get the no-op tracer
-                tx_tracer = NULL_TRACER if (index - 1) & skip else tracer
-                with tx_tracer.span("tx.apply", index=index - 1):
+                tx_tracer = NULL_TRACER if index & skip else tracer
+                with tx_tracer.span("tx.apply", index=index):
                     with self._tx_apply_timer.time_scope():
                         delta = LedgerDelta(outer=ledger_delta)
                         meta = TransactionMeta(0, [])
@@ -641,8 +646,13 @@ class LedgerManager:
                             log.error("exception during tx apply: %s", e)
                             tx.set_result_code(TransactionResultCode.txINTERNAL_ERROR)
                     self._tx_count_meter.mark()
-                    tx_result_set.results.append(tx.get_result_pair())
-                    rows.append(tx.history_row(seq, index, meta))
+                    pair = tx.get_result_pair()
+                    tx_result_set.results.append(pair)
+                    blobs.append(
+                        (index + 1, pair.transactionHash, tx.env_xdr(), pair.to_xdr(), meta.to_xdr())
+                    )
+            # the set's history rows in one encode call, as a shard leg's
+            rows = tx_history.transaction_rows(seq, blobs)
         with tracer.span("apply.rows", rows=len(rows)):
             tx_history.insert_transaction_rows(self.database, rows)
 

@@ -271,9 +271,15 @@ class Config:
         # write-back store buffer (ENTRY_WRITE_BUFFER): shard writes must
         # never reach SQL mid-apply.
         self.PARALLEL_APPLY = True
-        # worker threads for the parallel apply path; 0 = auto
-        # (os.cpu_count()).  An effective count of 1 short-circuits to
-        # the plain serial path with zero scheduling overhead.
+        # worker threads for the parallel apply path; 0 = auto, sized by
+        # applysched.sized_workers from what the interpreter is: ONE
+        # wherever it serialises Python threads (every CPython with the
+        # interpreter lock — the shard legs run Python, so more threads
+        # only take turns: 13 made a 5,000-tx apply 2.2x slower on the
+        # chip's host, PERF.md PR 25), os.cpu_count() only on an interpreter that
+        # does not.  An effective count of 1 short-circuits to the plain
+        # serial loop before any partitioning; an explicit count >= 2
+        # runs the threaded plane as given (tests and scenarios pin 4).
         self.APPLY_WORKERS = 0
 
     # -- loading -----------------------------------------------------------

@@ -97,8 +97,8 @@ class ScenarioSpec:
     straggler_stall_ms: Optional[float] = None
     # conflict-partitioned parallel apply (ledger/applysched.py) — None
     # keeps the Config default on every node; True also pins
-    # APPLY_WORKERS=4 so the 1-core CI host genuinely shards instead of
-    # auto-sizing to a single (serial-short-circuit) worker
+    # APPLY_WORKERS=4 so the closes genuinely shard: auto sizes to one
+    # thread (the serial loop) under an interpreter lock
     parallel_apply: Optional[bool] = None
     # floors/verdicts for the survival plane: a run must disconnect at
     # least one straggler (slow_reader), must shed at least this many

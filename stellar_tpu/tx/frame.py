@@ -494,17 +494,6 @@ class TransactionFrame:
         return not error_encountered
 
     # -- persistence (txhistory / txfeehistory) ----------------------------
-    def history_row(self, ledger_seq: int, tx_index: int, meta):
-        """Row tuple for the bulk txhistory insert at ledger close."""
-        return tx_history.transaction_row(
-            self.get_contents_hash(),
-            ledger_seq,
-            tx_index,
-            self.env_xdr(),
-            self.get_result_pair(),
-            meta,
-        )
-
     def fee_history_row(self, ledger_seq: int, tx_index: int, changes):
         return tx_history.fee_row(
             self.get_contents_hash(), ledger_seq, tx_index, changes

@@ -65,6 +65,40 @@ def transaction_row(
     )
 
 
+def transaction_rows(
+    ledger_seq: int, items: List[Tuple[int, bytes, bytes, bytes, bytes]]
+) -> List[Tuple]:
+    """[(tx_index, txid, envelope, result pair, meta)], the last four as
+    XDR bytes -> the rows ``transaction_row`` builds one at a time, for a
+    whole set in one call.
+
+    The close encodes a set's rows here once, after the apply loop (the
+    serial loop and each shard leg alike), instead of a hex, three
+    ``base64`` calls and three ``.decode()`` a transaction.  The native
+    `_applycore` leg does the batch in C; the pure-Python fallback keeps
+    the path alive where the toolchain can't build the extension.  Same
+    bytes either way (tests/test_applysched.py)."""
+    from ..native import load_applycore
+
+    mod = load_applycore()
+    blobs = [item[1:] for item in items]
+    if mod is not None:
+        enc = mod.encode_history_rows(blobs)
+    else:
+        enc = [
+            (
+                t.hex(),
+                base64.b64encode(b).decode(),
+                base64.b64encode(r).decode(),
+                base64.b64encode(m).decode(),
+            )
+            for t, b, r, m in blobs
+        ]
+    return [
+        (h, ledger_seq, item[0], b, r, m) for item, (h, b, r, m) in zip(items, enc)
+    ]
+
+
 def fee_row(tx_id: bytes, ledger_seq: int, tx_index: int, changes) -> Tuple:
     return (
         tx_id.hex(),

@@ -427,10 +427,12 @@ NEW_CLOSE_SPANS = {
 CLOSE_TXS = 130
 
 
-@pytest.fixture(scope="module", params=["parallel", "serial"])
+@pytest.fixture(scope="module", params=["parallel", "serial", "shipped"])
 def traced_closes(request):
-    """Two consecutive real-clock closes of 130 payments each (accounts in
-    groups of two), traced: -> the spans of each close."""
+    """Three consecutive real-clock closes of 130 payments each (accounts
+    in groups of two), traced: -> the spans of each close.  "shipped"
+    leaves PARALLEL_APPLY / APPLY_WORKERS at their defaults: sized to one
+    thread under the interpreter lock, the serial loop."""
     from stellar_tpu.ledger.accountframe import AccountFrame
     from stellar_tpu.main.application import Application
     from stellar_tpu.tx import testutils as T
@@ -438,8 +440,9 @@ def traced_closes(request):
 
     c = VirtualClock(REAL_TIME)
     cfg = T.get_test_config(175 if request.param == "parallel" else 176)
-    cfg.PARALLEL_APPLY = request.param == "parallel"
-    cfg.APPLY_WORKERS = 4  # a 1-core host would auto-size to the serial loop
+    if request.param != "shipped":
+        cfg.PARALLEL_APPLY = request.param == "parallel"
+        cfg.APPLY_WORKERS = 4  # auto sizes to the serial loop
     app = Application(c, cfg, new_db=True)
     try:
         lm = app.ledger_manager
@@ -463,8 +466,8 @@ def traced_closes(request):
             assert all(tx.get_result_code().name == "txSUCCESS" for tx in pay)
             closes.append((lm.last_closed.header.ledgerSeq, app.tracer.spans()))
         assert app.tracer.dropped == 0
-        mode = app.ledger_manager._apply_sched.last_close["mode"] if cfg.PARALLEL_APPLY else "serial"
-        assert mode == request.param
+        mode = app.ledger_manager._apply_sched.last_close["mode"]
+        assert mode == ("parallel" if request.param == "parallel" else "serial")
         yield request.param, closes
     finally:
         app.database.close()
@@ -528,6 +531,19 @@ class TestCloseFromInside:
             assert kids["close.apply"] == {"apply.serial", "apply.rows"}
             assert kids["apply.serial"] == {"tx.apply"}
         assert kids["tx.apply"] == {"tx.valid", "tx.ops"}
+
+    def test_the_serial_span_says_how_the_close_was_sized(self, traced_closes):
+        mode, closes = traced_closes
+        want = {
+            "parallel": None,
+            "serial": {"txs": CLOSE_TXS, "workers": 4, "reason": "parallel-apply-off"},
+            "shipped": {"txs": CLOSE_TXS, "workers": 1, "reason": "one-worker"},
+        }[mode]
+        for _seq, spans in closes:
+            serial = [s.attrs for s in spans if s.name == "apply.serial"]
+            assert serial == ([want] if want else [])
+            if want:
+                assert not [s for s in spans if s.name in ("apply.partition", "apply.shards", "apply.group", "apply.merge")]
 
     def test_every_span_of_a_close_carries_its_ledger(self, traced_closes):
         _mode, closes = traced_closes
