@@ -290,7 +290,11 @@ class CachingSigBackend(SigBackend):
         return self.inner.torsion_check(encs, caller=caller, vals=vals)
 
     def stats(self) -> dict:
-        return self.inner.stats()
+        s = self.inner.stats()
+        # verifications that bypassed every batch path: cache misses of the
+        # eager ``PubKeyUtils.verify_sig``, done by libsodium one at a time
+        s["eager_host_verifies"] = self.cache.eager_host_verifies
+        return s
 
 
 def _env_float(name: str, default: float) -> float:

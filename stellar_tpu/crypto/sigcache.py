@@ -20,6 +20,11 @@ class VerifySigCache:
         self._map: OrderedDict[bytes, bool] = OrderedDict()  # analysis: locked-by _lock
         self._hits = 0
         self._misses = 0
+        # monotonic twin of ``_misses`` (never flushed).  ``get`` serves the
+        # eager ``PubKeyUtils.verify_sig`` alone (the batch paths peek), and
+        # a miss there is verified by libsodium on the caller's thread: a
+        # signature no batch prefetch had latched
+        self.eager_host_verifies = 0
 
     @staticmethod
     def key_for(pubkey_raw: bytes, signature: bytes, msg: bytes) -> bytes:
@@ -37,6 +42,7 @@ class VerifySigCache:
                 self._hits += 1
                 return True, self._map[key]
             self._misses += 1
+            self.eager_host_verifies += 1
             return False, False
 
     def peek_many(self, keys) -> list:

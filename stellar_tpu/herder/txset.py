@@ -135,12 +135,29 @@ class TxSetFrame:
         prefetch — the eager check_signature path re-verifies anything the
         batch missed — so a memo gone stale against DB signer changes can
         only weaken the prefetch, never change a result.  Invalidated on
-        add_transaction/remove_tx."""
+        add_transaction/remove_tx.  A collection (not a memo hit) records
+        ``sig.collect``."""
         if self._triples_memo is None:
-            triples = []
-            for tx in self.transactions:
-                triples.extend(tx.candidate_signature_pairs(app.database))
-            self._triples_memo = triples
+            tracer = tracer_of(app)
+            with tracer.span("sig.collect", txs=len(self.transactions)) as sp:
+                triples = []
+                tally = {"accounts": 0}
+                db = app.database
+                for tx in self.transactions:
+                    triples.extend(tx.candidate_signature_pairs(db, tally))
+                self._triples_memo = triples
+                if sp is not None:
+                    # a set that hands over fewer triples than it carries
+                    # signatures leaves the rest to the eager verify
+                    # (``eager_host_verifies`` of the signature backend)
+                    tracer.end(
+                        sp,
+                        signatures=sum(
+                            len(tx.envelope.signatures) for tx in self.transactions
+                        ),
+                        triples=len(triples),
+                        accounts=tally["accounts"],
+                    )
         return self._triples_memo
 
     def _prewarm_signature_cache(self, app) -> None:

@@ -524,7 +524,10 @@ class AccountFrame(EntryFrame):
     )
 
     @classmethod
-    def upsert_batch(cls, db, entries) -> None:
+    def upsert_batch(cls, db, entries) -> dict:
+        """-> the rows written: ``account_rows`` upserted, ``signer_rows``
+        deleted plus inserted (every account's signer rows are rewritten
+        whether they changed or not); ``commit.flush`` reports both."""
         rows, aids, signer_rows = [], [], []
         for e in entries:
             a = e.data.value
@@ -537,13 +540,19 @@ class AccountFrame(EntryFrame):
             )
         with db.timed("flush", "account"):
             db.executemany(cls._UPSERT_SQL, rows)
-            db.executemany("DELETE FROM signers WHERE accountid=?", aids)
+            deleted = db.executemany(
+                "DELETE FROM signers WHERE accountid=?", aids
+            ).rowcount
             if signer_rows:
                 db.executemany(
                     "INSERT INTO signers (accountid, publickey, weight)"
                     " VALUES (?,?,?)",
                     signer_rows,
                 )
+        return {
+            "account_rows": len(rows),
+            "signer_rows": max(deleted, 0) + len(signer_rows),
+        }
 
     @classmethod
     def delete_batch(cls, db, keys) -> None:

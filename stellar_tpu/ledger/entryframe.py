@@ -264,7 +264,9 @@ class EntryFrame:
 
     # -- batched flush (EntryStoreBuffer) ----------------------------------
     @classmethod
-    def upsert_batch(cls, db, entries) -> None:
+    def upsert_batch(cls, db, entries) -> Optional[dict]:
+        """Write ``entries``; a class may return the rows it wrote by
+        name (``EntryStoreBuffer.flush`` sums them for ``commit.flush``)."""
         raise NotImplementedError
 
     @classmethod

@@ -520,8 +520,11 @@ class LedgerManager:
                 # closes attribute that cost here, not to no phase)
                 commit_sp = tracer.begin("close.commit")
                 if buf is not None:
-                    with tracer.span("commit.flush"), self._flush_timer.time_scope():
-                        buf.flush(self.database)
+                    flush_sp = tracer.begin("commit.flush")
+                    with self._flush_timer.time_scope():
+                        written = buf.flush(self.database)
+                    # account_rows, signer_rows: what the flush wrote
+                    tracer.end(flush_sp, **written)
             finally:
                 # success: overlay already flushed (deactivate clears
                 # nothing); exception: the enclosing SQL ROLLBACK drops the

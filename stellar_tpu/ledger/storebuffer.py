@@ -144,13 +144,17 @@ class EntryStoreBuffer:
                     self._offer_keys.add(kb)
 
     # -- flush -------------------------------------------------------------
-    def flush(self, db) -> None:
+    def flush(self, db) -> dict:
         """Write the net overlay as batched SQL and empty it.  Inside a
         savepoint (flush_through callers) the rows land in that savepoint —
         an enclosing rollback undoes them via SQL while the undo log
-        restores the overlay, keeping both planes consistent."""
+        restores the overlay, keeping both planes consistent.
+
+        -> the row counts the frame classes' ``upsert_batch`` report
+        (``AccountFrame``: ``account_rows``, ``signer_rows``), summed."""
+        written: Dict[str, int] = {}
         if not self._overlay:
-            return
+            return written
         # rows are about to land inside whatever scopes are open: give the
         # lazy (savepoint-less) buffered scopes real SQL savepoints first,
         # or an enclosing rollback could not undo these writes
@@ -170,10 +174,12 @@ class EntryStoreBuffer:
             if dels:
                 cls.delete_batch(db, dels)
             if ups:
-                cls.upsert_batch(db, ups)
+                for k, n in (cls.upsert_batch(db, ups) or {}).items():
+                    written[k] = written.get(k, 0) + n
         self._overlay.clear()
         self._offer_keys.clear()
         self.n_flushes += 1
+        return written
 
     flush_through = flush
 

@@ -227,10 +227,12 @@ class TransactionFrame:
                 return False
         return True
 
-    def candidate_signature_pairs(self, db):
+    def candidate_signature_pairs(self, db, tally: Optional[dict] = None):
         """All hint-matched (pubkey, contents_hash, sig) triples this tx could
         verify — the batch-prefetch set for the SigBackend (covers the tx
-        source and every op source account's signers)."""
+        source and every op source account's signers).  ``tally``, where
+        given, has its ``accounts`` raised by every account loaded here
+        (``sig.collect`` reports the set's total)."""
         triples = []
         seen_accounts = set()
         accounts = [self.get_source_id()]
@@ -243,6 +245,8 @@ class TransactionFrame:
                 continue
             seen_accounts.add(aid.value)
             af = AccountFrame.load_account(aid, db, readonly=True)
+            if tally is not None:
+                tally["accounts"] += 1
             if af is None:
                 continue
             keys = []
@@ -434,8 +438,21 @@ class TransactionFrame:
         if meta is None:
             meta = TransactionMeta(0, [])
         self.reset_signature_tracker()
-        with tracer.span("tx.valid"):
+        with tracer.span("tx.valid") as valid_sp:
             valid = self.common_valid(app, True, 0)
+            if valid_sp is not None:
+                # which way check_signature went and over how many keys:
+                # its fast path is one signature on an account with the
+                # master key alone (sigs 1, keys 1)
+                acc = self.signing_account
+                tracer.end(
+                    valid_sp,
+                    sigs=len(self.envelope.signatures),
+                    keys=0 if acc is None else (
+                        (1 if acc.account.thresholds[0] else 0)
+                        + len(acc.account.signers)
+                    ),
+                )
         if not valid:
             return False
 

@@ -48,7 +48,10 @@ def test_info_metrics_scp(app):
     info = ch.handle_info({})["info"]
     assert info["ledger"]["num"] == 1
     assert info["network"] == app.config.NETWORK_PASSPHRASE
-    assert info["sig_backend"] == {"backend": "cpu"}
+    # the backend's name and the process-wide count of eager verifies
+    sb = info["sig_backend"]
+    assert set(sb) == {"backend", "eager_host_verifies"} and sb["backend"] == "cpu"
+    assert sb["eager_host_verifies"] >= 0
     # the apply scheduler's block beside the verifier's: no close yet
     assert info["apply"] == {
         "workers": 1, "closes_parallel": 0, "closes_serial": 0, "reason": None,
