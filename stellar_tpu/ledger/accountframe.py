@@ -459,6 +459,37 @@ class AccountFrame(EntryFrame):
             _aid(a.accountID),
         )
 
+    @staticmethod
+    def signers_differ(prev: Optional[LedgerEntry], new: LedgerEntry) -> bool:
+        """Whether the rows of ``signers`` must be written when ``new`` is
+        stored over ``prev``, the snapshot stored before it — the one
+        decision both write modes take (``_persist``; ``_record`` for the
+        store buffer's slot, which ``upsert_batch`` obeys).  No snapshot
+        at hand (an account created or applied from a bucket, a line
+        evicted or erased by a rollback) means write: only a list known to
+        be what SQL holds, keys and weights in ``_normalize``'s order, is
+        left alone."""
+        if prev is None:
+            return True
+        if prev is new:
+            # a sealed frame stored again without a mutation in between
+            return False
+        was, now = prev.data.value.signers, new.data.value.signers
+        if len(was) != len(now):
+            return True
+        for w, n in zip(was, now):
+            if w.weight != n.weight or w.pubKey.value != n.pubKey.value:
+                return True
+        return False
+
+    _SIGNER_INSERT_SQL = (
+        "INSERT INTO signers (accountid, publickey, weight) VALUES (?,?,?)"
+    )
+
+    @staticmethod
+    def _signer_rows(aid: str, a):
+        return [(aid, _aid(s.pubKey), s.weight) for s in a.signers]
+
     def _persist(self, db, insert: bool) -> None:
         a = self.account
         params = self._sql_row(a, self.last_modified)
@@ -479,15 +510,19 @@ class AccountFrame(EntryFrame):
                        lastmodified=? WHERE accountid=?""",
                     params,
                 )
-        # replace signer rows wholesale (simpler than the reference's diffing,
-        # same observable state)
-        aid = _aid(a.accountID)
-        db.execute("DELETE FROM signers WHERE accountid=?", (aid,))
-        if a.signers:
-            db.executemany(
-                "INSERT INTO signers (accountid, publickey, weight) VALUES (?,?,?)",
-                [(aid, _aid(s.pubKey), s.weight) for s in a.signers],
-            )
+        # write-through has no overlay slot to carry a mark: the entry
+        # cache still holds the snapshot this store replaces (_record runs
+        # after this), so ask the same question of it here.  An insert
+        # and a store with no line at hand rewrite the rows wholesale
+        # (simpler than the reference's diffing, same observable state).
+        prev = None if insert else self.cache_of(db).stored(
+            key_bytes(self.get_key())
+        )
+        if self.signers_differ(prev, self.entry):
+            aid = params[-1]
+            db.execute("DELETE FROM signers WHERE accountid=?", (aid,))
+            if a.signers:
+                db.executemany(self._SIGNER_INSERT_SQL, self._signer_rows(aid, a))
 
     def store_delete(self, delta, db) -> None:
         self._assert_mutable()
@@ -524,34 +559,34 @@ class AccountFrame(EntryFrame):
     )
 
     @classmethod
-    def upsert_batch(cls, db, entries) -> dict:
-        """-> the rows written: ``account_rows`` upserted, ``signer_rows``
-        deleted plus inserted (every account's signer rows are rewritten
-        whether they changed or not); ``commit.flush`` reports both."""
+    def upsert_batch(cls, db, entries, signers_dirty) -> dict:
+        """-> the rows written: ``account_rows`` upserted; ``signer_rows``
+        deleted plus inserted, for the ``signer_accounts`` whose mark in
+        ``signers_dirty`` is set (``signers_differ``, taken at each store)
+        and for no other: an account whose signers the close left as they
+        were gets no statement against ``signers``.  ``commit.flush``
+        reports all three, zeros included."""
         rows, aids, signer_rows = [], [], []
-        for e in entries:
+        for e, dirty in zip(entries, signers_dirty):
             a = e.data.value
             row = cls._sql_row(a, e.lastModifiedLedgerSeq)
-            aid = row[-1]
-            aids.append((aid,))
             rows.append(row)
-            signer_rows.extend(
-                (aid, _aid(s.pubKey), s.weight) for s in a.signers
-            )
+            if dirty:
+                aids.append((row[-1],))
+                signer_rows.extend(cls._signer_rows(row[-1], a))
+        deleted = 0
         with db.timed("flush", "account"):
             db.executemany(cls._UPSERT_SQL, rows)
-            deleted = db.executemany(
-                "DELETE FROM signers WHERE accountid=?", aids
-            ).rowcount
+            if aids:
+                deleted = db.executemany(
+                    "DELETE FROM signers WHERE accountid=?", aids
+                ).rowcount
             if signer_rows:
-                db.executemany(
-                    "INSERT INTO signers (accountid, publickey, weight)"
-                    " VALUES (?,?,?)",
-                    signer_rows,
-                )
+                db.executemany(cls._SIGNER_INSERT_SQL, signer_rows)
         return {
             "account_rows": len(rows),
             "signer_rows": max(deleted, 0) + len(signer_rows),
+            "signer_accounts": len(aids),
         }
 
     @classmethod

@@ -123,6 +123,12 @@ class ShardEntryCache:
         self._check(kb)
         return kb in self._local or self._main.contains(kb)
 
+    def stored(self, kb: bytes):
+        self._check(kb)
+        if kb in self._local:
+            return self._local[kb]
+        return self._main.stored(kb)
+
     def erase(self, kb: bytes) -> None:
         # delta.rollback erases lines for every key the aborted scope
         # touched.  Dropping the LOCAL line is exactly right: the shard
@@ -152,10 +158,10 @@ class ShardStoreBuffer(EntryStoreBuffer):
         self._allowed = allowed
         self.active = True
 
-    def record(self, kb, key, entry, cls) -> None:
+    def record(self, kb, key, entry, cls, signers_dirty=False) -> None:
         if kb not in self._allowed:
             raise FootprintEscape(f"store outside shard footprint: {kb[:8].hex()}")
-        super().record(kb, key, entry, cls)
+        super().record(kb, key, entry, cls, signers_dirty)
 
     def get(self, kb: bytes):
         if kb not in self._allowed:
@@ -586,7 +592,9 @@ class ApplyScheduler:
                         # merged cache line, exactly like a cold close
                         main_fctx.evict(kb)
                 for kb, slot in sv._store_buffer._overlay.items():
-                    main_buf.record(kb, slot[0], slot[1], slot[2])
+                    # a mark the fee pass left on the main slot outlives
+                    # the shard's (EntryStoreBuffer.record: once set, set)
+                    main_buf.record(kb, *slot)
                     if kb not in local:
                         # a later transaction of the shard failed and its
                         # rollback erased the shard's line for an account an

@@ -70,6 +70,12 @@ class EntryCache:
         order (used by bulk prewarm to split warm/cold)."""
         return key in self._map
 
+    def stored(self, key: bytes) -> Optional[LedgerEntry]:
+        """The line's SHARED entry, or None where there is no line or a
+        known-absent one — counters and LRU order untouched.  A store
+        asks this for the snapshot it is about to replace."""
+        return self._map.get(key)
+
     def erase(self, key: bytes):
         self._map.pop(key, None)
 
@@ -264,9 +270,11 @@ class EntryFrame:
 
     # -- batched flush (EntryStoreBuffer) ----------------------------------
     @classmethod
-    def upsert_batch(cls, db, entries) -> Optional[dict]:
-        """Write ``entries``; a class may return the rows it wrote by
-        name (``EntryStoreBuffer.flush`` sums them for ``commit.flush``)."""
+    def upsert_batch(cls, db, entries, signers_dirty) -> Optional[dict]:
+        """Write ``entries``; ``signers_dirty`` holds each one's mark
+        (``signers_differ``), in step.  A class may return the rows it
+        wrote by name (``EntryStoreBuffer.flush`` sums them for
+        ``commit.flush``)."""
         raise NotImplementedError
 
     @classmethod
@@ -274,6 +282,13 @@ class EntryFrame:
         raise NotImplementedError
 
     # -- shared plumbing ---------------------------------------------------
+    @staticmethod
+    def signers_differ(prev: Optional[LedgerEntry], new: LedgerEntry) -> bool:
+        """Whether storing ``new`` over the snapshot stored before it
+        (None: none at hand) has rows to write outside the entry's own
+        table.  Only an account has such rows (AccountFrame)."""
+        return False
+
     def _stamp(self, delta) -> None:
         if delta.update_last_modified:
             self.last_modified = delta.header_ro().ledgerSeq
@@ -301,10 +316,15 @@ class EntryFrame:
         else:
             delta.mod_entry_snapshot(key, snap)
         kb = key_bytes(key)
-        entry_cache_of(db).put_owned(kb, snap)
+        cache = entry_cache_of(db)
+        # the line still holds the snapshot stored before this one: the
+        # one place every store passes that can say what SQL (or the
+        # overlay slot) has of this entry's signers
+        prev = cache.stored(kb)
+        cache.put_owned(kb, snap)
         buf = active_buffer(db)
         if buf is not None:
-            buf.record(kb, key, snap, type(self))
+            buf.record(kb, key, snap, type(self), self.signers_differ(prev, snap))
         if self.entry_type == LedgerEntryType.ACCOUNT:
             # the storing frame becomes the close's canonical working
             # frame for this account (identity convergence: a frame built

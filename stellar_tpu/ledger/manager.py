@@ -523,7 +523,8 @@ class LedgerManager:
                     flush_sp = tracer.begin("commit.flush")
                     with self._flush_timer.time_scope():
                         written = buf.flush(self.database)
-                    # account_rows, signer_rows: what the flush wrote
+                    # account_rows, signer_rows, signer_accounts: what the
+                    # flush wrote (signer rows only where a store changed them)
                     tracer.end(flush_sp, **written)
             finally:
                 # success: overlay already flushed (deactivate clears

@@ -298,7 +298,7 @@ class OfferFrame(EntryFrame):
 
     # -- store-buffer flush (ledger/storebuffer.py) ------------------------
     @classmethod
-    def upsert_batch(cls, db, entries) -> None:
+    def upsert_batch(cls, db, entries, _signers_dirty) -> None:
         rows = [
             cls._sql_row(e.data.value, e.lastModifiedLedgerSeq)
             for e in entries

@@ -26,6 +26,13 @@ This buffer makes the stores write-back instead of write-through during
   ``executemany`` batches (INSERT OR REPLACE + DELETE per entity), and
   PARANOID_MODE's delta-vs-database audit runs *after* the flush — the
   same safety net that guarded the write-through path guards this one.
+- a slot holds the key, the pending entry (None: a pending delete), the
+  frame class that writes it, and for an account whether its rows of
+  ``signers`` must be written with it: ``EntryFrame._record`` sets that
+  where the store changed the signer list, or could not tell
+  (``AccountFrame.signers_differ``); here it only stays set for the rest
+  of the close and rides the undo log with its slot.  The flush rewrites
+  the signer rows of the marked accounts and of no other.
 
 Aggregate queries that cannot read through an overlay (the inflation
 winners tally, ``AccountFrame.process_for_inflation``) call
@@ -43,8 +50,9 @@ from ..xdr.ledger import LedgerKey
 
 _ABSENT = object()
 
-# overlay value: (LedgerKey, entry-or-None (None = pending delete), frame cls)
-_Slot = Tuple[LedgerKey, Optional[LedgerEntry], type]
+# overlay value: (LedgerKey, entry-or-None (None = pending delete), frame cls,
+# signers_dirty: the account's signer rows must be written at the flush)
+_Slot = Tuple[LedgerKey, Optional[LedgerEntry], type, bool]
 
 
 class EntryStoreBuffer:
@@ -79,8 +87,13 @@ class EntryStoreBuffer:
 
     # -- store side (EntryFrame) -------------------------------------------
     def record(self, kb: bytes, key: LedgerKey, entry: Optional[LedgerEntry],
-               cls: type) -> None:
+               cls: type, signers_dirty: bool = False) -> None:
         """Pending upsert (entry) or delete (entry=None) of `key`.
+
+        `signers_dirty`: this store changed the account's signer list (or
+        nothing at hand says it did not).  A slot that was marked stays
+        marked whatever later stores of the close say: the rows in SQL are
+        still the ones from before the first of them.
 
         `entry` is the ONE shared immutable snapshot of the store
         (EntryFrame._record) — under seal-on-store it is the storing
@@ -89,9 +102,12 @@ class EntryStoreBuffer:
         it out under the copy-before-mutate contract below, and the undo
         log restores previous snapshot objects verbatim on rollback —
         eviction/restoration of slots, never mutation of entries."""
+        prev = self._overlay.get(kb, _ABSENT)
         if self._marks:
-            self._undo.append((kb, self._overlay.get(kb, _ABSENT)))
-        self._overlay[kb] = (key, entry, cls)
+            self._undo.append((kb, prev))
+        if prev is not _ABSENT and prev[3]:
+            signers_dirty = True
+        self._overlay[kb] = (key, entry, cls, signers_dirty)
         if key.type == LedgerEntryType.OFFER:
             self._offer_keys.add(kb)
         self.n_buffered_writes += 1
@@ -113,7 +129,7 @@ class EntryStoreBuffer:
         upserts = []
         touched = set()
         for kb in self._offer_keys:
-            key, entry, _cls = self._overlay[kb]
+            key, entry = self._overlay[kb][:2]
             touched.add(key.value.offerID)
             if entry is not None:
                 upserts.append(entry)
@@ -151,7 +167,8 @@ class EntryStoreBuffer:
         restores the overlay, keeping both planes consistent.
 
         -> the row counts the frame classes' ``upsert_batch`` report
-        (``AccountFrame``: ``account_rows``, ``signer_rows``), summed."""
+        (``AccountFrame``: ``account_rows``, ``signer_rows``,
+        ``signer_accounts``), summed."""
         written: Dict[str, int] = {}
         if not self._overlay:
             return written
@@ -163,18 +180,19 @@ class EntryStoreBuffer:
         if self._marks:
             for kb, slot in self._overlay.items():
                 self._undo.append((kb, slot))
-        by_cls: Dict[type, Tuple[list, list]] = {}
-        for key, entry, cls in self._overlay.values():
-            ups, dels = by_cls.setdefault(cls, ([], []))
+        by_cls: Dict[type, Tuple[list, list, list]] = {}
+        for key, entry, cls, signers_dirty in self._overlay.values():
+            ups, dirty, dels = by_cls.setdefault(cls, ([], [], []))
             if entry is None:
                 dels.append(key)
             else:
                 ups.append(entry)
-        for cls, (ups, dels) in by_cls.items():
+                dirty.append(signers_dirty)
+        for cls, (ups, dirty, dels) in by_cls.items():
             if dels:
                 cls.delete_batch(db, dels)
             if ups:
-                for k, n in (cls.upsert_batch(db, ups) or {}).items():
+                for k, n in (cls.upsert_batch(db, ups, dirty) or {}).items():
                     written[k] = written.get(k, 0) + n
         self._overlay.clear()
         self._offer_keys.clear()

@@ -444,3 +444,58 @@ def test_a_failed_tx_after_its_partners_payment_merges_bit_exact():
     (mode_a, hash_a, sql_a), (mode_b, hash_b, sql_b) = out
     assert (mode_a, mode_b) == ("serial", "parallel")
     assert hash_a == hash_b and sql_a == sql_b
+
+
+def test_signer_rows_written_only_where_changed_merge_bit_exact():
+    """The shard planes carry the store buffer's signer mark: a SET_OPTIONS
+    in a shard marks its slot, a later payment's store of the same account
+    there or a fee charged on the main slot does not clear it, and payments
+    among accounts with signers write no signer row on either plane.  Four
+    workers against the serial loop, PARANOID: same hashes, same SQL."""
+    from stellar_tpu.ledger.applysched import apply_scheduler_of
+
+    def signer(i, weight):
+        return T.set_options_op(
+            signer=X.Signer(T.get_account("sg-signer-%d" % i).get_public_key(), weight)
+        )
+
+    out = []
+    for instance, workers in ((178, 0), (179, 4)):
+        app, clock = _node(instance, workers)
+        app.config.PARANOID_MODE = True
+        try:
+            keys = [T.get_account("sg-%d" % i) for i in range(12)]
+            first = _funded(app, keys)
+            flushes = []
+
+            def close(txs):
+                app.tracer.clear()
+                _close(app, txs)
+                assert [tx.get_result_code().name for tx in txs] == ["txSUCCESS"] * len(txs)
+                spans, _, _ = app.tracer.snapshot()
+                (flush,) = [s.attrs for s in spans if s.name == "commit.flush"]
+                flushes.append((flush["signer_accounts"], flush["signer_rows"]))
+
+            close([T.tx_from_ops(app, k, first + 1, [signer(i, 1), signer(i + 100, 1)]) for i, k in enumerate(keys)])
+            close(_pair_payments(app, keys, first + 1))
+            # the even accounts change a weight, then pay their partner; the
+            # odd ones pay theirs
+            close(
+                [T.tx_from_ops(app, k, first + 3, [signer(i, 3)]) for i, k in enumerate(keys) if not i & 1]
+                + [T.tx_from_ops(app, k, first + 4, [T.payment_op(keys[i ^ 1], 7)]) for i, k in enumerate(keys) if not i & 1]
+                + [T.tx_from_ops(app, k, first + 3, [T.payment_op(keys[i ^ 1], 9)]) for i, k in enumerate(keys) if i & 1]
+            )
+            assert flushes == [(12, 24), (0, 0), (6, 24)]
+            assert app.invariants.total_violations == 0, app.invariants.dump_info()
+            out.append((
+                apply_scheduler_of(app.ledger_manager).last_close["mode"],
+                app.ledger_manager.last_closed.hash,
+                T.dump_state(app.database),
+            ))
+        finally:
+            app.graceful_stop()
+            clock.shutdown()
+    (mode_a, hash_a, sql_a), (mode_b, hash_b, sql_b) = out
+    assert (mode_a, mode_b) == ("serial", "parallel")
+    assert hash_a == hash_b and sql_a == sql_b
+    assert len(sql_a["signers"]) == 24

@@ -127,7 +127,11 @@ class World:
                 )
             ]
             install.append(T.tx_from_ops(app, k, self.next_seq(i), ops).envelope.to_xdr())
+        app.tracer.clear()
         self.feed(install)
+        spans, _, _ = app.tracer.snapshot(clear=True)
+        # what the close that installs the signers wrote (the span test)
+        (self.install_flush,) = [s.attrs for s in spans if s.name == "commit.flush"]
         self.rounds = 0
 
     def next_seq(self, i: int) -> int:
@@ -268,9 +272,13 @@ def test_the_prefetch_is_complete_and_the_spans_say_what_the_set_implies(world):
     (valid,) = by_name["tx.valid"]  # one transaction in 64: index 0
     assert valid.attrs == {"sigs": SIGN, "keys": PER}
     (flush,) = by_name["commit.flush"]
-    # 32 accounts touched, each one's five signer rows deleted and written
-    # again though no signer changed
-    assert flush.attrs == {"account_rows": 2 * WIDTH, "signer_rows": 2 * WIDTH * 2 * PER}
+    # 32 accounts touched and no signer of theirs changed: no row of
+    # ``signers`` written, and the span says 0 rather than leave the key out
+    assert flush.attrs == {"account_rows": 2 * WIDTH, "signer_rows": 0, "signer_accounts": 0}
+    # the close that installed them: five rows inserted an account, none
+    # there to delete
+    n = len(world.keys)
+    assert world.install_flush == {"account_rows": n, "signer_rows": n * PER, "signer_accounts": n}
 
 
 @pytest.fixture(scope="module")

@@ -9,10 +9,16 @@ every entry type, through rollbacks, crossings, deletes, and aggregate
 reads.
 """
 
+import sqlite3
+
 import pytest
 
 import stellar_tpu.xdr as X
-from stellar_tpu.crypto import SecretKey
+from stellar_tpu.crypto import SecretKey, strkey
+from stellar_tpu.ledger.accountframe import AccountFrame
+from stellar_tpu.ledger.delta import LedgerDelta
+from stellar_tpu.ledger.entryframe import key_bytes, store_add_or_change
+from stellar_tpu.ledger.storebuffer import store_buffer_of
 from stellar_tpu.main.application import Application
 from stellar_tpu.tx import testutils as T
 from stellar_tpu.util import VIRTUAL_TIME, VirtualClock
@@ -43,12 +49,19 @@ class _ScenarioRunner:
     """Drive the same close sequence through two apps (buffer on / off) and
     compare ledger hashes + raw SQL state after every close."""
 
-    def __init__(self, clock, instance_base):
+    def __init__(self, clock, instance_base, cow=True, tmp=None):
+        """`tmp`: keep each node's database in a file there, for a reader
+        that is not the program (`db_paths`)."""
         self.apps = []
+        self.db_paths = []
         for i, buffered in enumerate((True, False)):
             cfg = T.get_test_config(instance_base + i)
             cfg.ENTRY_WRITE_BUFFER = buffered
+            cfg.COW_ENTRY_SNAPSHOTS = cow
             cfg.PARANOID_MODE = True  # audit every close on both sides
+            if tmp is not None:
+                self.db_paths.append(str(tmp / f"node{i}.db"))
+                cfg.DATABASE = f"sqlite3://{self.db_paths[-1]}"
             self.apps.append(Application(clock, cfg, new_db=True))
 
     def close(self, build_txs):
@@ -194,6 +207,341 @@ def test_differential_signers_delete_and_inflation(runner):
     assert codes[0] == RC.txSUCCESS
 
 
+# -- signer rows are written only where a store changed them ---------------
+#
+# One world per case and CoW mode: a buffered and a write-through node under
+# PARANOID_MODE and every invariant (get_test_config), three accounts of which
+# `a` and `b` hold two signers each.  `_SignerWorld.close` compares result
+# codes, ledger hashes and SQL dumps of the two nodes (`_ScenarioRunner`),
+# then holds each node's `signers` table, read by sqlite3 alone, against the
+# signer lists of the entries the program holds.
+
+_SIGNER_ACCOUNTS = ("a", "b", "c")
+
+
+def _sk(name):
+    return T.get_account("wbuf-signers-" + name)
+
+
+def _strkey(sk_or_pk):
+    pk = sk_or_pk.get_public_key() if isinstance(sk_or_pk, SecretKey) else sk_or_pk
+    return strkey.to_account_strkey(pk.value)
+
+
+class _SignerWorld(_ScenarioRunner):
+    def __init__(self, clock, tmp, cow):
+        super().__init__(clock, 62, cow=cow, tmp=tmp)
+        self.accounts = {n: _sk(n) for n in _SIGNER_ACCOUNTS}
+        self.statements = []  # of the close or apply under way, per node
+        for app, seen in zip(self.apps, ([], [])):
+            self.statements.append(seen)
+            app.database._conn.set_trace_callback(
+                lambda sql, seen=seen: seen.append(sql)
+            )
+        a, b, c = (self.accounts[n] for n in _SIGNER_ACCOUNTS)
+        self.close(lambda app, root: [
+            T.tx_from_ops(app, root, _seq(app, root), [
+                T.create_account_op(k, 10**12) for k in (a, b, c)
+            ]),
+        ])
+        installed = self.close(lambda app, root: [
+            T.tx_from_ops(app, k, _seq(app, k), [
+                T.set_options_op(signer=X.Signer(_sk(s).get_public_key(), 1))
+                for s in ("s1", "s2")
+            ])
+            for k in (a, b)
+        ])
+        assert installed.flush == {
+            "account_rows": 2, "signer_rows": 4, "signer_accounts": 2,
+        }
+
+    def signer_statements(self, node):
+        """What the node ran against `signers` since the last close or
+        apply began, reads left out (the PARANOID audit reloads from SQL)."""
+        return [
+            sql for sql in self.statements[node]
+            if "signers" in sql and not sql.lstrip().upper().startswith("SELECT")
+        ]
+
+    def _begin(self):
+        for app, seen in zip(self.apps, self.statements):
+            seen.clear()
+            app.tracer.clear()
+
+    def close(self, build_txs):
+        self._begin()
+        codes = super().close(build_txs)
+        return self._outcome(codes)
+
+    def apply_direct(self, store):
+        """`store(app, delta, db)` outside a close, as a bucket apply or a
+        test does: on the first node with the store buffer switched on and
+        flushed by hand, on the second written through."""
+        self._begin()
+        flush = None
+        for app in self.apps:
+            db = app.database
+            with db.transaction():
+                buf = store_buffer_of(db) if app.config.ENTRY_WRITE_BUFFER else None
+                if buf is not None:
+                    buf.activate()
+                try:
+                    delta = LedgerDelta(app.ledger_manager.current.header, db)
+                    store(app, delta, db)
+                    delta.commit()
+                    if buf is not None:
+                        flush = buf.flush(db)
+                finally:
+                    if buf is not None:
+                        buf.deactivate()
+        assert _dump_entry_tables(self.apps[0].database) == _dump_entry_tables(
+            self.apps[1].database
+        ), "SQL entry state diverged"
+        return self._outcome(None, flush)
+
+    def _outcome(self, codes, flush=None):
+        if flush is None:
+            spans, _, _ = self.apps[0].tracer.snapshot()
+            (flush,) = [s.attrs for s in spans if s.name == "commit.flush"]
+        for app, path in zip(self.apps, self.db_paths):
+            assert _signers_by_sqlite3(path) == self.signers_held(app)
+        return _Outcome(codes, flush)
+
+    def signers_held(self, app):
+        """{account: {signer: weight}} of the entries as the program holds
+        them (the entry cache's line: the snapshot last stored)."""
+        held = {}
+        for (aid,) in app.database.query_all("SELECT accountid FROM accounts"):
+            pk = X.PublicKey.from_ed25519(strkey.from_account_strkey(aid))
+            entry = AccountFrame.load_account(pk, app.database, readonly=True)
+            if entry.account.signers:
+                held[aid] = {_strkey(s.pubKey): s.weight for s in entry.account.signers}
+        return held
+
+
+class _Outcome:
+    def __init__(self, codes, flush):
+        self.codes, self.flush = codes, flush
+
+
+def _signers_by_sqlite3(path):
+    con = sqlite3.connect(f"file:{path}?mode=ro", uri=True)
+    try:
+        rows = con.execute("SELECT accountid, publickey, weight FROM signers").fetchall()
+    finally:
+        con.close()
+    table = {}
+    for aid, pk, weight in rows:
+        table.setdefault(aid, {})[pk] = weight
+    return table
+
+
+def _set_signer(app, k, name, weight):
+    return T.tx_from_ops(app, k, _seq(app, k), [
+        T.set_options_op(signer=X.Signer(_sk(name).get_public_key(), weight)),
+    ])
+
+
+def _only_of(w, *names):
+    """Every statement either node ran against `signers` names one of
+    these accounts, and each of them is named."""
+    aids = {_strkey(w.accounts[n]) for n in names}
+    for node in (0, 1):
+        ran = w.signer_statements(node)
+        assert all(any(aid in sql for aid in aids) for sql in ran), ran
+        assert all(any(aid in sql for sql in ran) for aid in aids), ran
+
+
+def _case_payments(w, a, b, c):
+    out = w.close(lambda app, root: [
+        T.tx_from_ops(app, a, _seq(app, a), [T.payment_op(b, 10**7)]),
+        T.tx_from_ops(app, b, _seq(app, b), [T.payment_op(c, 10**6)]),
+    ])
+    assert out.codes == [RC.txSUCCESS, RC.txSUCCESS]
+    assert out.flush == {"account_rows": 3, "signer_rows": 0, "signer_accounts": 0}
+    _only_of(w)
+
+
+def _case_add(w, a, b, c):
+    out = w.close(lambda app, root: [
+        _set_signer(app, a, "s3", 1),
+        T.tx_from_ops(app, b, _seq(app, b), [T.payment_op(c, 10**6)]),
+    ])
+    assert out.codes == [RC.txSUCCESS, RC.txSUCCESS]
+    # two rows deleted, three inserted; b's two rows are left alone
+    assert out.flush == {"account_rows": 3, "signer_rows": 5, "signer_accounts": 1}
+    _only_of(w, "a")
+
+
+def _case_remove(w, a, b, c):
+    out = w.close(lambda app, root: [_set_signer(app, a, "s1", 0)])
+    assert out.codes == [RC.txSUCCESS]
+    assert out.flush == {"account_rows": 1, "signer_rows": 3, "signer_accounts": 1}
+    assert list(_signers_by_sqlite3(w.db_paths[0])[_strkey(a)]) == [_strkey(_sk("s2"))]
+
+
+def _case_reweigh(w, a, b, c):
+    # the payment's store, after the SET_OPTIONS', finds the list as stored:
+    # the slot's mark has to outlive it
+    out = w.close(lambda app, root: [
+        _set_signer(app, a, "s1", 5),
+        T.tx_from_ops(app, a, _seq(app, a) + 1, [T.payment_op(c, 10**6)]),
+    ])
+    assert out.codes == [RC.txSUCCESS, RC.txSUCCESS]
+    assert out.flush == {"account_rows": 2, "signer_rows": 4, "signer_accounts": 1}
+    assert _signers_by_sqlite3(w.db_paths[0])[_strkey(a)][_strkey(_sk("s1"))] == 5
+
+
+def _case_change_and_back(w, a, b, c):
+    def txs(app, root):
+        seq = _seq(app, a)
+        return [
+            T.tx_from_ops(app, a, seq, [
+                T.set_options_op(signer=X.Signer(_sk("s1").get_public_key(), 5)),
+            ]),
+            T.tx_from_ops(app, a, seq + 1, [
+                T.set_options_op(signer=X.Signer(_sk("s1").get_public_key(), 1)),
+            ]),
+            T.tx_from_ops(app, a, seq + 2, [T.payment_op(c, 10**6)]),
+        ]
+
+    before = _signers_by_sqlite3(w.db_paths[0])
+    out = w.close(txs)
+    assert out.codes == [RC.txSUCCESS] * 3
+    # once marked, marked for the close: the rows are written though the
+    # list is the stored one again
+    assert out.flush == {"account_rows": 2, "signer_rows": 4, "signer_accounts": 1}
+    assert _signers_by_sqlite3(w.db_paths[0]) == before
+
+
+def _case_rolled_back(w, a, b, c):
+    def txs(app, root):
+        seq = _seq(app, a)
+        return [
+            T.tx_from_ops(app, a, seq, [
+                T.set_options_op(signer=X.Signer(_sk("s3").get_public_key(), 1)),
+                T.payment_op(b, 10**15),  # underfunded: the SET_OPTIONS unwinds
+            ]),
+            T.tx_from_ops(app, a, seq + 1, [T.payment_op(b, 10**6)]),
+        ]
+
+    before = _signers_by_sqlite3(w.db_paths[0])
+    out = w.close(txs)
+    assert out.codes == [RC.txFAILED, RC.txSUCCESS]
+    assert _signers_by_sqlite3(w.db_paths[0]) == before
+    # the rollback erased a's line of the entry cache, so the payment's
+    # store has no stored snapshot at hand: written, unchanged
+    assert out.flush == {"account_rows": 2, "signer_rows": 4, "signer_accounts": 1}
+    _only_of(w, "a")
+
+
+def _new_account(sk, signers, balance=10**10):
+    frame = AccountFrame(account_id=sk.get_public_key())
+    frame.account.balance = balance
+    frame.account.signers = [
+        X.Signer(_sk(s).get_public_key(), weight) for s, weight in signers
+    ]
+    frame.account.numSubEntries = len(signers)
+    return frame.entry
+
+
+def _case_add_or_change(w, a, b, c):
+    d = _sk("d")
+    w.accounts["d"] = d
+    out = w.apply_direct(lambda app, delta, db: store_add_or_change(
+        _new_account(d, [("s1", 1), ("s2", 2)]), delta, db
+    ))
+    assert out.flush == {"account_rows": 1, "signer_rows": 2, "signer_accounts": 1}
+    _only_of(w, "d")
+    assert len(_signers_by_sqlite3(w.db_paths[0])[_strkey(d)]) == 2
+    # the same signers under another balance: the row of accounts alone
+    out = w.apply_direct(lambda app, delta, db: store_add_or_change(
+        _new_account(d, [("s1", 1), ("s2", 2)], balance=10**9), delta, db
+    ))
+    assert out.flush == {"account_rows": 1, "signer_rows": 0, "signer_accounts": 0}
+    _only_of(w)
+    out = w.apply_direct(lambda app, delta, db: store_add_or_change(
+        _new_account(d, [("s2", 2)]), delta, db
+    ))
+    assert out.flush == {"account_rows": 1, "signer_rows": 3, "signer_accounts": 1}
+    _only_of(w, "d")
+
+
+def _case_merged_away(w, a, b, c):
+    out = w.close(lambda app, root: [
+        T.tx_from_ops(app, b, _seq(app, b), [T.merge_op(a)]),
+    ])
+    assert out.codes == [RC.txSUCCESS]
+    # a is credited and keeps its rows; b's go with its account
+    assert out.flush == {"account_rows": 1, "signer_rows": 0, "signer_accounts": 0}
+    _only_of(w, "b")
+    assert _strkey(b) not in _signers_by_sqlite3(w.db_paths[0])
+
+
+def _case_no_snapshot(w, a, b, c):
+    def store(app, delta, db):
+        frame = AccountFrame.load_account(a.get_public_key(), db)
+        db._entry_cache.erase(key_bytes(frame.get_key()))
+        frame.add_balance(-1)
+        frame.store_change(delta, db)
+
+    out = w.apply_direct(store)
+    # nothing says what SQL holds of a's signers: written as they are
+    assert out.flush == {"account_rows": 1, "signer_rows": 4, "signer_accounts": 1}
+    _only_of(w, "a")
+    # a close on a cold cache warms it from SQL before the first store
+    for app in w.apps:
+        app.database._entry_cache.clear()
+    out = w.close(lambda app, root: [
+        T.tx_from_ops(app, a, _seq(app, a), [T.payment_op(b, 10**6)]),
+    ])
+    assert out.flush == {"account_rows": 2, "signer_rows": 0, "signer_accounts": 0}
+    _only_of(w)
+
+
+def _case_closes_in_a_row(w, a, b, c):
+    def pay(app, root):
+        return [
+            T.tx_from_ops(app, a, _seq(app, a), [T.payment_op(b, 10**6)]),
+            T.tx_from_ops(app, b, _seq(app, b), [T.payment_op(a, 10**5)]),
+        ]
+
+    out = w.close(lambda app, root: [_set_signer(app, a, "s3", 1)] + pay(app, root)[1:])
+    assert out.flush == {"account_rows": 2, "signer_rows": 5, "signer_accounts": 1}
+    for _ in range(2):
+        out = w.close(pay)
+        assert out.flush == {"account_rows": 2, "signer_rows": 0, "signer_accounts": 0}
+        _only_of(w)
+    out = w.close(lambda app, root: [_set_signer(app, b, "s2", 7)] + pay(app, root)[:1])
+    assert out.flush == {"account_rows": 2, "signer_rows": 4, "signer_accounts": 1}
+    _only_of(w, "b")
+    assert len(_signers_by_sqlite3(w.db_paths[0])[_strkey(a)]) == 3
+
+
+_SIGNER_CASES = {
+    "payments-among-multisig-accounts": _case_payments,
+    "add-a-signer": _case_add,
+    "remove-a-signer": _case_remove,
+    "change-a-weight": _case_reweigh,
+    "change-and-change-back-in-one-close": _case_change_and_back,
+    "rolled-back-set-options-then-a-payment": _case_rolled_back,
+    "store-add-or-change-with-signers": _case_add_or_change,
+    "an-account-merged-away": _case_merged_away,
+    "no-cached-previous-snapshot": _case_no_snapshot,
+    "two-closes-in-a-row": _case_closes_in_a_row,
+}
+
+
+@pytest.mark.parametrize("cow", [True, False], ids=["cow", "eager-copy"])
+@pytest.mark.parametrize("case", list(_SIGNER_CASES))
+def test_differential_signer_rows_written_only_where_changed(clock, tmp_path, case, cow):
+    w = _SignerWorld(clock, tmp_path, cow)
+    try:
+        _SIGNER_CASES[case](w, *(w.accounts[n] for n in _SIGNER_ACCOUNTS))
+    finally:
+        w.shutdown()
+
+
 class TestBufferMechanics:
     def _buf(self):
         from stellar_tpu.ledger.storebuffer import EntryStoreBuffer
@@ -232,6 +580,25 @@ class TestBufferMechanics:
         buf.release_mark()  # inner commits into outer scope
         buf.rollback_mark()  # outer rolls back: inner's write must unwind
         assert buf.get(b"k1") == (False, None)
+        buf.deactivate()
+
+    def test_signer_mark_is_sticky_and_rides_the_undo_log(self):
+        buf = self._buf()
+        buf.activate()
+        k1, k2 = self._key(1), self._key(2)
+        buf.record(b"k1", k1, "fee", object)  # signers as stored
+        buf.record(b"k2", k2, "set-options", object, True)
+        buf.push_mark()
+        buf.record(b"k1", k1, "set-options", object, True)
+        buf.record(b"k2", k2, "payment", object)  # list as last stored
+        assert buf._overlay[b"k1"][3] and buf._overlay[b"k2"][3]
+        buf.rollback_mark()
+        # each slot as it was before the savepoint, mark included
+        assert buf._overlay[b"k1"] == (k1, "fee", object, False)
+        assert buf._overlay[b"k2"] == (k2, "set-options", object, True)
+        buf.record(b"k2", k2, None, object)  # merged away, then created again
+        buf.record(b"k2", k2, "created", object)
+        assert buf._overlay[b"k2"][3]
         buf.deactivate()
 
     def test_flush_through_survives_enclosing_rollback(self, clock):

@@ -666,14 +666,14 @@ def test_paranoid_mode_audits_every_close(clock):
         dropped = []
         target = a.get_public_key()  # the payment DEST: its only write
 
-        def flaky_upsert(cls, db, entries):
+        def flaky_upsert(cls, db, entries, signers_dirty):
             kept = []
-            for e in entries:
+            for e, dirty in zip(entries, signers_dirty):
                 if e.data.value.accountID == target and not dropped:
                     dropped.append(target)
                     continue  # lose exactly one row from the flush
-                kept.append(e)
-            orig_upsert(cls, db, kept)
+                kept.append((e, dirty))
+            orig_upsert(cls, db, *zip(*kept))
 
         AccountFrame.upsert_batch = classmethod(flaky_upsert)
         try:
