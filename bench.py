@@ -1042,19 +1042,11 @@ def bench_ledger_close(n_txs=5000, n_ledgers=3):
             bucket_hash_backend,
         ) = _measure_bucket_hash_plane(app)
 
-        # parallel-apply scheduler counters (ISSUE r21): memoized on the
-        # manager by the first PARALLEL_APPLY close attempt; absent means
-        # the knob was off for the whole window
-        from stellar_tpu.ledger.applysched import ApplyScheduler
-
         # the timed closes claim the tpu backend's path: a batch the
         # watchdog finished on the host would be a host number
         assert app.sig_backend.stats()["wedge_fallback_items"] == 0, (
             "a close-leg signature batch fell back to the host"
         )
-
-        sched = getattr(lm, "_apply_sched", None)
-        sched_stats = sched.stats if sched is not None else ApplyScheduler(lm).stats
 
         times.sort()
         p50 = statistics.median(times)
@@ -1091,21 +1083,6 @@ def bench_ledger_close(n_txs=5000, n_ledgers=3):
             "xdr_copies_per_tx": round(d_copies / n_applied, 2),
             "cow_seals_per_tx": round(d_seals / n_applied, 2),
             "cow_copies_per_tx": round(d_unseals / n_applied, 2),
-            # conflict-partitioned parallel apply (ISSUE r21,
-            # ledger/applysched.py): effective worker count of the last
-            # sharded close (0 = every close ran the serial loop — e.g.
-            # a 1-core host auto-sizing to one worker), the fraction of
-            # txs applied inside parallel groups, and how many sets fell
-            # back serial on CONFLICTING classification or escape
-            "apply_workers": sched_stats["workers"],
-            "apply_parallel_pct": (
-                round(
-                    100.0 * sched_stats["parallel_txs"]
-                    / sched_stats["total_txs"], 1
-                )
-                if sched_stats["total_txs"] else 0.0
-            ),
-            "apply_conflict_fallbacks": sched_stats["conflict_fallbacks"],
             # close pipeline (ISSUE r10): verify wall hidden inside the
             # previous close's apply, and the lookahead depth it ran at
             "overlap_hidden_ms": (

@@ -11,7 +11,6 @@ ledger-close p50 (BASELINE.md second headline metric).  Usage:
     python profile_close.py cowab [n_txs] [n_ledgers]    # CoW-snapshot A/B
     python profile_close.py --copy-report [n_txs] [n_ledgers]  # xdr_copy sites
     python profile_close.py --pipeline-report [n_txs] [n_ledgers]  # close-pipeline A/B
-    python profile_close.py --apply-report [n_txs] [n_ledgers] [workers]  # parallel-apply A/B
     python profile_close.py --assert-budget [ms] [n_txs] # regression gate
 """
 
@@ -27,8 +26,7 @@ import time
 
 
 def _make_app(instance, n_txs, buffered=True, frame_context=True, cow=True,
-              paranoid=False, pipeline=True, sampled=True, real_time=False,
-              parallel_apply=None, apply_workers=None):
+              paranoid=False, pipeline=True, sampled=True, real_time=False):
     from stellar_tpu.main.application import Application
     from stellar_tpu.tx import testutils as T
     from stellar_tpu.util.clock import REAL_TIME, VirtualClock
@@ -40,10 +38,6 @@ def _make_app(instance, n_txs, buffered=True, frame_context=True, cow=True,
     cfg.COW_ENTRY_SNAPSHOTS = cow
     cfg.PARANOID_MODE = paranoid
     cfg.CLOSE_PIPELINE = pipeline
-    if parallel_apply is not None:
-        cfg.PARALLEL_APPLY = parallel_apply
-    if apply_workers is not None:
-        cfg.APPLY_WORKERS = apply_workers
     # invariant plane in SAMPLED mode, matching bench.py: this harness's
     # round-over-round p50s (and the close_budget regression gate) must
     # stay comparable with pre-r08 numbers — the all-on cost is tracked
@@ -524,142 +518,6 @@ def pipeline_report(n_txs=5000, n_ledgers=3, both=True):
     return 0
 
 
-def apply_report(n_txs=5000, n_ledgers=3, workers=4, both=True):
-    """Paired A/B of the threaded apply plane against the serial loop.
-
-    Both legs run PARANOID with the invariant plane ALL-ON and drive the
-    SAME payment closes in the same window; destinations pair off
-    (src[i] -> src[i^1]) so the footprint partitioner finds n_txs/2
-    disjoint two-tx groups — the shape that shards best.  The ON leg pins
-    ``APPLY_WORKERS = workers`` (auto sizes to one thread under the
-    interpreter lock and would never shard).  Prints, per leg, the
-    close-phase p50s (the scheduler's apply.partition / apply.group /
-    apply.merge spans on the ON leg, apply.serial on the OFF leg) and the
-    per-shard occupancy table from the scheduler's last-close ledger,
-    then asserts ledger hashes, SQL dumps, and tx/fee-history metas
-    bit-exact between legs and reports the apply-phase wall ratio.
-
-    What it has read (PERF.md, Findings, PR 25): the shard legs run Python
-    op bodies, so under one interpreter lock they take turns — on an
-    8-core sandbox the 5,000-tx apply is 3.1x slower on 13 threads than
-    on the serial loop and 1.6x slower on 2; on the chip's 13-core host
-    2.2x on 13, and 2 and 4 threads lose the close as much as 13 do; no
-    count above one wins.  The plane is kept for an
-    interpreter without the lock; this report is what would show a win
-    there.  A CPU-host tool: a rate from it is not a device number."""
-    from stellar_tpu.tx import testutils as T
-
-    def leg(instance, parallel):
-        app, clock = _make_app(
-            instance, n_txs, paranoid=True, sampled=False, real_time=True,
-            parallel_apply=parallel, apply_workers=workers,
-        )
-        try:
-            accounts = [T.get_account(i + 1) for i in range(n_txs + 1)]
-            created_at = _populate(app, accounts, n_txs)
-            # pair sources off so footprints are disjoint: a chain
-            # (i -> i+1) union-finds into ONE group and schedules serial
-            dest_of = lambda i: accounts[i ^ 1].get_public_key()
-            round_txs = [
-                _payment_txs(app, accounts, created_at, n_txs, j,
-                             dest_of=dest_of)
-                for j in range(n_ledgers)
-            ]
-            app.tracer.clear()  # spans must describe ONLY the timed closes
-            from stellar_tpu.crypto.keys import PubKeyUtils
-
-            PubKeyUtils.clear_verify_sig_cache()  # each leg starts cold
-            times = []
-            for j in range(n_ledgers):
-                _total_s, close_s = _drive_close(app, round_txs[j])
-                times.append(close_s)
-            agg = app.tracer.aggregates()
-            phases = {
-                name: round(agg[name]["p50_ms"], 2)
-                for name in (
-                    "ledger.close", "close.fees", "close.apply",
-                    "close.commit", "apply.partition", "apply.group",
-                    "apply.merge", "apply.serial",
-                )
-                if name in agg
-            }
-            sched = getattr(app.ledger_manager, "_apply_sched", None)
-            stats = dict(sched.stats) if sched is not None else None
-            last = sched.last_close if sched is not None else None
-            inv = app.invariants
-            assert inv.total_violations == 0, inv.dump_info()
-            assert inv.closes_checked >= n_ledgers
-            return (
-                statistics.median(times), phases, stats, last,
-                app.ledger_manager.last_closed.hash,
-                T.dump_state(app.database),  # the shared bit-exactness oracle
-            )
-        finally:
-            app.graceful_stop()
-            clock.shutdown()
-
-    def report(tag, p50, phases, stats, last):
-        print(f"\n== parallel apply {tag}: close p50 {p50 * 1e3:.0f} ms"
-              f" over {n_ledgers} closes of {n_txs} txs ==")
-        for name, ms in sorted(phases.items()):
-            print(f"  {name:<24} {ms:>9.2f} ms p50")
-        if stats is not None:
-            total = stats["total_txs"] or 1
-            print(
-                f"  scheduler: {stats['closes_parallel']} parallel /"
-                f" {stats['closes_serial']} serial closes,"
-                f" {100.0 * stats['parallel_txs'] / total:.1f}% of txs in"
-                f" parallel groups, {stats['conflict_fallbacks']}"
-                f" conflict fallbacks, {stats['escapes']} escapes"
-            )
-        if last is not None and last.get("mode") == "parallel":
-            sizes = last["group_sizes"]
-            shard_txs = last["shard_txs"]
-            peak = max(shard_txs)
-            print(
-                f"  last close: {last['txs']} txs -> {last['groups']}"
-                f" disjoint groups (sizes min/med/max "
-                f"{min(sizes)}/{sorted(sizes)[len(sizes) // 2]}/{max(sizes)})"
-                f" on {last['workers']} shards"
-            )
-            for i, n in enumerate(shard_txs):
-                bar = "#" * int(30 * n / peak) if peak else ""
-                print(f"    shard {i}: {n:>6} txs {bar}")
-            print(
-                f"  shard occupancy {100.0 * sum(shard_txs) / (peak * len(shard_txs)):.0f}%"
-                f" (sum/peak*shards — 100% = perfectly balanced)"
-            )
-
-    p50_on, ph_on, st_on, last_on, h_on, sql_on = leg(82, True)
-    report("ON", p50_on, ph_on, st_on, last_on)
-    if not both:
-        return 0
-    p50_off, ph_off, st_off, _last_off, h_off, sql_off = leg(83, False)
-    report("OFF", p50_off, ph_off, st_off, None)
-    assert h_on == h_off, "ledger hash diverged between apply modes!"
-    assert sql_on == sql_off, (
-        "SQL state (entries or history metas) diverged between apply modes!"
-    )
-    print("\nfinal ledger hashes + SQL dumps + history metas bit-exact")
-    if st_on is None or st_on["closes_parallel"] == 0:
-        print("parallel leg never sharded a close — nothing was certified")
-        return 1
-    a_on = ph_on.get("close.apply", 0.0)
-    a_off = ph_off.get("close.apply", 0.0)
-    if a_on > 0:
-        import os as _os
-
-        # no acceptance ratio: under an interpreter lock the threaded plane
-        # loses at every count (docstring); the report certifies that the
-        # two paths agree and says what the threads cost here
-        print(
-            f"apply-phase wall: {a_off:.2f} ms serial -> {a_on:.2f} ms"
-            f" with {st_on['workers']} workers ({a_off / a_on:.2f}x) on a"
-            f" {_os.cpu_count() or 1}-core host"
-        )
-    return 0
-
-
 def assert_budget(budget_ms=2000.0, n_txs=5000, n_ledgers=3):
     """Close-regression gate: clean (unprofiled) p50 of the standard
     close drive, exit nonzero when it exceeds the budget.  The default
@@ -720,16 +578,6 @@ if __name__ == "__main__":
             int(rest[0]) if rest else 5000,
             int(rest[1]) if len(rest) > 1 else 3,
             both="--single" not in args,
-        )
-    elif args and args[0] == "--apply-report":
-        rest = [a for a in args[1:] if a != "--single"]
-        sys.exit(
-            apply_report(
-                int(rest[0]) if rest else 5000,
-                int(rest[1]) if len(rest) > 1 else 3,
-                int(rest[2]) if len(rest) > 2 else 4,
-                both="--single" not in args,
-            )
         )
     elif args and args[0] == "--pipeline-report":
         rest = [a for a in args[1:] if a != "--single"]

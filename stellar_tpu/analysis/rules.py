@@ -590,90 +590,3 @@ class MetricsFastLaneRule(Rule):
         return any(
             k in text for k in ("metric", "timer", "meter", "histogram", "counter")
         )
-
-
-@register
-class ApplyShardIsolationRule(Rule):
-    """Parallel-apply worker isolation (PR 17): a function whose ``def``
-    line carries an ``# analysis: shard-leg`` comment runs concurrently
-    against per-shard planes (ShardView cache/buffer/frame-context) and
-    must receive every plane it touches as an explicit parameter.  Inside
-    the leg, reaching for a ``.database`` attribute, calling any SQL
-    surface (``execute``/``query_one``/...), or resolving a plane through
-    a global accessor (``entry_cache_of``/``active_buffer``/...) is a
-    main-plane dependency that the footprint partition cannot see — it
-    either races the other shards or silently reads pre-apply state.
-    The registry comment is the rule's input: new worker legs opt in on
-    their ``def`` line."""
-
-    id = "apply-shard-isolation"
-    doc = (
-        "main-plane access inside an `# analysis: shard-leg` worker —"
-        " `.database`, a SQL-surface call, or a plane-accessor lookup"
-    )
-
-    MARKER = "analysis: shard-leg"
-    # the ShardView raises FootprintEscape on these at runtime; the rule
-    # catches the dependency at review time instead
-    SQL_SURFACE = {
-        "execute", "executemany", "query_one", "query_all",
-        "materialize_savepoints", "flush", "flush_through",
-    }
-    # module-level accessors that resolve the MAIN planes off a database
-    PLANE_ACCESSORS = {
-        "entry_cache_of", "active_buffer", "active_frame_context",
-        "apply_scheduler_of",
-    }
-
-    def applies(self, ctx: FileContext) -> bool:
-        return not ctx.is_c and self.MARKER in ctx.text
-
-    def check(self, ctx: FileContext) -> Iterator[Hit]:
-        marked = {
-            line for line, text in ctx.comments.items() if self.MARKER in text
-        }
-        if not marked:
-            return
-        legs = []
-        for node in _walk(ctx):
-            if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                if node.lineno in marked:
-                    marked.discard(node.lineno)
-                    legs.append(node)
-        for line in sorted(marked):
-            yield (
-                line,
-                "`# analysis: shard-leg` must sit on the worker's `def`"
-                " line — the marker registers the whole function body",
-            )
-        for leg in legs:
-            yield from self._check_leg(leg)
-
-    def _check_leg(self, leg: ast.AST) -> Iterator[Hit]:
-        for node in ast.walk(leg):
-            if isinstance(node, ast.Attribute) and node.attr == "database":
-                chain = attr_chain(node) or ["?", "database"]
-                yield (
-                    node.lineno,
-                    f"`{'.'.join(chain)}` inside shard-leg `{leg.name}` —"
-                    " worker legs take their shard planes as parameters,"
-                    " never resolve them off an app/manager",
-                )
-            elif isinstance(node, ast.Call):
-                f = node.func
-                if isinstance(f, ast.Attribute) and f.attr in self.SQL_SURFACE:
-                    chain = attr_chain(f) or ["?", f.attr]
-                    yield (
-                        node.lineno,
-                        f"`{'.'.join(chain)}()` inside shard-leg"
-                        f" `{leg.name}` — SQL bypasses the shard overlay;"
-                        " reads outside the static footprint must raise"
-                        " FootprintEscape, not hit the main store",
-                    )
-                elif isinstance(f, ast.Name) and f.id in self.PLANE_ACCESSORS:
-                    yield (
-                        node.lineno,
-                        f"`{f.id}(...)` inside shard-leg `{leg.name}`"
-                        " resolves a MAIN plane — the shard's own"
-                        " cache/buffer/frame-context arrive as parameters",
-                    )

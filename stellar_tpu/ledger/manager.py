@@ -607,26 +607,12 @@ class LedgerManager:
         from ..tx import history as tx_history
         from ..tx.frame import TX_SAMPLE_STRIDE
         from ..xdr.txs import TransactionResultCode
-        from .applysched import apply_scheduler_of, sized_workers
-
-        # the scheduler sizes the apply (applysched.sized_workers): under
-        # an interpreter lock one thread, and it answers False at once;
-        # False also after a CONFLICTING classification, too few groups
-        # or a footprint escape.  The serial loop below is then the truth
-        sched = apply_scheduler_of(self)
-        if sched.apply(txs, ledger_delta, tx_result_set):
-            return
 
         blobs = []
         seq = self.current.header.ledgerSeq
         tracer = self.app.tracer
         skip = TX_SAMPLE_STRIDE - 1
-        with tracer.span(
-            "apply.serial",
-            txs=len(txs),
-            workers=sized_workers(self.app.config),
-            reason=sched.last_close["reason"],
-        ):
+        with tracer.span("apply.serial", txs=len(txs)):
             for index, tx in enumerate(txs):
                 # one transaction in TX_SAMPLE_STRIDE records tx.apply and
                 # its children; the others get the no-op tracer
@@ -655,7 +641,7 @@ class LedgerManager:
                     blobs.append(
                         (index + 1, pair.transactionHash, tx.env_xdr(), pair.to_xdr(), meta.to_xdr())
                     )
-            # the set's history rows in one encode call, as a shard leg's
+            # the set's history rows in one encode call
             rows = tx_history.transaction_rows(seq, blobs)
         with tracer.span("apply.rows", rows=len(rows)):
             tx_history.insert_transaction_rows(self.database, rows)

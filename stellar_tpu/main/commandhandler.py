@@ -18,7 +18,6 @@ import time
 from typing import Callable, Dict, Optional
 from urllib.parse import parse_qsl, urlparse
 
-from ..ledger.applysched import apply_scheduler_of
 from ..util import xlog
 from ..xdr.base import xdr_to_opaque
 from ..xdr.txs import TransactionEnvelope
@@ -226,10 +225,6 @@ class CommandHandler:
             # the device as JAX reports it, the kernel lowering, and the
             # dispatch / cutover / wedge-latch counters
             "sig_backend": app.sig_backend.stats(),
-            # which path this node's closes took through apply: the sized
-            # worker count, closes on the threaded plane / on the serial
-            # loop, and why the last close went serial (None: it did not)
-            "apply": apply_scheduler_of(lm).info(),
         }
         return {"info": info}
 

@@ -257,30 +257,6 @@ class Config:
         self.INGEST_RATE_LIMIT = 0
         self.INGEST_RATE_BURST = 32
         self.INGEST_SURGE_HIGH_WATER = 0
-        # TPU-native addition: conflict-partitioned parallel transaction
-        # apply (ledger/applysched.py) — a pre-pass extracts each tx's
-        # static account footprint, partitions disjoint-account groups via
-        # union-find, and applies groups on worker threads over isolated
-        # frame-context/store-buffer shards whose deltas merge back in
-        # canonical apply order.  Any tx whose footprint cannot be
-        # statically bounded (offers, path payments, inflation, ...) or a
-        # shard that trips the footprint-escape assertion falls the whole
-        # set back to the serial path — bit-exact either way; the
-        # differential suite (tests/test_framecontext.py) runs both and
-        # compares ledger hashes + SQL dumps + history metas.  Needs the
-        # write-back store buffer (ENTRY_WRITE_BUFFER): shard writes must
-        # never reach SQL mid-apply.
-        self.PARALLEL_APPLY = True
-        # worker threads for the parallel apply path; 0 = auto, sized by
-        # applysched.sized_workers from what the interpreter is: ONE
-        # wherever it serialises Python threads (every CPython with the
-        # interpreter lock — the shard legs run Python, so more threads
-        # only take turns: 13 made a 5,000-tx apply 2.2x slower on the
-        # chip's host, PERF.md PR 25), os.cpu_count() only on an interpreter that
-        # does not.  An effective count of 1 short-circuits to the plain
-        # serial loop before any partitioning; an explicit count >= 2
-        # runs the threaded plane as given (tests and scenarios pin 4).
-        self.APPLY_WORKERS = 0
 
     # -- loading -----------------------------------------------------------
     @classmethod
@@ -476,22 +452,6 @@ class Config:
                 raise ValueError(
                     f"{knob} must be an int >= 0 (0 = off), got {v!r}"
                 )
-        if not (
-            isinstance(self.PARALLEL_APPLY, bool)
-            or self.PARALLEL_APPLY in (0, 1)
-        ):
-            raise ValueError(
-                f"PARALLEL_APPLY must be a boolean, got {self.PARALLEL_APPLY!r}"
-            )
-        if not (
-            isinstance(self.APPLY_WORKERS, int)
-            and not isinstance(self.APPLY_WORKERS, bool)
-            and self.APPLY_WORKERS >= 0
-        ):
-            raise ValueError(
-                f"APPLY_WORKERS must be an int >= 0 (0 = auto), "
-                f"got {self.APPLY_WORKERS!r}"
-            )
 
     def to_short_string(self, pk: PublicKey) -> str:
         s = PubKeyUtils.to_strkey(pk)

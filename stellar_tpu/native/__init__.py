@@ -372,7 +372,7 @@ def load_halfagg():
         )
         return _halfagg_mod
 
-# -- applycore: the parallel-apply host leg (CPython extension) --------------
+# -- applycore: the apply loop's native leg (CPython extension) --------------
 
 _APPLYCORE_SRC = os.path.join(_HERE, "applycore.c")
 _APPLYCORE_SO = os.path.join(_HERE, "_applycore.so")
@@ -383,11 +383,10 @@ _applycore_tried = False
 
 
 def load_applycore():
-    """The compiled parallel-apply host leg
-    (``encode_history_rows(items)``), or None (ledger/applysched.py
-    falls back to per-row ``base64``/``hex`` in Python — correct, but
-    the worker shards then serialize on the GIL through the encode
-    tail)."""
+    """The compiled leg of the apply loop: a set's history rows in one
+    native call (``encode_history_rows(items)``), or None
+    (tx/history.transaction_rows then encodes per row with
+    ``base64``/``hex`` in Python — same bytes, slower)."""
     global _applycore_mod, _applycore_tried
     with _applycore_lock:
         if _applycore_mod is not None or _applycore_tried:

@@ -1,4 +1,6 @@
-/* applycore: the parallel-apply host leg (ledger/applysched.py).
+/* applycore: the native leg of the apply loop
+ * (ledger/manager.py _apply_transactions, via tx/history.transaction_rows):
+ * the set's history rows in one native call.
  *
  * One entry point:
  *
@@ -9,9 +11,8 @@
  * The per-tx history row encode (hex + 3x base64) is the dominant
  * residual Python cost of the apply tail once the stores are buffered.
  * This leg gathers all input pointers under the GIL, then releases it
- * for the whole batch encode — worker shards in ledger/applysched.py
- * overlap here even under CPython, which is what makes the thread-per-
- * shard close actually scale on a multi-core host.
+ * for the whole batch encode, so the close's other threads (bucket
+ * merges, the verify pipeline) run meanwhile.
  *
  * Encoding contract matches tx/history.py exactly: lowercase hex for
  * the txid, standard base64 alphabet WITH '=' padding for the blobs.

@@ -95,11 +95,6 @@ class ScenarioSpec:
     sendq_bytes: Optional[int] = None
     sendq_flood_msgs: Optional[int] = None
     straggler_stall_ms: Optional[float] = None
-    # conflict-partitioned parallel apply (ledger/applysched.py) — None
-    # keeps the Config default on every node; True also pins
-    # APPLY_WORKERS=4 so the closes genuinely shard: auto sizes to one
-    # thread (the serial loop) under an interpreter lock
-    parallel_apply: Optional[bool] = None
     # floors/verdicts for the survival plane: a run must disconnect at
     # least one straggler (slow_reader), must shed at least this many
     # FLOOD frames (overload shapes), and the per-peer queue-byte
@@ -220,10 +215,6 @@ class Scenario:
             cfg.INGEST_RATE_LIMIT = self.spec.ingest_rate_limit
         if self.spec.ingest_surge_high_water is not None:
             cfg.INGEST_SURGE_HIGH_WATER = self.spec.ingest_surge_high_water
-        if self.spec.parallel_apply is not None:
-            cfg.PARALLEL_APPLY = self.spec.parallel_apply
-            if self.spec.parallel_apply:
-                cfg.APPLY_WORKERS = 4
         if self.spec.disk_db or self.spec.archives:
             cfg.DATABASE = f"sqlite3://{self.workdir}/node{i}.db"
         if self.spec.archives:

@@ -42,21 +42,14 @@ from ..xdr.txs import (
 )
 from . import history as tx_history
 
-# Sampled transaction spans: the loops that apply a set (the serial loop of
-# ``LedgerManager._apply_transactions``, ``ApplyScheduler._run_shard``) hand
-# the close's tracer to one transaction in TX_SAMPLE_STRIDE, chosen by its
-# index in the set (no clock, no random number: the same indices on every
-# run), and the no-op tracer to the others; a sampled transaction records
-# ``tx.apply`` with ``tx.valid`` and ``tx.ops`` under it.  A power of two:
-# the test is ``index & (TX_SAMPLE_STRIDE - 1)``.
+# Sampled transaction spans: the loop that applies a set
+# (``LedgerManager._apply_transactions``) hands the close's tracer to one
+# transaction in TX_SAMPLE_STRIDE, chosen by its index in the set (no clock,
+# no random number: the same indices on every run), and the no-op tracer to
+# the others; a sampled transaction records ``tx.apply`` with ``tx.valid``
+# and ``tx.ops`` under it.  A power of two: the test is
+# ``index & (TX_SAMPLE_STRIDE - 1)``.
 TX_SAMPLE_STRIDE = 64
-
-
-def _acct_kb(pk: PublicKey) -> bytes:
-    """ACCOUNT cache key (prefix + raw pubkey) — the footprint pre-pass
-    builds thousands of these, so skip the LedgerKey/XDR round-trip the
-    same way AccountFrame.load_account does."""
-    return _ACCT_KEY_PREFIX + pk.value
 
 
 class TransactionFrame:
@@ -258,50 +251,6 @@ class TransactionFrame:
                     if PubKeyUtils.has_hint(pk, sig.hint):
                         triples.append((pk.value, contents_hash, sig.signature))
         return triples
-
-    # -- static footprint (ledger/applysched.py pre-pass) ------------------
-    def static_footprint(self):
-        """The set of ACCOUNT cache keys (prefix+pubkey bytes, the same
-        shape ``AccountFrame.load_account`` keys on) this tx can touch
-        during apply, or None when the footprint cannot be statically
-        bounded.
-
-        Bounded ops declare exactly the accounts their apply path loads:
-        native-asset payments (source + destination, no order-book walk),
-        create-account, account-merge, and set-options without an
-        inflation destination (the dest branch loads a THIRD account the
-        bulk warm never sees).  Everything that walks the order book
-        (offers, path payments, non-native payments) or aggregates over
-        the whole ledger (inflation) is unbounded — the scheduler
-        classifies those CONFLICTING and the whole set applies serially.
-        Signer keys are auth-only (verify-cache lookups, no entry loads),
-        so they do not widen the footprint."""
-        from ..xdr.entries import AssetType
-        from ..xdr.txs import OperationType as OT
-
-        keys = {_acct_kb(self.get_source_id())}
-        for op in self.envelope.tx.operations:
-            if op.sourceAccount is not None:
-                keys.add(_acct_kb(op.sourceAccount))
-            t = op.body.type
-            v = op.body.value
-            if t == OT.PAYMENT:
-                if v.asset.type != AssetType.ASSET_TYPE_NATIVE:
-                    return None  # trustlines + possible issuer loads
-                keys.add(_acct_kb(v.destination))
-            elif t == OT.CREATE_ACCOUNT:
-                keys.add(_acct_kb(v.destination))
-            elif t == OT.ACCOUNT_MERGE:
-                keys.add(_acct_kb(v))  # merge body is the destination
-            elif t == OT.SET_OPTIONS:
-                if v.inflationDest is not None:
-                    return None  # loads the dest account (cold cache)
-            else:
-                # PATH_PAYMENT / MANAGE_OFFER / CREATE_PASSIVE_OFFER /
-                # CHANGE_TRUST / ALLOW_TRUST / INFLATION: order-book or
-                # trustline or whole-ledger state — not boundable here
-                return None
-        return keys
 
     # -- account loading ---------------------------------------------------
     def load_account(self, db, readonly: bool = False):

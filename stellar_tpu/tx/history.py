@@ -72,12 +72,12 @@ def transaction_rows(
     XDR bytes -> the rows ``transaction_row`` builds one at a time, for a
     whole set in one call.
 
-    The close encodes a set's rows here once, after the apply loop (the
-    serial loop and each shard leg alike), instead of a hex, three
-    ``base64`` calls and three ``.decode()`` a transaction.  The native
-    `_applycore` leg does the batch in C; the pure-Python fallback keeps
-    the path alive where the toolchain can't build the extension.  Same
-    bytes either way (tests/test_applysched.py)."""
+    The close encodes a set's rows here once, after the apply loop,
+    instead of a hex, three ``base64`` calls and three ``.decode()`` a
+    transaction.  The native `_applycore` leg does the batch in C; the
+    pure-Python fallback keeps the path alive where the toolchain can't
+    build the extension.  Same bytes either way
+    (tests/test_txhistory_rows.py)."""
     from ..native import load_applycore
 
     mod = load_applycore()

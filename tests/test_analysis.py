@@ -60,12 +60,6 @@ RULE_FIXTURES = [
     ("send-path", "send_path_pos.py", "send_path_neg.py", 3),
     ("durable-write", "durable_write_pos.py", "durable_write_neg.py", 5),
     ("gil-region", "gil_region_pos.c", "gil_region_neg.c", 2),
-    (
-        "apply-shard-isolation",
-        "apply_shard_isolation_pos.py",
-        "apply_shard_isolation_neg.py",
-        4,
-    ),
 ]
 
 

@@ -41,15 +41,26 @@ def clock():
 _dump_state = T.dump_state  # the shared bit-exactness oracle (testutils)
 
 
+def plain_reference_config(cfg):
+    """What benchmarks/reference.py's `replay_hashes` sets on the node it
+    holds the chip's ledger hashes against: the host verifier and none of
+    the planes a shipped node adds around the close."""
+    cfg.SIGNATURE_BACKEND = "cpu"
+    cfg.CLOSE_PIPELINE = False
+    cfg.INGEST_BATCH = False
+    cfg.BACKGROUND_BUCKET_MERGE = False
+    cfg.INVARIANT_CHECKS = []
+
+
 class _Runner:
-    """Drive the same close sequence through two apps (`knob` on / off)
-    and compare ledger hashes + SQL + history after every close."""
+    """Drive the same close sequence through two apps (`knob` on / off, or
+    the shipped default / the plain reference node) and compare ledger
+    hashes + SQL + history after every close."""
 
     KNOBS = {
         "frame_context": "FRAME_CONTEXT",
         "cow": "COW_ENTRY_SNAPSHOTS",
         "close_pipeline": "CLOSE_PIPELINE",
-        "parallel_apply": "PARALLEL_APPLY",
     }
 
     def __init__(self, clock, instance_base, knob="frame_context"):
@@ -57,13 +68,12 @@ class _Runner:
         self.apps = []
         for i, on in enumerate((True, False)):
             cfg = T.get_test_config(instance_base + i)
-            setattr(cfg, self.KNOBS[knob], on)
-            if knob == "parallel_apply":
-                # the 1-core CI host auto-sizes to a single worker (which
-                # short-circuits to the serial path): pin 4 so the on-leg
-                # genuinely shards, partitions, and merges
-                cfg.APPLY_WORKERS = 4
-            cfg.PARANOID_MODE = True  # audit every close on both sides
+            if knob != "reference":
+                setattr(cfg, self.KNOBS[knob], on)
+            elif not on:
+                plain_reference_config(cfg)
+            # audit every close on both sides; the plain node audits nothing
+            cfg.PARANOID_MODE = on or knob != "reference"
             self.apps.append(Application(clock, cfg, new_db=True))
 
     def close(self, build_txs):
@@ -92,7 +102,7 @@ class _Runner:
         # the ledger-invariant plane (all-on by default in test configs)
         # audited both sides of every close above: FRAME_CONTEXT must stay
         # invariant-clean, not merely hash-identical to context-off
-        for app in self.apps:
+        for app in self.apps[: 1 if self.knob == "reference" else 2]:
             inv = app.invariants
             assert inv.total_violations == 0, inv.dump_info()
             assert inv.closes_checked > 0
@@ -109,25 +119,21 @@ class _Runner:
             app.database.close()
 
 
-@pytest.fixture(
-    params=["frame_context", "cow", "close_pipeline", "parallel_apply"]
-)
+@pytest.fixture(params=["frame_context", "cow", "close_pipeline", "reference"])
 def runner(clock, request):
     """Every differential scenario runs four times: FRAME_CONTEXT on/off,
-    COW_ENTRY_SNAPSHOTS on/off, CLOSE_PIPELINE on/off, and PARALLEL_APPLY
-    on/off (each vs an otherwise-default config) — the aliasing planes,
-    the pipelined close, and the conflict-partitioned parallel apply all
-    share one equivalence oracle.  The parallel-apply leg covers both
-    sides of its own fork: partitionable sets shard and merge, while the
-    offer-crossing / path-payment / inflation scenarios classify
-    CONFLICTING and must fall back to the serial loop bit-exactly."""
+    COW_ENTRY_SNAPSHOTS on/off, CLOSE_PIPELINE on/off (each vs an
+    otherwise-default config), and the shipped default against the plain
+    node benchmarks/reference.py builds — the equivalence that decides
+    `ledger_hashes_differing` on the chip, here over offers, path payments,
+    merges and inflation that no benchmark cell runs."""
     r = _Runner(
         clock,
         {
             "frame_context": 72,
             "cow": 84,
             "close_pipeline": 96,
-            "parallel_apply": 108,
+            "reference": 108,
         }[request.param],
         knob=request.param,
     )
@@ -274,59 +280,6 @@ def test_differential_offer_crossing(runner):
         ]),
     ])
     assert codes == [RC.txSUCCESS, RC.txSUCCESS]
-
-
-def test_parallel_apply_engages_and_falls_back(clock):
-    """White-box check on the parallel_apply runner's on-leg: a payment
-    set with disjoint sources genuinely shards (closes_parallel grows),
-    while a self path-payment classifies CONFLICTING and takes the
-    serial loop — with both legs still bit-exact (the runner asserts
-    hashes / SQL / metas after every close)."""
-    r = _Runner(clock, 110, knob="parallel_apply")
-    try:
-        a, b = T.get_account("pa-a"), T.get_account("pa-b")
-        c, d = T.get_account("pa-c"), T.get_account("pa-d")
-        r.close(lambda app, root: [
-            T.tx_from_ops(app, root, _seq(app, root), [
-                T.create_account_op(a, 10**12),
-                T.create_account_op(b, 10**12),
-                T.create_account_op(c, 10**12),
-                T.create_account_op(d, 10**12),
-            ]),
-        ])
-        codes = r.close(lambda app, root: [
-            T.tx_from_ops(app, a, _seq(app, a), [T.payment_op(b, 10**7)]),
-            T.tx_from_ops(app, c, _seq(app, c), [T.payment_op(d, 10**7)]),
-        ])
-        assert codes == [RC.txSUCCESS, RC.txSUCCESS]
-        sched = r.apps[0].ledger_manager._apply_sched
-        assert sched.stats["closes_parallel"] == 1
-        assert sched.stats["parallel_txs"] == 2
-        assert sched.stats["workers"] == 2
-        assert sched.last_close["mode"] == "parallel"
-        # a self path-payment's footprint cannot be statically bounded:
-        # the whole set must classify CONFLICTING and apply serially
-        codes = r.close(lambda app, root: [
-            T.tx_from_ops(app, a, _seq(app, a), [
-                T.op(
-                    X.OperationType.PATH_PAYMENT,
-                    X.PathPaymentOp(
-                        sendAsset=X.Asset.native(), sendMax=10**7,
-                        destination=a.get_public_key(),
-                        destAsset=X.Asset.native(), destAmount=10**7,
-                        path=[],
-                    ),
-                ),
-            ]),
-            T.tx_from_ops(app, b, _seq(app, b), [T.payment_op(c, 10**6)]),
-        ])
-        assert codes == [RC.txSUCCESS, RC.txSUCCESS]
-        assert sched.stats["conflict_fallbacks"] >= 1
-        assert sched.last_close == {
-            "mode": "serial", "reason": "conflicting-txset",
-        }
-    finally:
-        r.shutdown()
 
 
 class TestContextMechanics:

@@ -443,33 +443,6 @@ def test_deterministic_replay(cls):
     assert a.scoreboard.fast_rejects == b.scoreboard.fast_rejects
 
 
-def test_deterministic_replay_parallel_apply():
-    """ISSUE r21 satellite 4: the conflict-partitioned parallel apply
-    (ledger/applysched.py) must not perturb the replay contract — the
-    same chaos class with PARALLEL_APPLY pinned on (4 workers on every
-    node) produces identical ledger hashes AND an identical scoreboard
-    digest across two runs.  Worker interleaving is nondeterministic;
-    the canonical-order merge is what keeps it invisible."""
-    import dataclasses
-
-    from stellar_tpu.scenarios.scenario import Scenario
-
-    def once():
-        verify_cache().clear()
-        spec = dataclasses.replace(
-            small_specs()["overload_storm"], parallel_apply=True
-        )
-        r = Scenario(spec).run()
-        assert r.ok, r.failures
-        return r.scoreboard
-
-    a, b = once(), once()
-    assert a.ledgers_closed >= 10 and a.invariant_violations == 0
-    assert a.final_hash == b.final_hash
-    assert a.final_lcls == b.final_lcls
-    assert a.digest() == b.digest()
-
-
 @pytest.mark.slow
 def test_tcp_scale_100():
     """The 100+ node OVER_TCP shape (ISSUE r19 / ROADMAP 6(b')): a

@@ -214,12 +214,12 @@ class TestCause:
         tr = Tracer(clock=clock)
         seen = {}
 
-        def shard(parent):
-            sp = tr.begin("apply.group", parent=parent)
+        def leg(parent):
+            sp = tr.begin("worker.leg", parent=parent)
             with tr.span("tx.apply") as t:
                 seen["tx"] = (t.parent, t.req)
             tr.end(sp)
-            seen["group"] = (sp.parent, sp.req)
+            seen["leg"] = (sp.parent, sp.req)
             seen["after"] = tr.current()
 
         def worker(parent):
@@ -229,14 +229,14 @@ class TestCause:
             seen["worker_after"] = tr.current()
 
         with tr.span("close.apply", req=12) as ca:
-            for fn in (shard, worker):
+            for fn in (leg, worker):
                 t = threading.Thread(target=fn, args=(tr.current(),))
                 t.start()
                 t.join()
             assert tr.current() is ca  # the other threads' stacks are theirs
-        group = next(s for s in tr.spans() if s.name == "apply.group")
-        assert seen["group"] == (ca.sid, 12)
-        assert seen["tx"] == (group.sid, 12)
+        handed = next(s for s in tr.spans() if s.name == "worker.leg")
+        assert seen["leg"] == (ca.sid, 12)
+        assert seen["tx"] == (handed.sid, 12)
         assert seen["drain"] == (ca.sid, 12)
         assert seen["after"] is None and seen["worker_after"] is None
         # a thread with nothing handed to it starts from nothing
@@ -249,23 +249,23 @@ class TestCause:
         tr = Tracer(clock=clock)
         seen = {}
 
-        def shard(parent):
-            with tr.span("apply.group", parent=parent, txs=2) as sp:
-                seen["group"] = (sp.parent, sp.req)
+        def leg(parent):
+            with tr.span("worker.leg", parent=parent, txs=2) as sp:
+                seen["leg"] = (sp.parent, sp.req)
                 tr.end(sp, done=2)  # the block's exit is then a no-op
             seen["after"] = tr.current()
 
         with tr.span("close.apply", req=9) as ca:
-            t = threading.Thread(target=shard, args=(tr.current(),))
+            t = threading.Thread(target=leg, args=(tr.current(),))
             t.start()
             t.join()
-        assert seen == {"group": (ca.sid, 9), "after": None}
-        (group,) = [s for s in tr.spans() if s.name == "apply.group"]
-        assert group.attrs == {"txs": 2, "done": 2}
-        assert tr.aggregates()["apply.group"]["count"] == 1  # ended once
+        assert seen == {"leg": (ca.sid, 9), "after": None}
+        (handed,) = [s for s in tr.spans() if s.name == "worker.leg"]
+        assert handed.attrs == {"txs": 2, "done": 2}
+        assert tr.aggregates()["worker.leg"]["count"] == 1  # ended once
 
-    @pytest.mark.parametrize("site", ["sig.flush", "ingest.flush", "apply.group"])
-    def test_a_raising_body_leaves_no_stale_parent(self, clock, site):
+    @pytest.mark.parametrize("site", ["sig.flush", "ingest.flush", "apply.serial"])
+    def test_a_raising_body_leaves_no_stale_parent(self, clock, site, monkeypatch):
         """The spans that sit at the bottom of a long-lived thread's stack
         are ended on the failure path too: what the thread records next
         has no parent."""
@@ -308,21 +308,31 @@ class TestCause:
             finally:
                 app.graceful_stop()
         else:
-            from stellar_tpu.ledger.applysched import ApplyScheduler
+            # a transaction whose apply could not be unwound aborts the
+            # close from inside apply.serial, on the closing thread
+            from stellar_tpu.database.database import UnrollbackableWrite
+            from stellar_tpu.main.application import Application
+            from stellar_tpu.tx import testutils as T
+            from stellar_tpu.tx.frame import TransactionFrame
 
-            class ExplodingGroup:
-                def __len__(self):
-                    return 1
+            app = Application(clock, T.get_test_config(175), new_db=True)
+            try:
+                tr = app.tracer
+                lm = app.ledger_manager
+                root = T.root_key_for(app)
+                tx = T.tx_from_ops(app, root, 1, [T.create_account_op(T.get_account(9901), 10**9)])
 
-                def __iter__(self):
-                    raise Boom
+                def apply(self, delta, app_, meta=None, tracer=None):
+                    raise UnrollbackableWrite("rows written under no savepoint")
 
-            tr = Tracer(clock=clock)
-            errors = []
-            ApplyScheduler._run_shard(
-                None, None, None, [ExplodingGroup()], None, 2, None, tr, None, {}, {}, errors
-            )
-            assert [type(e) for e in errors] == [Boom]
+                monkeypatch.setattr(TransactionFrame, "apply", apply)
+                tr.clear()
+                with pytest.raises(UnrollbackableWrite):
+                    T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5, [tx])
+                (close,) = [s for s in tr.spans() if s.name == "ledger.close"]
+                assert close.attrs["failed"] is True
+            finally:
+                app.database.close()
         assert tr.current() is None
         assert site in [s.name for s in tr.spans()]  # ended, so recorded
         with tr.span("next") as nxt:
@@ -371,15 +381,15 @@ class TestSelfTime:
             sp.end = t1
             return sp
 
-        # two shards overlapping in time (other threads), one child that
+        # two worker legs overlapping in time (other threads), one child that
         # outlives the parent, a grandchild that must not count twice,
         # and a span still open (left out)
         spans = [
             span(1, "close.apply", 0.0, 10.0),
-            span(2, "apply.group", 1.0, 4.0, parent=1, tid=2),
-            span(3, "apply.group", 3.0, 6.0, parent=1, tid=3),
+            span(2, "worker.leg", 1.0, 4.0, parent=1, tid=2),
+            span(3, "worker.leg", 3.0, 6.0, parent=1, tid=3),
             span(4, "tx.apply", 1.0, 2.0, parent=2, tid=2),
-            span(5, "apply.merge", 9.0, 12.0, parent=1),
+            span(5, "apply.rows", 9.0, 12.0, parent=1),
             Span("open", 0.0, 1, None, 6, 1),
         ]
         st = self_times(spans)
@@ -391,7 +401,7 @@ class TestSelfTime:
             5: pytest.approx(3.0),
         }
         p50 = self_p50_ms(spans)
-        assert p50["apply.group"] == pytest.approx(2500.0)
+        assert p50["worker.leg"] == pytest.approx(2500.0)
         assert p50["close.apply"] == pytest.approx(4000.0)
         assert "open" not in p50
 
@@ -421,57 +431,83 @@ class TestSelfTime:
 
 NEW_CLOSE_SPANS = {
     "commit.flush", "commit.invariants", "commit.buckets", "commit.sql",
-    "fees.charge", "fees.rows", "apply.serial", "apply.shards", "apply.rows",
+    "fees.charge", "fees.rows", "apply.serial", "apply.rows",
     "tx.apply", "tx.valid", "tx.ops",
     # PR 26: the prefetch's collection, inside the close where the set was
     # not validated first (as here), else inside txset.validate
     "sig.collect",
 }
 CLOSE_TXS = 130
+# traffic -> (signatures an envelope, keys check_signature walks for it)
+TRAFFIC = {"payments": (1, 1), "multisig": (3, 5), "with-failures": (1, 1)}
 
 
-@pytest.fixture(scope="module", params=["parallel", "serial", "shipped"])
+def _failing(traffic, i):
+    """with-failures: every eighth payment is for more than its source holds."""
+    return traffic == "with-failures" and i % 8 == 0
+
+
+@pytest.fixture(scope="module", params=sorted(TRAFFIC))
 def traced_closes(request):
     """Three consecutive real-clock closes of 130 payments each (accounts
-    in groups of two), traced: -> the spans of each close.  "shipped"
-    leaves PARALLEL_APPLY / APPLY_WORKERS at their defaults: sized to one
-    thread under the interpreter lock, the serial loop."""
+    in groups of two), traced, through the one loop a close has: -> the
+    spans of each close.  The traffic differs: "payments" is one signature
+    by the master key; "multisig" holds every account under five signers
+    of weight 1 at thresholds 3 and master weight 0, three of them signing
+    each envelope (what `multisig5000.close` runs); "with-failures" makes
+    every eighth payment underfunded."""
+    from test_serial_apply import hold_under_signers, sign_with
+
     from stellar_tpu.ledger.accountframe import AccountFrame
     from stellar_tpu.main.application import Application
     from stellar_tpu.tx import testutils as T
     from stellar_tpu.util.clock import REAL_TIME
 
+    traffic = request.param
     c = VirtualClock(REAL_TIME)
-    cfg = T.get_test_config(175 if request.param == "parallel" else 176)
-    if request.param != "shipped":
-        cfg.PARALLEL_APPLY = request.param == "parallel"
-        cfg.APPLY_WORKERS = 4  # auto sizes to the serial loop
-    app = Application(c, cfg, new_db=True)
+    app = Application(c, T.get_test_config(176), new_db=True)
     try:
         lm = app.ledger_manager
+
+        def close(txs):
+            T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5, txs)
+
         root = T.root_key_for(app)
         keys = [T.get_account(7000 + i) for i in range(CLOSE_TXS)]
         seq = AccountFrame.load_account(root.get_public_key(), app.database).get_seq_num()
-        fund = [
+        close([
             T.tx_from_ops(app, root, seq + 1 + j, [T.create_account_op(k, 10**9) for k in keys[i : i + 70]])
             for j, i in enumerate(range(0, CLOSE_TXS, 70))
-        ]
-        T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5, fund)
+        ])
         first = lm.last_closed.header.ledgerSeq << 32
+        signers = None
+        if traffic == "multisig":
+            signers = [[T.get_account(8000 + 5 * i + j) for j in range(5)] for i in range(CLOSE_TXS)]
+            held = [hold_under_signers(app, k, first + 1, mine) for k, mine in zip(keys, signers)]
+            close(held)
+            assert all(tx.get_result_code().name == "txSUCCESS" for tx in held)
+            first += 1
         closes = []
         for r in range(3):
             app.tracer.clear()
             pay = [
-                T.tx_from_ops(app, k, first + 1 + r, [T.payment_op(keys[i ^ 1], 100)])
+                T.tx_from_ops(
+                    app, k, first + 1 + r,
+                    [T.payment_op(keys[i ^ 1], 10**12 if _failing(traffic, i) else 100)],
+                )
                 for i, k in enumerate(keys)
             ]
-            T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5, pay)
-            assert all(tx.get_result_code().name == "txSUCCESS" for tx in pay)
+            if signers is not None:
+                for i, tx in enumerate(pay):
+                    sign_with(tx, [signers[i][(i + r + 2 * j) % 5] for j in range(3)])
+            close(pay)
+            assert [tx.get_result_code().name for tx in pay] == [
+                "txFAILED" if _failing(traffic, i) else "txSUCCESS" for i in range(CLOSE_TXS)
+            ]
             closes.append((lm.last_closed.header.ledgerSeq, app.tracer.spans()))
         assert app.tracer.dropped == 0
-        mode = app.ledger_manager._apply_sched.last_close["mode"]
-        assert mode == ("parallel" if request.param == "parallel" else "serial")
-        yield request.param, closes
+        assert app.invariants.total_violations == 0, app.invariants.dump_info()
+        yield traffic, closes
     finally:
         app.database.close()
         c.shutdown()
@@ -482,24 +518,12 @@ class TestCloseFromInside:
 
     @pytest.mark.parametrize("phase", ["close.commit", "close.fees", "close.apply"])
     def test_direct_children_cover_the_phase(self, traced_closes, phase):
-        mode, closes = traced_closes
+        _traffic, closes = traced_closes
         shares = []
         for _seq, spans in closes:
             (sp,) = [s for s in spans if s.name == phase]
             kids = [(s.start, s.end) for s in spans if s.parent == sp.sid]
             assert kids, phase
-            if phase == "close.apply" and mode == "parallel":
-                # the shard threads' phase runs from the end of
-                # apply.shards to the start of apply.merge: what a loaded
-                # host adds between starting a thread and its first
-                # instruction is the phase's, not a hole in the tracing
-                (shards,) = [s for s in spans if s.name == "apply.shards"]
-                (merge,) = [s for s in spans if s.name == "apply.merge"]
-                groups = [s for s in spans if s.name == "apply.group"]
-                assert groups and all(
-                    shards.end <= g.start and g.end <= merge.start for g in groups
-                )
-                kids.append((shards.end, merge.start))
             covered, cursor = 0.0, sp.start
             for lo, hi in sorted(kids):
                 lo, hi = max(lo, cursor), min(hi, sp.end)
@@ -511,7 +535,7 @@ class TestCloseFromInside:
         assert max(shares) >= 0.95, (phase, shares)
 
     def test_children_by_name(self, traced_closes):
-        mode, closes = traced_closes
+        _traffic, closes = traced_closes
         _seq, spans = closes[1]
         by = {s.sid: s for s in spans}
         kids = {}
@@ -524,45 +548,41 @@ class TestCloseFromInside:
         assert all(n.startswith("invariant.") for n in kids["commit.invariants"])
         assert kids["close.fees"] == {"fees.charge", "fees.rows"}
         assert "fees.charge" not in kids  # no span a transaction in the fee loop
-        if mode == "parallel":
-            assert kids["close.apply"] == {
-                "apply.partition", "apply.shards", "apply.group", "apply.merge",
-            }
-            assert kids["apply.merge"] == {"apply.rows"}
-            assert kids["apply.group"] == {"tx.apply"}
-        else:
-            assert kids["close.apply"] == {"apply.serial", "apply.rows"}
-            assert kids["apply.serial"] == {"tx.apply"}
+        assert kids["close.apply"] == {"apply.serial", "apply.rows"}
+        assert kids["apply.serial"] == {"tx.apply"}
         assert kids["tx.apply"] == {"tx.valid", "tx.ops"}
 
-    def test_the_serial_span_says_how_the_close_was_sized(self, traced_closes):
-        mode, closes = traced_closes
-        want = {
-            "parallel": None,
-            "serial": {"txs": CLOSE_TXS, "workers": 4, "reason": "parallel-apply-off"},
-            "shipped": {"txs": CLOSE_TXS, "workers": 1, "reason": "one-worker"},
-        }[mode]
+    def test_the_spans_count_the_set_its_signatures_and_its_keys(self, traced_closes):
+        traffic, closes = traced_closes
+        sigs, keys = TRAFFIC[traffic]
         for _seq, spans in closes:
-            serial = [s.attrs for s in spans if s.name == "apply.serial"]
-            assert serial == ([want] if want else [])
-            if want:
-                assert not [s for s in spans if s.name in ("apply.partition", "apply.shards", "apply.group", "apply.merge")]
+            assert [s.attrs for s in spans if s.name == "apply.serial"] == [{"txs": CLOSE_TXS}]
+            assert [s.attrs for s in spans if s.name == "apply.rows"] == [{"rows": CLOSE_TXS}]
+            # one collection a close; every signature's hint finds one key
+            assert [s.attrs for s in spans if s.name == "sig.collect"] == [{
+                "txs": CLOSE_TXS, "signatures": sigs * CLOSE_TXS,
+                "triples": sigs * CLOSE_TXS, "accounts": CLOSE_TXS,
+            }]
+            # which way check_signature went under the sampled tx.valid:
+            # the general signer loop where the account is held by signers
+            valid = [s.attrs for s in spans if s.name == "tx.valid"]
+            assert valid == [{"sigs": sigs, "keys": keys}] * 3
 
     def test_every_span_of_a_close_carries_its_ledger(self, traced_closes):
-        _mode, closes = traced_closes
+        _traffic, closes = traced_closes
         for seq, spans in closes:
             under = [s for s in spans if s.name != "ledger.close"]
             assert under and all(s.req == seq for s in spans), {
                 (s.name, s.req) for s in spans if s.req != seq
             }
-            # the shard threads' and the prewarm worker's spans included
+            # the prewarm worker's spans included
             (close,) = [s for s in spans if s.name == "ledger.close"]
             assert {s.tid for s in spans} != {close.tid}
 
     def test_sampling_picks_the_same_indices_on_every_run(self, traced_closes):
         from stellar_tpu.tx.frame import TX_SAMPLE_STRIDE
 
-        _mode, closes = traced_closes
+        _traffic, closes = traced_closes
         picks = [
             sorted(s.attrs["index"] for s in spans if s.name == "tx.apply")
             for _seq, spans in closes
@@ -573,7 +593,7 @@ class TestCloseFromInside:
     def test_sampled_children_lie_in_the_transaction_in_order(self, traced_closes):
         # tx.apply's own time is what is left: the deltas' commits, the
         # result pair, the history row
-        _mode, closes = traced_closes
+        _traffic, closes = traced_closes
         for _seq, spans in closes:
             for tx in (s for s in spans if s.name == "tx.apply"):
                 parts = sorted((s for s in spans if s.parent == tx.sid), key=lambda s: s.start)
@@ -587,14 +607,14 @@ class TestCloseFromInside:
 
         from stellar_tpu.tx.frame import TX_SAMPLE_STRIDE
 
-        _mode, closes = traced_closes
+        _traffic, closes = traced_closes
         budget = lambda txs: 64 + 4 * math.ceil(txs / 64)  # noqa: E731
         for _seq, spans in closes:
             new = [s for s in spans if s.name in NEW_CLOSE_SPANS]
-            assert {s.name for s in spans} - NEW_CLOSE_SPANS - {"apply.group"} <= {
+            assert {s.name for s in spans} - NEW_CLOSE_SPANS <= {
                 "ledger.close", "close.sig_flush", "close.fees", "close.apply",
-                "close.commit", "close.pipeline.dispatch", "apply.partition",
-                "apply.merge", "sig.flush_async", "sig.flush",
+                "close.commit", "close.pipeline.dispatch",
+                "sig.flush_async", "sig.flush",
             } | {s.name for s in spans if s.name.startswith("invariant.")}
             assert len(new) <= budget(CLOSE_TXS)
             fixed = len([s for s in new if not s.name.startswith("tx.")])
