@@ -60,10 +60,11 @@ class EntryCache:
     def put_owned(self, key: bytes, entry: Optional[LedgerEntry]):
         """Store without copying — the caller relinquishes ownership and
         must not mutate `entry` afterwards."""
+        _retire(self._map.get(key))
         self._map[key] = entry
         self._map.move_to_end(key)
         while len(self._map) > self.CAPACITY:
-            self._map.popitem(last=False)
+            _retire(self._map.popitem(last=False)[1])
 
     def contains(self, key: bytes) -> bool:
         """Membership probe without touching hit/miss counters or LRU
@@ -77,10 +78,21 @@ class EntryCache:
         return self._map.get(key)
 
     def erase(self, key: bytes):
-        self._map.pop(key, None)
+        _retire(self._map.pop(key, None))
 
     def clear(self):
+        for entry in self._map.values():
+            _retire(entry)
         self._map.clear()
+
+
+def _retire(entry: Optional[LedgerEntry]) -> None:
+    """A line leaves the cache: cut its memoized readonly frame
+    (``AccountFrame.load_account``), which points back at the entry — as a
+    cycle a replaced line (one a transaction, every close) waited for a full
+    collector pass; cut, it is freed with its last reader."""
+    if entry is not None:
+        entry.__dict__.pop("_ro_frame", None)
 
 
 def key_bytes(key: LedgerKey) -> bytes:

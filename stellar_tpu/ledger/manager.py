@@ -12,7 +12,7 @@ from typing import Callable, Dict, List, Optional
 
 from ..crypto import sha256
 from ..crypto.keys import SecretKey
-from ..util import fs, xlog
+from ..util import collector, fs, xlog
 from ..xdr.base import xdr_copy, XdrError
 from ..xdr.ledger import (
     LedgerHeader,
@@ -381,6 +381,10 @@ class LedgerManager:
             ):
                 raise RuntimeError("corrupt transaction set")
             self._close_ledger_txn(ledger_data)
+            # the ledger boundary: the one place the node runs a full
+            # collector pass, when one is due — inside ledger.close, so the
+            # close pays for it
+            collector.ledger_boundary()
             tracer.end(close_sp)
         except BaseException:
             # the span leaves this thread's stack with whatever the failed

@@ -14,7 +14,7 @@ from ..crypto import make_backend, sha256
 from ..database.database import Database
 from ..history.manager import HistoryManager
 from ..ledger.manager import LedgerManager
-from ..util import MetricsRegistry, TmpDirManager, VirtualClock, xlog
+from ..util import MetricsRegistry, TmpDirManager, VirtualClock, collector, xlog
 from .config import Config
 from .persistentstate import (
     K_DATABASE_INITIALIZED,
@@ -145,6 +145,9 @@ class Application:
         # flood, loadgen, catchup replay) routes through here
         app.ingest = IngestPlane(app)
         app.command_handler = CommandHandler(app)
+        # a node schedules the collector's full passes itself, at ledger
+        # boundaries (util/collector.py); given back in graceful_stop
+        collector.install(app.tracer)
         return app
 
     def _needs_initialization(self) -> bool:
@@ -235,6 +238,7 @@ class Application:
         if self.process_manager is not None:
             self.process_manager.shutdown()
         self.database.close()
+        collector.release(self.tracer)
 
     def time_now(self) -> int:
         """Current time as unix seconds on this app's clock
@@ -252,6 +256,15 @@ class Application:
     def herder_notify_ledger_closed(self) -> None:
         if self.herder is not None:
             self.herder.ledger_closed()
+
+    def collector_idle_check(self) -> None:
+        """From the overlay's tick: a node that closes nothing (syncing,
+        idle, flooded by peers) still runs the full pass when it is due."""
+        collector.idle_check()
+
+    def collector_stats(self) -> dict:
+        """``/info`` ``collector``: process-wide, like the collector."""
+        return collector.stats()
 
     def request_catchup(self) -> None:
         if self.herder is not None:

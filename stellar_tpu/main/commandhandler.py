@@ -225,6 +225,9 @@ class CommandHandler:
             # the device as JAX reports it, the kernel lowering, and the
             # dispatch / cutover / wedge-latch counters
             "sig_backend": app.sig_backend.stats(),
+            # the full collector passes of this process and how many of
+            # them the node's own schedule ran at a ledger boundary
+            "collector": app.collector_stats(),
         }
         return {"info": info}
 

@@ -108,6 +108,7 @@ class OverlayManager:
         """Top up outbound connections (OverlayManagerImpl.cpp:215)."""
         if self._shutting_down:
             return
+        self.app.collector_idle_check()
         cfg = self.app.config
         need = cfg.TARGET_PEER_CONNECTIONS - len(self.peers)
         if need > 0:

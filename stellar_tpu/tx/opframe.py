@@ -8,6 +8,7 @@ Threshold categories (transactions/readme.md "Thresholds"):
 
 from __future__ import annotations
 
+import weakref
 from typing import Optional
 
 from ..ledger.accountframe import AccountFrame
@@ -58,8 +59,16 @@ class OperationFrame:
     def __init__(self, op: Operation, result: OperationResult, parent_tx):
         self.operation = op
         self.result = result
-        self.parent_tx = parent_tx
+        # weak: the transaction frame owns its operation frames.  A strong
+        # reference back made every frame cyclic garbage (envelope, results,
+        # loaded accounts: 26 objects a payment), which only a full collector
+        # pass could free; now a set dies when its last holder lets go
+        self._parent_tx = weakref.ref(parent_tx)
         self.source_account: Optional[AccountFrame] = None
+
+    @property
+    def parent_tx(self):
+        return self._parent_tx()
 
     # -- factory (OperationFrame::makeHelper) ------------------------------
     # built lazily ONCE: the op modules import this one, so the mapping

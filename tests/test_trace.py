@@ -803,6 +803,68 @@ class TestCommandHandlerTrace:
             app.graceful_stop()
 
 
+class TestCollectorTrace:
+    """Every full collector pass is one `gc.full` span, under whatever span
+    is open, and `/info` counts them (`util/collector.py`)."""
+
+    COUNTERS = {"full_passes", "full_pass_s", "boundary_checks", "boundary_passes", "closes_since_full"}
+
+    def test_gc_full_under_ledger_close_and_the_info_block(self, clock, monkeypatch):
+        import gc
+
+        from stellar_tpu.main.application import Application
+        from stellar_tpu.tx import testutils as T
+        from stellar_tpu.util import collector
+
+        cfg = T.get_test_config(94)
+        cfg.HTTP_PORT = 0
+        app = Application.create(clock, cfg, new_db=True)
+        try:
+            info = lambda: app.command_handler.execute("/info")["info"]["collector"]  # noqa: E731
+            before = info()
+            assert set(before) == self.COUNTERS
+            lm = app.ledger_manager
+            # a boundary at which no pass is due leaves no span
+            monkeypatch.setattr(collector, "_due", lambda: False)
+            T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5)
+            assert not [s for s in app.tracer.spans() if s.name == "gc.full"]
+            assert info()["boundary_checks"] == before["boundary_checks"] + 1
+            assert info()["boundary_passes"] == before["boundary_passes"]
+            # one at which it is due: the pass runs inside ledger.close,
+            # after the commit, and carries the close's ledger
+            app.tracer.clear()
+            monkeypatch.setattr(collector, "_due", lambda: True)
+            T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5)
+            spans = app.tracer.spans()
+            (close,) = [s for s in spans if s.name == "ledger.close"]
+            (commit,) = [s for s in spans if s.name == "close.commit"]
+            (full,) = [s for s in spans if s.name == "gc.full"]
+            assert full.parent == close.sid
+            assert full.req == close.req == lm.last_closed.header.ledgerSeq
+            assert full.attrs["cause"] == "boundary"
+            assert {"collected", "uncollectable"} <= set(full.attrs)
+            assert commit.end <= full.start and full.end <= close.end
+            after = info()
+            assert after["full_passes"] == before["full_passes"] + 1
+            assert after["boundary_checks"] == before["boundary_checks"] + 2
+            assert after["boundary_passes"] == before["boundary_passes"] + 1
+            assert after["closes_since_full"] == 0
+            assert after["full_pass_s"] > before["full_pass_s"]
+            # a pass somebody else asked for, under no span: nobody's child
+            app.tracer.clear()
+            gc.collect()
+            (full,) = [s for s in app.tracer.spans() if s.name == "gc.full"]
+            assert (full.parent, full.req, full.attrs["cause"]) == (None, None, "explicit")
+            assert info()["full_passes"] == after["full_passes"] + 1
+            assert "trace.gc.full" in app.metrics.to_json()
+        finally:
+            app.graceful_stop()
+        # given back: the passes of a process without a node leave no span
+        app.tracer.clear()
+        gc.collect()
+        assert app.tracer.spans() == []
+
+
 class TestOverhead:
     """The tracer must be cheap enough to leave on (a few µs per span) and
     free when off — guards the hot path against silent regressions."""
