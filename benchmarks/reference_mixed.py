@@ -1,14 +1,23 @@
-"""A reference apply by plain arithmetic (ROADMAP A11).
+"""The plain reference of ``mixed1000``: a ledger by plain arithmetic for the
+operations LoadGenerator's mix applies, and readers of what the node stored.
 
-It shares nothing with ``stellar_tpu/ledger`` or ``stellar_tpu/tx``: plain
-integers, dicts and sorted lists.  A ledger is
+The arithmetic is the benchmark's own copy of the repo's plain ledger
+(``tests/reference_apply.py``, ROADMAP A11; tier-1 holds the two to equal
+states and codes on seeded sets): plain integers, dicts and sorted lists,
+nothing imported from ``stellar_tpu``.  The readers open the node's database
+file with ``sqlite3`` alone: the four entry tables, and ``txhistory`` for the
+apply order (``txindex``) and the stored result codes, whose XDR is walked by
+hand (``result_codes_of``).
 
-- ``accounts``   ``{account: [balance, seq]}``, with beside it ``subentries``
-  ``{account: count}``, ``signers`` ``{account: {key: weight}}``,
-  ``thresholds`` ``{account: (master, low, medium, high)}`` and ``flags``;
-- ``trustlines`` ``{(account, asset): [balance, limit, authorised]}``;
-- ``offers``     ``{offer id: (seller, selling, buying, amount, n, d)}``;
-- a fee pool and an id pool.
+``replay`` feeds the plain ledger every closed set in the order the node
+applied it — the order is consensus's, an input — after checking that it is
+a permutation of the set that keeps each account's sequence numbers in
+order, and returns what the comparison counts.
+
+A ledger is ``accounts`` ``{account: [balance, seq]}`` with ``subentries``,
+``signers``, ``thresholds`` and ``flags`` beside it, ``trustlines``
+``{(account, asset): [balance, limit, authorised]}``, ``offers`` ``{id:
+(seller, selling, buying, amount, n, d)}``, a fee pool and an id pool.
 
 An asset is ``None`` (native) or ``(code, issuer)``.  A transaction is a
 source, a sequence number, a fee, the keys that signed it, and operations:
@@ -167,6 +176,9 @@ The apply order of a set is an input: consensus fixes it (``TxSetFrame
 
 from __future__ import annotations
 
+import base64
+import sqlite3
+import struct
 from dataclasses import dataclass
 from fractions import Fraction
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -672,3 +684,164 @@ class Ledger:
             del mine[key]
             self._sub_entry(source, -1)
         self._put(self.signers, source, mine)
+
+
+# -- what the node stored, by sqlite3 alone ----------------------------------------------
+
+TX_CODES = {
+    0: "txSUCCESS", -1: "txFAILED", -2: "txTOO_EARLY", -3: "txTOO_LATE", -4: "txMISSING_OPERATION",
+    -5: "txBAD_SEQ", -6: "txBAD_AUTH", -7: "txINSUFFICIENT_BALANCE", -8: "txNO_ACCOUNT",
+    -9: "txINSUFFICIENT_FEE", -10: "txBAD_AUTH_EXTRA", -11: "txINTERNAL_ERROR",
+}
+OP_CODES = {-1: "opBAD_AUTH", -2: "opNO_ACCOUNT"}
+# operation type -> (prefix, names of its result codes 0, -1, -2, ...; whether
+# a result carries more than its code, after which this walk cannot go on)
+INNER_CODES = {
+    0: ("CREATE_ACCOUNT", ("SUCCESS", "MALFORMED", "UNDERFUNDED", "LOW_RESERVE", "ALREADY_EXIST"), False),
+    1: ("PAYMENT", ("SUCCESS", "MALFORMED", "UNDERFUNDED", "SRC_NO_TRUST", "SRC_NOT_AUTHORIZED",
+                    "NO_DESTINATION", "NO_TRUST", "NOT_AUTHORIZED", "LINE_FULL", "NO_ISSUER"), False),
+    2: ("PATH_PAYMENT", ("SUCCESS", "MALFORMED", "UNDERFUNDED", "SRC_NO_TRUST", "SRC_NOT_AUTHORIZED",
+                         "NO_DESTINATION", "NO_TRUST", "NOT_AUTHORIZED", "LINE_FULL", "NO_ISSUER",
+                         "TOO_FEW_OFFERS", "OFFER_CROSS_SELF", "OVER_SENDMAX"), True),
+    3: ("MANAGE_OFFER", ("SUCCESS", "MALFORMED", "SELL_NO_TRUST", "BUY_NO_TRUST", "SELL_NOT_AUTHORIZED",
+                         "BUY_NOT_AUTHORIZED", "LINE_FULL", "UNDERFUNDED", "CROSS_SELF", "SELL_NO_ISSUER",
+                         "BUY_NO_ISSUER", "NOT_FOUND", "LOW_RESERVE"), True),
+    5: ("SET_OPTIONS", ("SUCCESS", "LOW_RESERVE", "TOO_MANY_SIGNERS", "BAD_FLAGS", "INVALID_INFLATION",
+                        "CANT_CHANGE", "UNKNOWN_FLAG", "THRESHOLD_OUT_OF_RANGE", "BAD_SIGNER",
+                        "INVALID_HOME_DOMAIN"), False),
+    6: ("CHANGE_TRUST", ("SUCCESS", "MALFORMED", "NO_ISSUER", "INVALID_LIMIT", "LOW_RESERVE"), False),
+}
+
+
+def result_codes_of(pair: bytes) -> Tuple[str, List[str]]:
+    """(transaction code, operation codes) of a TransactionResultPair's XDR:
+    the 32-byte hash, the 8-byte fee, the code, and for txSUCCESS / txFAILED
+    the operations' results — each ``opINNER`` followed by its type and its
+    own code.  A PATH_PAYMENT or MANAGE_OFFER result carries claimed offers
+    after its code, so the walk ends with it: every transaction of the cell
+    holds such an operation alone or last."""
+
+    def i32(at: int) -> int:
+        return struct.unpack_from(">i", pair, at)[0]
+
+    code = i32(40)
+    name = TX_CODES[code]
+    if code not in (0, -1):
+        return name, []
+    ops, at = [], 48
+    for _ in range(i32(44)):
+        outer = i32(at)
+        if outer != 0:
+            ops.append(OP_CODES[outer])
+            at += 4
+            continue
+        prefix, names, carries_more = INNER_CODES[i32(at + 4)]
+        ops.append(prefix + "_" + names[-i32(at + 8)])
+        at += 12
+        if carries_more:
+            break
+    return name, ops
+
+
+def _rows(db_path: str, sql: str) -> list:
+    con = sqlite3.connect(f"file:{db_path}?mode=ro", uri=True)
+    try:
+        return con.execute(sql).fetchall()
+    finally:
+        con.close()
+
+
+def _asset(kind: int, code, issuer):
+    return None if kind == 0 else (code, issuer)
+
+
+def stored_history(db_path: str) -> Dict[int, List[Tuple[str, Tuple[str, List[str]]]]]:
+    """ledger sequence -> [(txid, (code, operation codes))] in apply order."""
+    out: Dict[int, list] = {}
+    for seq, _index, txid, result in _rows(
+        db_path, "SELECT ledgerseq, txindex, txid, txresult FROM txhistory ORDER BY ledgerseq, txindex"
+    ):
+        out.setdefault(seq, []).append((txid, result_codes_of(base64.b64decode(result))))
+    return out
+
+
+def stored_state(db_path: str) -> dict:
+    """The four entry tables as the plain ledger holds them."""
+    accounts = {
+        aid: (balance, seq, subs)
+        for aid, balance, seq, subs in _rows(db_path, "SELECT accountid, balance, seqnum, numsubentries FROM accounts")
+    }
+    lines = {
+        (aid, (code, issuer)): (balance, limit, bool(flags & 1))
+        for aid, issuer, code, limit, balance, flags in _rows(
+            db_path, "SELECT accountid, issuer, assetcode, tlimit, balance, flags FROM trustlines"
+        )
+    }
+    offers = {
+        oid: (seller, _asset(st, sc, si), _asset(bt, bc, bi), amount, n, d)
+        for seller, oid, st, sc, si, bt, bc, bi, amount, n, d in _rows(
+            db_path,
+            "SELECT sellerid, offerid, sellingassettype, sellingassetcode, sellingissuer,"
+            " buyingassettype, buyingassetcode, buyingissuer, amount, pricen, priced FROM offers",
+        )
+    }
+    signer_rows = _rows(db_path, "SELECT accountid, publickey, weight FROM signers")
+    signers = {(aid, key): weight for aid, key, weight in signer_rows}
+    return {
+        "accounts": accounts, "trustlines": lines, "offers": offers, "signers": signers,
+        "duplicate_signer_rows": len(signer_rows) - len(signers),
+    }
+
+
+def state_of(ledger: Ledger) -> dict:
+    """The plain ledger in the shape of ``stored_state``."""
+    return {
+        "accounts": {
+            name: (balance, seq, ledger.subentries.get(name, 0)) for name, (balance, seq) in ledger.accounts.items()
+        },
+        "trustlines": {key: tuple(line) for key, line in ledger.trustlines.items()},
+        "offers": dict(ledger.offers),
+        "signers": {(name, key): w for name, mine in ledger.signers.items() for key, w in mine.items()},
+        "duplicate_signer_rows": 0,
+    }
+
+
+def rows_off(want: dict, have: dict) -> int:
+    """Rows that differ either way: missing, extra, or with another value."""
+    return sum(1 for k, v in want.items() if have.get(k) != v) + sum(1 for k in have if k not in want)
+
+
+def order_keeps_sequences(txs: Sequence[Tx]) -> bool:
+    """Whether every account's transactions come in their sequence order."""
+    last: Dict[str, int] = {}
+    for tx in txs:
+        if last.get(tx.source, tx.seq - 1) >= tx.seq:
+            return False
+        last[tx.source] = tx.seq
+    return True
+
+
+def replay(ledger: Ledger, closed: Sequence[Tuple[int, Dict[str, Tx]]], history: dict) -> dict:
+    """Close every recorded set on ``ledger`` in the order ``txhistory``
+    gives.  ``closed``: (ledger sequence, {txid: the plain transaction}) of
+    each close.  -> ``codes_differing`` (a stored code, transaction or
+    operation, that is not the plain ledger's; a transaction the node
+    stored no row for), ``orders_refused`` (closes whose stored order is
+    not a permutation of the set that keeps sequence order: those are not
+    replayed), ``failed_at_apply``, ``txs``."""
+    differing = refused = failed = total = 0
+    for seq, by_id in closed:
+        stored = history.get(seq, [])
+        total += len(by_id)
+        order = [by_id.get(txid) for txid, _codes in stored]
+        if len(stored) != len(by_id) or None in order or len({t for t, _ in stored}) != len(stored) \
+                or not order_keeps_sequences(order):
+            refused += 1
+            differing += len(by_id)
+            continue
+        for want, (_txid, have) in zip(ledger.close(seq, order), stored):
+            if want != have:
+                differing += 1
+            if want[0] != "txSUCCESS":
+                failed += 1
+    return {"codes_differing": differing, "orders_refused": refused, "failed_at_apply": failed, "txs": total}

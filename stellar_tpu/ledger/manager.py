@@ -70,6 +70,12 @@ class LedgerManager:
         self.last_closed: Optional[LastClosedLedger] = None
         self._close_timer = app.metrics.new_timer(("ledger", "ledger", "close"))
         self._flush_timer = app.metrics.new_timer(("ledger", "store", "flush"))
+        # /info "exchange": what the order book did since the node started
+        # (tx/offerexchange.py) and the transactions that failed at apply
+        self.exchange_stats = {
+            "conversions": 0, "offers_crossed": 0, "book_pages": 0,
+            "book_rows": 0, "txs_failed_at_apply": 0,
+        }
         self._tx_apply_timer = app.metrics.new_timer(
             ("ledger", "transaction", "apply")
         )
@@ -527,8 +533,9 @@ class LedgerManager:
                     flush_sp = tracer.begin("commit.flush")
                     with self._flush_timer.time_scope():
                         written = buf.flush(self.database)
-                    # account_rows, signer_rows, signer_accounts: what the
-                    # flush wrote (signer rows only where a store changed them)
+                    # account_rows, signer_rows, signer_accounts, trust_rows,
+                    # offer_rows: what the flush wrote or deleted (signer
+                    # rows only where a store changed them)
                     tracer.end(flush_sp, **written)
             finally:
                 # success: overlay already flushed (deactivate clears
@@ -616,12 +623,16 @@ class LedgerManager:
         seq = self.current.header.ledgerSeq
         tracer = self.app.tracer
         skip = TX_SAMPLE_STRIDE - 1
-        with tracer.span("apply.serial", txs=len(txs)):
+        failed = 0
+        with tracer.span("apply.serial", txs=len(txs)) as serial_sp:
             for index, tx in enumerate(txs):
                 # one transaction in TX_SAMPLE_STRIDE records tx.apply and
                 # its children; the others get the no-op tracer
                 tx_tracer = NULL_TRACER if index & skip else tracer
-                with tx_tracer.span("tx.apply", index=index):
+                with tx_tracer.span("tx.apply", index=index) as apply_sp:
+                    if apply_sp is not None:
+                        # which operation the sample timed (the first one's type)
+                        apply_sp.attrs["op"] = tx.envelope.tx.operations[0].body.type.name
                     with self._tx_apply_timer.time_scope():
                         delta = LedgerDelta(outer=ledger_delta)
                         meta = TransactionMeta(0, [])
@@ -629,6 +640,7 @@ class LedgerManager:
                             if tx.apply(delta, self.app, meta, tx_tracer):
                                 delta.commit()
                             else:
+                                failed += 1
                                 assert not delta.get_changes()
                         except UnrollbackableWrite:
                             # the SQL plane could not be unwound for this tx — DB
@@ -639,6 +651,7 @@ class LedgerManager:
                         except Exception as e:  # tx must never take down the close
                             log.error("exception during tx apply: %s", e)
                             tx.set_result_code(TransactionResultCode.txINTERNAL_ERROR)
+                            failed += 1
                     self._tx_count_meter.mark()
                     pair = tx.get_result_pair()
                     tx_result_set.results.append(pair)
@@ -647,6 +660,10 @@ class LedgerManager:
                     )
             # the set's history rows in one encode call
             rows = tx_history.transaction_rows(seq, blobs)
+            self.exchange_stats["txs_failed_at_apply"] += failed
+            if serial_sp is not None:
+                # fee kept, sequence number taken, effects unwound
+                serial_sp.attrs["failed"] = failed
         with tracer.span("apply.rows", rows=len(rows)):
             tx_history.insert_transaction_rows(self.database, rows)
 

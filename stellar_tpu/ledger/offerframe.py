@@ -164,11 +164,17 @@ class OfferFrame(EntryFrame):
 
     @classmethod
     def load_best_offers(
-        cls, num: int, offset: int, selling: Asset, buying: Asset, db
+        cls, num: int, offset: int, selling: Asset, buying: Asset, db,
+        tally: Optional[dict] = None,
     ) -> List["OfferFrame"]:
         """Offers selling `selling` for `buying`, cheapest first
         (OfferFrame::loadBestOffers; order by price then offerid for
-        determinism — consensus-critical!)."""
+        determinism — consensus-critical!).  Inside a close the table is
+        behind the close's write-back buffer: the page is the SQL scan with
+        every offer the buffer holds taken out and the buffer's own pending
+        offers of this book merged in.  ``tally``, where given, has its
+        ``pages`` raised by one and its ``rows`` by the rows the SELECT
+        returned plus the pending entries walked (``op.exchange``)."""
         satype, saissuer, sacode = asset_to_cols(selling)
         batype, baissuer, bacode = asset_to_cols(buying)
         cond_s = (
@@ -195,6 +201,9 @@ class OfferFrame(EntryFrame):
                     "ORDER BY price, offerid LIMIT ? OFFSET ?",
                     params + [num, offset],
                 )
+            if tally is not None:
+                tally["pages"] += 1
+                tally["rows"] += len(rows)
             return [cls._row_to_frame(r) for r in rows]
 
         # overlay merge: the buffer is authoritative for every touched
@@ -210,6 +219,9 @@ class OfferFrame(EntryFrame):
                 "ORDER BY price, offerid LIMIT ?",
                 params + [offset + num + len(touched)],
             )
+        if tally is not None:
+            tally["pages"] += 1
+            tally["rows"] += len(rows) + len(pending_entries)
         # the SQL sort key is (price DOUBLE, offerid) where price was
         # computed as n/d in Python at write time (_sql_row) — recomputing
         # it for pending entries gives the identical IEEE double, so the
@@ -298,7 +310,7 @@ class OfferFrame(EntryFrame):
 
     # -- store-buffer flush (ledger/storebuffer.py) ------------------------
     @classmethod
-    def upsert_batch(cls, db, entries, _signers_dirty) -> None:
+    def upsert_batch(cls, db, entries, _signers_dirty) -> dict:
         rows = [
             cls._sql_row(e.data.value, e.lastModifiedLedgerSeq)
             for e in entries
@@ -309,11 +321,13 @@ class OfferFrame(EntryFrame):
                 " VALUES (?,?,?,?,?,?,?,?,?,?,?,?,?,?)",
                 rows,
             )
+        return {"offer_rows": len(rows)}
 
     @classmethod
-    def delete_batch(cls, db, keys) -> None:
+    def delete_batch(cls, db, keys) -> dict:
         with db.timed("flush", "offer"):
             db.executemany(
                 "DELETE FROM offers WHERE offerid=?",
                 [(k.value.offerID,) for k in keys],
             )
+        return {"offer_rows": len(keys)}

@@ -166,9 +166,11 @@ class EntryStoreBuffer:
         an enclosing rollback undoes them via SQL while the undo log
         restores the overlay, keeping both planes consistent.
 
-        -> the row counts the frame classes' ``upsert_batch`` report
-        (``AccountFrame``: ``account_rows``, ``signer_rows``,
-        ``signer_accounts``), summed."""
+        -> the row counts the frame classes' ``upsert_batch`` and
+        ``delete_batch`` report (``AccountFrame``: ``account_rows``,
+        ``signer_rows``, ``signer_accounts``; ``TrustFrame``:
+        ``trust_rows``; ``OfferFrame``: ``offer_rows``, rows written plus
+        rows deleted), summed."""
         written: Dict[str, int] = {}
         if not self._overlay:
             return written
@@ -189,10 +191,13 @@ class EntryStoreBuffer:
                 ups.append(entry)
                 dirty.append(signers_dirty)
         for cls, (ups, dirty, dels) in by_cls.items():
+            counts = []
             if dels:
-                cls.delete_batch(db, dels)
+                counts.append(cls.delete_batch(db, dels))
             if ups:
-                for k, n in (cls.upsert_batch(db, ups, dirty) or {}).items():
+                counts.append(cls.upsert_batch(db, ups, dirty))
+            for reported in counts:
+                for k, n in (reported or {}).items():
                     written[k] = written.get(k, 0) + n
         self._overlay.clear()
         self._offer_keys.clear()

@@ -275,7 +275,7 @@ class TrustFrame(EntryFrame):
 
     # -- store-buffer flush (ledger/storebuffer.py) ------------------------
     @classmethod
-    def upsert_batch(cls, db, entries, _signers_dirty) -> None:
+    def upsert_batch(cls, db, entries, _signers_dirty) -> dict:
         rows = [
             cls._sql_row(e.data.value, e.lastModifiedLedgerSeq)
             for e in entries
@@ -287,9 +287,10 @@ class TrustFrame(EntryFrame):
                 " VALUES (?,?,?,?,?,?,?,?)",
                 rows,
             )
+        return {"trust_rows": len(rows)}
 
     @classmethod
-    def delete_batch(cls, db, keys) -> None:
+    def delete_batch(cls, db, keys) -> dict:
         rows = []
         for k in keys:
             _, issuer, code = asset_to_cols(k.value.asset)
@@ -300,3 +301,4 @@ class TrustFrame(EntryFrame):
                 " AND assetcode=?",
                 rows,
             )
+        return {"trust_rows": len(rows)}

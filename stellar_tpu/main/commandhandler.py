@@ -228,6 +228,9 @@ class CommandHandler:
             # the full collector passes of this process and how many of
             # them the node's own schedule ran at a ledger boundary
             "collector": app.collector_stats(),
+            # the order book's work and the transactions that failed at
+            # apply, since the node started (monotonic)
+            "exchange": dict(lm.exchange_stats),
         }
         return {"info": info}
 

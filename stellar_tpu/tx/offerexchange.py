@@ -4,6 +4,16 @@ Terminology follows the reference: the taker sends "sheep" to receive
 "wheat" from resting offers that sell wheat for sheep.  All division is
 floor((a*b)/c) on 128-bit-wide intermediates (util/xmath.big_divide) — the
 rounding direction is consensus-critical ("bias towards seller").
+
+Inside a close every store here is write-back: the crossed offer, its
+seller's account and lines land in the close's ``EntryStoreBuffer``
+(ledger/storebuffer.py) and reach SQL at ``commit.flush``; the book is read
+through that buffer (``OfferFrame.load_best_offers``).
+
+One conversion is one ``op.exchange`` span (``crossed``: offers taken or
+reduced; ``pages``: ``load_best_offers`` calls; ``rows``: rows the book's
+SELECT returned plus pending entries walked) and one step of the
+``exchange`` counters on ``/info`` (``LedgerManager.exchange_stats``).
 """
 
 from __future__ import annotations
@@ -182,6 +192,26 @@ class OfferExchange:
     ):
         """-> (ConvertResult, sheep_sent, wheat_received); walks the book
         cheapest-first in pages of 5 (convertWithOffers)."""
+        tally = {"pages": 0, "rows": 0}
+        trail = len(self.offer_trail)
+        tracer = self.lm.app.tracer
+        sp = tracer.begin("op.exchange")
+        try:
+            return self._walk_book(
+                sheep, max_sheep_send, wheat, max_wheat_receive, offer_filter, tally
+            )
+        finally:
+            crossed = len(self.offer_trail) - trail
+            stats = self.lm.exchange_stats
+            stats["conversions"] += 1
+            stats["offers_crossed"] += crossed
+            stats["book_pages"] += tally["pages"]
+            stats["book_rows"] += tally["rows"]
+            tracer.end(sp, crossed=crossed, **tally)
+
+    def _walk_book(
+        self, sheep, max_sheep_send, wheat, max_wheat_receive, offer_filter, tally
+    ):
         sheep_sent = 0
         wheat_received = 0
         db = self.lm.database
@@ -189,7 +219,9 @@ class OfferExchange:
         need_more = max_wheat_receive > 0 and max_sheep_send > 0
 
         while need_more:
-            batch = OfferFrame.load_best_offers(5, offer_offset, wheat, sheep, db)
+            batch = OfferFrame.load_best_offers(
+                5, offer_offset, wheat, sheep, db, tally
+            )
             offer_offset += len(batch)
             for wheat_offer in batch:
                 if offer_filter is not None:

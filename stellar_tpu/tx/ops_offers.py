@@ -169,9 +169,9 @@ class ManageOfferOpFrame(OperationFrame):
         )
 
         stop_code = []
+        temp_delta = LedgerDelta(outer=delta)
         try:
             with db.transaction():
-                temp_delta = LedgerDelta(outer=delta)
                 if mo.amount == 0:
                     sell_offer.mut().amount = 0
                 else:
@@ -281,6 +281,14 @@ class ManageOfferOpFrame(OperationFrame):
                 temp_delta.commit()
         except _OfferAbort:
             return False
+        finally:
+            # the savepoint took back the rows and the buffered writes of a
+            # failed offer's crossings; the stores also wrote the decoded-
+            # entry cache, under keys only temp_delta knows — the reference
+            # gets this from ~LedgerDelta (LedgerDelta.cpp:39-44); without
+            # it a later load of a crossed seller reads the aborted state.
+            # No-op when committed.
+            temp_delta.rollback()
 
         metrics.new_meter(("op-create-offer", "success", "apply"), "operation").mark()
         return True

@@ -556,7 +556,11 @@ class TestCloseFromInside:
         traffic, closes = traced_closes
         sigs, keys = TRAFFIC[traffic]
         for _seq, spans in closes:
-            assert [s.attrs for s in spans if s.name == "apply.serial"] == [{"txs": CLOSE_TXS}]
+            # the set, and how many of it failed at apply (fee kept, effects unwound)
+            failed = sum(1 for i in range(CLOSE_TXS) if _failing(traffic, i))
+            assert [s.attrs for s in spans if s.name == "apply.serial"] == [{"txs": CLOSE_TXS, "failed": failed}]
+            # the sample says which operation it timed
+            assert {s.attrs["op"] for s in spans if s.name == "tx.apply"} == {"PAYMENT"}
             assert [s.attrs for s in spans if s.name == "apply.rows"] == [{"rows": CLOSE_TXS}]
             # one collection a close; every signature's hint finds one key
             assert [s.attrs for s in spans if s.name == "sig.collect"] == [{
@@ -624,6 +628,13 @@ class TestCloseFromInside:
         for txs in (1000, 5000):
             worst = 9 + 3 * math.ceil(txs / TX_SAMPLE_STRIDE)
             assert worst <= budget(txs), txs
+        # a close that meets the order book adds one ``op.exchange`` a
+        # conversion (tests/test_mixed_close.py counts them): at
+        # ``mixed1000``'s mix under a fifth of a set — a path payment or an
+        # arriving offer in 5.5 of 100 transactions each way, two
+        # conversions for one path in ten
+        mixed = 9 + 3 * math.ceil(1000 / TX_SAMPLE_STRIDE) + math.ceil(1000 * (0.075 * 1.1 + 0.10))
+        assert mixed <= budget(1000) + 200
 
 
 class TestFrontDoorCounters:
