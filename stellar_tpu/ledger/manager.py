@@ -74,7 +74,7 @@ class LedgerManager:
         # (tx/offerexchange.py) and the transactions that failed at apply
         self.exchange_stats = {
             "conversions": 0, "offers_crossed": 0, "book_pages": 0,
-            "book_rows": 0, "txs_failed_at_apply": 0,
+            "book_rows": 0, "book_side_loads": 0, "txs_failed_at_apply": 0,
         }
         self._tx_apply_timer = app.metrics.new_timer(
             ("ledger", "transaction", "apply")

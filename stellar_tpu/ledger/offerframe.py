@@ -1,4 +1,13 @@
-"""OfferFrame: offers table + order-book queries (reference: src/ledger/OfferFrame.*)."""
+"""OfferFrame: offers table + order-book queries (reference: src/ledger/OfferFrame.*).
+
+``load_best_offers`` has two ways to a page.  With no store buffer active
+(outside a close, and in the write-through reference mode of the
+differential tests) it is the reference's scan, ``ORDER BY price, offerid
+LIMIT ? OFFSET ?``.  Inside a close it asks the close's ``EntryStoreBuffer``
+for a slice of its view of the side (ledger/storebuffer.py ``book_page``):
+SQL is read once a (side, close), with no ``LIMIT``, and the pending offers
+of the close are merged in from the buffer's own index.
+"""
 
 from __future__ import annotations
 
@@ -18,7 +27,7 @@ from ..xdr.entries import (
 from ..xdr.base import xdr_copy
 from ..xdr.ledger import LedgerKey, LedgerKeyOffer
 from .entryframe import EntryFrame, key_bytes
-from .storebuffer import active_buffer
+from .storebuffer import active_buffer, book_of
 from .trustframe import asset_from_cols, asset_to_cols
 
 
@@ -162,19 +171,10 @@ class OfferFrame(EntryFrame):
         cls.store_in_cache(db, key, frame.entry)
         return frame
 
-    @classmethod
-    def load_best_offers(
-        cls, num: int, offset: int, selling: Asset, buying: Asset, db,
-        tally: Optional[dict] = None,
-    ) -> List["OfferFrame"]:
-        """Offers selling `selling` for `buying`, cheapest first
-        (OfferFrame::loadBestOffers; order by price then offerid for
-        determinism — consensus-critical!).  Inside a close the table is
-        behind the close's write-back buffer: the page is the SQL scan with
-        every offer the buffer holds taken out and the buffer's own pending
-        offers of this book merged in.  ``tally``, where given, has its
-        ``pages`` raised by one and its ``rows`` by the rows the SELECT
-        returned plus the pending entries walked (``op.exchange``)."""
+    @staticmethod
+    def _book_where(selling: Asset, buying: Asset):
+        """-> (condition, parameters) of the rows that sell `selling` for
+        `buying`."""
         satype, saissuer, sacode = asset_to_cols(selling)
         batype, baissuer, bacode = asset_to_cols(buying)
         cond_s = (
@@ -189,15 +189,31 @@ class OfferFrame(EntryFrame):
         )
         params: list = [satype] if selling.is_native() else [satype, saissuer, sacode]
         params += [batype] if buying.is_native() else [batype, baissuer, bacode]
+        return f"{cond_s} AND {cond_b}", params
 
+    @classmethod
+    def load_best_offers(
+        cls, num: int, offset: int, selling: Asset, buying: Asset, db,
+        tally: Optional[dict] = None,
+    ) -> List["OfferFrame"]:
+        """Offers selling `selling` for `buying`, cheapest first
+        (OfferFrame::loadBestOffers; order by price then offerid for
+        determinism — consensus-critical!).  Inside a close the table is
+        behind the close's write-back buffer, and the page is a slice of
+        the buffer's view of this side (``EntryStoreBuffer.book_page``):
+        the side is read from SQL once a close, whole, and every later
+        page of it costs no SELECT.  Every frame handed out is freshly
+        decoded or copied: the exchange mutates it in place.  ``tally``,
+        where given, has its ``pages`` raised by one, its ``rows`` by the
+        rows a SELECT returned plus the pending entries the page looked
+        at, and its ``side_loads`` by one where the side was read
+        (``op.exchange``)."""
         buf = active_buffer(db)
-        touched = None
-        if buf is not None:
-            pending_entries, touched = buf.pending_offers()
-        if not touched:
+        if buf is None:
+            where, params = cls._book_where(selling, buying)
             with db.timed("select", "offer"):
                 rows = db.query_all(
-                    f"SELECT {cls._COLS} FROM offers WHERE {cond_s} AND {cond_b} "
+                    f"SELECT {cls._COLS} FROM offers WHERE {where} "
                     "ORDER BY price, offerid LIMIT ? OFFSET ?",
                     params + [num, offset],
                 )
@@ -206,38 +222,29 @@ class OfferFrame(EntryFrame):
                 tally["rows"] += len(rows)
             return [cls._row_to_frame(r) for r in rows]
 
-        # overlay merge: the buffer is authoritative for every touched
-        # offerid, so drop those rows from the SQL scan and splice the
-        # pending upserts in.  Over-fetch by len(touched) so the merged
-        # window [offset, offset+num) is still fully covered after the
-        # exclusions (OfferExchange pages with a cursor offset that
-        # assumes crossed offers vanish — with buffered deletes they
-        # vanish from the merged view instead of the table).
-        with db.timed("select", "offer"):
-            rows = db.query_all(
-                f"SELECT {cls._COLS} FROM offers WHERE {cond_s} AND {cond_b} "
-                "ORDER BY price, offerid LIMIT ?",
-                params + [offset + num + len(touched)],
-            )
+        def load_side():
+            where, params = cls._book_where(selling, buying)
+            with db.timed("select", "offer"):
+                rows = db.query_all(
+                    f"SELECT {cls._COLS} FROM offers WHERE {where} "
+                    "ORDER BY price, offerid",
+                    params,
+                )
+            return [(r[11], r[1], r, None) for r in rows]
+
+        page, loaded, pending = buf.book_page(
+            book_of(selling, buying), num, offset, load_side
+        )
         if tally is not None:
             tally["pages"] += 1
-            tally["rows"] += len(rows) + len(pending_entries)
-        # the SQL sort key is (price DOUBLE, offerid) where price was
-        # computed as n/d in Python at write time (_sql_row) — recomputing
-        # it for pending entries gives the identical IEEE double, so the
-        # merged order matches what the write-through table scan would
-        # have returned (consensus-critical).  Sort raw and slice BEFORE
-        # decoding: only the <=num surviving rows pay _row_to_frame, not
-        # the whole offset+num+touched over-fetch on every cursor page.
-        merged = [((r[11], r[1]), r, None) for r in rows if r[1] not in touched]
-        for e in pending_entries:
-            o = e.data.value
-            if o.selling == selling and o.buying == buying:
-                merged.append(((o.price.n / o.price.d, o.offerID), None, e))
-        merged.sort(key=lambda t: t[0])
+            tally["rows"] += pending
+            if loaded is not None:
+                tally["rows"] += loaded
+                tally["side_loads"] += 1
+        # only the <= num offers of the page are decoded
         return [
-            cls._row_to_frame(r) if r is not None else cls(xdr_copy(e))
-            for _, r, e in merged[offset : offset + num]
+            cls._row_to_frame(row) if row is not None else cls(xdr_copy(entry))
+            for _price, _id, row, entry in page
         ]
 
     @classmethod

@@ -14,10 +14,19 @@ This buffer makes the stores write-back instead of write-through during
   here (and, as before, in the LedgerDelta and the decoded-entry cache);
   no SQL is issued per store.
 - every keyed load / ``exists`` probe consults the buffer before SQL, and
-  ``OfferFrame.load_best_offers`` merges pending offers into the SQL
-  order-book scan — the overlay is **authoritative** for any key it
-  holds, so apply-path reads observe exactly the state the reference's
-  write-through rows would have shown.
+  ``OfferFrame.load_best_offers`` pages through ``book_page`` — the
+  overlay is **authoritative** for any key it holds, so apply-path reads
+  observe exactly the state the reference's write-through rows would have
+  shown.
+- the order book is read through a **per-close view of each side**
+  (``book_page``): the side's rows come from SQL once a (side, close), in
+  ``(price, offerid)`` order, and stay as raw tuples; no store of the close
+  changes the table, so they hold until the next flush.  The pending
+  offers are indexed as they are recorded — every pending offer id, and
+  the pending upserts grouped by book — and the undo log unwinds the index
+  with the slots.  A page is the side's rows whose id is not pending,
+  merged with the side's own pending upserts, sliced; it costs no
+  ``SELECT`` and never more than the side's depth.
 - SQL savepoints stay in charge of transactionality: ``Database``'s
   savepoint enter/rollback/release calls ``push_mark`` /
   ``rollback_mark`` / ``release_mark`` so a failed transaction unwinds
@@ -38,21 +47,42 @@ Aggregate queries that cannot read through an overlay (the inflation
 winners tally, ``AccountFrame.process_for_inflation``) call
 ``flush_through`` first: pending rows are written inside the current
 savepoint (so enclosing rollbacks still undo them via SQL) and the
-overlay empties while remaining consistent with outer marks.
+overlay empties while remaining consistent with outer marks.  The flush
+drops the book views (the table changed under them), and so does a
+rollback that crosses it (the table changes back).
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+from heapq import merge
+from itertools import islice
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from ..xdr.entries import LedgerEntry, LedgerEntryType
+from ..xdr.entries import Asset, LedgerEntry, LedgerEntryType
 from ..xdr.ledger import LedgerKey
 
 _ABSENT = object()
+# in the undo log, in a key's place, where a flush emptied the overlay into SQL
+_FLUSHED = object()
 
 # overlay value: (LedgerKey, entry-or-None (None = pending delete), frame cls,
 # signers_dirty: the account's signer rows must be written at the flush)
 _Slot = Tuple[LedgerKey, Optional[LedgerEntry], type, bool]
+
+# one offer of a book side as a page sees it: (price, offerid, the SQL row or
+# None, the pending entry or None) — ordered by the first two, which no two
+# offers of a page share
+_BookItem = Tuple[float, int, Optional[tuple], Optional[LedgerEntry]]
+
+
+def _asset_id(asset: Asset) -> tuple:
+    v = asset.value
+    return (asset.type,) if v is None else (asset.type, v.assetCode, v.issuer.value)
+
+
+def book_of(selling: Asset, buying: Asset) -> tuple:
+    """The hashable name of the book side that sells `selling` for `buying`."""
+    return _asset_id(selling), _asset_id(buying)
 
 
 class EntryStoreBuffer:
@@ -63,10 +93,16 @@ class EntryStoreBuffer:
         # indices into it, one per live SQL savepoint
         self._undo: List[Tuple[bytes, Any]] = []
         self._marks: List[int] = []
-        # OFFER-typed overlay keys, maintained incrementally — the
-        # order-book merge runs once per 5-offer page during crossing and
-        # must not rescan ~10k pending account/trust slots each time
-        self._offer_keys: set = set()
+        # the pending offers, indexed as they are recorded — a page of the
+        # order book runs once per five offers crossed and must neither
+        # rescan ~10k pending account/trust slots nor walk the offers of
+        # other books: offerid -> the book of its pending upsert (None: a
+        # pending delete), and book -> {offerid: pending entry}
+        self._offer_book: Dict[int, Optional[tuple]] = {}
+        self._book_pending: Dict[tuple, Dict[int, LedgerEntry]] = {}
+        # book -> the side's rows as SQL held them when the close first
+        # paged through it, in (price, offerid) order
+        self._sides: Dict[tuple, List[_BookItem]] = {}
         self.n_buffered_writes = 0
         self.n_flushes = 0
 
@@ -83,7 +119,12 @@ class EntryStoreBuffer:
         self._overlay.clear()
         self._undo.clear()
         self._marks.clear()
-        self._offer_keys.clear()
+        self._drop_book_views()
+
+    def _drop_book_views(self) -> None:
+        self._offer_book.clear()
+        self._book_pending.clear()
+        self._sides.clear()
 
     # -- store side (EntryFrame) -------------------------------------------
     def record(self, kb: bytes, key: LedgerKey, entry: Optional[LedgerEntry],
@@ -109,8 +150,25 @@ class EntryStoreBuffer:
             signers_dirty = True
         self._overlay[kb] = (key, entry, cls, signers_dirty)
         if key.type == LedgerEntryType.OFFER:
-            self._offer_keys.add(kb)
+            self._index_offer(key.value.offerID, entry)
         self.n_buffered_writes += 1
+
+    def _index_offer(self, offer_id: int, entry) -> None:
+        """The offer's slot now holds `entry` (None: a pending delete;
+        _ABSENT: no slot).  An update may have moved the offer to another
+        book (MANAGE_OFFER can swap its assets): it leaves the old group."""
+        was = self._offer_book.get(offer_id)
+        if was is not None:
+            del self._book_pending[was][offer_id]
+        if entry is _ABSENT:
+            self._offer_book.pop(offer_id, None)
+        elif entry is None:
+            self._offer_book[offer_id] = None
+        else:
+            o = entry.data.value
+            book = book_of(o.selling, o.buying)
+            self._offer_book[offer_id] = book
+            self._book_pending.setdefault(book, {})[offer_id] = entry
 
     # -- read side ---------------------------------------------------------
     def get(self, kb: bytes) -> Tuple[bool, Optional[LedgerEntry]]:
@@ -121,19 +179,39 @@ class EntryStoreBuffer:
             return False, None
         return True, slot[1]
 
-    def pending_offers(self):
-        """Pending offer upsert entries, plus the set of ALL offerids with
-        any pending state (upsert or delete) — the SQL order-book scan must
-        exclude the latter wholesale.  Iterates the OFFER key index only,
-        never the full (account/trust-dominated) overlay."""
-        upserts = []
-        touched = set()
-        for kb in self._offer_keys:
-            key, entry = self._overlay[kb][:2]
-            touched.add(key.value.offerID)
-            if entry is not None:
-                upserts.append(entry)
-        return upserts, touched
+    def book_page(
+        self, book: tuple, num: int, offset: int,
+        load_side: Callable[[], List[_BookItem]],
+    ) -> Tuple[List[_BookItem], Optional[int], int]:
+        """Offers [offset, offset + num) of `book` (``book_of``), cheapest
+        first, as the close sees them: the side's SQL rows whose offer the
+        overlay does not hold — it is authoritative for every pending id,
+        deletes included, wherever its row lies — merged with the pending
+        upserts of this book.
+
+        `load_side()` reads the side from SQL, whole and in (price,
+        offerid) order; it is called the first time the close pages through
+        the book and not again before the next flush.  The order is the
+        table's: the double it sorts by was computed as n / d in Python at
+        write time (``OfferFrame._sql_row``), and n / d of a pending entry
+        is the same double (consensus-critical).
+
+        -> (the page; the rows `load_side` returned, None where it was not
+        called; the pending upserts merged in).  Pending entries are the
+        shared snapshots: copy before mutating."""
+        side = self._sides.get(book)
+        loaded = None
+        if side is None:
+            side = self._sides[book] = load_side()
+            loaded = len(side)
+        held = self._offer_book
+        live = (item for item in side if item[1] not in held)
+        pending = sorted(
+            (e.data.value.price.n / e.data.value.price.d, oid, None, e)
+            for oid, e in self._book_pending.get(book, {}).items()
+        )
+        page = list(islice(merge(live, pending), offset, offset + num))
+        return page, loaded, len(pending)
 
     # -- savepoint integration (Database.transaction) ----------------------
     def push_mark(self) -> None:
@@ -151,13 +229,18 @@ class EntryStoreBuffer:
         m = self._marks.pop()
         while len(self._undo) > m:
             kb, prev = self._undo.pop()
-            if prev is _ABSENT:
-                self._overlay.pop(kb, None)
-                self._offer_keys.discard(kb)
+            if kb is _FLUSHED:
+                # SQL is about to roll the flushed rows back: a side read
+                # since the flush holds them
+                self._sides.clear()
+            elif prev is _ABSENT:
+                slot = self._overlay.pop(kb, None)
+                if slot is not None and slot[0].type == LedgerEntryType.OFFER:
+                    self._index_offer(slot[0].value.offerID, _ABSENT)
             else:
                 self._overlay[kb] = prev
                 if prev[0].type == LedgerEntryType.OFFER:
-                    self._offer_keys.add(kb)
+                    self._index_offer(prev[0].value.offerID, prev[1])
 
     # -- flush -------------------------------------------------------------
     def flush(self, db) -> dict:
@@ -182,6 +265,7 @@ class EntryStoreBuffer:
         if self._marks:
             for kb, slot in self._overlay.items():
                 self._undo.append((kb, slot))
+            self._undo.append((_FLUSHED, None))
         by_cls: Dict[type, Tuple[list, list, list]] = {}
         for key, entry, cls, signers_dirty in self._overlay.values():
             ups, dirty, dels = by_cls.setdefault(cls, ([], [], []))
@@ -200,7 +284,7 @@ class EntryStoreBuffer:
                 for k, n in (reported or {}).items():
                     written[k] = written.get(k, 0) + n
         self._overlay.clear()
-        self._offer_keys.clear()
+        self._drop_book_views()
         self.n_flushes += 1
         return written
 

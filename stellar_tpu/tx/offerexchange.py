@@ -8,12 +8,16 @@ rounding direction is consensus-critical ("bias towards seller").
 Inside a close every store here is write-back: the crossed offer, its
 seller's account and lines land in the close's ``EntryStoreBuffer``
 (ledger/storebuffer.py) and reach SQL at ``commit.flush``; the book is read
-through that buffer (``OfferFrame.load_best_offers``).
+through that buffer (``OfferFrame.load_best_offers``): a page of five is a
+slice of the buffer's view of the side, which reads the side from SQL once a
+close and merges the side's own pending offers in.
 
 One conversion is one ``op.exchange`` span (``crossed``: offers taken or
-reduced; ``pages``: ``load_best_offers`` calls; ``rows``: rows the book's
-SELECT returned plus pending entries walked) and one step of the
-``exchange`` counters on ``/info`` (``LedgerManager.exchange_stats``).
+reduced; ``pages``: ``load_best_offers`` calls; ``rows``: rows a SELECT of
+the book returned plus pending entries the pages looked at; ``side_loads``:
+sides read from SQL, at most one a side a close and one more after each
+mid-close flush) and one step of the ``exchange`` counters on ``/info``
+(``LedgerManager.exchange_stats``).
 """
 
 from __future__ import annotations
@@ -192,7 +196,7 @@ class OfferExchange:
     ):
         """-> (ConvertResult, sheep_sent, wheat_received); walks the book
         cheapest-first in pages of 5 (convertWithOffers)."""
-        tally = {"pages": 0, "rows": 0}
+        tally = {"pages": 0, "rows": 0, "side_loads": 0}
         trail = len(self.offer_trail)
         tracer = self.lm.app.tracer
         sp = tracer.begin("op.exchange")
@@ -207,6 +211,7 @@ class OfferExchange:
             stats["offers_crossed"] += crossed
             stats["book_pages"] += tally["pages"]
             stats["book_rows"] += tally["rows"]
+            stats["book_side_loads"] += tally["side_loads"]
             tracer.end(sp, crossed=crossed, **tally)
 
     def _walk_book(

@@ -56,7 +56,8 @@ def test_info_metrics_scp(app):
     assert "apply" not in info
     # the order book's work and the transactions that failed at apply, since the node started
     assert info["exchange"] == {
-        "conversions": 0, "offers_crossed": 0, "book_pages": 0, "book_rows": 0, "txs_failed_at_apply": 0,
+        "conversions": 0, "offers_crossed": 0, "book_pages": 0, "book_rows": 0, "book_side_loads": 0,
+        "txs_failed_at_apply": 0,
     }
     assert "metrics" in ch.handle_metrics({})
     assert isinstance(ch.handle_scp({}), dict)

@@ -541,6 +541,7 @@ def test_seeded_mixed_closes(two_backends, shape, seed):
         w.fee, dict(SHAPE, **SHAPES[shape]), seed,
     )
     whats = set()
+    exchange = [dict(n.lm.exchange_stats) for n in w.nodes]
     for _ in range(3):
         seq = w.nodes[0].lm.current.header.ledgerSeq
         planned = planner.plan(WIDTH, seq)
@@ -558,6 +559,12 @@ def test_seeded_mixed_closes(two_backends, shape, seed):
         assert "PATH_PAYMENT_SUCCESS" in w.seen
     # the device verified: the tpu-backend node's sets are over its cutover
     assert w.nodes[1].app.sig_backend.stats()["device_calls"] > 0
+    # a page of five costs what it needs, not a side: the book's rows are
+    # read once a (side, close), whatever the close holds pending by then
+    for node, before in zip(w.nodes, exchange):
+        did = {k: v - before[k] for k, v in node.lm.exchange_stats.items()}
+        assert did["conversions"] > 0 and 0 < did["book_side_loads"] <= did["book_pages"]
+        assert did["book_rows"] / did["book_pages"] < 20, did
 
 
 # -- spans, attributes, counters -------------------------------------------------------------------------
@@ -570,7 +577,9 @@ def test_spans_and_counters_of_a_mixed_close(world):
     app = w.nodes[0].app
     info = lambda: app.command_handler.handle_info({})["info"]["exchange"]  # noqa: E731
     before = info()
-    assert set(before) == {"conversions", "offers_crossed", "book_pages", "book_rows", "txs_failed_at_apply"}
+    assert set(before) == {
+        "conversions", "offers_crossed", "book_pages", "book_rows", "book_side_loads", "txs_failed_at_apply",
+    }
     app.tracer.clear()
     w.close([("A", [("path", w.n("D"), None, 9000, usd, 700, ())]),  # six offers, two pages
              ("D", [("trust", w.asset("EUR", "J"), BIG)]),
@@ -581,9 +590,10 @@ def test_spans_and_counters_of_a_mixed_close(world):
         by_name.setdefault(s.name, []).append(s)
     (exchange,) = by_name["op.exchange"]
     assert exchange.attrs["crossed"] == 6 and exchange.attrs["pages"] == 2
-    # the first page reads five rows; the second the row left plus, behind the
-    # write-back buffer, the over-fetch for the five offers it holds by then
-    assert exchange.attrs["rows"] >= 6
+    # the first page reads the side, six rows, once; the second is a slice of
+    # what the close's buffer holds of it (no offer of this book is pending
+    # as an upsert until the sixth is reduced, after the last page)
+    assert exchange.attrs["rows"] == 6 and exchange.attrs["side_loads"] == 1
     (serial,) = by_name["apply.serial"]
     assert serial.attrs == {"txs": 3, "failed": 1}
     (sampled,) = by_name["tx.apply"]  # index 0 of the set
@@ -594,11 +604,69 @@ def test_spans_and_counters_of_a_mixed_close(world):
     assert flush.attrs["signer_rows"] == 0
     after = info()
     assert {k: after[k] - before[k] for k in after} == {
-        "conversions": 1, "offers_crossed": 6, "book_pages": 2, "book_rows": exchange.attrs["rows"],
+        "conversions": 1, "offers_crossed": 6, "book_pages": 2, "book_rows": 6, "book_side_loads": 1,
         "txs_failed_at_apply": 1,
     }
     # one span a conversion beside the close's budget (tests/test_trace.py)
     assert len(by_name["op.exchange"]) == after["conversions"] - before["conversions"]
+
+
+def test_a_close_reads_each_side_of_the_book_once(world, monkeypatch):
+    """Fifty-two conversions over four sides in one close: one ``SELECT`` a
+    side, whatever the pages; and every page asked for twice, the twin's
+    frames changed as the exchange changes what it crosses — the close is
+    still the plain ledger's, so nothing handed out is handed out again."""
+    from stellar_tpu.ledger.offerframe import OfferFrame
+    from stellar_tpu.xdr.entries import Price
+
+    w = world
+    usd, eur = market(w), w.asset("EUR", "J")
+    w.close([(x, [("trust", eur, BIG)]) for x in "ABCD"])
+    w.close([("J", [("pay", w.n(x), HELD, eur) for x in "ABCD"])])
+    # B and C rest asks and bids in both credits, four price levels each
+    for seller in "BC":
+        w.close([(seller, [("offer", *pair, 700, (n, 100), 0) for n in (101, 102, 103, 104)])
+                 for credit in (usd, eur) for pair in ((credit, None), (None, credit))])
+    assert len(w.plain.offers) == 32
+    node = w.nodes[0]
+    sides, pages = set(), []
+    one_page = OfferFrame.load_best_offers.__func__
+
+    def asked_twice(cls, num, offset, selling, buying, db, tally=None):
+        page = one_page(cls, num, offset, selling, buying, db, tally)
+        twin = one_page(cls, num, offset, selling, buying, db)
+        for a, b in zip(page, twin, strict=True):
+            assert a is not b and a.entry is not b.entry and a.entry == b.entry
+            b.mut().amount = 0
+            b.mut().price = Price(b.offer.price.n + 1, b.offer.price.d)
+        sides.add((selling.to_xdr(), buying.to_xdr()))
+        pages.append(len(page))
+        return page
+
+    monkeypatch.setattr(OfferFrame, "load_best_offers", classmethod(asked_twice))
+    selects = []
+    node.app.database._conn.set_trace_callback(
+        lambda sql: selects.append(sql) if "FROM offers" in sql and "ORDER BY price" in sql else None
+    )
+    before = dict(node.lm.exchange_stats)
+    # A and D pay each other through each of the four books in turn
+    plan = []
+    for i in range(52):
+        payer, payee = ("A", "D") if i % 2 else ("D", "A")
+        credit = (usd, eur)[i // 2 % 2]
+        sent, got = ((None, credit), (credit, None))[i // 4 % 2]
+        plan.append((payer, [("path", w.n(payee), sent, 2000, got, 100 * (1 + i % 5), ())]))
+    done = w.close(plan)
+    node.app.database._conn.set_trace_callback(None)
+    did = {k: v - before[k] for k, v in node.lm.exchange_stats.items()}
+    assert sum(1 for _tx, codes in done if codes[0] == "txSUCCESS") >= 40
+    assert did["conversions"] == 52 and len(sides) == 4 and did["offers_crossed"] >= 52
+    assert did["book_pages"] == len(pages) >= 52
+    # one read a side: the SQL statements against the book are those and no others
+    assert did["book_side_loads"] == len(selects) == 4
+    assert not any(" LIMIT " in sql for sql in selects)
+    # 32 rows read once, and the few pending offers of its own book a page
+    assert did["book_rows"] / did["book_pages"] < 5, did
 
 
 def test_the_readers_tables_are_the_programs():
