@@ -406,6 +406,7 @@ class TpuSigBackend(SigBackend):
     # and a flush count of their own
     _tracer = NULL_TRACER
     n_device_flushes = 0
+    n_caller_items = None
 
     def __init__(
         self,
@@ -453,6 +454,9 @@ class TpuSigBackend(SigBackend):
         self.cpu_cutover = cpu_cutover
         self.n_cutover_items = 0
         self.n_cutover_torsion = 0
+        # verify items by caller class and by where they were verified
+        # (stats() "caller_items"): which plane the device serves
+        self.n_caller_items = {}
         self.n_wedge_fallback_items = 0
         # Host-fallback latch, scoped PER CALLER CLASS (ISSUE r10): a
         # stalled pipelined prewarm (caller="pipeline") latches only the
@@ -515,6 +519,7 @@ class TpuSigBackend(SigBackend):
             wedged = time.monotonic() < self._wedged_until.get(caller, 0.0)
         if wedged:
             self.n_wedge_fallback_items += n
+            self._note_host_finish(what, caller, n)
             with self._tracer.span(
                 span, items=n, reason="wedge-latch", caller=caller
             ):
@@ -552,6 +557,7 @@ class TpuSigBackend(SigBackend):
                     self.n_latch_flips.get(caller, 0) + 1
                 )
             self.n_wedge_fallback_items += n
+            self._note_host_finish(what, caller, n)
             _log.warning(
                 "device %s batch stalled >%.0fs (%d cold bucket(s));"
                 " finishing %d items on host and latching the %r caller"
@@ -573,11 +579,25 @@ class TpuSigBackend(SigBackend):
             raise err[0]
         return result[0]
 
+    def _note_caller(self, caller: str, where: str, n: int) -> None:
+        if self.n_caller_items is None:
+            self.n_caller_items = {}
+        by = self.n_caller_items.setdefault(caller, {"device": 0, "host": 0})
+        by[where] += n
+
+    def _note_host_finish(self, what: str, caller: str, n: int) -> None:
+        """A verify batch counted for the device (verify_batch) that the
+        host finished after all: latched, or the device stalled."""
+        if what == "verify":
+            self._note_caller(caller, "device", -n)
+            self._note_caller(caller, "host", n)
+
     def verify_batch(
         self, items: Sequence[VerifyTriple], caller: str = CALLER_CLOSE
     ) -> List[bool]:
         if len(items) < self.cpu_cutover:
             self.n_cutover_items += len(items)
+            self._note_caller(caller, "host", len(items))
             with self._tracer.span(
                 "sig.host_verify", items=len(items), reason="cutover"
             ):
@@ -587,6 +607,7 @@ class TpuSigBackend(SigBackend):
         # children.  req: this backend's flush ordinal, where the flush is
         # not already part of a ledger's close
         self.n_device_flushes += 1
+        self._note_caller(caller, "device", len(items))
         with self._tracer.span(
             "sig.device_flush", req=self.n_device_flushes, items=len(items)
         ) as sp:
@@ -643,6 +664,9 @@ class TpuSigBackend(SigBackend):
         s["cpu_cutover_torsion"] = self.n_cutover_torsion
         s["wedge_fallback_items"] = self.n_wedge_fallback_items
         s["wedge_latch_flips"] = dict(self.n_latch_flips)
+        # host-assist items (a share the verifier peels off for libsodium
+        # while the device works; off as shipped) are counted "device" here
+        s["caller_items"] = {k: dict(v) for k, v in (self.n_caller_items or {}).items()}
         return s
 
 

@@ -17,13 +17,16 @@ TxSetFrame.check_valid).
 from __future__ import annotations
 
 import heapq
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from ..crypto import PubKeyUtils, sha256
 from ..scp import SCP, SCPDriver
+from ..scp.quorum import SCANS as quorum_scans
 from ..scp.quorum import iter_all_nodes
 from ..scp.quorum import qset_hash as compute_qset_hash
+from ..scp.scp import SCP_SAMPLE_STRIDE
 from ..scp.slot import Slot
 from ..util import VirtualTimer, fs, xlog
 from ..xdr.base import xdr_getfield, xdr_to_opaque
@@ -179,6 +182,18 @@ class Herder(SCPDriver):
         # traffic clusters at the bracket's low end.
         self.scp_slot_buckets: Dict[int, Dict[int, int]] = {}
         self.MAX_SLOT_BUCKETS = 1024
+        # envelope intake, monotonic since the node started (``/info``
+        # ``scp``; counted with the tracer off too): envelopes handed to
+        # SCP, envelopes the tracking window turned away, encodings of an
+        # envelope's signed payload, and the seconds inside
+        # ``SCP.receive_envelope`` and inside the ledger close it set off
+        # (the second is not part of the first; with the close pipeline on
+        # the close is the drain at the end of the queue's sweep)
+        self.n_to_scp = 0
+        self.n_dropped_window = 0
+        self.n_payload_encodes = 0
+        self.scp_receive_s = 0.0
+        self.scp_close_s = 0.0
         # lazy-deletion max-heap (negated slots) over scp_slot_buckets:
         # the at-cap evict decision is O(log n) per envelope instead of a
         # max() scan over 1024 keys — the scan would sit on exactly the
@@ -307,6 +322,7 @@ class Herder(SCPDriver):
     # SCPDriver: crypto
     # ------------------------------------------------------------------
     def _envelope_payload(self, envelope: SCPEnvelope) -> bytes:
+        self.n_payload_encodes += 1
         return xdr_to_opaque(
             self.app.network_id, EnvelopeType.ENVELOPE_TYPE_SCP, envelope.statement
         )
@@ -636,7 +652,11 @@ class Herder(SCPDriver):
         self.trigger_timer.cancel()
 
         ledger_data = LedgerCloseData(slot_index, externalized_set, sv)
-        self.ledger_manager.externalize_value(ledger_data)
+        t0 = time.perf_counter()
+        try:
+            self.ledger_manager.externalize_value(ledger_data)
+        finally:
+            self.scp_close_s += time.perf_counter() - t0
 
         self._remove_received_txs(externalized_set.transactions)
 
@@ -803,6 +823,7 @@ class Herder(SCPDriver):
             min_seq = self.next_consensus_ledger_index()
             max_seq = min_seq + LEDGER_VALIDITY_BRACKET
             if not (min_seq <= envelope.statement.slotIndex <= max_seq):
+                self.n_dropped_window += 1
                 return
         # flood fast-reject (the reference's eager verify,
         # HerderImpl.cpp:347-364): an envelope whose signature fails must
@@ -1007,14 +1028,67 @@ class Herder(SCPDriver):
                     if self.tracking:
                         break  # a slot externalized; back to the regular flow
         finally:
-            self.ledger_manager.release_pipeline_drains()
+            # with the close pipeline on, the ledgers externalized in this
+            # sweep close here and not inside value_externalized
+            t0 = time.perf_counter()
+            try:
+                self.ledger_manager.release_pipeline_drains()
+            finally:
+                self.scp_close_s += time.perf_counter() - t0
 
     def _process_scp_queue_at_index(self, slot_index: int) -> None:
+        tracer = self.app.tracer
+        skip = SCP_SAMPLE_STRIDE - 1
         while True:
             env = self.pending_envelopes.pop(slot_index)
             if env is None:
                 return
-            self.scp.receive_envelope(env)
+            # SCP's own seconds: the close an envelope sets off is counted
+            # apart (value_externalized), so it comes off again here
+            sampled = None if self.n_to_scp & skip else tracer.begin("scp.receive")
+            self.n_to_scp += 1
+            closing = self.scp_close_s
+            t0 = time.perf_counter()
+            try:
+                self.scp.receive_envelope(env)
+            finally:
+                self.scp_receive_s += (
+                    time.perf_counter() - t0 - (self.scp_close_s - closing)
+                )
+                tracer.end(sampled)
+
+    def intake_counters(self) -> tuple:
+        return (
+            self.n_to_scp, self.n_dropped_window,
+            self.scp_receive_s, self.scp_close_s,
+        )
+
+    def intake_delta(self, before: tuple) -> dict:
+        """What a stretch of envelope intake did, as the attributes of the
+        span around it (``scp.deliver``, ``herder.recheck``)."""
+        now = self.intake_counters()
+        return dict(
+            zip(
+                ("to_scp", "dropped_window", "receive_s", "close_s"),
+                (b - a for a, b in zip(before, now)),
+            )
+        )
+
+    def scp_stats(self) -> dict:
+        """``/info`` ``scp``: the consensus-side intake since the node
+        started.  The quorum counters are the process's (scp/quorum.py)."""
+        om = self.app.overlay_manager
+        return {
+            "envelopes_flushed": om.m_scp_batch_size.count if om else 0,
+            "rejected_at_flush": om.m_scp_batch_rejected.count if om else 0,
+            "to_scp": self.n_to_scp,
+            "dropped_out_of_window": self.n_dropped_window,
+            "quorum_checks": quorum_scans.checks,
+            "quorum_nodes_scanned": quorum_scans.nodes,
+            "payload_encodes": self.n_payload_encodes,
+            "receive_s": round(self.scp_receive_s, 6),
+            "close_s": round(self.scp_close_s, 6),
+        }
 
     def send_scp_state_to_peer(self, ledger_seq: int, peer) -> None:
         if ledger_seq == 0:

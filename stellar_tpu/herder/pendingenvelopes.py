@@ -12,6 +12,7 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Dict, List, Optional
 
+from ..trace import tracer_of
 from ..util import xlog
 from ..xdr.ledger import StellarValue
 from ..xdr.overlay import MessageType
@@ -213,21 +214,30 @@ class PendingEnvelopes:
             self.herder.process_scp_queue()
 
     def _recheck_fetching(self) -> None:
-        ready = []
-        for slot, envs in self.fetching.items():
-            for key, env in list(envs.items()):
-                if self.is_fully_fetched(env):
-                    del envs[key]
-                    self._size_counter.dec()
-                    ready.append((env, key))
-        # queue the WHOLE ready batch before processing: when the batch
-        # spans several externalizable slots (a lagging node's replay),
-        # the herder's sweep sees them all pending and the ledger closes
-        # drain as one pipelined backlog rather than one close per item
-        for env, key in ready:
-            self._envelope_ready(env, process=False, key=key)
-        if ready:
-            self.herder.process_scp_queue()
+        tracer = tracer_of(self.app)
+        with tracer.span("herder.recheck") as sp:
+            before = self.herder.intake_counters()
+            ready = []
+            for slot, envs in self.fetching.items():
+                for key, env in list(envs.items()):
+                    if self.is_fully_fetched(env):
+                        del envs[key]
+                        self._size_counter.dec()
+                        ready.append((env, key))
+            # queue the WHOLE ready batch before processing: when the batch
+            # spans several externalizable slots (a lagging node's replay),
+            # the herder's sweep sees them all pending and the ledger closes
+            # drain as one pipelined backlog rather than one close per item
+            for env, key in ready:
+                self._envelope_ready(env, process=False, key=key)
+            if ready:
+                self.herder.process_scp_queue()
+            # envelopes that waited for a tx set or a quorum set reach SCP
+            # from here and not from the overlay's hand-over loop: the same
+            # four totals as ``scp.deliver``
+            tracer.end(
+                sp, readied=len(ready), **self.herder.intake_delta(before)
+            )
 
     def pop(self, slot_index: int) -> Optional[SCPEnvelope]:
         lst = self.pending.get(slot_index)

@@ -232,6 +232,11 @@ class CommandHandler:
             # apply, since the node started (monotonic)
             "exchange": dict(lm.exchange_stats),
         }
+        if app.herder is not None:
+            # the consensus side's intake since the node started: SCP
+            # envelopes through the overlay's batch flush, the herder and
+            # SCP, and what federated voting scanned for them (monotonic)
+            info["scp"] = app.herder.scp_stats()
         return {"info": info}
 
     def handle_metrics(self, q: dict) -> dict:

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+from ..scp.scp import SCP_SAMPLE_STRIDE
+from ..trace import tracer_of
 from ..util import VirtualTimer, xlog
 from ..xdr.overlay import MessageType, StellarMessage
 from .floodgate import Floodgate
@@ -218,38 +220,57 @@ class OverlayManager:
         if self._shutting_down or not batch:
             return
         herder = self.app.herder
-        triples = [herder.envelope_verify_triple(env) for env in batch]
-        # hand the batch SLOT-GROUPED to the node's SCP signature scheme
-        # (Config.SCP_SIG_SCHEME): the per-envelope scheme is exactly the
-        # old sig_backend.verify_batch(caller=CALLER_OVERLAY) call; the
-        # half-aggregation scheme buckets these triples per slot and
-        # verifies each bucket as one MSM check, with the same backend
-        # (same caller class, so the wedge latch stays per-plane) as the
-        # fallback for thin buckets and poisoned aggregates
-        slots = [env.statement.slotIndex for env in batch]
-        scheme = getattr(self.app, "scp_scheme", None)
-        if scheme is not None:
-            verdicts = scheme.verify_flush(triples, slots)
-        else:  # bare harness apps without an Application-built scheme
-            from ..crypto.sigbackend import CALLER_OVERLAY
+        tracer = tracer_of(self.app)
+        with tracer.span("overlay.scp_flush") as flush_sp:
+            with tracer.span("scp.collect", envelopes=len(batch)):
+                triples = [herder.envelope_verify_triple(env) for env in batch]
+            # hand the batch SLOT-GROUPED to the node's SCP signature scheme
+            # (Config.SCP_SIG_SCHEME): the per-envelope scheme is exactly the
+            # old sig_backend.verify_batch(caller=CALLER_OVERLAY) call; the
+            # half-aggregation scheme buckets these triples per slot and
+            # verifies each bucket as one MSM check, with the same backend
+            # (same caller class, so the wedge latch stays per-plane) as the
+            # fallback for thin buckets and poisoned aggregates
+            slots = [env.statement.slotIndex for env in batch]
+            scheme = getattr(self.app, "scp_scheme", None)
+            if scheme is not None:
+                verdicts = scheme.verify_flush(triples, slots)
+            else:  # bare harness apps without an Application-built scheme
+                from ..crypto.sigbackend import CALLER_OVERLAY
 
-            verdicts = self.app.sig_backend.verify_batch(
-                triples, caller=CALLER_OVERLAY
-            )
-        self.m_scp_batch_flush.mark()
-        self.m_scp_batch_size.inc(len(batch))
-        # strict-gate fast-reject at the flood boundary: the batch verify
-        # just computed every verdict, so invalid-sig envelopes drop HERE
-        # — they never reach the herder's fetch plane, and (since the
-        # verify cache latches only valid verdicts) they cannot park a
-        # verdict in the shared cache either.  Valid envelopes flow on;
-        # the herder's eager re-check is a warm-cache hit.
-        for env, ok in zip(batch, verdicts):
-            if ok:
-                herder.recv_scp_envelope(env)
-            else:
-                self.m_scp_batch_rejected.inc()
-                herder.note_envelope_rejected(env)
+                verdicts = self.app.sig_backend.verify_batch(
+                    triples, caller=CALLER_OVERLAY
+                )
+            self.m_scp_batch_flush.mark()
+            self.m_scp_batch_size.inc(len(batch))
+            # strict-gate fast-reject at the flood boundary: the batch verify
+            # just computed every verdict, so invalid-sig envelopes drop HERE
+            # — they never reach the herder's fetch plane, and (since the
+            # verify cache latches only valid verdicts) they cannot park a
+            # verdict in the shared cache either.  Valid envelopes flow on;
+            # the herder's eager re-check is a warm-cache hit.
+            rejected = 0
+            skip = SCP_SAMPLE_STRIDE - 1
+            with tracer.span("scp.deliver") as deliver_sp:
+                before = herder.intake_counters()
+                for index, (env, ok) in enumerate(zip(batch, verdicts)):
+                    if not ok:
+                        rejected += 1
+                        herder.note_envelope_rejected(env)
+                    elif index & skip:
+                        herder.recv_scp_envelope(env)
+                    else:
+                        # one envelope in SCP_SAMPLE_STRIDE is timed
+                        with tracer.span("herder.recv_envelope", index=index):
+                            herder.recv_scp_envelope(env)
+                # what the hand-over loop did with the batch: SCP's own time
+                # and a ledger close inside it are the herder's counters'
+                # growth over the loop (an envelope whose tx set or quorum set
+                # is still being fetched reaches SCP later, from the herder's
+                # recheck: ``herder.recheck`` carries the same four)
+                tracer.end(deliver_sp, **herder.intake_delta(before))
+            self.m_scp_batch_rejected.inc(rejected)
+            tracer.end(flush_sp, envelopes=len(batch), rejected=rejected)
 
     def recv_flooded_msg(self, msg: StellarMessage, peer: Peer) -> bool:
         """Record a flooded message arrival; False if already seen."""

@@ -46,6 +46,11 @@ from ..xdr.xtypes import HmacSha256Mac, PublicKey
 log = xlog.logger("Overlay")
 
 
+# an idle timer that fires this much after it was due says the node itself
+# was not running (Peer._idle_timer_expired)
+IDLE_TIMER_LATE_SECONDS = 1.0
+
+
 class PeerRole:
     WE_CALLED_REMOTE = "WE_CALLED_REMOTE"
     REMOTE_CALLED_US = "REMOTE_CALLED_US"
@@ -151,13 +156,22 @@ class Peer:
     def _start_idle_timer(self) -> None:
         if self.should_abort():
             return
+        self._idle_due = self.app.clock.now() + self.io_timeout_seconds()
         self._idle_timer.expires_from_now(self.io_timeout_seconds())
         self._idle_timer.async_wait(self._idle_timer_expired)
 
     def _idle_timer_expired(self) -> None:
         now = self.app.clock.now()
         timeout = self.io_timeout_seconds()
-        if now - self.last_read >= timeout and now - self.last_write >= timeout:
+        if now - self._idle_due >= IDLE_TIMER_LATE_SECONDS:
+            # the timer fired late: this node's own main thread was held
+            # (a bucket's first dispatch from the SCP flush is 32-73 s) and
+            # read nothing meanwhile, so the silence is its own — what the
+            # peer sent waits in the transport.  Give the connection a
+            # fresh window; without this a node dropped every peer the
+            # moment it came back.
+            self._start_idle_timer()
+        elif now - self.last_read >= timeout and now - self.last_write >= timeout:
             log.warning("idle timeout on %r", self)
             self._m_timeout_idle.mark()
             self.drop()

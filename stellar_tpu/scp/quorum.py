@@ -22,6 +22,24 @@ from ..xdr.xtypes import NodeID
 UINT64_MAX = 0xFFFFFFFFFFFFFFFF
 
 
+class _Scans:
+    """What the two federated-voting checks cost, monotonic and
+    process-wide (every node of a simulation adds to the same two):
+    ``checks`` calls of ``is_quorum_with`` / ``is_v_blocking_with`` and the
+    ``nodes`` they visited — every latest envelope once for the predicate,
+    and in ``is_quorum_with`` every surviving node once a round of the
+    fixpoint.  ``/info`` ``scp`` reports them."""
+
+    __slots__ = ("checks", "nodes")
+
+    def __init__(self):
+        self.checks = 0
+        self.nodes = 0
+
+
+SCANS = _Scans()
+
+
 def qset_hash(qset: SCPQuorumSet) -> bytes:
     return sha256(xdr_to_opaque(qset))
 
@@ -122,6 +140,8 @@ def is_v_blocking_with(
     envs: Dict[NodeID, SCPEnvelope],
     predicate: Callable[[SCPStatement], bool],
 ) -> bool:
+    SCANS.checks += 1
+    SCANS.nodes += len(envs)
     nodes = {n for n, e in envs.items() if predicate(e.statement)}
     return is_v_blocking(qset, nodes)
 
@@ -136,9 +156,12 @@ def is_quorum_with(
     whose statement passes `predicate`, iteratively drop any node whose own
     qset has no slice inside the surviving set, and test whether the fixpoint
     still contains a slice of the local qset."""
+    SCANS.checks += 1
+    SCANS.nodes += len(envs)
     nodes = {n for n, e in envs.items() if predicate(e.statement)}
     while True:
         before = len(nodes)
+        SCANS.nodes += before
 
         def keeps(n: NodeID) -> bool:
             q = qset_of(envs[n].statement)

@@ -15,6 +15,12 @@ from . import quorum
 from .driver import EnvelopeState, SCPDriver
 from .slot import Slot
 
+# one envelope in SCP_SAMPLE_STRIDE records a span of its own on its way in
+# (the overlay's ``herder.recv_envelope`` by its index in the flush, the
+# herder's ``scp.receive`` by its count of envelopes handed to SCP), as one
+# transaction in TX_SAMPLE_STRIDE records ``tx.apply``; a power of two
+SCP_SAMPLE_STRIDE = 64
+
 
 class SCP:
     def __init__(

@@ -157,7 +157,7 @@ def test_flood_scheme_wall_ab():
     so the ratio reads 1.0-1.45x across windows.  Per the repo's
     measurement convention the deterministic oracles (parity, liveness
     floor, cache cleanliness — the other tests in this file) carry the
-    evidence; this best-of-2 gate only catches a catastrophic cost
+    evidence; this best-of-4 gate only catches a catastrophic cost
     regression (<= 1.6x, e.g. re-proving cached validator keys every
     flush).  The throughput win is conditional on offloading the
     R-column proof to the TPU batch plane (ROADMAP lead — the verify
@@ -165,10 +165,16 @@ def test_flood_scheme_wall_ab():
     R:=identity))."""
     from stellar_tpu.scenarios.scenario import Scenario
 
-    walls = {}
-    for scheme in ("ed25519-halfagg", "ed25519"):
-        best = float("inf")
-        for rep in range(2):
+    # Steadied by PR 32: the two schemes take turns (a burst of the other
+    # workers' load falls on both) and the best of four stands for each;
+    # what the wall cannot say under six workers the run's own counts do:
+    # one aggregate check serves at least MIN_AGG envelopes and next to
+    # none of them pay the per-signature fallback on top.
+    from stellar_tpu.crypto.aggregate.scheme import HalfAggScheme
+
+    walls = {"ed25519-halfagg": float("inf"), "ed25519": float("inf")}
+    for rep in range(4):
+        for scheme in walls:
             spec = small_specs()["byzantine_flood_halfagg"]
             spec.scp_sig_scheme = scheme
             suffix = "_persig" if scheme == "ed25519" else ""
@@ -176,9 +182,14 @@ def test_flood_scheme_wall_ab():
             verify_cache().clear()
             r = Scenario(spec).run()
             assert r.ok, (scheme, r.failures)
-            best = min(best, r.scoreboard.aggregate["verify_wall_ms"])
-            assert r.scoreboard.aggregate["flush_envelopes"] > 3000
-        walls[scheme] = best
+            agg = r.scoreboard.aggregate
+            walls[scheme] = min(walls[scheme], agg["verify_wall_ms"])
+            assert agg["flush_envelopes"] > 3000
+            if scheme == "ed25519":
+                assert agg["agg_checks"] == agg["agg_envelopes"] == 0
+            else:
+                assert agg["agg_checks"] * HalfAggScheme.MIN_AGG <= agg["agg_envelopes"], agg
+                assert agg["fallback_envelopes"] <= 0.2 * agg["flush_envelopes"], agg
     ratio = walls["ed25519-halfagg"] / walls["ed25519"]
     assert ratio <= 1.6, (
         "aggregate scheme paid %.2fx the per-signature verify wall"

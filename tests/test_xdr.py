@@ -6,7 +6,7 @@ bytes computed independently from the spec, not from our own packer).
 """
 
 import pytest
-from hypothesis import given, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import stellar_tpu.xdr as X
 from stellar_tpu.xdr.base import XdrError, uint32, int32, uint64, int64, var_opaque
@@ -159,6 +159,10 @@ class TestUnions:
 # SURVEY.md §4; hypothesis is our equivalent).
 # ---------------------------------------------------------------------------
 
+# hypothesis's default deadline (200 ms an example) is wall time: under the
+# suite's six workers one slow example of a correct codec fails the test
+NO_DEADLINE = settings(deadline=None)
+
 pubkeys = st.binary(min_size=32, max_size=32).map(X.PublicKey.from_ed25519)
 hashes = st.binary(min_size=32, max_size=32)
 values = st.binary(max_size=64)
@@ -209,6 +213,7 @@ envelopes = st.builds(
 
 
 @given(envelopes)
+@NO_DEADLINE
 def test_scp_envelope_roundtrip(env):
     assert X.SCPEnvelope.from_xdr(env.to_xdr()) == env
 
@@ -274,11 +279,13 @@ tx_envelopes = st.builds(
 
 
 @given(tx_envelopes)
+@NO_DEADLINE
 def test_tx_envelope_roundtrip(te):
     assert X.TransactionEnvelope.from_xdr(te.to_xdr()) == te
 
 
 @given(tx_envelopes)
+@NO_DEADLINE
 def test_stellar_message_roundtrip(te):
     m = X.StellarMessage(X.MessageType.TRANSACTION, te)
     am = X.AuthenticatedMessage.v0_of(7, m, b"\x00" * 32)
@@ -286,6 +293,7 @@ def test_stellar_message_roundtrip(te):
 
 
 @given(st.binary(max_size=200))
+@NO_DEADLINE
 def test_unpack_never_crashes_unsafely(data):
     """Malformed input must raise XdrError, never other exceptions
     (this is what lets the overlay feed wire bytes straight into from_xdr,
