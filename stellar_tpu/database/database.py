@@ -32,7 +32,9 @@ from typing import Any, Iterable, List, Optional, Tuple
 from ..util import fs
 from .dialect import dialect_for, load_pg_driver
 
-SCHEMA_VERSION = 1
+# 2 (PR 35): txhistory / txfeehistory keyed by (ledgerseq, txindex), no
+# other index (tx/history.py); 1 keyed them (txid, ledgerseq)
+SCHEMA_VERSION = 2
 
 # the outermost COMMIT is THE durable boundary of the SQL plane: a kill
 # on the :pre side loses the whole transaction (restart sees the prior
@@ -426,6 +428,30 @@ class Database:
         ):
             dropper(self)
         self.put_schema_version(SCHEMA_VERSION)
+
+    def upgrade_to_current_schema(self) -> None:
+        """Bring an initialized database of an older schema to
+        ``SCHEMA_VERSION`` (Database::upgradeToCurrentSchema): the node
+        calls this as it opens the database, before anything reads it.
+        All steps and the version's bump are ONE transaction, so a kill
+        anywhere inside leaves the old version whole and the next open
+        upgrades again.  A database with no version (not initialized
+        yet) or at the current one is not touched."""
+        try:
+            v = self.get_schema_version()
+        except Exception:  # no storestate table: nothing to upgrade
+            return
+        if v in (0, SCHEMA_VERSION):
+            return
+        if v > SCHEMA_VERSION:
+            raise RuntimeError(
+                f"database schema {v} is newer than this build's {SCHEMA_VERSION}"
+            )
+        from ..tx.history import rekey_tx_history
+
+        with self.transaction():
+            rekey_tx_history(self)  # 1 -> 2, the one step there is
+            self.put_schema_version(SCHEMA_VERSION)
 
     def get_schema_version(self) -> int:
         from ..main.persistentstate import PersistentState

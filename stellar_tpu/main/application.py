@@ -59,6 +59,9 @@ class Application:
             metrics=self.metrics,
         )
         self.database = Database(config.DATABASE, self.metrics)
+        if not new_db:
+            # an older schema is rebuilt here, before anything reads it
+            self.database.upgrade_to_current_schema()
         # seal-on-store CoW entry snapshots (ledger/entryframe.py): the
         # knob rides the Database object because EntryFrame._record has
         # db, not config, in hand (same pattern as the entry cache /

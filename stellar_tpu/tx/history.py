@@ -20,31 +20,57 @@ from ..xdr.ledger import (
 from ..xdr.txs import TransactionEnvelope
 
 
-def drop_tx_history(db) -> None:
-    db.execute("DROP TABLE IF EXISTS txhistory")
-    db.execute("DROP TABLE IF EXISTS txfeehistory")
-    db.execute(
-        """CREATE TABLE txhistory (
+# Both tables are keyed by where a close writes and every reader reads:
+# (ledgerseq, txindex).  A close's rows arrive in ascending txindex under
+# one growing ledgerseq, so the table and its one index are both appended
+# to; the key's prefix serves every ``WHERE ledgerseq=?`` read and its
+# order is ``ORDER BY txindex``.  Nothing looks a row up by txid, so no
+# index on it: an index on a hash puts every row of a close on a random
+# leaf of a tree that only grows (PERF.md section 6, PR 35).
+_TX_COLUMNS = "txid, ledgerseq, txindex, txbody, txresult, txmeta"
+_FEE_COLUMNS = "txid, ledgerseq, txindex, txchanges"
+_TX_DDL = """CREATE TABLE {name} (
             txid      CHARACTER(64) NOT NULL,
             ledgerseq INT NOT NULL CHECK (ledgerseq >= 0),
             txindex   INT NOT NULL,
             txbody    TEXT NOT NULL,
             txresult  TEXT NOT NULL,
             txmeta    TEXT NOT NULL,
-            PRIMARY KEY (txid, ledgerseq)
+            PRIMARY KEY (ledgerseq, txindex)
         )"""
-    )
-    db.execute("CREATE INDEX histbyseq ON txhistory (ledgerseq)")
-    db.execute(
-        """CREATE TABLE txfeehistory (
+_FEE_DDL = """CREATE TABLE {name} (
             txid      CHARACTER(64) NOT NULL,
             ledgerseq INT NOT NULL CHECK (ledgerseq >= 0),
             txindex   INT NOT NULL,
             txchanges TEXT NOT NULL,
-            PRIMARY KEY (txid, ledgerseq)
+            PRIMARY KEY (ledgerseq, txindex)
         )"""
-    )
-    db.execute("CREATE INDEX histfeebyseq ON txfeehistory (ledgerseq)")
+_TABLES = (
+    ("txhistory", _TX_DDL, _TX_COLUMNS),
+    ("txfeehistory", _FEE_DDL, _FEE_COLUMNS),
+)
+
+
+def drop_tx_history(db) -> None:
+    for name, ddl, _ in _TABLES:
+        db.execute(f"DROP TABLE IF EXISTS {name}")
+        db.execute(ddl.format(name=name))
+
+
+def rekey_tx_history(db) -> None:
+    """Schema 1 -> 2: rebuild both tables, keyed ``(txid, ledgerseq)`` with
+    a second index by ledgerseq, under the key ``drop_tx_history`` gives
+    them now; every row kept, written in key order.  The caller
+    (``Database.upgrade_to_current_schema``) holds the one transaction
+    around it: a kill before its COMMIT leaves the old tables whole."""
+    for name, ddl, columns in _TABLES:
+        db.execute(ddl.format(name=f"{name}_rekeyed"))
+        db.execute(
+            f"INSERT INTO {name}_rekeyed ({columns}) SELECT {columns}"
+            f" FROM {name} ORDER BY ledgerseq, txindex"
+        )
+        db.execute(f"DROP TABLE {name}")  # its indexes go with it
+        db.execute(f"ALTER TABLE {name}_rekeyed RENAME TO {name}")
 
 
 def transaction_row(
@@ -108,14 +134,8 @@ def fee_row(tx_id: bytes, ledger_seq: int, tx_index: int, changes) -> Tuple:
     )
 
 
-_TX_INSERT = (
-    "INSERT INTO txhistory (txid, ledgerseq, txindex, txbody, txresult, txmeta)"
-    " VALUES (?,?,?,?,?,?)"
-)
-_FEE_INSERT = (
-    "INSERT INTO txfeehistory (txid, ledgerseq, txindex, txchanges)"
-    " VALUES (?,?,?,?)"
-)
+_TX_INSERT = f"INSERT INTO txhistory ({_TX_COLUMNS}) VALUES (?,?,?,?,?,?)"
+_FEE_INSERT = f"INSERT INTO txfeehistory ({_FEE_COLUMNS}) VALUES (?,?,?,?)"
 
 
 def insert_transaction_rows(db, rows: List[Tuple]) -> None:

@@ -67,7 +67,7 @@ class TestDatabase:
         assert ps.get_state("x") is None
 
     def test_schema_version(self, db):
-        assert db.get_schema_version() == 1
+        assert db.get_schema_version() == 2
 
 
 class TestAccountFrame:
