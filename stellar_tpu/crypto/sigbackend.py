@@ -481,15 +481,22 @@ class TpuSigBackend(SigBackend):
     #
     # The budget follows the COMPILED SHAPE: the first dispatch of each
     # pow-2 bucket traces, lowers and compiles its program inside the
-    # call — tens of seconds per bucket even on a persistent-cache hit,
-    # which skips only the XLA compile — so a call gets
-    # DEVICE_FIRST_TIMEOUT for every bucket it touches that has not
-    # compiled in this process (BatchVerifier.cold_buckets), and
+    # call — on a persistent-cache hit the compile is the read and load
+    # of the executable, the trace and the lower are paid all the same —
+    # so a call gets DEVICE_FIRST_TIMEOUT for every bucket it touches that
+    # has not run in this process (BatchVerifier.cold_buckets), and
     # DEVICE_TIMEOUT once they all have.  A false latch on a healthy
     # device would self-heal after RETRY_INTERVAL, but silently moves the
     # node's verifies onto host meanwhile.  Measured on the one-chip v5e
-    # host (PR 21, chip_smoke.py): 66-73 s per bucket cold, 32-39 s on a
-    # compile-cache hit; the default leaves 2x over the cold figure.
+    # host (my chip runs, PR 37; PERF.md "Where set-up goes"): 21-37 s a
+    # bucket on a persistent-cache hit (the Python trace 15-24 s, the
+    # lowering 5-17 s, the executable's read and load 0.06 s), 55-72 s on
+    # a miss (XLA + Mosaic 33-34 s more); the default leaves 2x over the
+    # cold figure.
+    # A node's own figures are in /info ``sig_backend`` ``first_dispatch``
+    # (per bucket: trace_s, lower_s, compile_s, cache, caller, when), and
+    # the flush that paid them is the ``sig.device_flush`` with ``cold``
+    # on /trace.
     # The price: a device wedged at a bucket's FIRST dispatch holds its
     # caller that long before the host takes over.  Compiling the
     # reachable buckets at node start would let every live dispatch keep
@@ -602,25 +609,49 @@ class TpuSigBackend(SigBackend):
                 "sig.host_verify", items=len(items), reason="cutover"
             ):
                 return _sodium_verify_loop(items)
-        # the flush as its caller waits for it: the hop to the guarded
-        # worker, the stager pool, staging, dispatch and drain are its
-        # children.  req: this backend's flush ordinal, where the flush is
-        # not already part of a ledger's close
-        self.n_device_flushes += 1
         self._note_caller(caller, "device", len(items))
+        return self._device_flush(
+            "verify",
+            len(items),
+            caller,
+            True,
+            lambda: self._verifier.verify(items),
+            lambda: _sodium_verify_loop(items),
+        )
+
+    def _device_flush(
+        self, what: str, n: int, caller: str, host_assist: bool,
+        device_fn, host_fn,
+    ):
+        """The flush as its caller waits for it: the hop to the guarded
+        worker, the stager pool, staging, dispatch and drain are the
+        span's children.  ``req``: this backend's flush ordinal, where the
+        flush is not already part of a ledger's close.  ``cold``, only
+        when not 0: the buckets this flush dispatches for the first time
+        in this process — the flush that ran under DEVICE_FIRST_TIMEOUT,
+        with the close or the overlay flush that caused it as its parent.
+        ``host_assist``: whether the verifier may peel a share off (not
+        for torsion batches)."""
+        cold = self._verifier.cold_buckets(n, host_assist=host_assist)
+
+        def device():
+            from ..ops import compile_events  # lazy: JAX import
+
+            # this worker's thread lives for the one flush
+            compile_events.serve(caller)
+            return device_fn()
+
+        self.n_device_flushes += 1
         with self._tracer.span(
-            "sig.device_flush", req=self.n_device_flushes, items=len(items)
+            "sig.device_flush", req=self.n_device_flushes, items=n
         ) as sp:
             if sp is not None:
-                sp.attrs["chunks"] = self._verifier.chunk_count(len(items))
-            return self._guarded(
-                "verify",
-                len(items),
-                caller,
-                self._verifier.cold_buckets(len(items)),
-                lambda: self._verifier.verify(items),
-                lambda: _sodium_verify_loop(items),
-            )
+                sp.attrs["chunks"] = self._verifier.chunk_count(
+                    n, host_assist=host_assist
+                )
+                if cold:
+                    sp.attrs["cold"] = cold
+            return self._guarded(what, n, caller, cold, device, host_fn)
 
     def torsion_check(
         self,
@@ -649,11 +680,11 @@ class TpuSigBackend(SigBackend):
                 "sig.host_torsion", items=len(encs), reason="cutover"
             ):
                 return host()
-        return self._guarded(
+        return self._device_flush(
             "torsion",
             len(encs),
             caller,
-            self._verifier.cold_buckets(len(encs), host_assist=False),
+            False,
             lambda: self._verifier.verify_torsion(encs),
             host,
         )

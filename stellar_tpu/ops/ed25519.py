@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import threading
 import time
 from functools import partial
 from typing import List, NamedTuple, Optional, Sequence, Tuple
@@ -31,8 +32,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import fe
+from ..util import xlog
+from . import STAGES, SUMS, StageTally, compile_events, fe
 from . import ref25519 as ref
+
+_log = xlog.logger("Tx")
 
 D = ref.D
 D2 = (2 * ref.D) % ref.P
@@ -354,8 +358,6 @@ class _StagingPool:
     the pipeline depth (at most depth+1 chunks hold buffers at once)."""
 
     def __init__(self):
-        import threading
-
         self._free = {}
         self._lock = threading.Lock()
 
@@ -380,6 +382,87 @@ class _StagingPool:
             return
         with self._lock:
             self._free.setdefault(bufs[0].shape, []).append(bufs)
+
+
+class _FirstDispatch:
+    """The account open on a thread while it dispatches a bucket this
+    process has not run yet (``ops/__init__.py`` ``CompileEvents``): what
+    JAX reports there of the program's trace, lowering and compilation,
+    between ``start`` and ``end`` on ``time.monotonic`` — the tracer's clock
+    and the device profile's (``trace.sync.<ns>``), so a record can be laid
+    over ``/trace`` and an ``.xplane.pb``."""
+
+    def __init__(self, bucket: int, caller: Optional[str]):
+        self.bucket = bucket
+        self.caller = caller
+        self.seen = dict.fromkeys((*STAGES.values(), *SUMS.values()), 0.0)
+        self.seen.update(cache_hits=0, cache_misses=0)
+        self.start = time.monotonic()
+
+    def add(self, field: str, value, bucket) -> None:
+        self.seen[field] += value
+
+    def close(self) -> dict:
+        """The record of ``stats()["first_dispatch"]["buckets"]``."""
+        end = time.monotonic()
+        seen = {k: max(v, 0) for k, v in self.seen.items()}
+        staged = seen["trace_s"] + seen["lower_s"] + seen["compile_s"]
+        hits, misses = seen["cache_hits"], seen["cache_misses"]
+        rec = {
+            "bucket": self.bucket,
+            "start": self.start,
+            "end": end,
+            "trace_s": seen["trace_s"],
+            "lower_s": seen["lower_s"],
+            # XLA / Mosaic on a miss; on a hit the read and the load
+            "compile_s": seen["compile_s"],
+            "cache_retrieval_s": seen["cache_retrieval_s"],
+            # "off": JAX asked the persistent cache nothing, or compiled
+            # for under the second from which it writes an entry
+            "cache": "miss" if misses else "hit" if hits else "off",
+            "cache_hits": hits,
+            "cache_misses": misses,
+            # the upload, the enqueue and what JAX does not report
+            "rest_s": max(end - self.start - staged, 0.0),
+            "caller": self.caller,
+            "thread": threading.current_thread().name,
+        }
+        if hits:
+            rec["compile_time_saved_s"] = seen["compile_time_saved_s"]
+        return rec
+
+
+# of a first dispatch's record, what its span carries beside ``first``
+_FIRST_SPAN_ATTRS = (
+    "trace_s",
+    "lower_s",
+    "compile_s",
+    "cache_retrieval_s",
+    "cache",
+    "compile_time_saved_s",
+    "rest_s",
+    "caller",
+)
+
+# and what stats() sums over the records
+_FIRST_SUMS = (
+    "trace_s",
+    "lower_s",
+    "compile_s",
+    "cache_retrieval_s",
+    "cache_hits",
+    "cache_misses",
+)
+
+
+def _union_seconds(intervals) -> float:
+    """Length of the union of ``(start, end)`` intervals."""
+    total, reach = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > reach:
+            total += end - max(start, reach)
+            reach = end
+    return total
 
 
 class BatchVerifier:
@@ -512,18 +595,19 @@ class BatchVerifier:
         # same program) — what cold_buckets() sizes a caller's watchdog
         # budget from
         self._warm_buckets: set = set()  # analysis: locked-by _calls_lock
+        # what each bucket's first dispatch cost, by bucket, and the stage
+        # events of dispatches after it: stats()["first_dispatch"]
+        self._first_dispatches: dict = {}  # analysis: locked-by _calls_lock
+        self._recompiles = StageTally()
         self.n_device_calls = 0
         self.n_lanes = 0
         self.n_items = 0
         self.n_gate_rejects = 0
         self.n_host_assist_items = 0
         self.n_torsion_items = 0
-        self.verify_seconds = 0.0
         # n_device_calls is bumped from every stager thread; += alone
         # drops increments under streams>1 and the counter feeds
         # profiling conclusions
-        import threading
-
         self._calls_lock = threading.Lock()
 
     def _make_kernel(self):
@@ -645,16 +729,19 @@ class BatchVerifier:
             for s in range(0, n_dev, self.max_batch)
         ]
 
-    def chunk_count(self, n: int) -> int:
-        """How many device chunks a verify call over ``n`` items makes."""
-        return len(self._chunks(n - self._host_assist_count(n)))
+    def chunk_count(self, n: int, host_assist: bool = True) -> int:
+        """How many device chunks a call over ``n`` items makes
+        (``host_assist`` as for ``cold_buckets``)."""
+        n_dev = n - self._host_assist_count(n) if host_assist else n
+        return len(self._chunks(n_dev))
 
     def cold_buckets(self, n: int, host_assist: bool = True) -> int:
         """How many distinct buckets a call over ``n`` items dispatches to
-        whose program has not compiled in this process yet.  Each costs a
-        Python trace + lower (and, without a persistent-cache hit, an XLA
-        compile) inside the call — tens of seconds per bucket — so the
-        caller's watchdog scales its budget by this count.
+        whose program has not run in this process yet.  Each costs a Python
+        trace + lower and a compile (on a persistent-cache hit, the read
+        and load of the executable) inside the call, so the caller's
+        watchdog scales its budget by this count; what each cost this
+        process is ``stats()["first_dispatch"]["buckets"]``.
         ``host_assist=False`` for torsion batches, which never peel."""
         n_dev = n - self._host_assist_count(n) if host_assist else n
         sizes = {self._bucket(count) for _, count in self._chunks(n_dev)}
@@ -686,7 +773,6 @@ class BatchVerifier:
             # the assist must not cap at one thread on the multi-core
             # hosts it exists for (r05 review)
             from ..crypto.sigbackend import _sodium_verify_loop
-            import threading
 
             def assist(start=n_dev, count=host_n):
                 # a raise here must NOT die silently with the thread:
@@ -717,7 +803,6 @@ class BatchVerifier:
         # chunks of device buffers are ever in flight (unbounded dispatch
         # could OOM the chip on huge replays).
         pending = []
-        t0 = time.perf_counter()
 
         def drain_one():
             (start, n), staged, fut = pending.pop(0)
@@ -743,9 +828,6 @@ class BatchVerifier:
             # failure would — after the join, so no orphan thread races a
             # retry for host cores
             raise assist_err[0]
-        # wall time of the whole batched call: staging + hashing + device
-        # compute + sync (NOT device-only — see stats())
-        self.verify_seconds += time.perf_counter() - t0
         return out
 
     def verify_torsion(self, encs: Sequence[bytes]) -> List[bool]:
@@ -893,8 +975,12 @@ class BatchVerifier:
             # the stager threads' spans name the span open here (the
             # caller's flush) as their cause
             parent = self._tracer.current()
+            # and serve the caller class this thread serves (the pool's
+            # threads live for this call)
+            caller = compile_events.serving()
 
             def stage_and_dispatch(rng):
+                compile_events.serve(caller)
                 with self._tracer.under(parent):
                     staged = stage(items, *rng)
                     return staged, self._dispatch_staged(staged)
@@ -1114,24 +1200,71 @@ class BatchVerifier:
         kernel.  Runs on the stager thread in the multi-chunk pipeline,
         on the caller's thread for single-chunk batches.  Returns the
         in-flight device result, or None when every lane was
-        gate-rejected (hostile floods never reach the chip)."""
+        gate-rejected (hostile floods never reach the chip).
+
+        A bucket's first dispatch in this process traces, lowers and
+        compiles its program inside ``self._kernel``: the thread opens an
+        account for what JAX reports of that, and the record goes to
+        ``stats()``, onto this one span and into one log line.  A later
+        dispatch marks its thread too, so that a compilation that should
+        not happen any more is counted against its bucket."""
         if staged is None or not staged.ok.any():
             return None
         dsp = self._tracer.begin("ed25519.device_dispatch")
         if self.mesh is not None:
-            arr = self._upload_sharded(staged.packed)
-            bucket = arr.shape[1]
+            bucket = sum(buf.shape[1] for buf in staged.packed)
         else:
-            arr = jnp.asarray(staged.packed)
             bucket = staged.packed.shape[1]
-        # returns once the program is compiled and the execution enqueued
-        ok = self._kernel(arr)
-        self._tracer.end(dsp, bucket=bucket, backend=self.backend)
+        with self._calls_lock:
+            cold = bucket not in self._warm_buckets
+        account = (
+            _FirstDispatch(bucket, compile_events.serving())
+            if cold
+            else self._recompiles
+        )
+        compile_events.charge(account, bucket)
+        try:
+            if self.mesh is not None:
+                arr = self._upload_sharded(staged.packed)
+            else:
+                arr = jnp.asarray(staged.packed)
+            # returns once the program is compiled and the execution enqueued
+            ok = self._kernel(arr)
+        finally:
+            compile_events.charge(None)
+        attrs = self._note_first_dispatch(account.close()) if cold else {}
+        self._tracer.end(dsp, bucket=bucket, backend=self.backend, **attrs)
         with self._calls_lock:
             self.n_device_calls += 1
             self.n_lanes += bucket
-            self._warm_buckets.add(bucket)
         return ok
+
+    def _note_first_dispatch(self, rec: dict) -> dict:
+        """Keep and log the record of a bucket's first dispatch; returns
+        what of it the dispatch's span carries (nothing for the loser of
+        two threads that dispatched one cold bucket at once)."""
+        bucket = rec["bucket"]
+        with self._calls_lock:
+            self._warm_buckets.add(bucket)
+            if self._first_dispatches.setdefault(bucket, rec) is not rec:
+                return {}
+        saved = rec.get("compile_time_saved_s")
+        _log.info(
+            "bucket %d first dispatch %.1f s: trace %.1f, lower %.1f,"
+            " compile %.1f (cache %s%s), rest %.1f; caller %s",
+            bucket,
+            rec["end"] - rec["start"],
+            rec["trace_s"],
+            rec["lower_s"],
+            rec["compile_s"],
+            rec["cache"],
+            "" if saved is None else ", %.1f s saved" % saved,
+            rec["rest_s"],
+            rec["caller"],
+        )
+        attrs = {k: rec[k] for k in _FIRST_SPAN_ATTRS if k in rec}
+        attrs["first"] = True
+        return attrs
 
     def _upload_sharded(self, shards):
         """One host->device transfer PER SHARD: each chip's C-contiguous
@@ -1178,7 +1311,7 @@ class BatchVerifier:
             # [L]·P == identity proofs served on the batch plane (the
             # aggregate scheme's fresh-R offload)
             "torsion_items": self.n_torsion_items,
-            "verify_seconds": self.verify_seconds,
+            "first_dispatch": self._first_dispatch_stats(),
             # 0 = unsharded single-queue dispatch; >0 = chips on the
             # batch-axis mesh (Config.SIG_MESH; bench close lines carry
             # this as sig_mesh_devices so every JSON records the mode)
@@ -1186,3 +1319,28 @@ class BatchVerifier:
                 len(self.mesh.devices.flat) if self.mesh is not None else 0
             ),
         }
+
+    def _first_dispatch_stats(self) -> dict:
+        """Where the seconds of each bucket's first dispatch went, as JAX
+        reported them on the dispatching thread (counted whether or not
+        the tracer is on; monotonic).  ``wall_s`` is the length of the
+        union of the records' intervals: two buckets first dispatched on
+        two threads interleave under the interpreter lock, and their sum
+        would count the overlap twice.  ``unattributed``: stage events of
+        the whole process that no dispatch was open for; ``recompiles``:
+        those of a dispatch whose bucket had run before — 0 on a healthy
+        node, whatever its age."""
+        with self._calls_lock:
+            recs = {b: dict(r) for b, r in self._first_dispatches.items()}
+        out: dict = {
+            "buckets": recs,
+            "wall_s": _union_seconds(
+                (r["start"], r["end"]) for r in recs.values()
+            ),
+        }
+        for k in _FIRST_SUMS:
+            out[k] = sum(r[k] for r in recs.values())
+        loose = compile_events.unattributed.stats()
+        out["unattributed"] = {k: loose[k] for k in ("events", "seconds")}
+        out["recompiles"] = self._recompiles.stats()
+        return out
