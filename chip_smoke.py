@@ -257,7 +257,13 @@ def db_rows(db_path: str, sql: str, args=()) -> list:
 
 def cache_entries(path: str) -> int:
     try:
-        return sum(1 for n in os.listdir(path) if not n.startswith("."))
+        # JAX's own entries: the program store's subdirectory
+        # (stellar_tpu/ops/programs.py) is not one
+        return sum(
+            1
+            for n in os.listdir(path)
+            if not n.startswith(".") and os.path.isfile(os.path.join(path, n))
+        )
     except OSError:
         return 0
 

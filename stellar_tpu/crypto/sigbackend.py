@@ -480,19 +480,24 @@ class TpuSigBackend(SigBackend):
     # interval, not one per batch).
     #
     # The budget follows the COMPILED SHAPE: the first dispatch of each
-    # pow-2 bucket traces, lowers and compiles its program inside the
-    # call — on a persistent-cache hit the compile is the read and load
-    # of the executable, the trace and the lower are paid all the same —
-    # so a call gets DEVICE_FIRST_TIMEOUT for every bucket it touches that
-    # has not run in this process (BatchVerifier.cold_buckets), and
-    # DEVICE_TIMEOUT once they all have.  A false latch on a healthy
-    # device would self-heal after RETRY_INTERVAL, but silently moves the
-    # node's verifies onto host meanwhile.  Measured on the one-chip v5e
-    # host (my chip runs, PR 37; PERF.md "Where set-up goes"): 21-37 s a
-    # bucket on a persistent-cache hit (the Python trace 15-24 s, the
-    # lowering 5-17 s, the executable's read and load 0.06 s), 55-72 s on
-    # a miss (XLA + Mosaic 33-34 s more); the default leaves 2x over the
-    # cold figure.
+    # pow-2 bucket gets its program ready inside the call — it loads the
+    # bucket's lowered program from the program store (ops/programs.py)
+    # and the executable from the persistent cache, or, the first time on
+    # a machine (and after an upgrade of JAX, libtpu or the kernel's
+    # sources), traces, lowers, stores and compiles it — so a call gets
+    # DEVICE_FIRST_TIMEOUT for every bucket it touches that has not run in
+    # this process (BatchVerifier.cold_buckets), and DEVICE_TIMEOUT once
+    # they all have.  A false latch on a healthy device would self-heal
+    # after RETRY_INTERVAL, but silently moves the node's verifies onto
+    # host meanwhile.  Measured on the one-chip v5e host (my chip runs,
+    # PR 38; PERF.md "Where set-up goes"): a machine's first dispatch of a
+    # bucket is 56-64 s (the Python trace 16-22 s, the export's lowering
+    # 7 s, XLA + Mosaic 32-33 s, the serialise and the write 0.3-2.7 s); a
+    # later process start loads the stored program and the executable in
+    # 0.09-0.21 s a bucket (20-35 s until PR 38: it traced and lowered).
+    # The default is sized for the machine's first start and leaves 2x
+    # over it; it can shrink for every later one once a deployment's own
+    # figures under the store are known (ROADMAP S9).
     # A node's own figures are in /info ``sig_backend`` ``first_dispatch``
     # (per bucket: trace_s, lower_s, compile_s, cache, caller, when), and
     # the flush that paid them is the ``sig.device_flush`` with ``cold``

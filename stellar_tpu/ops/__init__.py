@@ -7,6 +7,13 @@ and nothing is set here; otherwise the cache lives at the fixed path
 ``<checkout>/.jax_cache`` (the path is part of the cache key, so it must
 not move between processes).
 
+Beside the executables, in the subdirectory ``programs`` of the same
+directory, ``ops/programs.py`` keeps each bucket's lowered verify program, so
+that a process start loads it instead of tracing and lowering the kernel
+again.  The subdirectory is made here, at import, and never later: what
+counts the cache directory's own names never sees one appear, and JAX's
+bookkeeping of its directory never meets a file it did not write.
+
 It also registers, once a process, the listeners through which the program
 hears what JAX traced, lowered and compiled (``compile_events``): JAX calls
 them on the thread that compiles, so whoever is about to run a program for
@@ -31,6 +38,18 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+
+PROGRAMS_SUBDIR = "programs"
+if jax.config.jax_compilation_cache_dir:
+    try:
+        os.makedirs(
+            os.path.join(jax.config.jax_compilation_cache_dir, PROGRAMS_SUBDIR),
+            exist_ok=True,
+        )
+    except OSError:
+        # a cache directory this process may not write: the store is off
+        # for it (ops/programs.py), as JAX's own cache is
+        pass
 
 # The three stages of a compilation as the installed JAX reports them
 # (jax/_src/dispatch.py:60-62): wall intervals on the compiling thread, one

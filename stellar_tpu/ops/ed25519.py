@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ..util import xlog
-from . import STAGES, SUMS, StageTally, compile_events, fe
+from . import STAGES, SUMS, StageTally, compile_events, fe, programs
 from . import ref25519 as ref
 
 _log = xlog.logger("Tx")
@@ -384,23 +384,46 @@ class _StagingPool:
             self._free.setdefault(bufs[0].shape, []).append(bufs)
 
 
+# where a bucket's lowered program came from (``_FirstDispatch.program``)
+PROGRAM_STORED = "stored"
+PROGRAM_EXPORTED = "exported"
+PROGRAM_TRACED = "traced"
+
+
 class _FirstDispatch:
     """The account open on a thread while it dispatches a bucket this
     process has not run yet (``ops/__init__.py`` ``CompileEvents``): what
     JAX reports there of the program's trace, lowering and compilation,
     between ``start`` and ``end`` on ``time.monotonic`` — the tracer's clock
     and the device profile's (``trace.sync.<ns>``), so a record can be laid
-    over ``/trace`` and an ``.xplane.pb``."""
+    over ``/trace`` and an ``.xplane.pb``.
+
+    ``program`` says where the bucket's lowered program came from
+    (``BatchVerifier._first_program``): ``"stored"`` — loaded from the
+    program store, so the trace here is the wrapper's and the lowering the
+    stored module's parse; ``"exported"`` — traced, lowered and stored by
+    this process; ``"traced"`` — the store could not be used
+    (``program_error``: the exception's class) and ``jax.jit`` traced the
+    kernel as it did before there was a store."""
 
     def __init__(self, bucket: int, caller: Optional[str]):
         self.bucket = bucket
         self.caller = caller
         self.seen = dict.fromkeys((*STAGES.values(), *SUMS.values()), 0.0)
         self.seen.update(cache_hits=0, cache_misses=0)
+        self.program = PROGRAM_TRACED
+        self.program_error: Optional[str] = None
+        # the key, the read and the deserialize: no stage event lies in it
+        self.program_load_s = 0.0
+        # the stored program's file, once the key is known
+        self.program_path: Optional[str] = None
         self.start = time.monotonic()
 
     def add(self, field: str, value, bucket) -> None:
         self.seen[field] += value
+
+    def trace_lower_s(self) -> float:
+        return self.seen["trace_s"] + self.seen["lower_s"]
 
     def close(self) -> dict:
         """The record of ``stats()["first_dispatch"]["buckets"]``."""
@@ -422,13 +445,18 @@ class _FirstDispatch:
             "cache": "miss" if misses else "hit" if hits else "off",
             "cache_hits": hits,
             "cache_misses": misses,
-            # the upload, the enqueue and what JAX does not report
-            "rest_s": max(end - self.start - staged, 0.0),
+            "program": self.program,
+            "program_load_s": self.program_load_s,
+            # the upload, the enqueue, on "exported" the serialise and the
+            # write, and what JAX does not report
+            "rest_s": max(end - self.start - staged - self.program_load_s, 0.0),
             "caller": self.caller,
             "thread": threading.current_thread().name,
         }
         if hits:
             rec["compile_time_saved_s"] = seen["compile_time_saved_s"]
+        if self.program_error is not None:
+            rec["program_error"] = self.program_error
         return rec
 
 
@@ -442,6 +470,7 @@ _FIRST_SPAN_ATTRS = (
     "compile_time_saved_s",
     "rest_s",
     "caller",
+    "program",
 )
 
 # and what stats() sums over the records
@@ -588,12 +617,20 @@ class BatchVerifier:
                 self._granule,
                 -(-self.max_batch // self._granule) * self._granule,
             )
+        # the kernel as jax.jit traces it, a trace a shape: what a bucket's
+        # program is exported from, and what runs a bucket for which the
+        # program store cannot be used
         self._kernel = self._make_kernel()
-        # buckets whose program has traced, lowered and compiled in this
-        # process (one executable per padded batch size; layout, mesh and
-        # lowering are fixed per verifier, and torsion proofs ride the
-        # same program) — what cold_buckets() sizes a caller's watchdog
-        # budget from
+        # what a dispatch of a bucket calls, made once at the bucket's
+        # first dispatch (_first_program) and kept: the jit of its stored
+        # program, or self._kernel.  Never a new jit a dispatch: that would
+        # trace the wrapper again at every flush
+        self._calls: dict = {}  # analysis: locked-by _calls_lock
+        # buckets whose program has been loaded or lowered, and compiled,
+        # in this process (one executable per padded batch size; layout,
+        # mesh and lowering are fixed per verifier, and torsion proofs ride
+        # the same program) — what cold_buckets() sizes a caller's
+        # watchdog budget from
         self._warm_buckets: set = set()  # analysis: locked-by _calls_lock
         # what each bucket's first dispatch cost, by bucket, and the stage
         # events of dispatches after it: stats()["first_dispatch"]
@@ -633,6 +670,7 @@ class BatchVerifier:
             # buffers under exactly this sharding, so the jit below never
             # inserts a reshard in front of the kernel
             self._shard_sharding = shard
+            self._vec_sharding = vec
             if self.backend == "pallas":
                 from jax import shard_map
 
@@ -737,11 +775,12 @@ class BatchVerifier:
 
     def cold_buckets(self, n: int, host_assist: bool = True) -> int:
         """How many distinct buckets a call over ``n`` items dispatches to
-        whose program has not run in this process yet.  Each costs a Python
-        trace + lower and a compile (on a persistent-cache hit, the read
-        and load of the executable) inside the call, so the caller's
-        watchdog scales its budget by this count; what each cost this
-        process is ``stats()["first_dispatch"]["buckets"]``.
+        whose program has not run in this process yet.  Each costs, inside
+        the call, the load of its stored program (``ops/programs.py``; the
+        Python trace + lower where the machine has none yet) and a compile
+        (on a persistent-cache hit, the read and load of the executable),
+        so the caller's watchdog scales its budget by this count; what
+        each cost this process is ``stats()["first_dispatch"]["buckets"]``.
         ``host_assist=False`` for torsion batches, which never peel."""
         n_dev = n - self._host_assist_count(n) if host_assist else n
         sizes = {self._bucket(count) for _, count in self._chunks(n_dev)}
@@ -1202,9 +1241,10 @@ class BatchVerifier:
         in-flight device result, or None when every lane was
         gate-rejected (hostile floods never reach the chip).
 
-        A bucket's first dispatch in this process traces, lowers and
-        compiles its program inside ``self._kernel``: the thread opens an
-        account for what JAX reports of that, and the record goes to
+        A bucket's first dispatch in this process loads its lowered
+        program from the program store — or traces and lowers it, and
+        stores it — and compiles it (``_first_program``): the thread opens
+        an account for what JAX reports of that, and the record goes to
         ``stats()``, onto this one span and into one log line.  A later
         dispatch marks its thread too, so that a compilation that should
         not happen any more is counted against its bucket."""
@@ -1217,6 +1257,7 @@ class BatchVerifier:
             bucket = staged.packed.shape[1]
         with self._calls_lock:
             cold = bucket not in self._warm_buckets
+            call = None if cold else self._calls[bucket]
         account = (
             _FirstDispatch(bucket, compile_events.serving())
             if cold
@@ -1224,12 +1265,17 @@ class BatchVerifier:
         )
         compile_events.charge(account, bucket)
         try:
+            if cold:
+                call = self._first_program(bucket, account)
             if self.mesh is not None:
                 arr = self._upload_sharded(staged.packed)
             else:
                 arr = jnp.asarray(staged.packed)
             # returns once the program is compiled and the execution enqueued
-            ok = self._kernel(arr)
+            if cold and call is not self._kernel:
+                ok = self._first_call(bucket, account, call, arr)
+            else:
+                ok = call(arr)
         finally:
             compile_events.charge(None)
         attrs = self._note_first_dispatch(account.close()) if cold else {}
@@ -1238,6 +1284,127 @@ class BatchVerifier:
             self.n_device_calls += 1
             self.n_lanes += bucket
         return ok
+
+    def _program_fields(self, bucket: int) -> dict:
+        """Everything that decides the program a bucket lowers to, and
+        nothing that does not (``ops/programs.py``): a stale program is a
+        wrong verdict, so where in doubt a field is in."""
+        import jaxlib
+
+        dev = jax.devices()[0]
+        fields = {
+            "sources": programs.source_digests(),
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            # libtpu's build is in it
+            "platform_version": dev.client.platform_version,
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": len(jax.devices()),
+            "mesh": (
+                None
+                if self.mesh is None
+                else [list(self.mesh.axis_names), list(self.mesh.devices.shape)]
+            ),
+            "x64": bool(jax.config.jax_enable_x64),
+            "bucket": bucket,
+            "rows": self._rows,
+            "backend": self.backend,
+            "interpret": self.interpret,
+            "device_hash": self.device_hash,
+        }
+        # the module-level flags that change the traced body, by value
+        if self.backend == "pallas":
+            from . import ed25519_pallas as pallas
+
+            fields.update(
+                NT=pallas.NT,
+                batch_inv=pallas._BATCH_INV,
+                signed_win=pallas._SIGNED_WIN,
+            )
+        else:
+            fields["batch_inv"] = self.mesh is None
+        return fields
+
+    def _first_program(self, bucket: int, account: _FirstDispatch):
+        """-> what this verifier calls for ``bucket`` from now on.  A hit
+        of the program store deserialises the bucket's program; a miss
+        exports it from ``self._kernel`` — the one trace and lowering this
+        machine pays for the bucket — stores it, and runs through the
+        stored program on this process too, so that the executable the
+        persistent cache keeps is the one every later process asks for.
+        Whatever goes wrong leaves the bucket on ``self._kernel``, is
+        logged once and counted (``programs_traced``); it never fails a
+        flush, and nothing is tried again for the bucket in this process."""
+        t0 = time.monotonic()
+        try:
+            directory = programs.store_dir()
+            if directory is None:
+                raise FileNotFoundError("no directory for the program store")
+            path = account.program_path = programs.path_of(
+                directory, self._program_fields(bucket)
+            )
+            try:
+                exported = programs.load(path)
+            finally:
+                account.program_load_s = time.monotonic() - t0
+            if exported is not None:
+                account.program = PROGRAM_STORED
+            else:
+                if not os.access(directory, os.W_OK):
+                    # asked before the export, not found out at the write:
+                    # the trace and the lowering would be paid twice
+                    raise PermissionError(directory)
+                seen, t1 = account.trace_lower_s(), time.monotonic()
+                traced = jax.export.export(self._kernel)(
+                    jax.ShapeDtypeStruct((self._rows, bucket), jnp.uint8)
+                )
+                if account.trace_lower_s() <= seen:
+                    # a JAX that reports no stage from inside the export:
+                    # the call's own time, or the account would go blind
+                    # on the one path that still costs a minute
+                    account.add("trace_s", time.monotonic() - t1, bucket)
+                exported = programs.save(path, traced)
+                account.program = PROGRAM_EXPORTED
+            if self.mesh is not None:
+                call = jax.jit(
+                    exported.call,
+                    in_shardings=(self._shard_sharding,),
+                    out_shardings=self._vec_sharding,
+                )
+            else:
+                call = jax.jit(exported.call)
+        except Exception as e:
+            call = self._program_unusable(bucket, account, e)
+        with self._calls_lock:
+            # of two threads at one cold bucket both run what the first kept
+            return self._calls.setdefault(bucket, call)
+
+    def _program_unusable(self, bucket, account, err):
+        """The program store failed ``bucket``: remove the file where there
+        is one, say so once, and leave the bucket on the traced kernel."""
+        if account.program_path is not None:
+            programs.discard(account.program_path)
+        account.program = PROGRAM_TRACED
+        account.program_error = type(err).__name__
+        _log.warning(
+            "bucket %d: no stored program (%s: %s); tracing the kernel",
+            bucket,
+            type(err).__name__,
+            err,
+        )
+        return self._kernel
+
+    def _first_call(self, bucket, account, call, arr):
+        """A stored program's first call, where it is lowered into its
+        wrapper and compiled: a module that does not parse or a program
+        that refuses the platform or the device count shows here."""
+        try:
+            return call(arr)
+        except Exception as e:
+            with self._calls_lock:
+                self._calls[bucket] = self._program_unusable(bucket, account, e)
+            return self._kernel(arr)
 
     def _note_first_dispatch(self, rec: dict) -> dict:
         """Keep and log the record of a bucket's first dispatch; returns
@@ -1250,10 +1417,11 @@ class BatchVerifier:
                 return {}
         saved = rec.get("compile_time_saved_s")
         _log.info(
-            "bucket %d first dispatch %.1f s: trace %.1f, lower %.1f,"
-            " compile %.1f (cache %s%s), rest %.1f; caller %s",
+            "bucket %d first dispatch %.1f s: program %s, trace %.1f,"
+            " lower %.1f, compile %.1f (cache %s%s), rest %.1f; caller %s",
             bucket,
             rec["end"] - rec["start"],
+            rec["program"],
             rec["trace_s"],
             rec["lower_s"],
             rec["compile_s"],
@@ -1340,6 +1508,11 @@ class BatchVerifier:
         }
         for k in _FIRST_SUMS:
             out[k] = sum(r[k] for r in recs.values())
+        # how often the program store engages
+        for kind in (PROGRAM_STORED, PROGRAM_EXPORTED, PROGRAM_TRACED):
+            out["programs_" + kind] = sum(
+                1 for r in recs.values() if r["program"] == kind
+            )
         loose = compile_events.unattributed.stats()
         out["unattributed"] = {k: loose[k] for k in ("events", "seconds")}
         out["recompiles"] = self._recompiles.stats()

@@ -27,6 +27,8 @@ from stellar_tpu.trace.tracer import Tracer
 
 STAGES = ("trace_s", "lower_s", "compile_s")
 SUMMED = STAGES + ("cache_retrieval_s", "cache_hits", "cache_misses")
+PROGRAMS = ("stored", "exported", "traced")
+COUNTED = tuple("programs_" + p for p in PROGRAMS)
 
 
 def triples(n: int, salt: int = 0):
@@ -46,7 +48,13 @@ def check_record(rec: dict, bucket: int) -> None:
     # JAX's nested reports are counted once: the stages fit the wall time
     assert sum(rec[k] for k in STAGES) <= wall
     assert rec["trace_s"] > 0 and rec["lower_s"] > 0 and rec["compile_s"] > 0
-    assert rec["rest_s"] == pytest.approx(wall - sum(rec[k] for k in STAGES))
+    # PR 38: where the program came from, and what finding it cost; the
+    # stages and the load lie one beside the other inside the wall time
+    assert rec["program"] in PROGRAMS
+    assert ("program_error" in rec) == (rec["program"] == "traced")
+    assert rec["program_load_s"] >= 0
+    assert sum(rec[k] for k in STAGES) + rec["program_load_s"] <= wall
+    assert rec["rest_s"] == pytest.approx(wall - sum(rec[k] for k in STAGES) - rec["program_load_s"])
     assert rec["cache"] in ("hit", "miss", "off")
     assert rec["cache_hits"] + rec["cache_misses"] <= 1  # one program, asked once
     assert ("compile_time_saved_s" in rec) == (rec["cache"] == "hit")
@@ -98,7 +106,7 @@ def test_nothing_dispatched_nothing_recorded(plain):
     _, seen = plain
     fd = seen["empty"]
     assert fd["buckets"] == {} and fd["wall_s"] == 0
-    assert all(fd[k] == 0 for k in SUMMED)
+    assert all(fd[k] == 0 for k in SUMMED + COUNTED)
     assert fd["recompiles"] == {"events": 0, "seconds": 0.0, "bucket": None}
     assert set(fd["unattributed"]) == {"events", "seconds"}
 
@@ -114,6 +122,7 @@ def test_first_verify_leaves_one_record_a_bucket(plain):
     assert fd["wall_s"] == pytest.approx(rec["end"] - rec["start"])
     for k in SUMMED:
         assert fd[k] == rec[k]
+    assert {k: fd[k] for k in COUNTED} == {k: int(k == "programs_" + rec["program"]) for k in COUNTED}
     json.dumps(fd)  # /info serializes it
 
 
@@ -135,6 +144,7 @@ def test_wider_batch_adds_exactly_the_new_bucket(plain):
     assert fd["wall_s"] == pytest.approx(sum(walls))
     for k in SUMMED:
         assert fd[k] == pytest.approx(sum(r[k] for r in fd["buckets"].values()))
+    assert sum(fd[k] for k in COUNTED) == 2
     assert fd["recompiles"]["events"] == 0
 
 
@@ -204,7 +214,7 @@ def test_first_dispatch_span_carries_the_account(backend):
     for s in got[:2]:
         rec = recs[s.attrs["bucket"]]
         assert s.attrs["first"] is True and s.attrs["backend"] == "xla"
-        for k in STAGES + ("cache_retrieval_s", "cache", "rest_s", "caller"):
+        for k in STAGES + ("cache_retrieval_s", "cache", "rest_s", "caller", "program"):
             assert s.attrs[k] == rec[k]
         assert ("compile_time_saved_s" in s.attrs) == (rec["cache"] == "hit")
         # the record lies inside its span, on the tracer's clock
@@ -276,7 +286,7 @@ def test_info_returns_the_block():
         json.dumps(sb)
         fd = sb["first_dispatch"]
         assert fd["buckets"] == {} and fd["wall_s"] == 0
-        assert set(fd) == {"buckets", "wall_s", *SUMMED, "unattributed", "recompiles"}
+        assert set(fd) == {"buckets", "wall_s", *SUMMED, *COUNTED, "unattributed", "recompiles"}
     finally:
         a.graceful_stop()
         clock.shutdown()
