@@ -1084,14 +1084,11 @@ def bench_ledger_close(n_txs=5000, n_ledgers=3):
             "cow_seals_per_tx": round(d_seals / n_applied, 2),
             "cow_copies_per_tx": round(d_unseals / n_applied, 2),
             # close pipeline (ISSUE r10): verify wall hidden inside the
-            # previous close's apply, and the lookahead depth it ran at
+            # previous close's apply
             "overlap_hidden_ms": (
                 app.close_pipeline.stats()["overlap_hidden_ms"]
                 if pipe is not None
                 else 0.0
-            ),
-            "close_pipeline_depth": (
-                app.close_pipeline.depth if pipe is not None else 0
             ),
             # multi-chip sharded verify (ISSUE r13): chips on the sig
             # backend's batch-axis mesh — 0 records unsharded dispatch

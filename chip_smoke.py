@@ -13,8 +13,9 @@ admin HTTP routes only.
            ledger 7 is published to a file archive.
   Phase B  the node under test, on the chip.  A fresh SIGNATURE_BACKEND="tpu"
            node, every verify knob at its default, replays that history
-           with /catchup?mode=complete — one signature flush per archived
-           ledger, 4096 + 1024 lanes for a 5000-tx ledger — must land on
+           with /catchup?mode=complete — the signatures of the ledgers ahead
+           prefetched through the close pipeline in SIG_BATCH_MAX-lane
+           batches filled across ledger boundaries — must land on
            the producer's anchor hash, then closes one ledger of its own
            from /tx submissions.  What did the work is read back from
            /info, /trace and /invariants and asserted: a Mosaic-compiled
@@ -358,22 +359,15 @@ def phase_a(ctx: dict) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def expected_split(txs_per_ledger, size: dict):
-    """(device items, device dispatches, cutover items) a replay of these
-    ledgers must produce: one flush of all-miss signatures per ledger, to
-    the device in SIG_BATCH_MAX chunks at or above the cutover."""
-    dev_items = dev_calls = host_items = 0
-    for n in txs_per_ledger:
-        if n >= size["cpu_cutover"]:
-            dev_items += n
-            dev_calls += -(-n // size["sig_batch_max"])
-        else:
-            host_items += n
-    return dev_items, dev_calls, host_items
-
-
 def check_backend(ctx: dict, sb: dict, hist: dict) -> None:
-    """What did the replay's work, from /info's sig_backend block."""
+    """What did the replay's work, from /info's sig_backend block.  The
+    replay prefetches through the close pipeline, across ledger boundaries
+    (``ledger/closepipeline.py``), so which flush carried which ledger is
+    the scheduler's business; what has to hold is that every signature of
+    the history was verified once, in a batch — none by the eager
+    one-at-a-time path, none by the watchdog's — that the device took at
+    least the payment ledgers, and that it took them in SIG_BATCH_MAX
+    chunks."""
     size = ctx["size"]
     want_platform = "cpu" if ctx["rehearsal"] else "tpu"
     check(sb.get("platform") == want_platform, f"node runs jax on {sb.get('platform')!r}")
@@ -386,21 +380,23 @@ def check_backend(ctx: dict, sb: dict, hist: dict) -> None:
             f"kernel lowering is {sb['kernel']!r} interpret={sb['interpret']!r}",
         )
     check(sb["native_host_stage"] is True, "the native host stage is not live")
-    dev_items, dev_calls, host_items = expected_split(
-        hist["txs_per_ledger"].values(), size
+    signatures = sum(hist["txs_per_ledger"].values())
+    check(
+        sb["items"] + sb["cpu_cutover_items"] == signatures,
+        f"device verified {sb['items']} items and the cutover {sb['cpu_cutover_items']};"
+        f" history holds {signatures} signatures",
     )
     check(
-        dev_items >= size["payment_ledgers"] * size["sig_batch_max"],
-        f"history holds only {dev_items} device-bound signatures",
+        sb["items"] >= size["payment_ledgers"] * size["sig_batch_max"],
+        f"the device got only {sb['items']} of the history's signatures",
     )
     check(
-        sb["items"] == dev_items and sb["device_calls"] == dev_calls,
-        f"device verified {sb['items']} items in {sb['device_calls']} dispatches,"
-        f" history needs {dev_items} in {dev_calls}",
+        sb["device_calls"] >= -(-sb["items"] // size["sig_batch_max"]) and sb["lanes"] >= sb["items"],
+        f"{sb['items']} items in {sb['device_calls']} dispatches of {sb['lanes']} lanes",
     )
     check(
-        sb["cpu_cutover_items"] == host_items,
-        f"cutover took {sb['cpu_cutover_items']} items, expected {host_items}",
+        sb.get("eager_host_verifies", 0) == 0,
+        f"{sb.get('eager_host_verifies')} signature checks at apply missed the prefetch",
     )
     check(sb["gate_rejects"] == 0 and sb["host_assist_items"] == 0, f"{sb}")
     check(

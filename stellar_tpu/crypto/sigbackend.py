@@ -71,6 +71,9 @@ class SigFlushFuture:
         # a put_many it doesn't see
         self._latch = None  # analysis: locked-by _lock
         self._latched = False  # analysis: locked-by _lock
+        # the close pipeline's: set at the first join of a flush that
+        # several ledgers' sets rode, so its hidden work is counted once
+        self.joined = False
 
     def done(self) -> bool:
         return self._done.is_set()
@@ -418,6 +421,7 @@ class TpuSigBackend(SigBackend):
         native_hash: Optional[bool] = None,
         device_hash: Optional[bool] = None,
         tracer=None,
+        shared_programs: bool = False,
     ):
         from ..ops.ed25519 import BatchVerifier  # lazy: JAX import
 
@@ -446,6 +450,7 @@ class TpuSigBackend(SigBackend):
             native_hash=native_hash,
             device_hash=device_hash,
             tracer=tracer,
+            shared_programs=shared_programs,
         )
         # Below this many cache misses a device round-trip costs more than
         # looping libsodium on host — lone SCP envelopes and small tx sets

@@ -7,10 +7,27 @@ Two modes (HistoryManager.h:186-197):
   ledger-header chain, replay the buckets into the SQL store
   (Bucket.apply), adopt the bucket-list shape (assumeState), and jump the
   LCL to the anchor header.
-- COMPLETE: fetch every ledger/transactions/results checkpoint from the
-  local LCL forward, verify the header hash-chain back from the anchor,
-  and replay each ledger through the normal ``close_ledger`` path (full
-  signature checks — this is the reference's replay semantics).
+- COMPLETE: fetch every ledger/transactions checkpoint from the local LCL
+  forward, verify the header hash-chain back from the anchor, and replay
+  each ledger through the normal ``close_ledger`` path (full signature
+  checks — this is the reference's replay semantics), ONE LEDGER A CLOCK
+  POST: between two replayed ledgers the node answers its HTTP routes,
+  keeps its peers and fires its timers.  The whole verified range is
+  handed to the close pipeline as upcoming sets before the first ledger
+  applies, so the signatures of ledgers ahead verify in device batches
+  filled across ledger boundaries (``ledger/closepipeline.py``) while the
+  ledgers before them apply; the prefetch only warms the verify cache —
+  every signature check at apply still decides for itself, and every
+  replayed hash is compared with the archive's.
+
+A round records ``catchup.round`` (mode, first and last ledger) with, as its
+children, ``catchup.fetch`` (download + gunzip: files, bytes),
+``catchup.decode`` (files, headers, transactions), ``catchup.verify_chain``,
+``catchup.prefetch`` (the hand-over to the pipeline: sets, signatures, and
+what its first dispatch collected and flushed) and one
+``catchup.apply_ledger`` (seq, txs) a replayed ledger, in which
+``ledger.close`` nests; ``HistoryManager.stats`` (``/info`` ``history``)
+counts rounds, ledgers and transactions replayed and triples prefetched.
 
 Failures retry with a fresh random archive after a backoff, up to
 ``MAX_RETRIES`` (CatchupStateMachine.h RETRYING loop).
@@ -20,9 +37,10 @@ from __future__ import annotations
 
 import os
 import random
+from collections import deque
 from typing import Callable, Dict, List, Optional
 
-from ..util import VirtualTimer, xlog
+from ..util import VirtualTimer, collector, xlog
 from ..util.xdrstream import XDRInputFileStream
 from ..xdr.ledger import (
     LedgerHeaderHistoryEntry,
@@ -79,7 +97,13 @@ class CatchupStateMachine:
         self.has: Optional[HistoryArchiveState] = None
         self.tmp = app.tmp_dirs.tmp_dir("catchup")
         self.headers: Dict[int, LedgerHeaderHistoryEntry] = {}
+        # COMPLETE: each ledger's set decoded once, as the frame it applies as
         self.tx_sets: Dict[int, object] = {}
+        # COMPLETE: (header entry, set) of the ledgers still to replay
+        self._replay: deque = deque()
+        self._round_sp = None
+        # the pipeline's count of prefetched triples as the replay began
+        self._prefetched_before: Optional[int] = None
         self._timer = VirtualTimer(app.clock)
         # archive spread is load-balancing; seed the pick from the node's
         # identity XOR a per-process construction nonce so a catchup run
@@ -100,6 +124,10 @@ class CatchupStateMachine:
     # -- BEGIN: pick archive, fetch root state -----------------------------
     def begin(self) -> None:
         self.state = "BEGIN"
+        self._round_sp = self.app.tracer.begin(
+            "catchup.round", detached=True, mode=self.mode
+        )
+        self.app.history_manager.replay_stats["rounds"] += 1
         readable = [
             HistoryArchive(name, spec)
             for name, spec in self.app.config.HISTORY.items()
@@ -198,12 +226,25 @@ class CatchupStateMachine:
             self._verify([])
             return
         counter = {"left": len(files), "ok": True}
+        fetch_sp = self.app.tracer.begin(
+            "catchup.fetch", parent=self._round_sp, detached=True,
+            files=len(files),
+        )
 
         def file_done(fi, ok):
             fi.state = FILE_VERIFIED if ok else FILE_FAILED
             counter["left"] -= 1
             counter["ok"] = counter["ok"] and ok
             if counter["left"] == 0:
+                self.app.tracer.end(
+                    fetch_sp,
+                    ok=counter["ok"],
+                    bytes=sum(
+                        os.path.getsize(f.local_path)
+                        for f in files
+                        if os.path.exists(f.local_path)
+                    ),
+                )
                 if counter["ok"]:
                     self._verify(files)
                 else:
@@ -238,19 +279,38 @@ class CatchupStateMachine:
             # adoption (CatchupStateMachine.cpp:718-721); no header chain
             self._apply(files)
             return
+        from ..herder.txset import TxSetFrame
+
+        tracer = self.app.tracer
         try:
             self.headers.clear()
             self.tx_sets.clear()
-            for fi in files:
-                if fi.category == CAT_LEDGER:
-                    with XDRInputFileStream(fi.local_path) as f:
-                        for lhe in f.read_all(LedgerHeaderHistoryEntry):
-                            self.headers[lhe.header.ledgerSeq] = lhe
-                elif fi.category == CAT_TRANSACTIONS:
-                    with XDRInputFileStream(fi.local_path) as f:
-                        for the in f.read_all(TransactionHistoryEntry):
-                            self.tx_sets[the.ledgerSeq] = the.txSet
-            ok = self._verify_header_chain()
+            with tracer.span(
+                "catchup.decode", parent=self._round_sp, files=len(files)
+            ) as sp:
+                for fi in files:
+                    if fi.category == CAT_LEDGER:
+                        with XDRInputFileStream(fi.local_path) as f:
+                            for lhe in f.read_all(LedgerHeaderHistoryEntry):
+                                self.headers[lhe.header.ledgerSeq] = lhe
+                    elif fi.category == CAT_TRANSACTIONS:
+                        with XDRInputFileStream(fi.local_path) as f:
+                            for the in f.read_all(TransactionHistoryEntry):
+                                self.tx_sets[the.ledgerSeq] = (
+                                    TxSetFrame.from_xdr_set(
+                                        self.app.network_id, the.txSet
+                                    )
+                                )
+                tracer.end(
+                    sp,
+                    headers=len(self.headers),
+                    txs=sum(ts.size() for ts in self.tx_sets.values()),
+                )
+            with tracer.span(
+                "catchup.verify_chain", parent=self._round_sp,
+                headers=len(self.headers),
+            ):
+                ok = self._verify_header_chain()
         except Exception as e:
             log.error("catchup: verification error: %s", e)
             ok = False
@@ -298,6 +358,7 @@ class CatchupStateMachine:
                 log.error("bucket repair: adopt failed: %s", e)
                 self._retry()
                 return
+            self._end_round(True)
             self.state = "END"
             self.done(True, None)
             self.app.tmp_dirs.forget(self.tmp)
@@ -306,12 +367,19 @@ class CatchupStateMachine:
             if self.mode == CATCHUP_MINIMAL:
                 self._apply_minimal(files)
             else:
+                # replays from the clock, ledger by ledger, and finishes there
                 self._apply_complete()
+                return
         except Exception as e:
             log.error("catchup: apply failed: %s", e)
             self._retry()
             return
+        self._finish()
+
+    def _finish(self) -> None:
+        """The range is applied: hand the anchor to the completion handler."""
         anchor = self.headers[self.has.current_ledger]
+        self._end_round(True)
         try:
             self.state = "END"
             self.done(True, anchor)
@@ -380,41 +448,119 @@ class CatchupStateMachine:
         bm.assume_state(has.to_json())
 
     def _apply_complete(self) -> None:
-        """Replay each fetched ledger through close_ledger (full checks)."""
-        from ..herder.ledgerclose import LedgerCloseData
+        """Queue the fetched range for replay, hand every set of it to the
+        close pipeline as upcoming, and post the first ledger."""
         from ..herder.txset import TxSetFrame
 
         lm = self.app.ledger_manager
-        seq = lm.get_last_closed_ledger_num() + 1
+        first = lm.get_last_closed_ledger_num() + 1
         anchor = self.has.current_ledger
-        while seq <= anchor:
+        self._replay.clear()
+        for seq in range(first, anchor + 1):
             lhe = self.headers.get(seq)
             if lhe is None:
                 raise RuntimeError(f"missing header {seq} in archive")
-            xdr_set = self.tx_sets.get(seq)
-            if xdr_set is not None:
-                ts = TxSetFrame.from_xdr_set(self.app.network_id, xdr_set)
-            else:
-                ts = TxSetFrame(lm.last_closed.hash)
+            # a ledger with no entry in the transactions file closed empty
+            ts = self.tx_sets.pop(seq, None) or TxSetFrame(
+                lhe.header.previousLedgerHash
+            )
+            self._replay.append((lhe, ts))
+        self.tx_sets.clear()
+        if self._round_sp is not None:
+            self._round_sp.attrs.update(first=first, last=anchor)
+        pipe = lm._close_pipeline()
+        if pipe is not None:
+            # every upcoming set is known before the first applies: the
+            # one path on which the prefetch can fill whole device batches
+            # across ledger boundaries
+            tracer = self.app.tracer
+            with tracer.span(
+                "catchup.prefetch", parent=self._round_sp,
+                sets=len(self._replay),
+                signatures=sum(
+                    len(tx.envelope.signatures)
+                    for _, ts in self._replay
+                    for tx in ts.transactions
+                ),
+            ):
+                self._prefetched_before = pipe.n_items
+                for _, ts in self._replay:
+                    pipe.note_upcoming(ts.transactions)
+                pipe.dispatch_ahead(tracer)
+        # the range stays decoded until its last ledger applies: keep it
+        # out of the full collector passes the closes in between run
+        collector.park()
+        self.app.clock.post(self._apply_next)
+
+    def _apply_next(self) -> None:
+        """Replay one ledger through ``close_ledger`` (its close joins its
+        prefetch at the top and dispatches further ahead before it
+        applies), compare its hash with the archive's, and post the next."""
+        if self.state != "APPLYING":
+            return  # the round was abandoned between two posts
+        if not self._replay:
+            self._finish()
+            return
+        from ..herder.ledgerclose import LedgerCloseData
+
+        lm = self.app.ledger_manager
+        lhe, ts = self._replay.popleft()
+        seq = lhe.header.ledgerSeq
+        tracer = self.app.tracer
+        sp = tracer.begin(
+            "catchup.apply_ledger", parent=self._round_sp, req=seq, seq=seq,
+            txs=ts.size(),
+        )
+        try:
             lm.close_ledger(LedgerCloseData(seq, ts, lhe.header.scpValue))
             if lm.last_closed.hash != lhe.hash:
                 raise RuntimeError(
                     f"replayed ledger {seq} hash mismatch vs archive"
                 )
-            seq += 1
+        except Exception as e:
+            tracer.end(sp, failed=True)
+            log.error("catchup: apply failed: %s", e)
+            self._retry()
+            return
+        tracer.end(sp)
+        stats = self.app.history_manager.replay_stats
+        stats["ledgers_replayed"] += 1
+        stats["txs_replayed"] += ts.size()
+        self.app.clock.post(self._apply_next)
 
     # -- retry loop --------------------------------------------------------
+    def _end_round(self, ok: bool) -> None:
+        """Close the round's span and its books; of a round that failed,
+        whatever of the range was not replayed is let go and what the
+        pipeline still had in flight for it is quarantined."""
+        self._replay.clear()
+        if self.mode == CATCHUP_COMPLETE:
+            collector.unpark()
+        pipe = self.app.ledger_manager._close_pipeline()
+        if pipe is not None and self.mode == CATCHUP_COMPLETE:
+            if not ok:
+                pipe.abort_inflight()
+            if self._prefetched_before is not None:
+                self.app.history_manager.replay_stats[
+                    "triples_prefetched"
+                ] += pipe.n_items - self._prefetched_before
+                self._prefetched_before = None
+        self.app.tracer.end(self._round_sp, ok=ok)
+        self._round_sp = None
+
     def _retry(self) -> None:
         self.retries += 1
         if self.retries > MAX_RETRIES:
             self._fail()
             return
+        self._end_round(False)
         self.state = "RETRYING"
         log.info("catchup: retry %d/%d", self.retries, MAX_RETRIES)
         self._timer.expires_from_now(RETRY_DELAY_SECONDS)
         self._timer.async_wait(self.begin)
 
     def _fail(self) -> None:
+        self._end_round(False)
         self.state = "FAILED"
         self.app.tmp_dirs.forget(self.tmp)
         self.done(False, None)

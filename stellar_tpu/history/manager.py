@@ -30,6 +30,16 @@ class HistoryManager:
         self.catchup: Optional[CatchupStateMachine] = None
         self._publish_success = 0
         self._publish_failure = 0
+        # what catch-up did since the node started (monotonic): rounds
+        # begun, and by CATCHUP_COMPLETE's replay the ledgers and
+        # transactions applied and the signature triples handed to the
+        # close pipeline's prefetch
+        self.replay_stats = {
+            "rounds": 0,
+            "ledgers_replayed": 0,
+            "txs_replayed": 0,
+            "triples_prefetched": 0,
+        }
 
     @property
     def checkpoint_frequency(self) -> int:
@@ -172,6 +182,25 @@ class HistoryManager:
         """Smallest queued-but-unpublished checkpoint ledger, 0 if none
         (reference: getMinLedgerQueuedToPublish, gates maintenance)."""
         return publish_queue.min_queued(self.app.database)
+
+    def stats(self) -> dict:
+        """``/info`` ``history``: the catch-up in progress, if any, and the
+        counters above."""
+        out = dict(self.replay_stats)
+        fsm = self.catchup
+        out["catchup"] = (
+            None
+            if fsm is None
+            else {
+                "mode": fsm.mode,
+                "state": fsm.state,
+                "retries": fsm.retries,
+                "ledgers_left": len(fsm._replay),
+            }
+        )
+        out["published"] = self._publish_success
+        out["publish_failures"] = self._publish_failure
+        return out
 
     def get_publish_success_count(self) -> int:
         return self._publish_success

@@ -13,12 +13,22 @@ already complete — the device/host verify cost hid inside N's apply wall.
 
 Shapes that genuinely present a >1 backlog (where the overlap pays):
 
-- catchup replay (``LedgerManager.history_caught_up``): every buffered
-  ledger enqueues before the drain closes them in sequence;
+- catchup replay: the state machine's CATCHUP_COMPLETE range
+  (``history/catchupsm.py`` notes every verified set as upcoming before the
+  first applies) and ``LedgerManager.history_caught_up``'s buffered ledgers,
+  which enqueue before the drain closes them in sequence;
 - a validator lagging consensus: externalized values arrive faster than
   closes complete and queue here instead of closing inline;
 - steady state still prewarms the overlay's pending SCP envelope batch,
   so the next crank's flush is a cache hit.
+
+With more than one set upcoming the prefetch COALESCES: triples are
+collected set after set into one carry and leave it in whole batches of
+``SIG_BATCH_MAX`` lanes, across ledger boundaries — a 1,000-tx set alone is
+under the device cutover, sixty of them fill fifteen 4,096-lane chunks —
+running ahead no further than the verify cache can hold (``_horizon``).
+With one set upcoming the carry is that set and leaves at once: the one
+flush a set of before.
 
 Correctness contract: the pipeline is a pure PREFETCH plane.  Verdicts
 enter the shared verify cache only when a flush future completes
@@ -48,6 +58,18 @@ log = xlog.logger("Ledger")
 _MAX_SCP_FUTURES = 16
 
 
+class _Prefetch:
+    """What was collected for one upcoming set: how many triples, and the
+    flushes that carry them (a flush may carry several sets' triples, and
+    a set's may ride more than one flush)."""
+
+    __slots__ = ("items", "futures")
+
+    def __init__(self, items: int):
+        self.items = items
+        self.futures: List[SigFlushFuture] = []
+
+
 def _prewarm_key(txs) -> bytes:
     """Linkage-independent identity of a transaction bag: the txset
     contents hash covers previousLedgerHash, which an upcoming (not yet
@@ -64,12 +86,18 @@ class ClosePipeline:
 
     def __init__(self, app):
         self.app = app
-        self.depth = int(getattr(app.config, "CLOSE_PIPELINE_DEPTH", 2))
         self._queue: deque = deque()  # LedgerCloseData, consensus order
-        self._futures: Dict[bytes, SigFlushFuture] = {}
+        # upcoming sets whose triples were collected, in close order
+        self._futures: Dict[bytes, _Prefetch] = {}
         self._scp_futures: List[SigFlushFuture] = []
-        # upcoming txsets eligible for a prewarm dispatch: key -> [txs]
-        self._candidates: "dict[bytes, list]" = {}
+        # upcoming txsets eligible for a prewarm dispatch, in close order:
+        # key -> (txs, signatures they carry)
+        self._candidates: "dict[bytes, tuple]" = {}
+        # collected triples not yet handed to the backend, in close order:
+        # (key, triples) a set
+        self._carry: deque = deque()
+        # triples collected for sets that have not closed yet
+        self._ahead = 0
         self._draining = False
         # >0: a multi-slot SCP sweep is in progress (Herder.process_scp_
         # queue) — enqueues accumulate and the drain runs at release, so a
@@ -78,7 +106,9 @@ class ClosePipeline:
         self.n_held_sweeps = 0  # sweeps that released a >1 backlog
         # overlap accounting (bench.py overlap_hidden_ms / profile_close
         # --pipeline-report read these)
-        self.n_dispatched = 0
+        self.n_dispatched = 0  # sets whose triples were handed over
+        self.n_flushes = 0  # the async flushes they rode
+        self.n_items = 0  # the triples in those flushes
         self.n_joined = 0
         self.n_joined_warm = 0  # future already complete at join
         self.n_quarantined = 0
@@ -161,42 +191,72 @@ class ClosePipeline:
     # -- prewarm plane -------------------------------------------------------
     def note_upcoming(self, txs) -> None:
         """Register a transaction bag expected to close soon as a prewarm
-        candidate; dispatch happens at the next ``dispatch_ahead`` (i.e.
-        while the current ledger applies), bounded by the pipeline depth."""
+        candidate; collection and dispatch happen at the next
+        ``dispatch_ahead`` (i.e. while the current ledger applies), as far
+        ahead as the verify cache allows."""
         txs = list(txs)
         if not txs:
             return
         key = _prewarm_key(txs)
         if key not in self._candidates and key not in self._futures:
-            self._candidates[key] = txs
+            self._candidates[key] = (
+                txs, sum(len(tx.envelope.signatures) for tx in txs)
+            )
 
     def dispatch_ahead(self, tracer) -> None:
-        """Stage + dispatch async signature flushes for up to ``depth``
-        upcoming txsets and the overlay's pending SCP envelope batch.
-        Called by LedgerManager right before ``close.apply`` — triple
-        collection (DB reads) runs here on the close's own thread (sqlite
-        connections stay single-threaded); only the pure-compute verify
-        rides the worker."""
+        """Collect the upcoming txsets' signature triples, hand them to the
+        backend in async flushes of whole ``SIG_BATCH_MAX`` batches, and
+        flush the overlay's pending SCP envelope batch.  Called by
+        LedgerManager right before ``close.apply`` (and by the catch-up
+        replay before its first ledger) — triple collection (DB reads)
+        runs here on the close's own thread (sqlite connections stay
+        single-threaded); only the pure-compute verify rides the worker.
+
+        A set's triples are collected against the state of NOW, so a set
+        waits, uncollected, until every account it names loads — in a
+        replay the ledgers between create them — and until the cache has
+        room for it (``_horizon``; the set that closes next always has).  A
+        set that closes before it was collected is flushed by its own
+        close, inline and whole.  The carry leaves in whole batches; it is
+        flushed whole, short of a batch, when the set that closes next has
+        triples in it."""
         backend = getattr(self.app, "sig_backend", None)
-        if backend is None or not self._space():
+        if backend is None:
             return
         sp = tracer.begin("close.pipeline.dispatch")
-        n_sets = n_items = n_scp = 0
+        n_sets = n_items = n_flushed = n_scp = 0
         db = self.app.database
-        while self._candidates and self._space():
-            key, txs = next(iter(self._candidates.items()))
-            del self._candidates[key]
+        horizon = self._horizon(backend)
+        head = next(iter(self._futures), None) or next(
+            iter(self._candidates), None
+        )
+        while self._candidates:
+            key, (txs, n_sigs) = next(iter(self._candidates.items()))
+            if key != head and self._ahead + n_sigs > horizon:
+                break
             triples = []
+            tally = {"accounts": 0, "missing": 0}
             for tx in txs:
-                triples.extend(tx.candidate_signature_pairs(db))
+                triples.extend(tx.candidate_signature_pairs(db, tally))
+                if tally["missing"]:
+                    break
+            if tally["missing"]:
+                break
+            del self._candidates[key]
             if not triples:
                 continue
-            self._futures[key] = backend.verify_batch_async(
-                triples, caller=CALLER_PIPELINE
-            )
-            self.n_dispatched += 1
+            self._futures[key] = _Prefetch(len(triples))
+            self._carry.append((key, triples))
+            self._ahead += len(triples)
             n_sets += 1
             n_items += len(triples)
+        if self._carry:
+            n = sum(len(triples) for _, triples in self._carry)
+            if self._carry[0][0] != head:
+                n -= n % self.app.config.SIG_BATCH_MAX
+            if n:
+                self._flush_carry(backend, n)
+                n_flushed = n
         # pending SCP envelopes coalesced for this crank's batch flush:
         # verify them while apply runs so the flush is a cache hit.  Only
         # for schemes that verify per-envelope anyway — under
@@ -220,50 +280,92 @@ class ClosePipeline:
                         )
                     )
                     n_scp = len(scp_triples)
-        tracer.end(sp, sets=n_sets, items=n_items, scp_items=n_scp)
+        tracer.end(
+            sp, sets=n_sets, items=n_items, flushed=n_flushed, scp_items=n_scp
+        )
 
-    def _space(self) -> bool:
-        return len(self._futures) < self.depth
+    def _horizon(self, backend) -> int:
+        """How many triples may be collected for sets that have not closed
+        yet.  The verify cache evicts by last touch, and a prefetched
+        verdict has to outlive everything touched between its latch and
+        its use: the prefetches that follow it (a horizon of them at most)
+        and the older verdicts that the closes in between use (a horizon
+        again), with a batch of room for what those closes verify eagerly:
+        half the cache less a batch.  The set that closes next is exempt
+        (``dispatch_ahead``): a live node's one upcoming set is prefetched
+        whatever its width, as ever."""
+        cache = getattr(backend, "cache", None)
+        capacity = cache.capacity if cache is not None else 0xFFFF
+        return capacity // 2 - self.app.config.SIG_BATCH_MAX
+
+    def _flush_carry(self, backend, n: int) -> None:
+        """Hand the carry's first ``n`` triples to the backend as ONE async
+        flush (the verifier chunks it into ``SIG_BATCH_MAX`` lanes) and
+        note it on every set that has triples in it."""
+        batch: list = []
+        keys = []
+        while len(batch) < n:
+            key, triples = self._carry.popleft()
+            room = n - len(batch)
+            if len(triples) > room:
+                self._carry.appendleft((key, triples[room:]))
+                triples = triples[:room]
+            batch.extend(triples)
+            keys.append(key)
+        fut = backend.verify_batch_async(batch, caller=CALLER_PIPELINE)
+        self.n_flushes += 1
+        self.n_items += n
+        for key in keys:
+            pre = self._futures.get(key)
+            if pre is None:  # closed out of turn: the inline path's
+                continue
+            if not pre.futures:
+                self.n_dispatched += 1
+            pre.futures.append(fut)
 
     def join_prewarm(self, tx_set, tracer) -> bool:
-        """The join point at the top of a close: if an in-flight flush
-        covers this txset, wait for it (usually already complete — the
-        verify hid inside the previous apply) and report True so the
-        caller skips the inline prewarm.  A failed future is quarantined
-        and False returned — the close falls back to the inline path, no
-        less robust than pipeline-off."""
+        """The join point at the top of a close: if in-flight flushes cover
+        this txset, wait for them (usually already complete — the verify
+        hid inside the closes before) and report True so the caller skips
+        the inline prewarm.  A failed future is quarantined and False
+        returned — the close falls back to the inline path, no less robust
+        than pipeline-off."""
         txs = tx_set.transactions
         if not txs:
             return False
         key = _prewarm_key(txs)
         self._candidates.pop(key, None)  # closing now; candidate is stale
-        fut = self._futures.pop(key, None)
-        if fut is None:
+        pre = self._futures.pop(key, None)
+        if pre is None:
             return False
-        sp = tracer.begin("close.pipeline.join", items=fut.items)
-        warm = fut.done()
+        self._ahead -= pre.items
+        sp = tracer.begin("close.pipeline.join", items=pre.items)
+        warm = all(fut.done() for fut in pre.futures)
         t0 = time.perf_counter()
-        try:
-            fut.result()
-        except BaseException as e:
-            fut.quarantine()
-            self.n_quarantined += 1
-            self.n_fallback += 1
-            log.warning(
-                "pipelined sig prewarm failed (%s: %s); falling back to"
-                " the inline flush",
-                type(e).__name__,
-                e,
-            )
-            tracer.end(sp, ok=False, warm=warm)
-            return False
+        hidden_ms = 0.0
+        for fut in pre.futures:
+            t1 = time.perf_counter()
+            try:
+                fut.result()
+            except BaseException as e:
+                fut.quarantine()
+                self.n_quarantined += 1
+                self.n_fallback += 1
+                log.warning(
+                    "pipelined sig prewarm failed (%s: %s); falling back to"
+                    " the inline flush",
+                    type(e).__name__,
+                    e,
+                )
+                tracer.end(sp, ok=False, warm=warm)
+                return False
+            if not fut.joined:
+                # a flush that several sets rode hid its work once
+                fut.joined = True
+                total_ms = (fut.completed_at - fut.dispatched_at) * 1000.0
+                waited_ms = (time.perf_counter() - t1) * 1000.0
+                hidden_ms += max(0.0, total_ms - waited_ms)
         wait_ms = (time.perf_counter() - t0) * 1000.0
-        total_ms = (
-            (fut.completed_at - fut.dispatched_at) * 1000.0
-            if fut.completed_at is not None
-            else 0.0
-        )
-        hidden_ms = max(0.0, total_ms - wait_ms)
         self.n_joined += 1
         self.n_joined_warm += 1 if warm else 0
         self.overlap_hidden_ms += hidden_ms
@@ -282,10 +384,17 @@ class ClosePipeline:
         its successors) collected these triples against state that is
         rolling back — their verdicts must neither latch into nor remain
         in the shared verify cache."""
-        for fut in self._futures.values():
+        inflight = {
+            id(fut): fut
+            for pre in self._futures.values()
+            for fut in pre.futures
+        }
+        for fut in inflight.values():
             fut.quarantine()
             self.n_quarantined += 1
         self._futures.clear()
+        self._carry.clear()
+        self._ahead = 0
         for fut in self._scp_futures:
             fut.quarantine()
             self.n_quarantined += 1
@@ -295,11 +404,12 @@ class ClosePipeline:
     # -- telemetry -----------------------------------------------------------
     def stats(self) -> dict:
         return {
-            "depth": self.depth,
             "backlog_drains": self.n_held_sweeps,
             "queued": len(self._queue),
             "inflight": len(self._futures),
             "dispatched": self.n_dispatched,
+            "flushes": self.n_flushes,
+            "prefetched_items": self.n_items,
             "joined": self.n_joined,
             "joined_warm": self.n_joined_warm,
             "quarantined": self.n_quarantined,

@@ -277,10 +277,12 @@ class LedgerManager:
 
     def start_catchup(self, mode: Optional[str] = None) -> None:
         pipe = self._close_pipeline()
-        if pipe is not None:
+        if pipe is not None and self.state != LedgerState.LM_CATCHING_UP_STATE:
             # catchup interrupt: in-flight prewarm futures quarantine (the
             # cache must not keep verdicts from a plane that just forked)
-            # and queued-but-unclosed ledgers move into the catchup buffer
+            # and queued-but-unclosed ledgers move into the catchup buffer.
+            # Not while a catch-up is already running: the pipeline then
+            # holds nothing but that replay's own prefetch
             self.syncing_ledgers.extend(pipe.interrupt())
         self.state = LedgerState.LM_CATCHING_UP_STATE
         self.app.request_catchup()

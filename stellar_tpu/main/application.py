@@ -79,6 +79,9 @@ class Application:
             cpu_cutover=config.TPU_CPU_CUTOVER,
             streams=config.SIG_VERIFY_STREAMS,
             tracer=self.tracer,
+            # a process's nodes share the verify programs: a second node
+            # loads, traces and compiles nothing the first one has run
+            shared_programs=True,
         )
         # the SCP_SIG_SCHEME knob (crypto/aggregate/): how the overlay's
         # per-crank envelope flush and the herder's eager checks dispatch

@@ -231,6 +231,11 @@ class CommandHandler:
             # the order book's work and the transactions that failed at
             # apply, since the node started (monotonic)
             "exchange": dict(lm.exchange_stats),
+            # catch-up since the node started: rounds, ledgers and
+            # transactions replayed, triples prefetched, and the state of
+            # the one in progress (a node replays one ledger a clock post
+            # and answers this in between)
+            "history": app.history_manager.stats(),
         }
         if app.herder is not None:
             # the consensus side's intake since the node started: SCP

@@ -220,9 +220,6 @@ class Config:
         # suite (tests/test_framecontext.py, test_closepipeline.py) runs
         # both and compares ledger hashes + SQL dumps + history metas.
         self.CLOSE_PIPELINE = True
-        # how many upcoming txsets may hold an in-flight prewarm future at
-        # once (the lookahead window; 1 = classic two-stage pipeline)
-        self.CLOSE_PIPELINE_DEPTH = 2
         # TPU-native addition: boot self-check & repair
         # (main/selfcheck.py) — verify every durable artifact (bucket
         # file hashes, header chain, persisted SCP state, publish queue)
@@ -404,14 +401,6 @@ class Config:
             raise ValueError(
                 f"SELFCHECK_ON_BOOT must be a boolean, "
                 f"got {self.SELFCHECK_ON_BOOT!r}"
-            )
-        if not (
-            isinstance(self.CLOSE_PIPELINE_DEPTH, int)
-            and self.CLOSE_PIPELINE_DEPTH >= 1
-        ):
-            raise ValueError(
-                f"CLOSE_PIPELINE_DEPTH must be an int >= 1, "
-                f"got {self.CLOSE_PIPELINE_DEPTH!r}"
             )
         if not (
             isinstance(self.INGEST_BATCH, bool)

@@ -494,6 +494,16 @@ def _union_seconds(intervals) -> float:
     return total
 
 
+# What the nodes of one process share (``BatchVerifier(shared_programs=
+# True)``, as every Application's backend asks): by everything that decides
+# the traced kernel, the kernel, what a dispatch of each bucket calls, and
+# the record of each bucket's first dispatch in this process.  A second node
+# — a catch-up's fresh one, a simulation's — then loads, traces and compiles
+# nothing for a bucket the process has run.
+_process_programs: dict = {}
+_process_programs_lock = threading.Lock()
+
+
 class BatchVerifier:
     """Pads batches to pow-2 buckets (one XLA compile per bucket), runs the
     kernel, scatters results; host gate verdicts mask the device results,
@@ -507,6 +517,10 @@ class BatchVerifier:
     slice of the batch; no cross-shard communication — XLA inserts only
     the output all-gather), so multi-chip keeps the fast kernel."""
 
+    # class-level default: a harness that builds the planning state by hand
+    # (tests) shares nothing
+    _process_firsts: Optional[dict] = None
+
     def __init__(
         self,
         max_batch: int = 4096,
@@ -518,6 +532,7 @@ class BatchVerifier:
         native_hash: Optional[bool] = None,
         device_hash: Optional[bool] = None,
         tracer=None,
+        shared_programs: bool = False,
     ):
         from ..trace import NULL_TRACER
 
@@ -626,6 +641,15 @@ class BatchVerifier:
         # program, or self._kernel.  Never a new jit a dispatch: that would
         # trace the wrapper again at every flush
         self._calls: dict = {}  # analysis: locked-by _calls_lock
+        # shared_programs: kernel and calls are the process's (above), and
+        # a bucket another verifier of this process dispatched first is
+        # warm here too, under that dispatch's record (_process_firsts)
+        if shared_programs:
+            key = (self.backend, self.interpret, self.device_hash, mesh)
+            with _process_programs_lock:
+                self._kernel, self._calls, self._process_firsts = (
+                    _process_programs.setdefault(key, (self._kernel, {}, {}))
+                )
         # buckets whose program has been loaded or lowered, and compiled,
         # in this process (one executable per padded batch size; layout,
         # mesh and lowering are fixed per verifier, and torsion proofs ride
@@ -785,7 +809,7 @@ class BatchVerifier:
         n_dev = n - self._host_assist_count(n) if host_assist else n
         sizes = {self._bucket(count) for _, count in self._chunks(n_dev)}
         with self._calls_lock:
-            return len(sizes - self._warm_buckets)
+            return len(sizes - self._warm_buckets - set(self._process_firsts or ()))
 
     def verify(self, items: Sequence[Tuple[bytes, bytes, bytes]]) -> List[bool]:
         """items: (pubkey32, msg, sig64) triples -> list of bool.
@@ -1257,6 +1281,13 @@ class BatchVerifier:
             bucket = staged.packed.shape[1]
         with self._calls_lock:
             cold = bucket not in self._warm_buckets
+            if cold and self._process_firsts is not None:
+                paid = self._process_firsts.get(bucket)
+                if paid is not None:
+                    # another verifier of this process paid for the bucket
+                    self._warm_buckets.add(bucket)
+                    self._first_dispatches[bucket] = paid
+                    cold = False
             call = None if cold else self._calls[bucket]
         account = (
             _FirstDispatch(bucket, compile_events.serving())
@@ -1415,6 +1446,8 @@ class BatchVerifier:
             self._warm_buckets.add(bucket)
             if self._first_dispatches.setdefault(bucket, rec) is not rec:
                 return {}
+            if self._process_firsts is not None:
+                self._process_firsts.setdefault(bucket, rec)
         saved = rec.get("compile_time_saved_s")
         _log.info(
             "bucket %d first dispatch %.1f s: program %s, trace %.1f,"

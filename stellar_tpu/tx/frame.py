@@ -225,7 +225,9 @@ class TransactionFrame:
         verify — the batch-prefetch set for the SigBackend (covers the tx
         source and every op source account's signers).  ``tally``, where
         given, has its ``accounts`` raised by every account loaded here
-        (``sig.collect`` reports the set's total)."""
+        (``sig.collect`` reports the set's total) and, where it has one,
+        its ``missing`` by every one that does not exist (the close
+        pipeline leaves a set further ahead for later)."""
         triples = []
         seen_accounts = set()
         accounts = [self.get_source_id()]
@@ -241,6 +243,8 @@ class TransactionFrame:
             if tally is not None:
                 tally["accounts"] += 1
             if af is None:
+                if tally is not None and "missing" in tally:
+                    tally["missing"] += 1
                 continue
             keys = []
             if af.account.thresholds[0]:

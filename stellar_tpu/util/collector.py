@@ -39,6 +39,14 @@ of them were garbage.  A close of payments leaves none: its frames hold no
 cycle (``OperationFrame.parent_tx`` is weak, a cache line that leaves the
 entry cache drops its memoized frame) and die with their last holder.
 
+A catch-up that replays a range holds every decoded set of it until its
+ledger applies — 61,000 frames of a 64-ledger checkpoint of 1,000-tx ledgers
+— and each full pass would walk them all to free none (0.6 s a pass at a
+round's start, a fifth of the replay).  ``park()`` moves what is live now
+out of the full passes' sight (``gc.freeze``) and ``unpark()`` brings back
+what is left of it; reference counts free a parked object as ever, only a
+cycle among them waits for ``unpark``.
+
 Every full pass, whoever asked for it, is one ``gc.full`` span in each
 holder's tracer (``cause``: ``boundary`` / ``timer`` / ``explicit``;
 ``collected``, ``uncollectable``), under whatever span is open there, and is
@@ -115,6 +123,17 @@ def idle_check() -> None:
     """The same rule for a node that closes nothing, from a timer."""
     if _holders and _due():
         _collect("timer")
+
+
+def park() -> None:
+    """Leave everything live now out of the full passes until ``unpark``."""
+    gc.freeze()
+
+
+def unpark() -> None:
+    """Give the parked objects back to the full passes (all of them,
+    whoever parked them: a second holder's then cost a pass again)."""
+    gc.unfreeze()
 
 
 def _due() -> bool:

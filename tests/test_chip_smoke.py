@@ -19,7 +19,7 @@ def _run(args, env, timeout):
 
 
 def test_rehearsal_runs_the_whole_flow_on_the_cpu(tmp_path):
-    """Producer → publish → tpu-backend replay (two buckets per flush) →
+    """Producer → publish → tpu-backend replay (prefetched across ledgers) →
     --forcescp restart → own ledger from /tx → kernel leg, through the CLI
     and the admin routes, at 80-tx ledgers."""
     r = _run(["--rehearse-cpu", "--out", str(tmp_path / "out")], dict(os.environ), 600)
@@ -32,19 +32,22 @@ def test_rehearsal_runs_the_whole_flow_on_the_cpu(tmp_path):
     }
     assert "REHEARSAL platform=cpu" in lines[-2]
     s = json.loads((tmp_path / "out" / "summary.json").read_text())
-    # every replayed signature is accounted for: 20 under the cutover,
-    # the rest on the "device" in 64 + 16 lane buckets, nothing on the
-    # watchdog's host path
+    # every replayed signature is accounted for, on the "device": the three
+    # root-signed ledgers in one prefetched flush (120: 64 + 64 lanes; alone
+    # ledger 2's 20 would be under the cutover), ledger 5 by its own close's
+    # flush (its accounts did not exist before: 64 + 16), ledgers 6 + 7 in
+    # one prefetched flush (160: 64 + 64 + 32) — nothing verified one at a
+    # time at apply, nothing on the watchdog's host path
     assert s["phase_a"]["txs_per_ledger"] == {
         "2": 20, "3": 50, "4": 50, "5": 80, "6": 80, "7": 80,
     }
     b = s["phase_b"]
     sb = b["sig_backend"]
-    assert sb["items"] == 340 and sb["device_calls"] == 8
-    assert sb["cpu_cutover_items"] == 20
+    assert sb["items"] == 360 and sb["device_calls"] == 7 and sb["lanes"] == 368
+    assert sb["cpu_cutover_items"] == 0 and sb["eager_host_verifies"] == 0
     assert sb["wedge_fallback_items"] == 0 and sb["wedge_latch_flips"] == {}
-    assert sorted(b["buckets"]) == ["16", "64"]
-    assert b["host_verify_reasons"] == ["cutover"]
+    assert sorted(b["buckets"]) == ["16", "32", "64"]
+    assert b["host_verify_reasons"] == []  # not one batch under the cutover
     assert "phase_b_warm" not in s  # times the chip's warm start; not rehearsed
     assert b["anchor_hash"] == s["phase_a"]["anchor_hash"]
     assert b["accounts"] == s["phase_a"]["accounts"] == 121
