@@ -71,10 +71,12 @@ class LedgerManager:
         self._close_timer = app.metrics.new_timer(("ledger", "ledger", "close"))
         self._flush_timer = app.metrics.new_timer(("ledger", "store", "flush"))
         # /info "exchange": what the order book did since the node started
-        # (tx/offerexchange.py) and the transactions that failed at apply
+        # (tx/offerexchange.py), the transactions that failed at apply and the
+        # PAYMENT operations that reached their body (tx/ops_payment.py)
         self.exchange_stats = {
             "conversions": 0, "offers_crossed": 0, "book_pages": 0,
             "book_rows": 0, "book_side_loads": 0, "txs_failed_at_apply": 0,
+            "payments_applied": 0,
         }
         self._tx_apply_timer = app.metrics.new_timer(
             ("ledger", "transaction", "apply")
@@ -626,6 +628,8 @@ class LedgerManager:
         tracer = self.app.tracer
         skip = TX_SAMPLE_STRIDE - 1
         failed = 0
+        stats = self.exchange_stats
+        payments_before = stats["payments_applied"]
         with tracer.span("apply.serial", txs=len(txs)) as serial_sp:
             for index, tx in enumerate(txs):
                 # one transaction in TX_SAMPLE_STRIDE records tx.apply and
@@ -662,10 +666,12 @@ class LedgerManager:
                     )
             # the set's history rows in one encode call
             rows = tx_history.transaction_rows(seq, blobs)
-            self.exchange_stats["txs_failed_at_apply"] += failed
+            stats["txs_failed_at_apply"] += failed
             if serial_sp is not None:
                 # fee kept, sequence number taken, effects unwound
                 serial_sp.attrs["failed"] = failed
+                # PAYMENT operations that went through credit / debit
+                serial_sp.attrs["payments"] = stats["payments_applied"] - payments_before
         with tracer.span("apply.rows", rows=len(rows)):
             tx_history.insert_transaction_rows(self.database, rows)
 

@@ -1,8 +1,11 @@
 """Payment and PathPayment operations (reference:
 src/transactions/PaymentOpFrame.cpp, PathPaymentOpFrame.cpp).
 
-Payment is sugar over PathPayment with a single-asset path (the reference
-literally builds a PathPaymentOp and maps its result codes back).
+A payment is a path payment with nothing to convert: the two operations
+share the halves around the walk over the book — ``credit_destination`` and
+``debit_source`` below — and each frame builds only its own result around
+them.  (The reference shares the same code by having PaymentOpFrame build a
+one-hop PathPaymentOp and translate its result back.)
 """
 
 from __future__ import annotations
@@ -11,13 +14,6 @@ from ..ledger.accountframe import AccountFrame
 from ..ledger.trustframe import TrustFrame
 from ..util.xmath import INT64_MAX
 from ..xdr.txs import (
-    Operation,
-    OperationBody,
-    OperationResult,
-    OperationResultCode,
-    OperationResultTr,
-    OperationType,
-    PathPaymentOp,
     PathPaymentResult,
     PathPaymentResultCode,
     PathPaymentSuccess,
@@ -27,6 +23,8 @@ from ..xdr.txs import (
 )
 from .offerexchange import ConvertResult, OfferExchange, OfferFilterResult
 from .opframe import OperationFrame, is_asset_valid
+
+_SUCCESS = PathPaymentResultCode.PATH_PAYMENT_SUCCESS
 
 _PP_TO_PAYMENT = {
     PathPaymentResultCode.PATH_PAYMENT_UNDERFUNDED: PaymentResultCode.PAYMENT_UNDERFUNDED,
@@ -38,6 +36,85 @@ _PP_TO_PAYMENT = {
     PathPaymentResultCode.PATH_PAYMENT_LINE_FULL: PaymentResultCode.PAYMENT_LINE_FULL,
     PathPaymentResultCode.PATH_PAYMENT_NO_ISSUER: PaymentResultCode.PAYMENT_NO_ISSUER,
 }
+
+
+def _pays_issuer(asset, destination) -> bool:
+    """send-credits-back-to-issuer: the destination of a direct single-asset
+    payment need not exist (nor the issuer be looked up) when it IS the
+    asset's issuer."""
+    return not asset.is_native() and asset.code_and_issuer()[1] == destination
+
+
+def _stopped(metrics, tag, code):
+    metrics.new_meter(("op-path-payment", "failure", tag), "operation").mark()
+    return code
+
+
+def credit_destination(metrics, delta, db, destination_id, asset, amount, bypass_issuer_check):
+    """The first half of a payment: `amount` of `asset` reaches the
+    destination, stored through `delta`.  -> PATH_PAYMENT_SUCCESS, or the
+    code it stopped at with nothing stored (NO_ISSUER is about `asset`)."""
+    destination = None
+    if not bypass_issuer_check:
+        destination = AccountFrame.load_account(destination_id, db)
+        if destination is None:
+            return _stopped(
+                metrics, "no-destination", PathPaymentResultCode.PATH_PAYMENT_NO_DESTINATION
+            )
+
+    if asset.is_native():
+        destination.mut().balance += amount
+        destination.store_change(delta, db)
+        return _SUCCESS
+
+    if bypass_issuer_check:
+        dest_line = TrustFrame.load_trust_line(destination_id, asset, db)
+    else:
+        dest_line, issuer = TrustFrame.load_trust_line_issuer(destination_id, asset, db)
+        if issuer is None:
+            return _stopped(metrics, "no-issuer", PathPaymentResultCode.PATH_PAYMENT_NO_ISSUER)
+    if dest_line is None:
+        return _stopped(metrics, "no-trust", PathPaymentResultCode.PATH_PAYMENT_NO_TRUST)
+    if not dest_line.is_authorized():
+        return _stopped(
+            metrics, "not-authorized", PathPaymentResultCode.PATH_PAYMENT_NOT_AUTHORIZED
+        )
+    if not dest_line.add_balance(amount):
+        return _stopped(metrics, "line-full", PathPaymentResultCode.PATH_PAYMENT_LINE_FULL)
+    dest_line.store_change(delta, db)
+    return _SUCCESS
+
+
+def debit_source(metrics, delta, lm, source_account, asset, amount, bypass_issuer_check):
+    """The last half: `amount` of `asset` leaves the source, stored through
+    `delta`.  -> PATH_PAYMENT_SUCCESS, or the code it stopped at with
+    nothing stored (NO_ISSUER is about `asset`)."""
+    db = lm.database
+    if asset.is_native():
+        min_balance = source_account.get_minimum_balance(lm)
+        if source_account.get_balance() - amount < min_balance:
+            return _stopped(metrics, "underfunded", PathPaymentResultCode.PATH_PAYMENT_UNDERFUNDED)
+        source_account.mut().balance -= amount
+        source_account.store_change(delta, db)
+        return _SUCCESS
+
+    source_id = source_account.get_id()
+    if bypass_issuer_check:
+        source_line = TrustFrame.load_trust_line(source_id, asset, db)
+    else:
+        source_line, issuer = TrustFrame.load_trust_line_issuer(source_id, asset, db)
+        if issuer is None:
+            return _stopped(metrics, "no-issuer", PathPaymentResultCode.PATH_PAYMENT_NO_ISSUER)
+    if source_line is None:
+        return _stopped(metrics, "src-no-trust", PathPaymentResultCode.PATH_PAYMENT_SRC_NO_TRUST)
+    if not source_line.is_authorized():
+        return _stopped(
+            metrics, "src-not-authorized", PathPaymentResultCode.PATH_PAYMENT_SRC_NOT_AUTHORIZED
+        )
+    if not source_line.add_balance(-amount):
+        return _stopped(metrics, "underfunded", PathPaymentResultCode.PATH_PAYMENT_UNDERFUNDED)
+    source_line.store_change(delta, db)
+    return _SUCCESS
 
 
 class PaymentOpFrame(OperationFrame):
@@ -61,43 +138,31 @@ class PaymentOpFrame(OperationFrame):
         return True
 
     def do_apply(self, metrics, delta, lm) -> bool:
-        if self.payment.destination == self.get_source_id():
+        payment = self.payment
+        if payment.destination == self.get_source_id():
             metrics.new_meter(("op-payment", "success", "apply"), "operation").mark()
             self.set_inner_result(PaymentResult(PaymentResultCode.PAYMENT_SUCCESS))
             return True
 
-        pp_op = Operation(
-            self.operation.sourceAccount,
-            OperationBody(
-                OperationType.PATH_PAYMENT,
-                PathPaymentOp(
-                    sendAsset=self.payment.asset,
-                    sendMax=self.payment.amount,
-                    destination=self.payment.destination,
-                    destAsset=self.payment.asset,
-                    destAmount=self.payment.amount,
-                    path=[],
-                ),
-            ),
+        # from here on the payment has a body: the two halves it shares with
+        # PATH_PAYMENT, destination first, so a source that cannot pay
+        # unwinds the destination's store through the operation's delta
+        lm.exchange_stats["payments_applied"] += 1
+        asset = payment.asset
+        bypass_issuer_check = _pays_issuer(asset, payment.destination)
+        code = credit_destination(
+            metrics, delta, lm.database, payment.destination, asset, payment.amount,
+            bypass_issuer_check,
         )
-        pp_res = OperationResult(
-            OperationResultCode.opINNER,
-            OperationResultTr(OperationType.PATH_PAYMENT, None),
-        )
-        pp = PathPaymentOpFrame(pp_op, pp_res, self.parent_tx)
-        pp.source_account = self.source_account
-
-        if not pp.do_check_valid(metrics) or not pp.do_apply(metrics, delta, lm):
-            if pp.get_result_code() != OperationResultCode.opINNER:
-                raise RuntimeError("Unexpected error code from pathPayment")
-            inner_code = pp.inner_result().type
-            mapped = _PP_TO_PAYMENT.get(inner_code)
-            if mapped is None:
-                raise RuntimeError("Unexpected error code from pathPayment")
-            self.set_inner_result(PaymentResult(mapped))
+        if code == _SUCCESS:
+            code = debit_source(
+                metrics, delta, lm, self.source_account, asset, payment.amount,
+                bypass_issuer_check,
+            )
+        if code != _SUCCESS:
+            self.set_inner_result(PaymentResult(_PP_TO_PAYMENT[code]))
             return False
 
-        assert pp.inner_result().type == PathPaymentResultCode.PATH_PAYMENT_SUCCESS
         metrics.new_meter(("op-payment", "success", "apply"), "operation").mark()
         self.set_inner_result(PaymentResult(PaymentResultCode.PAYMENT_SUCCESS))
         return True
@@ -108,13 +173,15 @@ class PathPaymentOpFrame(OperationFrame):
     def pp(self):
         return self.operation.body.value
 
-    def _fail(self, metrics, tag, code, no_issuer_asset=None):
-        metrics.new_meter(("op-path-payment", "failure", tag), "operation").mark()
+    def _stop(self, code, no_issuer_asset=None):
         if code == PathPaymentResultCode.PATH_PAYMENT_NO_ISSUER:
             self.set_inner_result(PathPaymentResult(code, no_issuer_asset))
         else:
             self.set_inner_result(PathPaymentResult(code))
         return False
+
+    def _fail(self, metrics, tag, code, no_issuer_asset=None):
+        return self._stop(_stopped(metrics, tag, code), no_issuer_asset)
 
     def do_check_valid(self, metrics) -> bool:
         pp = self.pp
@@ -151,58 +218,18 @@ class PathPaymentOpFrame(OperationFrame):
         cur_b = pp.destAsset
         full_path = [pp.sendAsset] + list(pp.path)
 
-        # send-credits-back-to-issuer shortcut: destination account need not
-        # exist when it IS the issuer of a direct single-asset payment
         bypass_issuer_check = (
-            not cur_b.is_native()
-            and len(full_path) == 1
+            len(full_path) == 1
             and pp.sendAsset == pp.destAsset
-            and cur_b.code_and_issuer()[1] == pp.destination
+            and _pays_issuer(cur_b, pp.destination)
         )
 
-        destination = None
-        if not bypass_issuer_check:
-            destination = AccountFrame.load_account(pp.destination, db)
-            if destination is None:
-                return self._fail(
-                    metrics,
-                    "no-destination",
-                    PathPaymentResultCode.PATH_PAYMENT_NO_DESTINATION,
-                )
-
         # credit the last hop
-        if cur_b.is_native():
-            destination.mut().balance += cur_b_received
-            destination.store_change(delta, db)
-        else:
-            if bypass_issuer_check:
-                dest_line = TrustFrame.load_trust_line(pp.destination, cur_b, db)
-            else:
-                dest_line, issuer = TrustFrame.load_trust_line_issuer(
-                    pp.destination, cur_b, db
-                )
-                if issuer is None:
-                    return self._fail(
-                        metrics,
-                        "no-issuer",
-                        PathPaymentResultCode.PATH_PAYMENT_NO_ISSUER,
-                        cur_b,
-                    )
-            if dest_line is None:
-                return self._fail(
-                    metrics, "no-trust", PathPaymentResultCode.PATH_PAYMENT_NO_TRUST
-                )
-            if not dest_line.is_authorized():
-                return self._fail(
-                    metrics,
-                    "not-authorized",
-                    PathPaymentResultCode.PATH_PAYMENT_NOT_AUTHORIZED,
-                )
-            if not dest_line.add_balance(cur_b_received):
-                return self._fail(
-                    metrics, "line-full", PathPaymentResultCode.PATH_PAYMENT_LINE_FULL
-                )
-            dest_line.store_change(delta, db)
+        code = credit_destination(
+            metrics, delta, db, pp.destination, cur_b, cur_b_received, bypass_issuer_check
+        )
+        if code != _SUCCESS:
+            return self._stop(code, cur_b)
 
         success.last = SimplePaymentResult(pp.destination, cur_b, cur_b_received)
 
@@ -264,47 +291,11 @@ class PathPaymentOpFrame(OperationFrame):
                 metrics, "over-send-max", PathPaymentResultCode.PATH_PAYMENT_OVER_SENDMAX
             )
 
-        if cur_b.is_native():
-            min_balance = self.source_account.get_minimum_balance(lm)
-            if self.source_account.get_balance() - cur_b_sent < min_balance:
-                return self._fail(
-                    metrics,
-                    "underfunded",
-                    PathPaymentResultCode.PATH_PAYMENT_UNDERFUNDED,
-                )
-            self.source_account.mut().balance -= cur_b_sent
-            self.source_account.store_change(delta, db)
-        else:
-            if bypass_issuer_check:
-                source_line = TrustFrame.load_trust_line(
-                    self.get_source_id(), cur_b, db
-                )
-            else:
-                source_line, issuer = TrustFrame.load_trust_line_issuer(
-                    self.get_source_id(), cur_b, db
-                )
-                if issuer is None:
-                    return self._fail(
-                        metrics,
-                        "no-issuer",
-                        PathPaymentResultCode.PATH_PAYMENT_NO_ISSUER,
-                        cur_b,
-                    )
-            if source_line is None:
-                return self._fail(
-                    metrics, "src-no-trust", PathPaymentResultCode.PATH_PAYMENT_SRC_NO_TRUST
-                )
-            if not source_line.is_authorized():
-                return self._fail(
-                    metrics,
-                    "src-not-authorized",
-                    PathPaymentResultCode.PATH_PAYMENT_SRC_NOT_AUTHORIZED,
-                )
-            if not source_line.add_balance(-cur_b_sent):
-                return self._fail(
-                    metrics, "underfunded", PathPaymentResultCode.PATH_PAYMENT_UNDERFUNDED
-                )
-            source_line.store_change(delta, db)
+        code = debit_source(
+            metrics, delta, lm, self.source_account, cur_b, cur_b_sent, bypass_issuer_check
+        )
+        if code != _SUCCESS:
+            return self._stop(code, cur_b)
 
         metrics.new_meter(("op-path-payment", "success", "apply"), "operation").mark()
         return True

@@ -54,10 +54,11 @@ def test_info_metrics_scp(app):
     assert sb["eager_host_verifies"] >= 0
     # a close is applied by one loop: /info has nothing to say about which
     assert "apply" not in info
-    # the order book's work and the transactions that failed at apply, since the node started
+    # the order book's work, the transactions that failed at apply and the
+    # PAYMENTs that went through credit / debit, since the node started
     assert info["exchange"] == {
         "conversions": 0, "offers_crossed": 0, "book_pages": 0, "book_rows": 0, "book_side_loads": 0,
-        "txs_failed_at_apply": 0,
+        "txs_failed_at_apply": 0, "payments_applied": 0,
     }
     assert "metrics" in ch.handle_metrics({})
     assert isinstance(ch.handle_scp({}), dict)

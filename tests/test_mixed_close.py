@@ -579,6 +579,7 @@ def test_spans_and_counters_of_a_mixed_close(world):
     before = info()
     assert set(before) == {
         "conversions", "offers_crossed", "book_pages", "book_rows", "book_side_loads", "txs_failed_at_apply",
+        "payments_applied",
     }
     app.tracer.clear()
     w.close([("A", [("path", w.n("D"), None, 9000, usd, 700, ())]),  # six offers, two pages
@@ -595,7 +596,8 @@ def test_spans_and_counters_of_a_mixed_close(world):
     # as an upsert until the sixth is reduced, after the last page)
     assert exchange.attrs["rows"] == 6 and exchange.attrs["side_loads"] == 1
     (serial,) = by_name["apply.serial"]
-    assert serial.attrs == {"txs": 3, "failed": 1}
+    # E's payment reaches its body (and fails there); A's path payment has no PAYMENT
+    assert serial.attrs == {"txs": 3, "failed": 1, "payments": 1}
     (sampled,) = by_name["tx.apply"]  # index 0 of the set
     assert sampled.attrs["op"] in ("PATH_PAYMENT", "CHANGE_TRUST", "PAYMENT")
     (flush,) = by_name["commit.flush"]
@@ -605,7 +607,7 @@ def test_spans_and_counters_of_a_mixed_close(world):
     after = info()
     assert {k: after[k] - before[k] for k in after} == {
         "conversions": 1, "offers_crossed": 6, "book_pages": 2, "book_rows": 6, "book_side_loads": 1,
-        "txs_failed_at_apply": 1,
+        "txs_failed_at_apply": 1, "payments_applied": 1,
     }
     # one span a conversion beside the close's budget (tests/test_trace.py)
     assert len(by_name["op.exchange"]) == after["conversions"] - before["conversions"]

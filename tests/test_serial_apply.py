@@ -319,6 +319,109 @@ def _duplicate_source_seq_chain(app, monkeypatch):
     assert held[keys[3].get_strkey_public()] == (START + 60, first)
 
 
+def _payments_counted(app, monkeypatch):
+    """`apply.serial` carries `payments`: the PAYMENT operations of the set
+    that went through credit / debit.  A payment that fails there counts (and
+    the credit its destination was given is unwound: account row, cache line
+    and the close's delta are the ones from before the close); a payment to
+    oneself, a PATH_PAYMENT, a CREATE_ACCOUNT and a transaction refused
+    before its operations do not.  `/info` shows the node's total."""
+    from stellar_tpu.ledger.entryframe import entry_cache_of
+
+    keys = [T.get_account("pc-%d" % i) for i in range(12)]
+    first = funded(app, keys)
+    native = X.Asset.native()
+    lm = app.ledger_manager
+    info = lambda: app.command_handler.handle_info({})["info"]["exchange"]  # noqa: E731
+    before = info()
+    assert before["payments_applied"] == 0  # funding is one CREATE_ACCOUNT a key
+
+    def line_of(key):
+        """(account row, decoded-entry cache line as XDR or None)"""
+        frame = AccountFrame.load_account(key.get_public_key(), app.database)
+        hit, entry = entry_cache_of(app.database).peek(frame.get_key().to_xdr())
+        row = app.database.query_all(
+            "SELECT * FROM accounts WHERE accountid=?", (key.get_strkey_public(),))
+        return row, (entry.to_xdr() if hit and entry is not None else None)
+
+    unpaid = keys[9]  # destination of the payment that fails, in no other transaction
+    unpaid_before = line_of(unpaid)
+    assert unpaid_before[1] is not None
+    handed_to_buckets = []
+    add_batch = app.bucket_manager.add_batch
+
+    def recording(seq, live, dead):
+        handed_to_buckets.extend(e.data.value.accountID for e in live)
+        return add_batch(seq, live, dead)
+
+    monkeypatch.setattr(app.bucket_manager, "add_batch", recording)
+
+    txs = [pay(app, keys[i], first + 1, keys[i + 1], 10 + i) for i in (0, 2, 4)]
+    txs += [
+        pay(app, keys[6], first + 1, keys[6], 50),  # to oneself: returns before its body
+        T.tx_from_ops(app, keys[7], first + 1, [T.path_payment_op(keys[1], native, 30, native, 30)]),
+        T.tx_from_ops(app, keys[8], first + 1, [T.create_account_op(T.get_account("pc-new"), 3 * 10**8)]),
+        pay(app, keys[10], first + 1, unpaid, 10**12),  # underfunded, after its destination was credited
+        sign_with(pay(app, keys[11], first + 1, keys[0], 5), [keys[0]]),  # refused before its operations
+    ]
+    app.tracer.clear()
+    close(app, txs)
+    assert codes_of(txs) == ["txSUCCESS"] * 6 + ["txFAILED", "txBAD_AUTH"]
+    assert T.inner_op_code(txs[6]).name == "PAYMENT_UNDERFUNDED"
+    (serial,) = [s.attrs for s in app.tracer.spans() if s.name == "apply.serial"]
+    assert serial == {"txs": 8, "failed": 2, "payments": 4}
+    after = info()
+    assert after["payments_applied"] - before["payments_applied"] == 4
+    assert after["txs_failed_at_apply"] - before["txs_failed_at_apply"] == 2
+    assert after["payments_applied"] == lm.exchange_stats["payments_applied"]
+    # the destination that was credited and never paid
+    assert line_of(unpaid) == unpaid_before
+    assert unpaid.get_public_key() not in handed_to_buckets
+    assert keys[10].get_public_key() in handed_to_buckets  # the fee its source paid
+    held = accounts_of(app)
+    assert held[unpaid.get_strkey_public()] == (START, first)
+    assert held[keys[10].get_strkey_public()] == (START - FEE, first + 1)
+    assert held[keys[6].get_strkey_public()] == (START - FEE, first + 1)
+    assert held[keys[1].get_strkey_public()] == (START + 10 + 30, first)
+    assert app.invariants.total_violations == 0, app.invariants.dump_info()
+    # a close with no PAYMENT in it says so
+    app.tracer.clear()
+    close(app, [T.tx_from_ops(app, keys[7], first + 2, [T.path_payment_op(keys[1], native, 3, native, 3)])])
+    (serial,) = [s.attrs for s in app.tracer.spans() if s.name == "apply.serial"]
+    assert serial == {"txs": 1, "failed": 0, "payments": 0}
+
+
+def _payment_builds_no_path_payment_frame(app, monkeypatch):
+    """PAYMENT applies through the two halves it shares with PATH_PAYMENT,
+    not through a PathPaymentOpFrame of its own making: with that frame's
+    constructor (and `PathPaymentOp`'s) raising, payments that succeed, fail
+    in either half or go to their own source still close as they should."""
+    from stellar_tpu.tx import ops_payment
+
+    keys = [T.get_account("np-%d" % i) for i in range(6)]
+    first = funded(app, keys)
+
+    def refuse(*a, **kw):
+        raise AssertionError("a PAYMENT built a path payment")
+
+    monkeypatch.setattr(ops_payment.PathPaymentOpFrame, "__init__", refuse)
+    monkeypatch.setattr(X.PathPaymentOp, "__init__", refuse)
+    txs = [
+        pay(app, keys[0], first + 1, keys[1], 11),
+        pay(app, keys[2], first + 1, keys[2], 12),
+        pay(app, keys[3], first + 1, T.get_account("np-nobody"), 13),
+        pay(app, keys[4], first + 1, keys[5], 10**12),
+    ]
+    close(app, txs)
+    assert codes_of(txs) == ["txSUCCESS", "txSUCCESS", "txFAILED", "txFAILED"]
+    assert [T.inner_op_code(tx).name for tx in txs[2:]] == ["PAYMENT_NO_DESTINATION", "PAYMENT_UNDERFUNDED"]
+    held = accounts_of(app)
+    assert held[keys[1].get_strkey_public()] == (START + 11, first)
+    assert held[keys[5].get_strkey_public()] == (START, first)
+    assert app.ledger_manager.exchange_stats["payments_applied"] == 3
+    assert app.invariants.total_violations == 0, app.invariants.dump_info()
+
+
 OUTCOMES = {
     "failed-after-partners-payment": _failed_after_partners_payment,
     "bad-auth": _bad_auth,
@@ -328,6 +431,8 @@ OUTCOMES = {
     "unrollbackable-write": _unrollbackable_write,
     "signers-changed-and-unchanged-in-one-close": _signers_changed_and_unchanged,
     "duplicate-source-seq-chain": _duplicate_source_seq_chain,
+    "payments-counted-on-the-span-and-in-info": _payments_counted,
+    "payment-builds-no-path-payment-frame": _payment_builds_no_path_payment_frame,
 }
 
 

@@ -556,9 +556,12 @@ class TestCloseFromInside:
         traffic, closes = traced_closes
         sigs, keys = TRAFFIC[traffic]
         for _seq, spans in closes:
-            # the set, and how many of it failed at apply (fee kept, effects unwound)
+            # the set, how many of it failed at apply (fee kept, effects unwound)
+            # and how many PAYMENTs went through credit / debit (the failing too)
             failed = sum(1 for i in range(CLOSE_TXS) if _failing(traffic, i))
-            assert [s.attrs for s in spans if s.name == "apply.serial"] == [{"txs": CLOSE_TXS, "failed": failed}]
+            assert [s.attrs for s in spans if s.name == "apply.serial"] == [
+                {"txs": CLOSE_TXS, "failed": failed, "payments": CLOSE_TXS}
+            ]
             # the sample says which operation it timed
             assert {s.attrs["op"] for s in spans if s.name == "tx.apply"} == {"PAYMENT"}
             assert [s.attrs for s in spans if s.name == "apply.rows"] == [{"rows": CLOSE_TXS}]

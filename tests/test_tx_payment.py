@@ -12,6 +12,7 @@ import pytest
 
 import stellar_tpu.xdr as X
 from stellar_tpu.ledger.accountframe import AccountFrame
+from stellar_tpu.ledger.delta import LedgerDelta
 from stellar_tpu.ledger.offerframe import OfferFrame
 from stellar_tpu.ledger.trustframe import TrustFrame
 from stellar_tpu.main.application import Application
@@ -386,3 +387,226 @@ class TestPathPayment:
         check_amounts(line_balance(app, b1, usd), 50 * M)
         check_amounts(line_balance(app, a1, idr), 0)
         check_amounts(line_balance(app, a1, usd), TL_START - 50 * M)
+
+
+# -- PAYMENT against the one-hop PATH_PAYMENT it shares its halves with -----
+#
+# PAYMENT no longer goes through a PathPaymentOpFrame: both frames call
+# ``ops_payment.credit_destination`` / ``debit_source``.  Each case below
+# builds the same state on two nodes, applies the PAYMENT on one and the
+# PATH_PAYMENT with ``sendMax`` = ``destAmount`` and an empty path on the
+# twin, and holds the two to each other.
+
+M_IDR = 1000  # what a funded holder's line starts with
+
+
+def _twin(instance):
+    clock = VirtualClock(VIRTUAL_TIME)
+    return clock, Application(clock, T.get_test_config(instance), new_db=True)
+
+
+def _idr(gw):
+    return X.Asset.alphanum4(b"IDR", gw.get_public_key())
+
+
+def _holders(app, root, auth=False):
+    """gateway, a1 holding M_IDR of its IDR, b1 trusting it with room for
+    100 more; with `auth` the gateway requires (revocable) authorisation
+    and has given it to both."""
+    gw = fund(app, root, T.get_account(100), 50_000 * M)
+    a1 = fund(app, root, T.get_account(1), 50_000 * M)
+    b1 = fund(app, root, T.get_account(2), 50_000 * M)
+    idr = _idr(gw)
+    if auth:
+        flags = int(X.AccountFlags.AUTH_REQUIRED_FLAG) | int(
+            X.AccountFlags.AUTH_REVOCABLE_FLAG)
+        apply_one(app, gw, T.set_options_op(set_flags=flags))
+    apply_one(app, a1, T.change_trust_op(idr, TL_LIMIT))
+    apply_one(app, b1, T.change_trust_op(idr, 100))
+    if auth:
+        apply_one(app, gw, T.allow_trust_op(a1, b"IDR", True))
+        apply_one(app, gw, T.allow_trust_op(b1, b"IDR", True))
+    apply_one(app, gw, T.payment_op(a1, M_IDR, asset=idr))
+    return gw, a1, b1, idr
+
+
+def _native(amount_of, dest_funded=True, to_self=False):
+    def build(app, root):
+        a1 = fund(app, root, T.get_account(1), 50_000 * M)
+        b1 = T.get_account(2)
+        if dest_funded:
+            fund(app, root, b1, 50_000 * M)
+        lm = app.ledger_manager
+        spare = 50_000 * M - lm.get_tx_fee() - lm.get_min_balance(0)
+        return a1, (a1 if to_self else b1), None, amount_of(spare)
+    return build
+
+
+def _credit(amount=100, auth=False, then=None, source="a1", dest="b1"):
+    """`then(app, root, gw, a1, b1, idr)` bends the world after it is
+    built; `source` / `dest` pick the payment's ends from it."""
+    def build(app, root):
+        gw, a1, b1, idr = _holders(app, root, auth)
+        if then is not None:
+            then(app, root, gw, a1, b1, idr)
+        ends = {"gw": gw, "a1": a1, "b1": b1, "nobody": T.get_account(9)}
+        return ends[source], ends[dest], idr, amount
+    return build
+
+
+def _revoke(who):
+    def then(app, root, gw, a1, b1, idr):
+        apply_one(app, gw, T.allow_trust_op(
+            {"a1": a1, "b1": b1}[who], b"IDR", False))
+    return then
+
+
+def _drop_line(who):
+    def then(app, root, gw, a1, b1, idr):
+        holder = {"a1": a1, "b1": b1}[who]
+        if who == "a1":
+            apply_one(app, a1, T.payment_op(gw, M_IDR, asset=idr))
+        apply_one(app, holder, T.change_trust_op(idr, 0))
+    return then
+
+
+def _merge_issuer(app, root, gw, a1, b1, idr):
+    apply_one(app, gw, T.merge_op(root))
+
+
+# case -> (world, PaymentResultCode, the halves' failure meter or None,
+#          whether the twin's state is the payment's)
+HALVES = {
+    "native-success": (_native(lambda spare: 100 * M), PC.PAYMENT_SUCCESS, None, True),
+    # a PATH_PAYMENT to oneself has no early return: it credits a copy of
+    # the account and debits the stale signing frame (the reference's own
+    # behaviour, test_framecontext's self-path-payment leg), so only the
+    # codes are held to each other and the PAYMENT must leave no change
+    "native-self": (_native(lambda spare: 100 * M, to_self=True), PC.PAYMENT_SUCCESS, None, False),
+    "native-no-destination": (
+        _native(lambda spare: 100 * M, dest_funded=False),
+        PC.PAYMENT_NO_DESTINATION, "no-destination", True),
+    "native-underfunded": (
+        _native(lambda spare: 50_000 * M), PC.PAYMENT_UNDERFUNDED, "underfunded", True),
+    "native-down-to-the-reserve": (_native(lambda spare: spare), PC.PAYMENT_SUCCESS, None, True),
+    "native-one-under-the-reserve": (
+        _native(lambda spare: spare + 1), PC.PAYMENT_UNDERFUNDED, "underfunded", True),
+    "credit-success": (_credit(), PC.PAYMENT_SUCCESS, None, True),
+    "credit-self": (_credit(dest="a1"), PC.PAYMENT_SUCCESS, None, False),
+    "credit-no-destination": (
+        _credit(dest="nobody"), PC.PAYMENT_NO_DESTINATION, "no-destination", True),
+    "credit-underfunded": (
+        _credit(then=lambda app, root, gw, a1, b1, idr: apply_one(
+            app, a1, T.payment_op(gw, M_IDR - 99, asset=idr))),
+        PC.PAYMENT_UNDERFUNDED, "underfunded", True),
+    "credit-line-full": (_credit(amount=101), PC.PAYMENT_LINE_FULL, "line-full", True),
+    "credit-no-trust": (_credit(then=_drop_line("b1")), PC.PAYMENT_NO_TRUST, "no-trust", True),
+    "credit-not-authorized": (
+        _credit(auth=True, then=_revoke("b1")),
+        PC.PAYMENT_NOT_AUTHORIZED, "not-authorized", True),
+    "credit-src-no-trust": (
+        _credit(then=_drop_line("a1")), PC.PAYMENT_SRC_NO_TRUST, "src-no-trust", True),
+    "credit-src-not-authorized": (
+        _credit(auth=True, then=_revoke("a1")),
+        PC.PAYMENT_SRC_NOT_AUTHORIZED, "src-not-authorized", True),
+    "credit-no-issuer": (_credit(then=_merge_issuer), PC.PAYMENT_NO_ISSUER, "no-issuer", True),
+    # the bypass: the issuer as destination is never loaded ...
+    "credit-back-to-issuer": (_credit(dest="gw"), PC.PAYMENT_SUCCESS, None, True),
+    # ... so credit can be burnt at an issuer that no longer exists
+    "credit-back-to-merged-issuer": (
+        _credit(then=_merge_issuer, dest="gw"), PC.PAYMENT_SUCCESS, None, True),
+    "credit-issuer-pays-out": (_credit(source="gw", dest="b1"), PC.PAYMENT_SUCCESS, None, True),
+    "credit-issuer-pays-out-line-full": (
+        _credit(amount=101, source="gw", dest="b1"), PC.PAYMENT_LINE_FULL, "line-full", True),
+}
+
+
+def _apply_recording(app, source, op_):
+    """One iteration of the close's loop (fee, then apply), keeping what a
+    close would hand on: the meta, and the delta's live and dead entries
+    and header — the inputs of the bucket list and so of the ledger hash."""
+    lm = app.ledger_manager
+    tx = T.tx_from_ops(app, source, seq_of(app, source) + 1, [op_])
+    meta = X.TransactionMeta(0, [])
+    with app.database.transaction():
+        delta = LedgerDelta(lm.current.header, app.database)
+        tx.process_fee_seq_num(delta, lm)
+        tx.apply(delta, app, meta)
+        handed_on = (
+            meta.to_xdr(),
+            sorted(e.to_xdr() for e in delta.get_live_entries()),
+            sorted(k.to_xdr() for k in delta.get_dead_entries()),
+            delta.get_header().to_xdr(),
+        )
+        delta.commit()
+    return tx, handed_on
+
+
+def _meter(app, *parts):
+    return app.metrics.new_meter(parts, "operation").count
+
+
+@pytest.mark.parametrize("case", sorted(HALVES))
+def test_payment_equals_one_hop_path_payment(case):
+    from stellar_tpu.tx.ops_payment import _PP_TO_PAYMENT
+
+    world, want, failure_meter, same_state = HALVES[case]
+    clock_a, pay_app = _twin(0)
+    clock_b, path_app = _twin(1)
+    try:
+        built = []
+        for app in (pay_app, path_app):
+            built.append(world(app, T.root_key_for(app)))
+            assert T.dump_state(app.database) == T.dump_state(pay_app.database)
+        source, dest, asset, amount = built[0]
+        assert [k.get_public_key() for k in built[1][:2]] == [
+            source.get_public_key(), dest.get_public_key()]
+        native = X.Asset.native()
+        before = T.dump_state(pay_app.database)
+        balance_before = balance_of(pay_app, source)
+
+        pay_tx, pay_handed = _apply_recording(
+            pay_app, source, T.payment_op(dest, amount, asset=asset))
+        path_tx, path_handed = _apply_recording(
+            path_app, source,
+            T.path_payment_op(dest, asset or native, amount, asset or native, amount))
+
+        # the outcome is the case's, and the two agree on it
+        assert T.inner_op_code(pay_tx) == want
+        assert pay_tx.get_result_code() == path_tx.get_result_code()
+        path_code = T.inner_op_code(path_tx)
+        if want == PC.PAYMENT_SUCCESS:
+            assert pay_tx.get_result_code() == RC.txSUCCESS
+            assert path_code == PPC.PATH_PAYMENT_SUCCESS
+            last = T.op_result_of(path_tx).value.value.value.last
+            assert (last.destination, last.amount) == (dest.get_public_key(), amount)
+        else:
+            assert pay_tx.get_result_code() == RC.txFAILED
+            assert _PP_TO_PAYMENT[path_code] == want
+            if want == PC.PAYMENT_NO_ISSUER:
+                assert T.op_result_of(path_tx).value.value.value == asset
+
+        # the halves' meters keep their names on both nodes; the success
+        # meter of the path payment counts path payments alone
+        if failure_meter is not None:
+            for app in (pay_app, path_app):
+                assert _meter(app, "op-path-payment", "failure", failure_meter) == 1
+        ok = int(want == PC.PAYMENT_SUCCESS)
+        assert _meter(path_app, "op-path-payment", "success", "apply") == ok
+        # (the payments that built the world raise it no more either)
+        assert _meter(pay_app, "op-path-payment", "success", "apply") == 0
+
+        if same_state:
+            assert pay_handed == path_handed
+            assert T.dump_state(pay_app.database) == T.dump_state(path_app.database)
+        else:
+            # the payment to oneself: only the fee and the sequence number
+            meta_xdr, live, dead, _header = pay_handed
+            assert meta_xdr == X.TransactionMeta(0, [X.OperationMeta([])]).to_xdr()
+            assert len(live) == 1 and not dead
+            assert T.dump_state(pay_app.database)["trustlines"] == before["trustlines"]
+            assert balance_of(pay_app, source) == balance_before - pay_tx.get_fee()
+    finally:
+        for clock, app in ((clock_a, pay_app), (clock_b, path_app)):
+            app.database.close()
+            clock.shutdown()
