@@ -21,9 +21,15 @@ from __future__ import annotations
 
 import os
 import uuid
+from itertools import islice
 from typing import Iterable, Iterator, List, Optional, Tuple
 
-from ..ledger.entryframe import ledger_key_of, store_add_or_change, store_delete_key
+from ..ledger.entryframe import (
+    entry_cache_of,
+    frame_class_of,
+    key_bytes,
+    ledger_key_of,
+)
 from ..util import fs
 from . import hashplane
 from ..util.xdrstream import XDRInputFileStream, XDROutputFileStream
@@ -127,22 +133,31 @@ class Bucket:
         ident = entry_identity(e)
         return any(entry_identity(x) == ident for x in self)
 
-    def apply(self, db) -> None:
-        """Replay entries into the SQL store (catchup-minimal path).  Buckets
-        are header-independent, so a throwaway header/delta is used."""
-        from ..ledger.delta import LedgerDelta
-        from ..xdr.ledger import LedgerHeader
+    # entries decoded ahead of one round of batched SQL writes
+    APPLY_BATCH = 8192
 
+    def apply(self, db) -> int:
+        """Replay entries into the SQL store (catchup-minimal path), a
+        batch of rows a statement: the live entries of each table through
+        its ``upsert_batch``, the dead keys through its ``delete_batch`` —
+        the statements a close's store-buffer flush issues.  Row for row
+        what ``store_add_or_change`` / ``store_delete_key`` leave an entry
+        at a time (tier-1 holds the two equal); that path — an existence
+        SELECT, a throwaway delta and three statements an entry — was 52 s
+        of a 10^6-account catch-up (PERF.md §6, PR 41).  Identities are
+        unique inside a bucket, so the order of effect that matters, bucket
+        after bucket, is the caller's.  Each entry is left in the entry
+        cache as the per-entry path left it (a dead key as known-absent).
+        -> entries applied."""
         if self.is_empty():
-            return
+            return 0
+        applied = 0
+        entries = iter(self)
         with db.transaction():
-            for e in self:
-                delta = LedgerDelta(LedgerHeader(), db, update_last_modified=False)
-                if e.type == BucketEntryType.LIVEENTRY:
-                    store_add_or_change(e.value, delta, db)
-                else:
-                    store_delete_key(e.value, delta, db)
-                delta.commit()
+            while batch := list(islice(entries, self.APPLY_BATCH)):
+                _apply_batch(db, batch)
+                applied += len(batch)
+        return applied
 
     # -- construction ------------------------------------------------------
     @staticmethod
@@ -227,6 +242,28 @@ class Bucket:
             shadow_iters,
             keep_dead_entries,
         )
+
+
+def _apply_batch(db, batch: List[BucketEntry]) -> None:
+    """One batch of ``Bucket.apply``: grouped by table, written by the
+    frame classes' batch statements, every key's cache line replaced."""
+    live, dead = {}, {}
+    cache = entry_cache_of(db)
+    for e in batch:
+        if e.type == BucketEntryType.LIVEENTRY:
+            entry = e.value
+            cls = frame_class_of(entry.data.type)
+            cls.canonicalize(entry)
+            live.setdefault(cls, []).append(entry)
+            cache.put_owned(key_bytes(ledger_key_of(entry)), entry)
+        else:
+            dead.setdefault(frame_class_of(e.value.type), []).append(e.value)
+            cache.put_owned(key_bytes(e.value), None)
+    for cls, keys in dead.items():
+        cls.delete_batch(db, keys)
+    for cls, entries in live.items():
+        # no snapshot of what SQL holds is at hand: write the signer rows
+        cls.upsert_batch(db, entries, [True] * len(entries))
 
 
 def _merge_fresh_batch(live, dead):

@@ -23,6 +23,7 @@ Two modes (HistoryManager.h:186-197):
 A round records ``catchup.round`` (mode, first and last ledger) with, as its
 children, ``catchup.fetch`` (download + gunzip: files, bytes),
 ``catchup.decode`` (files, headers, transactions), ``catchup.verify_chain``,
+in mode minimal one ``bucket.apply`` (level, entries) a bucket replayed into SQL,
 ``catchup.prefetch`` (the hand-over to the pipeline: sets, signatures, and
 what its first dispatch collected and flushed) and one
 ``catchup.apply_ledger`` (seq, txs) a replayed ledger, in which
@@ -37,6 +38,7 @@ from __future__ import annotations
 
 import os
 import random
+import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
@@ -441,10 +443,21 @@ class CatchupStateMachine:
             entry_cache_of(db).clear()
             # oldest level first so younger entries overwrite older ones
             has = self.has
-            for lev_state in reversed(has.current_buckets):
+            tracer = self.app.tracer
+            stats = self.app.history_manager.replay_stats
+            for level in reversed(range(len(has.current_buckets))):
+                lev_state = has.current_buckets[level]
                 for h in (lev_state.snap, lev_state.curr):
-                    if h != ZERO_HASH:
-                        bm.get_bucket_by_hash(h).apply(db)
+                    if h == ZERO_HASH:
+                        continue
+                    t0 = time.monotonic()
+                    with tracer.span(
+                        "bucket.apply", parent=self._round_sp, level=level
+                    ) as sp:
+                        entries = bm.get_bucket_by_hash(h).apply(db)
+                        tracer.end(sp, entries=entries)
+                    stats["bucket_apply_entries"] += entries
+                    stats["bucket_apply_s"] += time.monotonic() - t0
         bm.assume_state(has.to_json())
 
     def _apply_complete(self) -> None:

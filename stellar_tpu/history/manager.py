@@ -33,12 +33,15 @@ class HistoryManager:
         # what catch-up did since the node started (monotonic): rounds
         # begun, and by CATCHUP_COMPLETE's replay the ledgers and
         # transactions applied and the signature triples handed to the
-        # close pipeline's prefetch
+        # close pipeline's prefetch; by CATCHUP_MINIMAL the entries its
+        # buckets replayed into SQL and the seconds that took
         self.replay_stats = {
             "rounds": 0,
             "ledgers_replayed": 0,
             "txs_replayed": 0,
             "triples_prefetched": 0,
+            "bucket_apply_entries": 0,
+            "bucket_apply_s": 0.0,
         }
 
     @property

@@ -271,6 +271,7 @@ class AccountFrame(EntryFrame):
         key = LedgerKey(LedgerEntryType.ACCOUNT, LedgerKeyAccount(account_id))
         key._kb = kb
         aid = _aid(account_id)
+        cache.sql_loads += 1
         with db.timed("select", "account"):
             row = db.query_one(
                 """SELECT balance, seqnum, numsubentries, inflationdest,
@@ -318,20 +319,27 @@ class AccountFrame(EntryFrame):
         return frame
 
     @classmethod
-    def bulk_warm_cache(cls, db, account_ids) -> None:
+    def bulk_warm_cache(cls, db, account_ids) -> dict:
         """Prime the entry cache for many accounts with chunked IN()
         selects — one statement per ~500 accounts instead of one point
         SELECT per cache miss.  Missing accounts cache as known-absent.
+        -> what it did, for the caller's ``accounts.warm`` span: accounts
+        ``asked``, ``missed`` by the cache, ``selects`` (chunks: an accounts
+        and a signers statement each), account ``rows`` found.
 
-        The close path warms every account its txset touches before apply:
-        at 10^6-account scale random payment destinations made every load
-        a point SELECT against a deep B-tree (PROFILE.md round-4 ladder —
-        the 2.6x cliff's dominant term)."""
+        The close path warms every account its txset touches before apply.
+        It does nothing while the whole ledger fits the cache (every cell
+        but one); over 10^6 accounts, a 5,000-tx set's 7,500 residents
+        nearly all miss, and what the ~20 chunks cost a close is
+        ``accounts_warm_ms_per_close`` of ``state1m.close`` (PERF.md §6,
+        PR 41, read on the chip)."""
         # runs before the store buffer activates (close_ledger warms first,
         # then turns the buffer on), so SQL rows are never stale here
         cache = cls.cache_of(db)
         todo = []
+        asked = found = 0
         for pk in account_ids:
+            asked += 1
             if not cache.contains(_ACCT_KEY_PREFIX + pk.value):
                 todo.append(pk)
         CHUNK = 500
@@ -389,6 +397,15 @@ class AccountFrame(EntryFrame):
                         0,
                     ),
                 )
+                found += 1
+        cache.warm_asked += asked
+        cache.sql_loads += len(todo)
+        return {
+            "asked": asked,
+            "missed": len(todo),
+            "selects": -(-len(todo) // CHUNK),
+            "rows": found,
+        }
 
     @classmethod
     def exists(cls, db, key: LedgerKey) -> bool:
@@ -427,6 +444,13 @@ class AccountFrame(EntryFrame):
                     return
                 self.touch()
                 s = self.account.signers
+            s.sort(key=lambda sg: sg.pubKey.value)
+
+    @staticmethod
+    def canonicalize(entry: LedgerEntry) -> None:
+        """``_normalize`` for an entry outside any frame."""
+        s = entry.data.value.signers
+        if len(s) > 1:
             s.sort(key=lambda sg: sg.pubKey.value)
 
     def store_add(self, delta, db) -> None:

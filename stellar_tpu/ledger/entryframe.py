@@ -36,6 +36,26 @@ class EntryCache:
         self._map: OrderedDict[bytes, Optional[LedgerEntry]] = OrderedDict()
         self.hits = 0
         self.misses = 0
+        # lines pushed out at CAPACITY; accounts the closes' bulk warm
+        # probed (``contains``: ``hits`` / ``misses`` count loads, and after
+        # a warm every load hits); accounts asked of SQL because no line (or
+        # pending write) had them — a row read or known-absent after
+        # (``bulk_warm_cache``, ``AccountFrame.load_account``'s miss)
+        self.evictions = 0
+        self.warm_asked = 0
+        self.sql_loads = 0
+
+    def stats(self) -> dict:
+        """``/info`` ``entry_cache`` (monotonic but ``lines``)."""
+        return {
+            "hits": self.hits,
+            "misses": self.misses,
+            "evictions": self.evictions,
+            "warm_asked": self.warm_asked,
+            "sql_loads": self.sql_loads,
+            "lines": len(self._map),
+            "capacity": self.CAPACITY,
+        }
 
     def get(self, key: bytes):
         """(hit, entry-copy-or-None); the caller owns the returned entry."""
@@ -65,6 +85,7 @@ class EntryCache:
         self._map.move_to_end(key)
         while len(self._map) > self.CAPACITY:
             _retire(self._map.popitem(last=False)[1])
+            self.evictions += 1
 
     def contains(self, key: bytes) -> bool:
         """Membership probe without touching hit/miss counters or LRU
@@ -301,6 +322,12 @@ class EntryFrame:
         table.  Only an account has such rows (AccountFrame)."""
         return False
 
+    @staticmethod
+    def canonicalize(entry: LedgerEntry) -> None:
+        """Put an entry that no frame holds into the form every store
+        writes (``Bucket.apply``'s batches store without a frame).  Only
+        an account has one: its signers' order (AccountFrame)."""
+
     def _stamp(self, delta) -> None:
         if delta.update_last_modified:
             self.last_modified = delta.header_ro().ledgerSeq
@@ -379,21 +406,31 @@ def ledger_key_of(entry: LedgerEntry) -> LedgerKey:
     raise ValueError(f"unknown ledger entry type {ty}")
 
 
+_FRAME_CLASSES: dict = {}
+
+
+def frame_class_of(ty: LedgerEntryType):
+    """The frame class of an entry type (or of a LedgerKey's)."""
+    if not _FRAME_CLASSES:
+        from .accountframe import AccountFrame
+        from .offerframe import OfferFrame
+        from .trustframe import TrustFrame
+
+        _FRAME_CLASSES.update({
+            LedgerEntryType.ACCOUNT: AccountFrame,
+            LedgerEntryType.TRUSTLINE: TrustFrame,
+            LedgerEntryType.OFFER: OfferFrame,
+        })
+    cls = _FRAME_CLASSES.get(ty)
+    if cls is None:
+        raise ValueError(f"unknown ledger entry type {ty}")
+    return cls
+
+
 def frame_from_entry(entry: LedgerEntry) -> "EntryFrame":
     """Factory: wrap a LedgerEntry in its typed frame
     (reference: EntryFrame::FromXDR, src/ledger/EntryFrame.cpp:33)."""
-    from .accountframe import AccountFrame
-    from .offerframe import OfferFrame
-    from .trustframe import TrustFrame
-
-    ty = entry.data.type
-    if ty == LedgerEntryType.ACCOUNT:
-        return AccountFrame(entry)
-    if ty == LedgerEntryType.TRUSTLINE:
-        return TrustFrame(entry)
-    if ty == LedgerEntryType.OFFER:
-        return OfferFrame(entry)
-    raise ValueError(f"unknown ledger entry type {ty}")
+    return frame_class_of(entry.data.type)(entry)
 
 
 def store_add_or_change(entry: LedgerEntry, delta, db) -> None:
@@ -424,13 +461,4 @@ def load_entry_by_key(key: LedgerKey, db) -> Optional["EntryFrame"]:
 def store_delete_key(key: LedgerKey, delta, db) -> None:
     """Delete by LedgerKey regardless of whether the row exists
     (reference: EntryFrame::storeDelete(delta, db, key))."""
-    from .accountframe import AccountFrame
-    from .offerframe import OfferFrame
-    from .trustframe import TrustFrame
-
-    cls = {
-        LedgerEntryType.ACCOUNT: AccountFrame,
-        LedgerEntryType.TRUSTLINE: TrustFrame,
-        LedgerEntryType.OFFER: OfferFrame,
-    }[key.type]
-    cls.store_delete_by_key(delta, db, key)
+    frame_class_of(key.type).store_delete_by_key(delta, db, key)

@@ -442,9 +442,14 @@ class LedgerManager:
             from .framecontext import frame_context_of
             from .storebuffer import store_buffer_of
 
-            AccountFrame.bulk_warm_cache(
-                self.database, ledger_data.tx_set.collect_account_ids()
-            )
+            with tracer.span("accounts.warm") as warm_sp:
+                tracer.end(
+                    warm_sp,
+                    **AccountFrame.bulk_warm_cache(
+                        self.database,
+                        ledger_data.tx_set.collect_account_ids(),
+                    ),
+                )
             # write-back store buffer: entry mutations accumulate in an
             # overlay (reads see through it) and flush as batched SQL
             # before the PARANOID audit, instead of ~8 statements per tx.

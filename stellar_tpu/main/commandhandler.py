@@ -204,6 +204,8 @@ class CommandHandler:
 
     # -- routes -------------------------------------------------------------
     def handle_info(self, q: dict) -> dict:
+        from ..ledger.entryframe import entry_cache_of
+
         app = self.app
         lm = app.ledger_manager
         lcl = lm.last_closed
@@ -236,6 +238,10 @@ class CommandHandler:
             # the one in progress (a node replays one ledger a clock post
             # and answers this in between)
             "history": app.history_manager.stats(),
+            # the decoded-entry cache of this node's database: loads it
+            # answered and missed, lines it pushed out at capacity, accounts
+            # it had to ask SQL for (monotonic; ``lines`` is now)
+            "entry_cache": entry_cache_of(app.database).stats(),
         }
         if app.herder is not None:
             # the consensus side's intake since the node started: SCP

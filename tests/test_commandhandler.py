@@ -60,6 +60,14 @@ def test_info_metrics_scp(app):
         "conversions": 0, "offers_crossed": 0, "book_pages": 0, "book_rows": 0, "book_side_loads": 0,
         "txs_failed_at_apply": 0, "payments_applied": 0,
     }
+    # the decoded-entry cache: genesis stored the root account as a line;
+    # nothing was evicted, warmed or asked of SQL yet
+    ec = info["entry_cache"]
+    assert ec == {
+        "hits": ec["hits"], "misses": ec["misses"], "evictions": 0, "warm_asked": 0, "sql_loads": 0,
+        "lines": 1, "capacity": 131072,
+    }
+    assert info["history"]["bucket_apply_entries"] == 0 and info["history"]["bucket_apply_s"] == 0.0
     assert "metrics" in ch.handle_metrics({})
     assert isinstance(ch.handle_scp({}), dict)
 
