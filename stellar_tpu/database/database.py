@@ -36,6 +36,28 @@ from .dialect import dialect_for, load_pg_driver
 # other index (tx/history.py); 1 keyed them (txid, ledgerseq)
 SCHEMA_VERSION = 2
 
+# sqlite's page cache, in KiB, for every node (PRAGMA cache_size, which
+# sqlite leaves at 2 MB).  What it must hold is the pages ONE close dirties
+# between BEGIN and COMMIT — bounded by the set's width, not by the state's
+# size: at most ~2 pages a row the flush writes (the table leaf and a leaf
+# of ``accountbalances``; interior pages are shared) and the close's own
+# history rows: ~13,300 + ~1,500 pages = 60 MB for a 5,000-tx close over
+# 10^6 accounts.  A dirty page the cache cannot hold is spilled to the WAL
+# before the COMMIT, read back when the flush touches it again and written
+# once more at the COMMIT: under 2 MB that was all but ~250 of them and nine
+# tenths of `commit.flush` (1,150 -> 130 ms a close on the chip's host,
+# PERF.md §6, PR 42).  sqlite allocates a page when it is used and keeps
+# clean pages too, so a node pays the smaller of its file's size and this
+# ceiling in resident memory (+260-340 MB peak RSS over the 200-300 MB file
+# of `state1m.close`; a few MB over a small ledger).  A constant, as
+# `synchronous` is: no Config field.  Why 256 MB and not the 64 MB that
+# holds 60: read on the chip's host, 64 MB left `commit.flush` at 326 ms
+# against 129 — a cache filled to nine tenths with dirty pages spills as
+# soon as a read needs a page — and the account SELECTs before the flush
+# found fewer interior pages; 256 MB leaves a wider set, or trust lines
+# and offers beside the accounts, four times a 5,000-tx close's room.
+SQLITE_CACHE_KIB = 262_144
+
 # the outermost COMMIT is THE durable boundary of the SQL plane: a kill
 # on the :pre side loses the whole transaction (restart sees the prior
 # state), on the :post side the transaction survives (restart resumes
@@ -162,12 +184,16 @@ class Database:
                 "PRAGMA journal_mode=MEMORY" if path == ":memory:"
                 else "PRAGMA journal_mode=WAL")
             self._conn.execute("PRAGMA synchronous=OFF")
+            self._conn.execute(f"PRAGMA cache_size=-{SQLITE_CACHE_KIB}")
         self._metrics = metrics
         self._tx_depth = 0
         self._sp_counter = 0
         self._lazy_sps = []  # one slot per open buffered scope; see transaction()
         self.excluded_time = 0.0  # DBTimeExcluder support
         self.query_count = 0
+        # rows the entry flush appended to `accounts` instead of updating
+        # where they lay (AccountFrame.upsert_batch; monotonic, /info)
+        self.rowids_taken = 0
         self.closed = False
 
     @staticmethod
@@ -248,6 +274,36 @@ class Database:
         if self._sql_translate is not None:
             sql = self._sql_translate(sql)
         return self._conn.execute(sql, tuple(params)).fetchall()
+
+    def max_rowid(self, table: str) -> int:
+        """sqlite: the highest rowid of ``table`` (0: empty) — one walk down
+        the right edge of its B-tree.  The difference across a batch of
+        upserts is the rows it appended: the new keys, and no row it
+        updated in place.  postgres has no rowid: 0."""
+        if self.dialect.name != "sqlite3":
+            return 0
+        top = self._conn.execute(f"SELECT max(rowid) FROM {table}").fetchone()[0]
+        return top or 0
+
+    def stats(self) -> dict:
+        """``/info`` ``database``: what this node's store runs with, each
+        setting read back from sqlite by PRAGMA (never off the source), the
+        file's size in pages, and ``rowids_taken``.  On postgres the six
+        settings are None."""
+        out = dict.fromkeys(
+            ("journal_mode", "synchronous", "wal_autocheckpoint", "cache_kib",
+             "page_size", "page_count")
+        )
+        if self.dialect.name == "sqlite3":
+            for name in out:
+                pragma = "cache_size" if name == "cache_kib" else name
+                out[name] = self._conn.execute(f"PRAGMA {pragma}").fetchone()[0]
+            # sqlite reports a size it was given in KiB as a negative
+            # number, one it was given in pages as a positive one
+            size = out["cache_kib"]
+            out["cache_kib"] = -size if size < 0 else size * out["page_size"] // 1024
+        out["rowids_taken"] = self.rowids_taken
+        return out
 
     # -- timed access (reference: getSelect/Insert/Update/DeleteTimer) ------
     @contextmanager

@@ -15,14 +15,27 @@ statement flow through a ``Dialect`` object (``Database.dialect``):
   ``statement_abort_credits_total_changes`` says the backend supports
   it, and falls back to materializing real savepoints otherwise.
 
+**The store buffer's flush is spelled once, here, for both databases**
+(PR 42): ``upsert_sql(table, cols)`` is ``INSERT INTO t (cols) VALUES (…)
+ON CONFLICT (pk) DO UPDATE SET col=EXCLUDED.col, …`` — every column but
+the key — and ``AccountFrame`` / ``TrustFrame`` / ``OfferFrame
+.upsert_batch`` (a close's flush, and ``Bucket.apply``) run that text on
+sqlite (≥ 3.24) and on postgres alike.  A row that exists is updated
+where it lies: on sqlite it keeps its rowid, its primary-key index entry
+is not touched, and only the table leaf and an index whose column changed
+are written.  Until PR 42 sqlite was given ``INSERT OR REPLACE``, which
+deletes the row and appends it under a new rowid — three B-trees lost an
+entry on a random leaf and gained one, and the table grew by a close's
+rows every close — while postgres got this statement by rewrite.
+
 ``rewrite`` is the statement-rewrite pass that makes the seam LIVE: a
 non-sqlite backend sees every statement before placeholder translation,
 so ``PostgresDialect`` routes the CREATE TABLE corpus through
-``column_type`` and rewrites the four ``INSERT OR REPLACE`` upsert
-batches (accounts / trustlines / offers / publishqueue — the store
-buffer's flush surface) into ``ON CONFLICT (pk) DO UPDATE`` form.  An
-upsert against a table the conflict-target map does not know is refused
-loudly — a silently-dropped rewrite would corrupt the flush.
+``column_type`` and still rewrites an ``INSERT OR REPLACE`` (the one left
+is ``publishqueue``'s, outside a close) into the same ``ON CONFLICT``
+form; the flush statements pass through it unchanged.  An upsert against
+a table the conflict-target map does not know is refused loudly — a
+silently-dropped rewrite would corrupt the flush.
 ``CacheIsConsistentWithDatabase`` (stellar_tpu/invariant/) is the live
 oracle for the whole pipeline: it runs against postgres whenever
 ``STELLAR_TPU_PG_DSN`` names a reachable server.
@@ -58,6 +71,42 @@ def load_pg_driver() -> Optional[Tuple[object, str]]:
         except ImportError:
             continue
     return None
+
+
+#: table -> primary-key columns, mirroring the CREATE TABLE corpus: the
+#: ON CONFLICT target of an upsert (sqlite's INSERT OR REPLACE keyed on the
+#: PK implicitly; ON CONFLICT needs it named, on both databases)
+UPSERT_CONFLICT_TARGETS = {
+    "accounts": ("accountid",),
+    "trustlines": ("accountid", "issuer", "assetcode"),
+    "offers": ("offerid",),
+    "publishqueue": ("ledger",),
+}
+
+
+def _on_conflict(table: str, cols) -> str:
+    target = UPSERT_CONFLICT_TARGETS.get(table.lower())
+    if target is None:
+        raise ValueError(
+            f"upsert against {table!r} has no registered conflict target"
+            " — add it to dialect.UPSERT_CONFLICT_TARGETS"
+        )
+    updates = ", ".join(
+        f"{c}=EXCLUDED.{c}" for c in cols if c.lower() not in target
+    )
+    return f" ON CONFLICT ({', '.join(target)}) DO UPDATE SET {updates}"
+
+
+def upsert_sql(table: str, cols: str) -> str:
+    """The flush statement of an entry table, the same text on sqlite and
+    postgres: insert the row, or where its key exists update every other
+    column in place.  ``cols``: the column list, comma-separated."""
+    names = [c.strip() for c in cols.split(",")]
+    return (
+        f"INSERT INTO {table} ({', '.join(names)})"
+        f" VALUES ({','.join('?' * len(names))})"
+        + _on_conflict(table, names)
+    )
 
 
 class Dialect:
@@ -127,10 +176,11 @@ class SqliteDialect(Dialect):
 
 class PostgresDialect(Dialect):
     """The postgres half of the seam, live: ``rewrite`` routes the CREATE
-    TABLE corpus through ``type_map`` and turns the INSERT OR REPLACE
-    upsert batches (the store buffer's flush surface) into
+    TABLE corpus through ``type_map`` and turns an INSERT OR REPLACE
+    (``publishqueue``'s; the store buffer's flush arrives as
+    ``upsert_sql`` spelled it and passes through) into
     ``ON CONFLICT (pk) DO UPDATE SET col=EXCLUDED.col`` form using the
-    conflict-target registry below.  The registry is authoritative: an
+    conflict-target registry.  The registry is authoritative: an
     upsert against an unregistered table raises instead of passing
     through — postgres would reject the sqlite spelling anyway, and a
     half-rewritten flush must never limp into the server."""
@@ -151,15 +201,7 @@ class PostgresDialect(Dialect):
         "VARCHAR(12)": "VARCHAR(12)",
         "BLOB": "BYTEA",
     }
-    #: table -> primary-key columns, mirroring the CREATE TABLE corpus.
-    #: sqlite's INSERT OR REPLACE keys on the PK implicitly; postgres
-    #: needs it named in the ON CONFLICT target.
-    upsert_conflict_targets = {
-        "accounts": ("accountid",),
-        "trustlines": ("accountid", "issuer", "assetcode"),
-        "offers": ("offerid",),
-        "publishqueue": ("ledger",),
-    }
+    upsert_conflict_targets = UPSERT_CONFLICT_TARGETS
 
     _UPSERT_RE = re.compile(
         r"^\s*INSERT\s+OR\s+REPLACE\s+INTO\s+(\w+)\s*\(([^)]*)\)(.*)$",
@@ -171,20 +213,10 @@ class PostgresDialect(Dialect):
         m = self._UPSERT_RE.match(sql)
         if m:
             table, collist, rest = m.group(1), m.group(2), m.group(3)
-            target = self.upsert_conflict_targets.get(table.lower())
-            if target is None:
-                raise ValueError(
-                    f"INSERT OR REPLACE against {table!r} has no registered"
-                    " conflict target — add it to"
-                    " PostgresDialect.upsert_conflict_targets"
-                )
             cols = [c.strip() for c in collist.split(",")]
-            updates = ", ".join(
-                f"{c}=EXCLUDED.{c}" for c in cols if c.lower() not in target
-            )
             return (
                 f"INSERT INTO {table} ({', '.join(cols)}){rest.rstrip()}"
-                f" ON CONFLICT ({', '.join(target)}) DO UPDATE SET {updates}"
+                + _on_conflict(table, cols)
             )
         if self._CREATE_RE.match(sql):
             # the DDL corpus spells types in the generic names type_map

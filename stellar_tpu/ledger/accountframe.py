@@ -6,6 +6,7 @@ import base64
 from typing import List, Optional
 
 from ..crypto import strkey
+from ..database.dialect import upsert_sql
 from ..xdr.entries import (
     AccountEntry,
     AccountFlags,
@@ -576,20 +577,23 @@ class AccountFrame(EntryFrame):
             ctx.evict(key_bytes(key))
 
     # -- store-buffer flush (ledger/storebuffer.py) ------------------------
-    _UPSERT_SQL = (
-        "INSERT OR REPLACE INTO accounts (balance, seqnum, numsubentries,"
-        " inflationdest, homedomain, thresholds, flags, lastmodified,"
-        " accountid) VALUES (?,?,?,?,?,?,?,?,?)"
+    # an account that exists is updated where it lies (dialect.upsert_sql)
+    _UPSERT_SQL = upsert_sql(
+        "accounts",
+        "balance, seqnum, numsubentries, inflationdest, homedomain,"
+        " thresholds, flags, lastmodified, accountid",
     )
 
     @classmethod
     def upsert_batch(cls, db, entries, signers_dirty) -> dict:
-        """-> the rows written: ``account_rows`` upserted; ``signer_rows``
+        """-> the rows written: ``account_rows`` upserted, of which
+        ``rowids_taken`` were appended under a new rowid and not updated in
+        place (sqlite: the new accounts); ``signer_rows``
         deleted plus inserted, for the ``signer_accounts`` whose mark in
         ``signers_dirty`` is set (``signers_differ``, taken at each store)
         and for no other: an account whose signers the close left as they
         were gets no statement against ``signers``.  ``commit.flush``
-        reports all three, zeros included."""
+        reports all four, zeros included."""
         rows, aids, signer_rows = [], [], []
         for e, dirty in zip(entries, signers_dirty):
             a = e.data.value
@@ -600,7 +604,10 @@ class AccountFrame(EntryFrame):
                 signer_rows.extend(cls._signer_rows(row[-1], a))
         deleted = 0
         with db.timed("flush", "account"):
+            top = db.max_rowid("accounts")
             db.executemany(cls._UPSERT_SQL, rows)
+            taken = db.max_rowid("accounts") - top
+            db.rowids_taken += taken
             if aids:
                 deleted = db.executemany(
                     "DELETE FROM signers WHERE accountid=?", aids
@@ -609,6 +616,7 @@ class AccountFrame(EntryFrame):
                 db.executemany(cls._SIGNER_INSERT_SQL, signer_rows)
         return {
             "account_rows": len(rows),
+            "rowids_taken": taken,
             "signer_rows": max(deleted, 0) + len(signer_rows),
             "signer_accounts": len(aids),
         }

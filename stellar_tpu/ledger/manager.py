@@ -542,9 +542,11 @@ class LedgerManager:
                     flush_sp = tracer.begin("commit.flush")
                     with self._flush_timer.time_scope():
                         written = buf.flush(self.database)
-                    # account_rows, signer_rows, signer_accounts, trust_rows,
-                    # offer_rows: what the flush wrote or deleted (signer
-                    # rows only where a store changed them)
+                    # account_rows, rowids_taken, signer_rows,
+                    # signer_accounts, trust_rows, offer_rows: what the
+                    # flush wrote or deleted (signer rows only where a store
+                    # changed them; rowids taken: account rows appended, not
+                    # updated in place — the accounts the close created)
                     tracer.end(flush_sp, **written)
             finally:
                 # success: overlay already flushed (deactivate clears

@@ -14,6 +14,7 @@ from __future__ import annotations
 from typing import List, Optional
 
 from ..crypto import strkey
+from ..database.dialect import upsert_sql
 from ..xdr.entries import (
     Asset,
     LedgerEntry,
@@ -316,6 +317,8 @@ class OfferFrame(EntryFrame):
         cls.store_in_cache(db, key, None)
 
     # -- store-buffer flush (ledger/storebuffer.py) ------------------------
+    _UPSERT_SQL = upsert_sql("offers", _COLS)
+
     @classmethod
     def upsert_batch(cls, db, entries, _signers_dirty) -> dict:
         rows = [
@@ -323,11 +326,7 @@ class OfferFrame(EntryFrame):
             for e in entries
         ]
         with db.timed("flush", "offer"):
-            db.executemany(
-                f"INSERT OR REPLACE INTO offers ({cls._COLS})"
-                " VALUES (?,?,?,?,?,?,?,?,?,?,?,?,?,?)",
-                rows,
-            )
+            db.executemany(cls._UPSERT_SQL, rows)
         return {"offer_rows": len(rows)}
 
     @classmethod

@@ -32,9 +32,18 @@ This buffer makes the stores write-back instead of write-through during
   ``rollback_mark`` / ``release_mark`` so a failed transaction unwinds
   its buffered writes in lockstep with its (now row-less) savepoint.
 - at the end of the close the net overlay flushes as a handful of
-  ``executemany`` batches (INSERT OR REPLACE + DELETE per entity), and
+  ``executemany`` batches (an upsert + a DELETE per entity), and
   PARANOID_MODE's delta-vs-database audit runs *after* the flush — the
   same safety net that guarded the write-through path guards this one.
+  The upsert is ``INSERT … ON CONFLICT (pk) DO UPDATE`` on sqlite and
+  postgres alike (``database/dialect.py`` ``upsert_sql``, PR 42): a row
+  that exists is updated where it lies — it keeps its rowid, its
+  primary-key index entry is not touched — and only a new key appends
+  (``rowids_taken`` on ``commit.flush``: the accounts the close created).
+  The pages the flush dirties wait in sqlite's page cache for the COMMIT
+  (``database.py`` ``SQLITE_CACHE_KIB``: sized to hold one close's, which
+  a set's width bounds, not the state's size); under sqlite's 2 MB default
+  they were written to the WAL, read back and written again.
 - a slot holds the key, the pending entry (None: a pending delete), the
   frame class that writes it, and for an account whether its rows of
   ``signers`` must be written with it: ``EntryFrame._record`` sets that

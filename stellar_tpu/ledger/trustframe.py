@@ -5,6 +5,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 from ..crypto import strkey
+from ..database.dialect import upsert_sql
 from ..xdr.entries import (
     Asset,
     AssetType,
@@ -274,6 +275,12 @@ class TrustFrame(EntryFrame):
         cls.store_in_cache(db, key, None)
 
     # -- store-buffer flush (ledger/storebuffer.py) ------------------------
+    _UPSERT_SQL = upsert_sql(
+        "trustlines",
+        "accountid, assettype, issuer, assetcode, tlimit, balance, flags,"
+        " lastmodified",
+    )
+
     @classmethod
     def upsert_batch(cls, db, entries, _signers_dirty) -> dict:
         rows = [
@@ -281,12 +288,7 @@ class TrustFrame(EntryFrame):
             for e in entries
         ]
         with db.timed("flush", "trust"):
-            db.executemany(
-                "INSERT OR REPLACE INTO trustlines (accountid, assettype,"
-                " issuer, assetcode, tlimit, balance, flags, lastmodified)"
-                " VALUES (?,?,?,?,?,?,?,?)",
-                rows,
-            )
+            db.executemany(cls._UPSERT_SQL, rows)
         return {"trust_rows": len(rows)}
 
     @classmethod

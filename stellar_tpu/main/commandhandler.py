@@ -242,6 +242,11 @@ class CommandHandler:
             # answered and missed, lines it pushed out at capacity, accounts
             # it had to ask SQL for (monotonic; ``lines`` is now)
             "entry_cache": entry_cache_of(app.database).stats(),
+            # what the SQL store runs with, read back from sqlite by PRAGMA
+            # (journal mode, synchronous, checkpoint cadence, page cache,
+            # the file's pages), and the rows the entry flush appended
+            # under a new rowid instead of updating in place (monotonic)
+            "database": app.database.stats(),
         }
         if app.herder is not None:
             # the consensus side's intake since the node started: SCP

@@ -274,11 +274,11 @@ def test_the_prefetch_is_complete_and_the_spans_say_what_the_set_implies(world):
     (flush,) = by_name["commit.flush"]
     # 32 accounts touched and no signer of theirs changed: no row of
     # ``signers`` written, and the span says 0 rather than leave the key out
-    assert flush.attrs == {"account_rows": 2 * WIDTH, "signer_rows": 0, "signer_accounts": 0}
+    assert flush.attrs == {"account_rows": 2 * WIDTH, "rowids_taken": 0, "signer_rows": 0, "signer_accounts": 0}
     # the close that installed them: five rows inserted an account, none
     # there to delete
     n = len(world.keys)
-    assert world.install_flush == {"account_rows": n, "signer_rows": n * PER, "signer_accounts": n}
+    assert world.install_flush == {"account_rows": n, "rowids_taken": 0, "signer_rows": n * PER, "signer_accounts": n}
 
 
 @pytest.fixture(scope="module")

@@ -252,7 +252,8 @@ class _SignerWorld(_ScenarioRunner):
             for k in (a, b)
         ])
         assert installed.flush == {
-            "account_rows": 2, "signer_rows": 4, "signer_accounts": 2,
+            "account_rows": 2, "rowids_taken": 0, "signer_rows": 4,
+            "signer_accounts": 2,
         }
 
     def signer_statements(self, node):
@@ -358,7 +359,7 @@ def _case_payments(w, a, b, c):
         T.tx_from_ops(app, b, _seq(app, b), [T.payment_op(c, 10**6)]),
     ])
     assert out.codes == [RC.txSUCCESS, RC.txSUCCESS]
-    assert out.flush == {"account_rows": 3, "signer_rows": 0, "signer_accounts": 0}
+    assert out.flush == {"account_rows": 3, "rowids_taken": 0, "signer_rows": 0, "signer_accounts": 0}
     _only_of(w)
 
 
@@ -369,14 +370,14 @@ def _case_add(w, a, b, c):
     ])
     assert out.codes == [RC.txSUCCESS, RC.txSUCCESS]
     # two rows deleted, three inserted; b's two rows are left alone
-    assert out.flush == {"account_rows": 3, "signer_rows": 5, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 3, "rowids_taken": 0, "signer_rows": 5, "signer_accounts": 1}
     _only_of(w, "a")
 
 
 def _case_remove(w, a, b, c):
     out = w.close(lambda app, root: [_set_signer(app, a, "s1", 0)])
     assert out.codes == [RC.txSUCCESS]
-    assert out.flush == {"account_rows": 1, "signer_rows": 3, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 1, "rowids_taken": 0, "signer_rows": 3, "signer_accounts": 1}
     assert list(_signers_by_sqlite3(w.db_paths[0])[_strkey(a)]) == [_strkey(_sk("s2"))]
 
 
@@ -388,7 +389,7 @@ def _case_reweigh(w, a, b, c):
         T.tx_from_ops(app, a, _seq(app, a) + 1, [T.payment_op(c, 10**6)]),
     ])
     assert out.codes == [RC.txSUCCESS, RC.txSUCCESS]
-    assert out.flush == {"account_rows": 2, "signer_rows": 4, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 2, "rowids_taken": 0, "signer_rows": 4, "signer_accounts": 1}
     assert _signers_by_sqlite3(w.db_paths[0])[_strkey(a)][_strkey(_sk("s1"))] == 5
 
 
@@ -410,7 +411,7 @@ def _case_change_and_back(w, a, b, c):
     assert out.codes == [RC.txSUCCESS] * 3
     # once marked, marked for the close: the rows are written though the
     # list is the stored one again
-    assert out.flush == {"account_rows": 2, "signer_rows": 4, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 2, "rowids_taken": 0, "signer_rows": 4, "signer_accounts": 1}
     assert _signers_by_sqlite3(w.db_paths[0]) == before
 
 
@@ -431,7 +432,7 @@ def _case_rolled_back(w, a, b, c):
     assert _signers_by_sqlite3(w.db_paths[0]) == before
     # the rollback erased a's line of the entry cache, so the payment's
     # store has no stored snapshot at hand: written, unchanged
-    assert out.flush == {"account_rows": 2, "signer_rows": 4, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 2, "rowids_taken": 0, "signer_rows": 4, "signer_accounts": 1}
     _only_of(w, "a")
 
 
@@ -451,19 +452,20 @@ def _case_add_or_change(w, a, b, c):
     out = w.apply_direct(lambda app, delta, db: store_add_or_change(
         _new_account(d, [("s1", 1), ("s2", 2)]), delta, db
     ))
-    assert out.flush == {"account_rows": 1, "signer_rows": 2, "signer_accounts": 1}
+    # a new account: the one row the flush appends
+    assert out.flush == {"account_rows": 1, "rowids_taken": 1, "signer_rows": 2, "signer_accounts": 1}
     _only_of(w, "d")
     assert len(_signers_by_sqlite3(w.db_paths[0])[_strkey(d)]) == 2
     # the same signers under another balance: the row of accounts alone
     out = w.apply_direct(lambda app, delta, db: store_add_or_change(
         _new_account(d, [("s1", 1), ("s2", 2)], balance=10**9), delta, db
     ))
-    assert out.flush == {"account_rows": 1, "signer_rows": 0, "signer_accounts": 0}
+    assert out.flush == {"account_rows": 1, "rowids_taken": 0, "signer_rows": 0, "signer_accounts": 0}
     _only_of(w)
     out = w.apply_direct(lambda app, delta, db: store_add_or_change(
         _new_account(d, [("s2", 2)]), delta, db
     ))
-    assert out.flush == {"account_rows": 1, "signer_rows": 3, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 1, "rowids_taken": 0, "signer_rows": 3, "signer_accounts": 1}
     _only_of(w, "d")
 
 
@@ -473,7 +475,7 @@ def _case_merged_away(w, a, b, c):
     ])
     assert out.codes == [RC.txSUCCESS]
     # a is credited and keeps its rows; b's go with its account
-    assert out.flush == {"account_rows": 1, "signer_rows": 0, "signer_accounts": 0}
+    assert out.flush == {"account_rows": 1, "rowids_taken": 0, "signer_rows": 0, "signer_accounts": 0}
     _only_of(w, "b")
     assert _strkey(b) not in _signers_by_sqlite3(w.db_paths[0])
 
@@ -487,7 +489,7 @@ def _case_no_snapshot(w, a, b, c):
 
     out = w.apply_direct(store)
     # nothing says what SQL holds of a's signers: written as they are
-    assert out.flush == {"account_rows": 1, "signer_rows": 4, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 1, "rowids_taken": 0, "signer_rows": 4, "signer_accounts": 1}
     _only_of(w, "a")
     # a close on a cold cache warms it from SQL before the first store
     for app in w.apps:
@@ -495,7 +497,7 @@ def _case_no_snapshot(w, a, b, c):
     out = w.close(lambda app, root: [
         T.tx_from_ops(app, a, _seq(app, a), [T.payment_op(b, 10**6)]),
     ])
-    assert out.flush == {"account_rows": 2, "signer_rows": 0, "signer_accounts": 0}
+    assert out.flush == {"account_rows": 2, "rowids_taken": 0, "signer_rows": 0, "signer_accounts": 0}
     _only_of(w)
 
 
@@ -507,13 +509,13 @@ def _case_closes_in_a_row(w, a, b, c):
         ]
 
     out = w.close(lambda app, root: [_set_signer(app, a, "s3", 1)] + pay(app, root)[1:])
-    assert out.flush == {"account_rows": 2, "signer_rows": 5, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 2, "rowids_taken": 0, "signer_rows": 5, "signer_accounts": 1}
     for _ in range(2):
         out = w.close(pay)
-        assert out.flush == {"account_rows": 2, "signer_rows": 0, "signer_accounts": 0}
+        assert out.flush == {"account_rows": 2, "rowids_taken": 0, "signer_rows": 0, "signer_accounts": 0}
         _only_of(w)
     out = w.close(lambda app, root: [_set_signer(app, b, "s2", 7)] + pay(app, root)[:1])
-    assert out.flush == {"account_rows": 2, "signer_rows": 4, "signer_accounts": 1}
+    assert out.flush == {"account_rows": 2, "rowids_taken": 0, "signer_rows": 4, "signer_accounts": 1}
     _only_of(w, "b")
     assert len(_signers_by_sqlite3(w.db_paths[0])[_strkey(a)]) == 3
 
