@@ -35,6 +35,10 @@ class TxSetFrame:
         self.transactions: List[TransactionFrame] = list(transactions or [])
         self._hash: Optional[bytes] = None
         self._triples_memo: Optional[list] = None
+        # the accounts the set can touch, and whether ``warm_asked`` has
+        # counted them: both dropped with the triples when the set changes
+        self._account_ids_memo: Optional[set] = None
+        self._warm_counted = False
 
     @classmethod
     def from_xdr_set(cls, network_id: bytes, xdr_set: TransactionSet) -> "TxSetFrame":
@@ -58,18 +62,22 @@ class TxSetFrame:
             self._hash = h.finish()
         return self._hash
 
-    def add_transaction(self, tx: TransactionFrame) -> None:
-        self.transactions.append(tx)
+    def _changed(self) -> None:
         self._hash = None
         self._triples_memo = None
+        self._account_ids_memo = None
+        self._warm_counted = False
+
+    def add_transaction(self, tx: TransactionFrame) -> None:
+        self.transactions.append(tx)
+        self._changed()
 
     def remove_tx(self, tx: TransactionFrame) -> None:
         try:
             self.transactions.remove(tx)
         except ValueError:
             pass
-        self._hash = None
-        self._triples_memo = None
+        self._changed()
 
     def size(self) -> int:
         return len(self.transactions)
@@ -107,8 +115,11 @@ class TxSetFrame:
     def collect_account_ids(self) -> set:
         """Every account this set can touch: tx sources, op sources, and
         op targets (create/payment/path destinations, merge target,
-        allow-trust trustor).  Feeds AccountFrame.bulk_warm_cache before
-        apply so big random-access ledgers avoid per-miss point SELECTs."""
+        allow-trust trustor).  Feeds ``warm_accounts`` so big
+        random-access ledgers avoid per-miss point SELECTs.  Memoized per
+        set; invalidated on add_transaction/remove_tx."""
+        if self._account_ids_memo is not None:
+            return self._account_ids_memo
         from ..xdr.txs import OperationType as OT
 
         ids = set()
@@ -125,19 +136,46 @@ class TxSetFrame:
                     ids.add(v)  # merge body is the destination AccountID
                 elif t == OT.ALLOW_TRUST:
                     ids.add(v.trustor)
+        self._account_ids_memo = ids
         return ids
+
+    def warm_accounts(self, app, site: str) -> None:
+        """Bulk-load every account the set can touch into the entry cache
+        (``AccountFrame.bulk_warm_cache``: chunked IN() selects of the ids
+        the cache lacks) under an ``accounts.warm`` span.  Two sites ask:
+        ``collect``, before the set's signature triples are first gathered
+        (``check_valid``, ``trim_invalid``, a close's own prewarm), and
+        ``close``, before a close applies the set.  Whoever comes first
+        pays the loads; the other finds every line there (``missed`` 0)
+        unless the cache was cleared or evicted in between, and then
+        reloads — nothing is skipped on the strength of a memo.  The set's
+        accounts count into ``warm_asked`` once a set, whoever asks."""
+        from ..ledger.accountframe import AccountFrame
+
+        tracer = tracer_of(app)
+        with tracer.span("accounts.warm", site=site) as sp:
+            did = AccountFrame.bulk_warm_cache(
+                app.database,
+                self.collect_account_ids(),
+                count_asked=not self._warm_counted,
+            )
+            self._warm_counted = True
+            tracer.end(sp, **did)
 
     # -- shared validity core ----------------------------------------------
     def _collect_signature_triples(self, app) -> list:
         """Memoized per set: collection does a readonly account load per tx
-        (hint-matching needs the signers), and close_ledger prewarms the
-        same set check_valid just prewarmed.  The triples are a pure
+        (hint-matching needs the signers) — a cache hit each, since the
+        set's accounts are bulk-warmed first (``warm_accounts``, outside
+        ``sig.collect``) — and close_ledger prewarms the same set
+        check_valid just prewarmed.  The triples are a pure
         prefetch — the eager check_signature path re-verifies anything the
         batch missed — so a memo gone stale against DB signer changes can
         only weaken the prefetch, never change a result.  Invalidated on
         add_transaction/remove_tx.  A collection (not a memo hit) records
         ``sig.collect``."""
         if self._triples_memo is None:
+            self.warm_accounts(app, "collect")
             tracer = tracer_of(app)
             with tracer.span("sig.collect", txs=len(self.transactions)) as sp:
                 triples = []

@@ -320,7 +320,7 @@ class AccountFrame(EntryFrame):
         return frame
 
     @classmethod
-    def bulk_warm_cache(cls, db, account_ids) -> dict:
+    def bulk_warm_cache(cls, db, account_ids, count_asked: bool = True) -> dict:
         """Prime the entry cache for many accounts with chunked IN()
         selects — one statement per ~500 accounts instead of one point
         SELECT per cache miss.  Missing accounts cache as known-absent.
@@ -328,21 +328,30 @@ class AccountFrame(EntryFrame):
         ``asked``, ``missed`` by the cache, ``selects`` (chunks: an accounts
         and a signers statement each), account ``rows`` found.
 
-        The close path warms every account its txset touches before apply.
-        It does nothing while the whole ledger fits the cache (every cell
-        but one); over 10^6 accounts, a 5,000-tx set's 7,500 residents
-        nearly all miss, and what the ~20 chunks cost a close is
+        A transaction set warms every account it can touch before anything
+        reads one of them (``TxSetFrame.warm_accounts``): where its
+        signature triples are first collected, and again before a close
+        applies it.  It does nothing while the whole ledger fits the cache
+        (every cell but one); over 10^6 accounts, a 5,000-tx set's 7,500
+        residents nearly all miss, and what the ~20 chunks cost is
         ``accounts_warm_ms_per_close`` of ``state1m.close`` (PERF.md §6,
-        PR 41, read on the chip)."""
-        # runs before the store buffer activates (close_ledger warms first,
-        # then turns the buffer on), so SQL rows are never stale here
+        PR 41 and PR 43, read on the chip).  ``count_asked`` False leaves
+        ``warm_asked`` alone — a set's second ask; ``sql_loads`` counts
+        every account really asked of SQL, whoever asks."""
+        # a caller may ask while a close's store buffer is live (the
+        # close's own signature prewarm): a key the buffer holds is the
+        # buffer's — its SQL row may be stale, so it is neither read nor
+        # put in the cache here (``load_account``'s miss path, same rule)
         cache = cls.cache_of(db)
+        buf = active_buffer(db)
         todo = []
         asked = found = 0
         for pk in account_ids:
             asked += 1
-            if not cache.contains(_ACCT_KEY_PREFIX + pk.value):
-                todo.append(pk)
+            kb = _ACCT_KEY_PREFIX + pk.value
+            if cache.contains(kb) or (buf is not None and buf.get(kb)[0]):
+                continue
+            todo.append(pk)
         CHUNK = 500
         for lo in range(0, len(todo), CHUNK):
             chunk = todo[lo : lo + CHUNK]
@@ -399,7 +408,8 @@ class AccountFrame(EntryFrame):
                     ),
                 )
                 found += 1
-        cache.warm_asked += asked
+        if count_asked:
+            cache.warm_asked += asked
         cache.sql_loads += len(todo)
         return {
             "asked": asked,

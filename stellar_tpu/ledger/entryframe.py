@@ -36,11 +36,12 @@ class EntryCache:
         self._map: OrderedDict[bytes, Optional[LedgerEntry]] = OrderedDict()
         self.hits = 0
         self.misses = 0
-        # lines pushed out at CAPACITY; accounts the closes' bulk warm
-        # probed (``contains``: ``hits`` / ``misses`` count loads, and after
-        # a warm every load hits); accounts asked of SQL because no line (or
-        # pending write) had them — a row read or known-absent after
-        # (``bulk_warm_cache``, ``AccountFrame.load_account``'s miss)
+        # lines pushed out at CAPACITY; accounts the sets' bulk warm probed,
+        # once a set however often it asks (``contains``: ``hits`` /
+        # ``misses`` count loads, and after a warm every load hits);
+        # accounts asked of SQL because no line (or pending write) had them
+        # — a row read or known-absent after (``bulk_warm_cache``,
+        # ``AccountFrame.load_account``'s miss)
         self.evictions = 0
         self.warm_asked = 0
         self.sql_loads = 0

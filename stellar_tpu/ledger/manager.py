@@ -435,21 +435,16 @@ class LedgerManager:
             ledger_delta = LedgerDelta(self.current.header, self.database)
 
             txs = ledger_data.tx_set.sort_for_apply()
-            # bulk-load every account the set touches into the entry cache
-            # (chunked IN() selects) BEFORE the signature prewarm collects
-            # its triples — both it and apply then run on a warm cache
-            from .accountframe import AccountFrame
+            # the set's accounts reach the entry cache in bulk (chunked IN()
+            # selects) before fees, prewarm or apply read one of them.  A set
+            # that was validated first was warmed where its triples were
+            # collected and finds every line here, short of what the cache
+            # evicted since; a set that was not (catch-up replay, a direct
+            # close) is loaded now
             from .framecontext import frame_context_of
             from .storebuffer import store_buffer_of
 
-            with tracer.span("accounts.warm") as warm_sp:
-                tracer.end(
-                    warm_sp,
-                    **AccountFrame.bulk_warm_cache(
-                        self.database,
-                        ledger_data.tx_set.collect_account_ids(),
-                    ),
-                )
+            ledger_data.tx_set.warm_accounts(self.app, "close")
             # write-back store buffer: entry mutations accumulate in an
             # overlay (reads see through it) and flush as batched SQL
             # before the PARANOID audit, instead of ~8 statements per tx.

@@ -190,22 +190,36 @@ def test_bucket_apply_spans_and_history_counters(world):
 
 
 def test_accounts_warm_span_a_close(world):
+    """A reading validates the set, then closes it: the set's accounts are
+    loaded where its triples are collected, and the close's own ask finds
+    them — but for a line that was there before the collect's warm and old
+    enough for the warm's own puts to push it out of the 256."""
     half = WIDTH // 2
     for spans in world.close_spans:
-        (warm,) = [s for s in spans if s.name == "accounts.warm"]
+        at_collect, at_close = [s for s in spans if s.name == "accounts.warm"]
+        (validate,) = [s for s in spans if s.name == "txset.validate"]
+        (collect,) = [s for s in spans if s.name == "sig.collect"]
         (close,) = [s for s in spans if s.name == "ledger.close"]
-        assert warm.parent == close.sid
-        a = warm.attrs
+        assert (at_collect.attrs["site"], at_close.attrs["site"]) == ("collect", "close")
+        assert at_collect.parent == validate.sid == collect.parent and at_collect.end <= collect.start
+        assert at_close.parent == close.sid
+        a = at_collect.attrs
         # 3 x half residents and half destinations that do not exist yet
         assert a["asked"] == 4 * half
         assert a["rows"] + half <= a["missed"] <= a["asked"]
         assert a["selects"] == -(-a["missed"] // 500)
+        c = at_close.attrs
+        assert c["asked"] == 4 * half and c["rows"] <= c["missed"] <= a["asked"] - a["missed"]
+        assert c["selects"] == -(-c["missed"] // 500)
+        # the hint-matching loop ran on lines: one account a transaction, all there
+        assert collect.attrs["accounts"] == WIDTH
 
 
 def test_entry_cache_block_counts(world):
     b, a = world.before["entry_cache"], world.info["entry_cache"]
     assert set(a) == {"hits", "misses", "evictions", "warm_asked", "sql_loads", "lines", "capacity"}
     assert a["capacity"] == LINES and a["lines"] == LINES
+    # once a set, though a reading asks twice (the collect's warm, the close's)
     assert a["warm_asked"] - b["warm_asked"] == CLOSES * 2 * WIDTH
     # 2,000 residents against 256 lines: most of a set's residents are asked of SQL
     asked_of_sql = a["sql_loads"] - b["sql_loads"]
