@@ -654,7 +654,7 @@ def kernel_leg(rehearsal: bool, lanes: int) -> dict:
     import __graft_entry__ as graft
     from stellar_tpu.ops import sha256 as dsha256
     from stellar_tpu.ops import sha512 as dsha512
-    from stellar_tpu.ops.ed25519 import BatchVerifier
+    from stellar_tpu.ops.verifier import BatchVerifier
 
     out = {"device": _device_or_fail(rehearsal), "lanes": lanes, "programs": {}}
     pallas = not rehearsal

@@ -38,10 +38,9 @@ def _make_app(instance, n_txs, buffered=True, frame_context=True, cow=True,
     cfg.COW_ENTRY_SNAPSHOTS = cow
     cfg.PARANOID_MODE = paranoid
     cfg.CLOSE_PIPELINE = pipeline
-    # invariant plane in SAMPLED mode, matching bench.py: this harness's
+    # invariant plane in SAMPLED mode, the production default: this harness's
     # round-over-round p50s (and the close_budget regression gate) must
-    # stay comparable with pre-r08 numbers — the all-on cost is tracked
-    # separately as bench.py's invariant_overhead_ms.  --pipeline-report
+    # stay comparable with pre-r08 numbers.  --pipeline-report
     # overrides to ALL-ON (its acceptance contract audits every close).
     cfg.INVARIANT_SAMPLED = sampled
     # span durations need a real clock (a virtual one stamps every span

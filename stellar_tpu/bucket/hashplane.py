@@ -199,8 +199,7 @@ class DeviceBackend(BucketHashBackend):
 
 class _Stats:
     """Whole-process hash-plane throughput ledger: bytes hashed and wall
-    seconds per backend, read by selfcheck's boot report and bench.py's
-    close lines."""
+    seconds per backend, read by selfcheck's boot report."""
 
     def __init__(self):
         self._lock = threading.Lock()

@@ -417,13 +417,13 @@ class TpuSigBackend(SigBackend):
         mesh=None,
         sig_mesh=0,
         cpu_cutover: int = DEFAULT_TPU_CPU_CUTOVER,
-        streams: Optional[int] = None,
-        native_hash: Optional[bool] = None,
-        device_hash: Optional[bool] = None,
+        streams: int = 1,
+        native_hash: bool = True,
+        device_hash: bool = False,
         tracer=None,
         shared_programs: bool = False,
     ):
-        from ..ops.ed25519 import BatchVerifier  # lazy: JAX import
+        from ..ops.verifier import BatchVerifier  # lazy: JAX import
 
         self._tracer = tracer if tracer is not None else NULL_TRACER
         # sig_mesh: the Config.SIG_MESH production wiring — 0/off,
@@ -437,12 +437,11 @@ class TpuSigBackend(SigBackend):
 
             mesh = mesh_from_spec(sig_mesh)
         # native_hash: the C host stage (gate + batch SHA-512 mod L,
-        # native/sighash.c) — default auto (on when it builds); stats()
-        # reports which stage is live as "native_host_stage".
+        # native/sighash.c) — on when it builds; stats() reports which
+        # stage is live as "native_host_stage".
         # device_hash: the Config.DEVICE_HASH production wiring — the
         # SHA-512 stage runs ON DEVICE fused ahead of the verify kernel
-        # (ops/sha512.py) and the host keeps only the strict gate; None
-        # defers to the STELLAR_TPU_DEVICE_HASH env default (off).
+        # (ops/sha512.py) and the host keeps only the strict gate.
         self._verifier = BatchVerifier(
             max_batch=max_batch,
             mesh=mesh,

@@ -29,7 +29,6 @@ not just that it stays quiet on healthy closes.
 from __future__ import annotations
 
 import random
-from collections import deque
 from time import perf_counter
 from typing import Callable, List, Optional
 
@@ -58,15 +57,11 @@ class InvariantManager:
         self.sample_cap = int(getattr(cfg, "INVARIANT_CACHE_SAMPLE", 16))
         self.total_violations = 0
         self.closes_checked = 0
-        # per-close total invariant cost in ms, most recent last — bench.py
-        # reads this for invariant_overhead_ms (all-on vs sampled vs off)
-        self.close_costs = deque(maxlen=256)
         self._stats = {
             inv.name: {"runs": 0, "violations": 0, "last_violation": None}
             for inv in self._invariants
         }
         self._injections: List[Callable] = []
-        self._baseline_ms = 0.0
 
     # -- introspection ------------------------------------------------------
     @property
@@ -109,20 +104,13 @@ class InvariantManager:
         """CloseBaseline for a close about to start.  The whole-ledger
         balance sum is captured ONLY when conservation is enabled in
         all-on mode — it is the invariant plane's one full-table scan,
-        and sampled mode trades it away (bench.py measures the trade as
-        invariant_overhead_ms)."""
+        and sampled mode trades it away."""
         from .invariants import CloseBaseline
 
         want_sum = not self.sampled and any(
             inv.name == "ConservationOfLumens" for inv in self._invariants
         )
-        t0 = perf_counter()
-        baseline = CloseBaseline.of(header, db if want_sum else None)
-        # the baseline's full-table scan is half of all-on mode's cost;
-        # charge it to the close it serves so close_costs (and bench.py's
-        # invariant_overhead_ms) carry the WHOLE per-close overhead
-        self._baseline_ms = (perf_counter() - t0) * 1000.0
-        return baseline
+        return CloseBaseline.of(header, db if want_sum else None)
 
     # -- test injection seam ------------------------------------------------
     def inject_once(self, fn: Callable) -> None:
@@ -162,7 +150,6 @@ class InvariantManager:
         tracer = self.app.tracer
         metrics = self.app.metrics
         failures = []
-        close_ms, self._baseline_ms = self._baseline_ms, 0.0
         self.closes_checked += 1
         for inv in invs:
             st = self._stats[inv.name]
@@ -170,7 +157,6 @@ class InvariantManager:
                 t0 = perf_counter()
                 msg = inv.check(ctx)
                 dt = perf_counter() - t0
-            close_ms += dt * 1000.0
             st["runs"] += 1
             metrics.new_timer(("invariant", inv.name, "run")).update(dt)
             if msg is not None:
@@ -188,6 +174,5 @@ class InvariantManager:
                     inv.name, header.ledgerSeq, msg,
                 )
                 failures.append((inv.name, msg))
-        self.close_costs.append(close_ms)
         if failures and self.fail_policy == "raise":
             raise InvariantViolation(failures)

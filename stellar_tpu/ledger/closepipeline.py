@@ -104,8 +104,7 @@ class ClosePipeline:
         # lagging node's replayed run closes as ONE pipelined backlog
         self._held = 0
         self.n_held_sweeps = 0  # sweeps that released a >1 backlog
-        # overlap accounting (bench.py overlap_hidden_ms / profile_close
-        # --pipeline-report read these)
+        # overlap accounting (stats(); profile_close --pipeline-report)
         self.n_dispatched = 0  # sets whose triples were handed over
         self.n_flushes = 0  # the async flushes they rode
         self.n_items = 0  # the triples in those flushes

@@ -135,9 +135,9 @@ def entry_cache_of(db) -> EntryCache:
     return cache
 
 
-# seal-on-store copy-on-write counters (process-wide, monotonic — bench.py
-# differences two samples per timed close window; profile_close.py
-# --copy-report prints them next to the per-site xdr_copy attribution).
+# seal-on-store copy-on-write counters (process-wide, monotonic: readers
+# difference two samples; profile_close.py --copy-report prints them next
+# to the per-site xdr_copy attribution).
 # seals   = stores that shared the live entry instead of deep-copying
 # unseals = lazy CoW copies actually paid at the next mutating access —
 #           the old scheme paid one copy per STORE, so (seals - unseals)

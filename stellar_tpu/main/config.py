@@ -7,7 +7,6 @@ libsodium; we route every verify through the chosen SigBackend).
 
 from __future__ import annotations
 
-import os
 import tomllib
 from typing import Dict, List, Optional
 
@@ -139,14 +138,9 @@ class Config:
         self.SCP_SIG_SCHEME = "ed25519"
         # dispatch streams for multi-chunk verify batches: 2 overlaps one
         # chunk's upload with another's execution — worth it only when
-        # the transfer pipelines with the kernel (bench.py A/Bs it;
-        # ops/ed25519.py BatchVerifier docs).  The TOML knob
-        # wins; its default honors the STELLAR_TPU_VERIFY_STREAMS env var
-        # so the documented operator override keeps working on the node
-        # path too
-        self.SIG_VERIFY_STREAMS = int(
-            os.environ.get("STELLAR_TPU_VERIFY_STREAMS", "1")
-        )
+        # the transfer pipelines with the kernel (ops/verifier.py
+        # BatchVerifier)
+        self.SIG_VERIFY_STREAMS = 1
         # below this many cache-miss verifies the tpu backend loops
         # libsodium instead of paying a device round-trip (tests set 0 to
         # force every batch onto the device path; breakeven arithmetic at
@@ -189,8 +183,7 @@ class Config:
         # PRODUCTION default — all-on puts two full-table SUM scans plus
         # per-changed-entry SQL re-reads on every close, which a large
         # ledger cannot pay silently.  Tests run all-on
-        # (tx/testutils.get_test_config flips this off) and bench.py
-        # measures both modes as invariant_overhead_ms.
+        # (tx/testutils.get_test_config flips this off).
         self.INVARIANT_SAMPLED = True
         self.INVARIANT_CACHE_SAMPLE = 16
         # TPU-native addition: close-scoped frame identity map — ONE

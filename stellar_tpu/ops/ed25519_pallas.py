@@ -26,7 +26,6 @@ the same code path on the CPU mesh.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -39,17 +38,17 @@ from . import ed25519 as ed
 
 NT = 512  # batch tile (lanes); must divide the padded batch
 
-# Compress-stage lane-tree Montgomery inversion (round-4 optimization,
-# ~11% modeled).  Env-switchable so profile_kernel.py can A/B it against
-# the per-lane pow-chain inversion inside one run.
-_BATCH_INV = os.environ.get("STELLAR_TPU_BATCH_INV", "1") != "0"
+# Compress-stage lane-tree Montgomery inversion: one field inversion a tile
+# in place of one a lane (a tile's batch axis is local: ed.compress).
+_BATCH_INV = True
 
-# Signed-digit windows (round-5 experiment): recode the radix-16 scalar
-# digits to [-8, 7] with carry, so both niels tables need only k = 1..8
-# (half the dynamic-table build, ~half the select where-chains; the sign
-# is applied at select time — a niels negation is one component swap plus
-# one field negation).  Env-switchable for the same-window device A/B.
-_SIGNED_WIN = os.environ.get("STELLAR_TPU_SIGNED_WINDOWS", "0") != "0"
+# Signed-digit windows: recode the radix-16 scalar digits to [-8, 7] with
+# carry, so both niels tables need only k = 1..8 (half the dynamic-table
+# build, ~half the select where-chains; the sign is applied at select time
+# — a niels negation is one component swap plus one field negation).  Off:
+# never measured on the chip; the path stays for the one paired run that
+# decides it (ROADMAP S5: flip this in a scratch copy).
+_SIGNED_WIN = False
 
 _CONST_NAMES = ("SUB_PAD", "P_COL", "D", "D2", "SQRT_M1")
 
@@ -181,7 +180,7 @@ def verify_kernel_pallas(
     all little-endian) — 8x less host->device transfer than the XLA
     kernel's int32+nibble interface.  N must be a multiple of NT.
     ``signed`` picks the signed-digit window variant (default: the
-    STELLAR_TPU_SIGNED_WINDOWS env flag).  PRECONDITION for equivalence:
+    module's ``_SIGNED_WIN``).  PRECONDITION for equivalence:
     s and h < 2^253 — i.e. gate-canonical s (strict_input_ok_batch
     rejects s >= L, exactly libsodium's rule) and host-reduced h.  Every
     BatchVerifier path guarantees this; a RAW caller feeding an ungated

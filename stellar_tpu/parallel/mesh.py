@@ -75,7 +75,7 @@ def make_sharded_verifier(mesh=None, max_batch: int = 8192, **kw):
     output all-gather), keeping the 4x-faster kernel at multi-chip scale;
     on CPU meshes the XLA kernel (or interpreter-mode Pallas with
     backend="pallas") provides the same bit-exact semantics."""
-    from ..ops.ed25519 import BatchVerifier
+    from ..ops.verifier import BatchVerifier
 
     if mesh is None:
         mesh = make_mesh()

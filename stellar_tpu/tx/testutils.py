@@ -56,7 +56,7 @@ def get_test_config(instance: int = 0, backend: str = "cpu") -> Config:
     # per-entry re-reads, so an aliasing/copy-elision regression fails
     # loudly here first (ROADMAP "Correctness" policy).  Perf harnesses
     # that need round-comparable p50s re-pin sampled themselves
-    # (bench.py, profile_close.py).
+    # (profile_close.py).
     cfg.INVARIANT_SAMPLED = False
     return cfg
 

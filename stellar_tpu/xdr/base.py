@@ -837,11 +837,10 @@ def unpack_var_arrays(data: bytes, classes) -> Tuple[list, ...]:
     return tuple(out)
 
 
-# process-wide xdr_copy call counter: the copy plane is the ledger close's
-# dominant remaining host cost (PROFILE.md r7/r8), so bench.py surfaces
-# copies-per-tx on every close line and profile_close.py --copy-report
-# attributes them per call site.  A bare int += keeps the hot path cost
-# to nanoseconds; readers only ever difference two samples.
+# process-wide xdr_copy call counter: the copy plane is a large host cost of
+# the ledger close, and profile_close.py --copy-report attributes the
+# copies per call site.  A bare int += keeps the hot path cost to
+# nanoseconds; readers only ever difference two samples.
 _N_COPIES = 0
 
 

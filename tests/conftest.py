@@ -3,7 +3,7 @@
 Tests run on a virtual 8-device CPU mesh (the reference's multi-node story is
 in-process simulation over a shared clock, SURVEY.md §4; our multi-chip story
 is jax.sharding over a Mesh, validated here without TPU hardware).  The real
-TPU chip is exercised by ``chip_smoke.py`` and ``bench.py``, not by the
+TPU chip is exercised by ``chip_smoke.py`` and ``benchmarks/run.py``, not by the
 unit suite.
 
 JAX reads ``JAX_PLATFORMS`` itself; it is set here, before any test imports

@@ -103,7 +103,7 @@ class TestStrKey:
 class TestKeys:
     def test_sign_verify_roundtrip(self):
         """CryptoTests.cpp:276-326 'sign tests' (the 100k-iteration
-        benchmarking case CryptoTests.cpp:328 is bench.py's libsodium control leg)."""
+        benchmarking case CryptoTests.cpp:328 has no twin here)."""
         sk = SecretKey.pseudo_random_for_testing(1)
         msg = b"hello consensus"
         sig = sk.sign(msg)

@@ -23,6 +23,7 @@ import jax.numpy as jnp  # noqa: E402
 from stellar_tpu.crypto import SecretKey, sodium  # noqa: E402
 from stellar_tpu.ops import fe, ref25519 as ref  # noqa: E402
 from stellar_tpu.ops import ed25519 as ed  # noqa: E402
+from stellar_tpu.ops.verifier import BatchVerifier  # noqa: E402
 
 pytestmark = pytest.mark.tpu_kernel
 
@@ -164,7 +165,7 @@ class TestPointOps:
 class TestBatchVerifier:
     @pytest.fixture(scope="class")
     def bv(self):
-        return ed.BatchVerifier(max_batch=64, min_device_batch=16)
+        return BatchVerifier(max_batch=64, min_device_batch=16)
 
     def test_rfc8032_vectors(self, bv):
         """RFC 8032 §7.1 TEST 1-3."""
@@ -240,7 +241,7 @@ class TestBatchVerifier:
                 sig[rng.randrange(64)] ^= 1 << rng.randrange(8)
             items.append((sk.public_raw, msg, bytes(sig)))
         want = bv.verify(items)
-        ha = ed.BatchVerifier(
+        ha = BatchVerifier(
             max_batch=64, min_device_batch=16, host_assist=0.4
         )
         got = ha.verify(items)
@@ -488,7 +489,7 @@ class TestPipelineAbort:
         in the executor teardown (ed25519.py:399-427)."""
         import threading
 
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+        from stellar_tpu.ops.verifier import BatchVerifier
 
         bv = BatchVerifier(max_batch=16)  # small chunks -> many of them
         calls = []
@@ -563,7 +564,7 @@ class TestShardedVerifier:
         slow: shard_map × pallas-interpret compiles for minutes on CPU
         hosts — it would eat the tier-1 budget, so it runs only when slow
         tests are selected (real-TPU runs compile it with Mosaic quickly)."""
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+        from stellar_tpu.ops.verifier import BatchVerifier
         from stellar_tpu.ops.ed25519_pallas import NT
         from stellar_tpu.parallel.mesh import make_mesh
 
@@ -632,8 +633,8 @@ class TestShardedVerifier:
 
         devs = jax.devices()
         mesh = make_mesh(devs[:8])
-        sbv = ed.BatchVerifier(max_batch=64, mesh=mesh, min_device_batch=16)
-        ubv = ed.BatchVerifier(max_batch=64, min_device_batch=16)
+        sbv = BatchVerifier(max_batch=64, mesh=mesh, min_device_batch=16)
+        ubv = BatchVerifier(max_batch=64, min_device_batch=16)
         items, want = self._mixed_hostile_items(43, seed=11)
         got_s = sbv.verify(items)
         got_u = ubv.verify(items)
@@ -651,8 +652,8 @@ class TestShardedVerifier:
 
         devs = jax.devices()
         mesh = make_mesh(devs[:8])
-        sbv = ed.BatchVerifier(max_batch=64, mesh=mesh, min_device_batch=16)
-        ubv = ed.BatchVerifier(max_batch=64, min_device_batch=16)
+        sbv = BatchVerifier(max_batch=64, mesh=mesh, min_device_batch=16)
+        ubv = BatchVerifier(max_batch=64, min_device_batch=16)
         items, want = self._mixed_hostile_items(192, seed=23)
         # chunk 2 (items 64:128) becomes pure hostile-s: every lane fails
         # the host strict gate, so that chunk must never dispatch
@@ -682,7 +683,7 @@ class TestShardedVerifier:
         devs = jax.devices()
         assert len(devs) >= 3
         mesh = make_mesh(devs[:3])
-        bv = ed.BatchVerifier(max_batch=48, mesh=mesh, min_device_batch=3)
+        bv = BatchVerifier(max_batch=48, mesh=mesh, min_device_batch=3)
         assert bv.max_batch % 3 == 0
         items, want = self._mixed_hostile_items(40, seed=37)
         assert bv.verify(items) == want
@@ -713,7 +714,7 @@ class TestMultiStream:
         device-only dispatch mode — stream overlap is meaningless off
         the real transport, and the 1-stream BatchVerifier differentials
         keep the verify plane covered in tier-1."""
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+        from stellar_tpu.ops.verifier import BatchVerifier
 
         items = []
         for i in range(16 * 5):  # 5 chunks
@@ -733,14 +734,12 @@ class TestMultiStream:
         assert not out2[3] and not out2[40] and not out2[70]
         assert sum(out2) == len(items) - 3
 
-    def test_streams_env_default(self, monkeypatch):
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+    def test_streams_resolve_as_passed(self):
+        from stellar_tpu.ops.verifier import BatchVerifier
 
-        monkeypatch.setenv("STELLAR_TPU_VERIFY_STREAMS", "2")
-        assert BatchVerifier(max_batch=16).streams == 2
-        monkeypatch.delenv("STELLAR_TPU_VERIFY_STREAMS")
         assert BatchVerifier(max_batch=16).streams == 1
         assert BatchVerifier(max_batch=16, streams=3).streams == 3
+        assert BatchVerifier(max_batch=16, streams=0).streams == 1
 
     def test_streams_plumbs_through_sig_backend(self):
         from stellar_tpu.crypto.sigbackend import TpuSigBackend
@@ -759,7 +758,7 @@ class TestMultiStream:
 
         import numpy as np
 
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+        from stellar_tpu.ops.verifier import BatchVerifier
 
         bv = BatchVerifier(max_batch=16, streams=2)
         real_stage = bv._stage_chunk

@@ -65,7 +65,7 @@ def check_record(rec: dict, bucket: int) -> None:
 @pytest.fixture(scope="module")
 def plain():
     """A verifier of its own, tracer off: 16, then 16 again, then 32."""
-    from stellar_tpu.ops.ed25519 import BatchVerifier
+    from stellar_tpu.ops.verifier import BatchVerifier
 
     bv = BatchVerifier(max_batch=32, min_device_batch=16)
     seen = {"empty": bv.stats()["first_dispatch"]}
@@ -250,7 +250,7 @@ def test_a_compile_after_warm_up_is_named(plain):
     events = ops.compile_events
     trace, lower, compile_ = ops.STAGES
     loose = events.unattributed.stats()
-    events.charge(bv._recompiles, 32)
+    events.charge(bv._programs._recompiles, 32)
     try:
         # a jit inside a jit: the inner trace is reported first and lies
         # inside the outer one, which takes its seconds back

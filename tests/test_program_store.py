@@ -24,7 +24,7 @@ from stellar_tpu.crypto import SecretKey, sodium
 from stellar_tpu.ops import ed25519 as ed
 from stellar_tpu.ops import programs
 from stellar_tpu.ops import ref25519 as ref
-from stellar_tpu.ops.ed25519 import BatchVerifier
+from stellar_tpu.ops.verifier import BatchVerifier
 
 BUCKET = 16
 KINDS = ("stored", "exported", "traced")
@@ -115,8 +115,8 @@ def store(tmp_path, monkeypatch):
 def shared_kernel(ways, monkeypatch):
     """Every verifier made in the test runs the traced verifier's jit: the
     fallback costs no second trace and compile of the same program."""
-    kernel = ways["traced"]._kernel
-    monkeypatch.setattr(BatchVerifier, "_make_kernel", lambda self: kernel)
+    kernel = ways["traced"]._programs.kernel
+    monkeypatch.setattr(BatchVerifier, "_make_kernel", lambda self, batch_inv: kernel)
     return kernel
 
 
@@ -131,7 +131,7 @@ def test_miss_exports_and_stores_one_file(ways):
     assert rec["trace_s"] > 0 and rec["lower_s"] > 0
     (name,) = ways["files_after_export"]
     assert name.endswith(".jaxexport") and not name.startswith(".")
-    assert name[: -len(".jaxexport")] == programs.key(ways["exported"]._program_fields(BUCKET))
+    assert name[: -len(".jaxexport")] == programs.key(ways["exported"]._programs.fields(BUCKET))
 
 
 def test_hit_loads_and_never_runs_the_python_body(ways):
@@ -156,18 +156,18 @@ def test_no_store_means_the_traced_kernel(ways):
     rec = record(bv)
     assert rec["program"] == "traced" and rec["program_error"] == "FileNotFoundError"
     assert counts(bv) == {"stored": 0, "exported": 0, "traced": 1}
-    with bv._calls_lock:
-        assert bv._calls[BUCKET] is bv._kernel
+    with bv._programs._lock:
+        assert bv._programs._calls[BUCKET] is bv._programs.kernel
 
 
 def test_one_callable_a_bucket_kept_across_dispatches(ways):
     bv = ways["stored"]
-    with bv._calls_lock:
-        before = dict(bv._calls)
-    assert list(before) == [BUCKET] and before[BUCKET] is not bv._kernel
+    with bv._programs._lock:
+        before = dict(bv._programs._calls)
+    assert list(before) == [BUCKET] and before[BUCKET] is not bv._programs.kernel
     assert bv.verify(signed(40, salt=1)) == [True] * 40  # three chunks
-    with bv._calls_lock:
-        assert bv._calls == before
+    with bv._programs._lock:
+        assert bv._programs._calls == before
     fd = bv.stats()["first_dispatch"]
     assert fd["recompiles"]["events"] == 0 and list(fd["buckets"]) == [BUCKET]
 
@@ -287,7 +287,7 @@ def _source(name):
 
 
 def _devices(**changed):
-    return lambda mp, bv, tmp_path: mp.setattr(ed.jax, "devices", fake_devices(**changed))
+    return lambda mp, bv, tmp_path: mp.setattr(programs.jax, "devices", fake_devices(**changed))
 
 
 def _attr(obj_of, name, value):
@@ -297,7 +297,7 @@ def _attr(obj_of, name, value):
 def _mesh(shape, names):
     def change(mp, bv, tmp_path):
         devices = types.SimpleNamespace(shape=shape)
-        mp.setattr(bv, "mesh", types.SimpleNamespace(axis_names=names, devices=devices))
+        mp.setattr(bv._programs, "mesh", types.SimpleNamespace(axis_names=names, devices=devices))
 
     return change
 
@@ -316,12 +316,13 @@ XLA_CHANGES = {
     "platform": _devices(platform="tpu"),
     "device_kind": _devices(device_kind="TPU v5 lite"),
     "device_count": _devices(device_count=4),
-    "rows": _attr(lambda bv: bv, "_rows", 160),
-    "device_hash": _attr(lambda bv: bv, "device_hash", True),
-    "backend": _attr(lambda bv: bv, "backend", "other"),
-    "interpret": _attr(lambda bv: bv, "interpret", True),
+    "rows": _attr(lambda bv: bv._programs, "rows", 160),
+    "device_hash": _attr(lambda bv: bv._programs, "device_hash", True),
+    "backend": _attr(lambda bv: bv._programs, "backend", "other"),
+    "interpret": _attr(lambda bv: bv._programs, "interpret", True),
+    "batch_inv": lambda mp, bv, tmp_path: mp.setitem(bv._programs.lowering, "batch_inv", False),
     "x64": lambda mp, bv, tmp_path: mp.setattr(
-        ed.jax, "config", types.SimpleNamespace(jax_enable_x64=True)
+        programs.jax, "config", types.SimpleNamespace(jax_enable_x64=True)
     ),
     "mesh:none->2x2": _mesh((2, 2), ("batch", "model")),
 }
@@ -342,11 +343,11 @@ PALLAS_CHANGES = {
 @pytest.mark.parametrize("what", XLA_CHANGES)
 def test_key_changes_with_each_keyed_thing(ways, tmp_path, what):
     bv = ways["exported"]
-    old = bv._program_fields(BUCKET)
+    old = bv._programs.fields(BUCKET)
     with pytest.MonkeyPatch.context() as mp:
         XLA_CHANGES[what](mp, bv, tmp_path)
-        new = bv._program_fields(BUCKET)
-    assert bv._program_fields(BUCKET) == old  # the change is undone
+        new = bv._programs.fields(BUCKET)
+    assert bv._programs.fields(BUCKET) == old  # the change is undone
     assert new != old and programs.key(new) != programs.key(old)
     # and the program stored under the old key is not what the new one finds
     directory = str(ways["dir"])
@@ -357,37 +358,64 @@ def test_key_changes_with_each_keyed_thing(ways, tmp_path, what):
 @pytest.mark.parametrize("what", PALLAS_CHANGES)
 def test_key_changes_with_each_pallas_flag(monkeypatch, tmp_path, what):
     bv = verifier(backend="pallas")  # interpreted here; nothing is dispatched
-    assert bv.interpret and bv._granule == _pallas().NT
-    old = bv._program_fields(_pallas().NT)
+    bucket = _pallas().NT
+    assert bv.interpret and bv._granule == bucket
+    old = bv._programs.fields(bucket)
     assert (old["NT"], old["batch_inv"], old["signed_win"]) == (
         _pallas().NT, _pallas()._BATCH_INV, _pallas()._SIGNED_WIN,
     )
+    # a verifier hands the lowering's constants over once, when it is built
     PALLAS_CHANGES[what](monkeypatch, bv, tmp_path)
-    new = bv._program_fields(old["bucket"])
+    new = verifier(backend="pallas")._programs.fields(bucket)
     assert new != old and programs.key(new) != programs.key(old)
 
 
-def test_key_changes_with_bucket_and_mesh_shape_and_the_xla_batch_inv(monkeypatch):
+def test_key_changes_with_bucket_and_mesh_shape_and_the_xla_batch_inv():
+    import numpy as np
+    from jax.sharding import Mesh
+
     bv = verifier()
-    base = bv._program_fields(BUCKET)
+    base = bv._programs.fields(BUCKET)
     assert base["batch_inv"] is True and base["mesh"] is None
-    keys = {programs.key(base), programs.key(bv._program_fields(2 * BUCKET))}
+    keys = {programs.key(base), programs.key(bv._programs.fields(2 * BUCKET))}
+    chips = np.array(jax.devices()[:4])
     for shape, names in (((4,), ("batch",)), ((2, 2), ("batch", "model")), ((4,), ("lanes",))):
-        _mesh(shape, names)(monkeypatch, bv, None)
-        fields = bv._program_fields(BUCKET)
+        fields = verifier(mesh=Mesh(chips.reshape(shape), names))._programs.fields(BUCKET)
         # under a mesh the XLA path drops the lane-tree inversion
         assert fields["batch_inv"] is False and fields["mesh"] == [list(names), list(shape)]
         keys.add(programs.key(fields))
     assert len(keys) == 5
 
 
+@pytest.mark.parametrize(
+    "edited, rekeys",
+    [("verifier.py", False), ("programs.py", False), ("__init__.py", False), ("ed25519.py", True)],
+)
+def test_an_edit_to_the_host_pipeline_keeps_every_stored_program(ways, tmp_path, monkeypatch, edited, rekeys):
+    """A copy of ``ops/`` with one byte more in one file: only a file the
+    kernel's body is traced through changes a bucket's key."""
+    here = os.path.dirname(programs.__file__)
+    root = tmp_path / "ops"
+    root.mkdir()
+    for name in os.listdir(here):
+        if name.endswith(".py"):
+            data = open(os.path.join(here, name), "rb").read()
+            (root / name).write_bytes(data + b"#" if name == edited else data)
+    bv = ways["exported"]
+    old = programs.key(bv._programs.fields(BUCKET))
+    altered = programs.source_digests(str(root))
+    monkeypatch.setattr(programs, "source_digests", lambda: altered)
+    assert (programs.key(bv._programs.fields(BUCKET)) != old) == rekeys
+    assert (edited in programs.SOURCE_FILES) == rekeys
+
+
 def test_key_names_no_path_host_or_stack(ways):
-    fields = ways["exported"]._program_fields(BUCKET)
+    fields = ways["exported"]._programs.fields(BUCKET)
     flat = repr(fields)
     assert os.path.dirname(programs.__file__) not in flat and os.getcwd() not in flat
     # the same fields from another thread and call depth: the same name
     got = []
-    t = threading.Thread(target=lambda: got.append((lambda: ways["stored"]._program_fields(BUCKET))()))
+    t = threading.Thread(target=lambda: got.append((lambda: ways["stored"]._programs.fields(BUCKET))()))
     t.start()
     t.join(30)
     assert got == [fields]
@@ -420,7 +448,7 @@ def _stored_blob(ways) -> bytes:
 
 def _plant(data):
     def arrange(mp, ways, directory, bv):
-        path = programs.path_of(str(directory), bv._program_fields(BUCKET))
+        path = programs.path_of(str(directory), bv._programs.fields(BUCKET))
         with open(path, "wb") as f:
             f.write(data(_stored_blob(ways)))
 
@@ -444,7 +472,7 @@ def _deserialize_raises(mp, ways, directory, bv):
 def _read_only(mp, ways, directory, bv):
     # (the tests run as root, whom no mode bits stop)
     real = os.access
-    mp.setattr(ed.os, "access", lambda p, mode: False if p == str(directory) else real(p, mode))
+    mp.setattr(programs.os, "access", lambda p, mode: False if p == str(directory) else real(p, mode))
 
 
 def _disk_full(mp, ways, directory, bv):
@@ -463,7 +491,7 @@ def _refuses_the_platform(mp, ways, directory, bv):
     alien = jax.export.export(jax.jit(lambda p: p[0] == p[32]), platforms=["tpu"])(
         jax.ShapeDtypeStruct((128, BUCKET), jnp.uint8)
     )
-    programs.save(programs.path_of(str(directory), bv._program_fields(BUCKET)), alien)
+    programs.save(programs.path_of(str(directory), bv._programs.fields(BUCKET)), alien)
 
 
 FAULTS = {
@@ -495,8 +523,8 @@ def test_a_fault_falls_back_counts_and_leaves_no_bad_file(ways, store, shared_ke
     assert rec["program_error"] == error or (error is None and rec["program_error"])
     assert counts(bv) == {"stored": 0, "exported": 0, "traced": 1}
     assert files(store) == []  # neither the bad file nor a temporary
-    with bv._calls_lock:
-        assert bv._calls[BUCKET] is bv._kernel
+    with bv._programs._lock:
+        assert bv._programs._calls[BUCKET] is bv._programs.kernel
     # nothing is tried again for the bucket: a good file appearing later
     # (another process stored it) is not looked at by this one
     (store / ways["files_after_export"][0]).write_bytes(_stored_blob(ways))
@@ -540,8 +568,8 @@ def test_two_threads_at_one_cold_bucket_leave_one_whole_file(ways, store, shared
     assert programs.load(str(store / name)) is not None  # and it is whole
     # one record, one callable: the loser's account is dropped
     assert counts(bv) == {"stored": 0, "exported": 1, "traced": 0}
-    with bv._calls_lock:
-        assert list(bv._calls) == [BUCKET]
+    with bv._programs._lock:
+        assert list(bv._programs._calls) == [BUCKET]
     # and a third verifier loads what the two left
     third = verifier()
     assert third.verify(jobs[0]) == out[0] and record(third)["program"] == "stored"
@@ -581,7 +609,7 @@ def test_an_export_that_reports_no_stage_is_timed_by_the_call(ways, store, share
 
         return lower
 
-    monkeypatch.setattr(ed.jax.export, "export", silent_export)
+    monkeypatch.setattr(programs.export, "export", silent_export)
     bv = verifier()
     assert bv.verify(ways["items"]) == ways["want"]
     rec = record(bv)
@@ -601,19 +629,19 @@ def test_the_span_and_the_log_line_carry_the_program(store, shared_kernel):
         def emit(self, rec):
             lines.append(rec.getMessage())
 
-    keep, level = Keep(), ed._log.level
-    ed._log.addHandler(keep)
-    ed._log.setLevel(logging.INFO)
+    keep, level = Keep(), programs._log.level
+    programs._log.addHandler(keep)
+    programs._log.setLevel(logging.INFO)
     tracer = Tracer()
     bv = verifier(tracer=tracer)
     try:
         assert bv.verify(signed(5, salt=8)) == [True] * 5
     finally:
-        ed._log.setLevel(level)
-        ed._log.removeHandler(keep)
+        programs._log.setLevel(level)
+        programs._log.removeHandler(keep)
     (first,) = [s for s in tracer.spans() if s.name == "ed25519.device_dispatch"]
     assert first.attrs["first"] is True and first.attrs["program"] == "exported"
-    assert "program" in ed._FIRST_SPAN_ATTRS
+    assert "program" in programs._FIRST_SPAN_ATTRS
     lines = [m for m in lines if "first dispatch" in m]
     assert len(lines) == 1 and "program exported" in lines[0]
 

@@ -387,9 +387,10 @@ def test_byte_cap_sheds_flood_and_bounds_high_water():
         for i in range(120):
             peer.send_message(flood_msg(a, i))
         assert sq.queued_bytes <= cap
-        assert sq.bytes_high_water <= cap
+        assert 0 < sq.bytes_high_water <= cap
         assert sq.shed_msgs[CLASS_FLOOD] > 0
         assert sq.shed_bytes[CLASS_FLOOD] > 0
+        assert sq.shed_msgs[CLASS_CRITICAL] == 0
         assert a.overlay_manager.sendq_stats.bytes_high_water <= cap
     finally:
         a.graceful_stop()

@@ -30,7 +30,8 @@ import jax.numpy as jnp  # noqa: E402
 from stellar_tpu.crypto import SecretKey, sodium  # noqa: E402
 from stellar_tpu.ops import ref25519 as ref  # noqa: E402
 from stellar_tpu.ops import sha512 as dsha  # noqa: E402
-from stellar_tpu.ops.ed25519 import BatchVerifier, L  # noqa: E402
+from stellar_tpu.ops.ref25519 import L  # noqa: E402
+from stellar_tpu.ops.verifier import BatchVerifier  # noqa: E402
 
 pytestmark = pytest.mark.tpu_kernel
 
@@ -153,7 +154,7 @@ class TestDeviceSha512:
                 assert mod._reduce512(v.to_bytes(64, "little")) == want
 
     def test_native_stage_raw_vs_python_fallback(self):
-        """The C stage_raw buffer is byte-identical to _stage_py_raw on
+        """The C stage_raw buffer is byte-identical to _stage_py's on
         valid, hostile, malformed-length and residual lanes (toolchain-less
         hosts run the Python twin, so the layouts must agree exactly)."""
         from stellar_tpu import native
@@ -163,15 +164,16 @@ class TestDeviceSha512:
             pytest.skip("native sighash not built")
         items = _valid_items(24) + _hostile_items()
         n = len(items)
-        from stellar_tpu.ops.ed25519 import _BLACKLIST
+        from stellar_tpu.ops.verifier import _BLACKLIST
 
         c_out = np.zeros((dsha.DH_ROWS, n + 3), dtype=np.uint8)
         c_ok = np.zeros(n, dtype=np.uint8)
         rej_c = mod.stage_raw(items, 0, n, c_out, c_ok, _BLACKLIST)
         bv = BatchVerifier.__new__(BatchVerifier)
+        bv.device_hash = True
         py_out = np.ones((dsha.DH_ROWS, n + 3), dtype=np.uint8)
         py_ok = np.zeros(n, dtype=np.uint8)
-        rej_py = bv._stage_py_raw(items, 0, n, py_out, py_ok)
+        rej_py = bv._stage_py(items, 0, n, py_out, py_ok)
         assert rej_c == rej_py
         assert (c_ok == py_ok).all()
         assert (c_out == py_out).all()
@@ -223,7 +225,7 @@ class TestDeviceHashVerifier:
             device_hash=True,
             native_hash=False,
         )
-        py._kernel = dev._kernel
+        py._programs.kernel = dev._programs.kernel
         items = _valid_items(20, seed=93000) + _hostile_items()
         want = [
             sodium.verify_detached(sig, msg, pk) for pk, msg, sig in items
@@ -374,7 +376,7 @@ class TestTorsionDevicePlane:
             device_hash=True,
         )
         # share the already-compiled kernel + bucket shape (budget policy)
-        be.inner._verifier._kernel = bv._kernel
+        be.inner._verifier._programs.kernel = bv._programs.kernel
         be.inner._verifier.min_device_batch = 64
         items, slots = [], []
         for i in range(12):
@@ -439,16 +441,16 @@ class TestConfigAndWiring:
         be_off = make_backend("tpu", cache=VerifySigCache(), max_batch=64)
         assert be_off.inner._verifier.device_hash is False
 
-    def test_env_knob_default(self, monkeypatch):
-        # knob resolution only — the kernel build is stubbed out so no
-        # compile shape is added
-        monkeypatch.setattr(BatchVerifier, "_make_kernel", lambda self: None)
-        monkeypatch.setenv("STELLAR_TPU_DEVICE_HASH", "1")
-        bv = BatchVerifier(max_batch=64)
+    def test_device_hash_resolves_as_passed(self, monkeypatch):
+        # resolution only — the kernel build is stubbed out so no compile
+        # shape is added
+        monkeypatch.setattr(BatchVerifier, "_make_kernel", lambda self, batch_inv: None)
+        bv = BatchVerifier(max_batch=64, device_hash=True)
         assert bv.device_hash is True and bv._rows == dsha.DH_ROWS
-        monkeypatch.delenv("STELLAR_TPU_DEVICE_HASH")
+        assert bv.stats()["device_hash"] is True and bv._programs.fields(64)["rows"] == dsha.DH_ROWS
         bv = BatchVerifier(max_batch=64)
         assert bv.device_hash is False and bv._rows == 128
+        assert bv.stats()["device_hash"] is False and bv._programs.fields(64)["rows"] == 128
 
 
 @pytest.mark.slow

@@ -143,7 +143,7 @@ class TestBackendWiring:
         # non-pow2 mesh widths: every bucket must stay a whole multiple
         # of the device count (the per-shard staging buffers are fixed
         # equal slices) — no kernel dispatch, pure bucketing arithmetic
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+        from stellar_tpu.ops.verifier import BatchVerifier
 
         for width in (2, 3, 5, 8):
             bv = BatchVerifier(

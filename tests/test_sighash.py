@@ -263,7 +263,7 @@ class TestPipelineOverlap:
         runs while chunk k's device result is still in flight — i.e.
         BEFORE the main thread has drained it.  A serial implementation
         (stage, dispatch, drain, stage, ...) fails this ordering."""
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+        from stellar_tpu.ops.verifier import BatchVerifier
 
         bv = BatchVerifier(max_batch=64, streams=1)
         assert bv._sighash is not None
@@ -326,7 +326,7 @@ class TestVerifierPaths:
         """BatchVerifier(native_hash=True/False) must return identical
         verdicts over a mixed valid/corrupt/hostile batch (the bench
         host-stage A/B's correctness precondition)."""
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+        from stellar_tpu.ops.verifier import BatchVerifier
 
         rng = random.Random(23)
         items = []
@@ -351,7 +351,7 @@ class TestVerifierPaths:
         pyv = BatchVerifier(max_batch=64, min_device_batch=16,
                             native_hash=False)
         assert nat._sighash is not None and pyv._sighash is None
-        pyv._kernel = nat._kernel  # share the compiled kernel
+        pyv._programs.kernel = nat._programs.kernel  # share the compiled kernel
         got_nat = nat.verify(items)
         got_py = pyv.verify(items)
         assert got_nat == got_py
@@ -361,16 +361,16 @@ class TestVerifierPaths:
         want = [sodium.verify_detached(s, m, p) for p, m, s in items]
         assert got_nat == want
 
-    def test_native_env_knob(self, monkeypatch):
-        from stellar_tpu.ops.ed25519 import BatchVerifier
+    def test_native_hash_resolves_as_passed(self):
+        from stellar_tpu.ops.verifier import BatchVerifier
 
-        monkeypatch.setenv("STELLAR_TPU_NATIVE_SIGHASH", "0")
-        assert BatchVerifier(max_batch=16)._sighash is None
-        monkeypatch.delenv("STELLAR_TPU_NATIVE_SIGHASH")
-        assert BatchVerifier(max_batch=16)._sighash is not None
+        off = BatchVerifier(max_batch=16, native_hash=False)
+        assert off._sighash is None and off.stats()["native_host_stage"] is False
+        on = BatchVerifier(max_batch=16)
+        assert on._sighash is not None and on.stats()["native_host_stage"] is True
 
     def test_staging_pool_reuses_buffers(self):
-        from stellar_tpu.ops.ed25519 import _StagingPool
+        from stellar_tpu.ops.verifier import _StagingPool
 
         pool = _StagingPool()
         bufs = pool.acquire(64)

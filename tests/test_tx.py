@@ -794,7 +794,8 @@ def test_first_dispatch_of_each_bucket_gets_the_compile_budget():
     import time as _time
 
     from stellar_tpu.crypto.sigbackend import CALLER_CLOSE, TpuSigBackend
-    from stellar_tpu.ops.ed25519 import BatchVerifier
+    from stellar_tpu.ops.programs import BucketPrograms
+    from stellar_tpu.ops.verifier import BatchVerifier
 
     be = TpuSigBackend.__new__(TpuSigBackend)  # skip JAX verifier init
     be.cpu_cutover = 0
@@ -816,19 +817,18 @@ def test_first_dispatch_of_each_bucket_gets_the_compile_budget():
             self.min_device_batch = 16
             self._granule = 1
             self.host_assist = 0.0
-            self._calls_lock = threading.Lock()
-            self._warm_buckets = set()
+            self._programs = BucketPrograms(
+                None, rows=128, backend="xla", interpret=False, device_hash=False, lowering={}
+            )
             self.dispatched = []
 
         def verify(self, items):
             for _, count in self._chunks(len(items)):
                 bucket = self._bucket(count)
-                with self._calls_lock:
-                    cold = bucket not in self._warm_buckets
-                if cold:
+                if self._programs.cold({bucket}):
                     _time.sleep(0.6)
-                with self._calls_lock:
-                    self._warm_buckets.add(bucket)
+                with self._programs._lock:
+                    self._programs._warm_buckets.add(bucket)
                 self.dispatched.append(bucket)
             return [True] * len(items)
 

@@ -267,10 +267,3 @@ class TestConfigStreamsKnob:
         with pytest.raises(ValueError, match="SIG_VERIFY_STREAMS"):
             cfg.validate()
 
-    def test_sig_verify_streams_env_default(self, monkeypatch):
-        from stellar_tpu.main.config import Config
-
-        monkeypatch.setenv("STELLAR_TPU_VERIFY_STREAMS", "2")
-        assert Config().SIG_VERIFY_STREAMS == 2
-        monkeypatch.delenv("STELLAR_TPU_VERIFY_STREAMS")
-        assert Config().SIG_VERIFY_STREAMS == 1
