@@ -96,8 +96,11 @@ class BatchVerifier:
     measured 4× the XLA lowering on v5e in round 3) on a real
     accelerator and the plain XLA kernel on CPU.  With a mesh, the Pallas
     kernel runs PER SHARD under shard_map (each chip grids its local
-    slice of the batch; no cross-shard communication — XLA inserts only
-    the output all-gather), so multi-chip keeps the fast kernel."""
+    slice of the batch), so multi-chip keeps the fast kernel.  The
+    compiled program holds no collective at all: the verdicts stay
+    sharded (``out_shardings``) and the drain reads each chip's piece
+    (the 2x2 v5e host's trace, PR 45: four planes, the same two kernel
+    programs on each, nothing else)."""
 
     def __init__(
         self,
@@ -224,6 +227,11 @@ class BatchVerifier:
         self.n_gate_rejects = 0
         self.n_host_assist_items = 0
         self.n_torsion_items = 0
+        # under a mesh, of the chunks dispatched: shard buffers that held no
+        # live lane, and the live lanes each device was handed, in the
+        # mesh's order: stats()["mesh"]
+        self.n_dead_shards = 0
+        self._device_lanes = [0] * n_shards if mesh is not None else []
         # the counters above are bumped from every stager thread; += alone
         # drops increments under streams>1 and they feed profiling
         # conclusions
@@ -448,7 +456,8 @@ class BatchVerifier:
         device -> host copy, the gate mask and the list.  The wait first
         queues the copy behind the kernel, as ``np.asarray`` on a pending
         result does: waiting and only then copying costs a host round trip
-        a chunk (~120 us, my chip run, PR 24)."""
+        a chunk (~120 us, my chip run, PR 24).  Under a mesh ``fut`` is
+        sharded: one copy a chip, joined on the host by ``np.asarray``."""
         with self._tracer.span("ed25519.wait"):
             jax.copy_to_host_async(fut)
             jax.block_until_ready(fut)
@@ -689,9 +698,10 @@ class BatchVerifier:
         return n - int(ok.sum())
 
     def _dispatch_staged(self, staged: Optional[_Staged]):
-        """Upload the packed staging buffer (ONE transfer) and launch the
-        kernel.  Runs on the stager thread in the multi-chunk pipeline,
-        on the caller's thread for single-chunk batches.  Returns the
+        """Upload the packed staging buffer (ONE transfer; one a shard under
+        a mesh: the ``ed25519.upload`` span) and launch the kernel.  Runs
+        on the stager thread in the multi-chunk pipeline, on the caller's
+        thread for single-chunk batches.  Returns the
         in-flight device result, or None when every lane was
         gate-rejected (hostile floods never reach the chip).
 
@@ -701,30 +711,43 @@ class BatchVerifier:
         if staged is None or not staged.ok.any():
             return None
         dsp = self._tracer.begin("ed25519.device_dispatch")
-        if self.mesh is not None:
-            bucket = sum(buf.shape[1] for buf in staged.packed)
-        else:
-            bucket = staged.packed.shape[1]
+        shards = staged.packed if self.mesh is not None else [staged.packed]
+        shard_bucket = shards[0].shape[1]
+        bucket = shard_bucket * len(shards)
         with self._programs.dispatch(bucket) as (call, first):
-            if self.mesh is not None:
-                arr = self._upload_sharded(staged.packed)
-            else:
-                arr = jnp.asarray(staged.packed)
+            # the host->device copy, apart from the program's call
+            with self._tracer.span("ed25519.upload"):
+                if self.mesh is not None:
+                    arr = self._upload_sharded(shards)
+                else:
+                    arr = jnp.asarray(staged.packed)
             # returns once the program is compiled and the execution enqueued
             ok = call(arr)
-        self._tracer.end(dsp, bucket=bucket, backend=self.backend, **first)
+        self._tracer.end(
+            dsp,
+            bucket=bucket,
+            backend=self.backend,
+            shards=len(shards),
+            upload_bytes=self._rows * bucket,
+            **first,
+        )
         with self._calls_lock:
             self.n_device_calls += 1
             self.n_lanes += bucket
+            if self.mesh is not None:
+                for i in range(len(shards)):
+                    live = min(shard_bucket, max(0, staged.n - i * shard_bucket))
+                    self._device_lanes[i] += live
+                    self.n_dead_shards += int(live == 0)
         return ok
 
     def _upload_sharded(self, shards):
         """One host->device transfer PER SHARD: each chip's C-contiguous
         staging buffer goes straight to that chip, and the global chunk
         array is assembled from the single-device pieces under the exact
-        sharding the jitted kernel expects — XLA inserts no reshard, so
-        the only collective in the whole round-trip is the (N,) bool
-        output all-gather the drain joins."""
+        sharding the jitted kernel expects — XLA inserts no reshard, and
+        the round-trip has no collective: the (N,) verdicts come back
+        sharded and ``_read_back`` copies one piece a chip to the host."""
         devices = list(self.mesh.devices.flat)
         singles = [
             jax.device_put(buf, dev) for buf, dev in zip(shards, devices)
@@ -767,7 +790,15 @@ class BatchVerifier:
             # 0 = unsharded single-queue dispatch; >0 = chips on the
             # batch-axis mesh (Config.SIG_MESH; bench close lines carry
             # this as sig_mesh_devices so every JSON records the mode)
-            "mesh_devices": (
-                len(self.mesh.devices.flat) if self.mesh is not None else 0
-            ),
+            "mesh_devices": len(self._device_lanes),
+            # what the mesh's chips were handed (all 0 / empty unsharded):
+            # host->device copies, one a shard a chunk; shard buffers with
+            # no live lane (a short tail chunk's: inert padding the chip
+            # still computes); live lanes by device, in the mesh's order
+            "mesh": {
+                "devices": len(self._device_lanes),
+                "shard_uploads": self.n_device_calls * len(self._device_lanes),
+                "dead_shards": self.n_dead_shards,
+                "lanes_per_device": list(self._device_lanes),
+            },
         }

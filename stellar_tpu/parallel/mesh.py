@@ -4,8 +4,8 @@ SURVEY.md §2.14/§5.8: the reference's only intra-validator parallelism is a
 worker thread pool; the TPU-native axis is *batch data parallelism* of the
 signature-verify plane.  A verify batch is embarrassingly parallel over items,
 so the sharding story is one mesh axis ("batch"): inputs sharded over chips,
-no collectives needed in the kernel itself (XLA inserts the final all-gather
-of the (N,) bool output).
+no collective anywhere — the (N,) verdicts stay sharded and the host reads
+one piece a chip (the four-chip v5e host's trace, PR 45: PERF.md §5).
 
 The byzantine inter-validator plane stays on the overlay's TCP sockets —
 ICI/DCN collectives cannot replace signed flooding (SURVEY.md §5.8); this
@@ -71,8 +71,8 @@ def make_sharded_verifier(mesh=None, max_batch: int = 8192, **kw):
     """BatchVerifier whose kernel is sharded over the mesh's batch axis.
 
     On real TPU the Pallas kernel runs PER SHARD under jax.shard_map
-    (each chip grids its local batch slice; the only collective is XLA's
-    output all-gather), keeping the 4x-faster kernel at multi-chip scale;
+    (each chip grids its local batch slice; the program holds no
+    collective), keeping the 4x-faster kernel at multi-chip scale;
     on CPU meshes the XLA kernel (or interpreter-mode Pallas with
     backend="pallas") provides the same bit-exact semantics."""
     from ..ops.verifier import BatchVerifier

@@ -297,16 +297,20 @@ class TestBatchVerifier:
         first = [s for s in spans if s.req == flushes[0].req and s is not flushes[0]]
         names = sorted(s.name for s in first)
         assert names == sorted(
-            2 * ["ed25519.host_hash", "ed25519.device_dispatch", "ed25519.drain",
-                 "ed25519.wait", "ed25519.readback"]
+            2 * ["ed25519.host_hash", "ed25519.device_dispatch", "ed25519.upload",
+                 "ed25519.drain", "ed25519.wait", "ed25519.readback"]
         )
-        # at most 1 + 2 * chunks new spans a flush
-        assert len([s for s in first if s.name in ("ed25519.wait", "ed25519.readback")]) + 1 == 5
+        # at most 1 + 3 * chunks new spans a flush
+        children = ("ed25519.upload", "ed25519.wait", "ed25519.readback")
+        assert len([s for s in first if s.name in children]) + 1 == 7
         for s in first:
-            if s.name in ("ed25519.wait", "ed25519.readback"):
-                drain = by[s.parent]
-                assert drain.name == "ed25519.drain"
-                assert drain.start <= s.start and s.end <= drain.end
+            if s.name in children:
+                # the upload parts its dispatch, wait and read-back their drain
+                over = by[s.parent]
+                assert over.name == (
+                    "ed25519.device_dispatch" if s.name == "ed25519.upload" else "ed25519.drain"
+                )
+                assert over.start <= s.start and s.end <= over.end
             else:
                 # across the worker hop (drain) and the stager pool
                 # (host_hash, device_dispatch): threads other than the caller's

@@ -219,8 +219,9 @@ def test_first_dispatch_span_carries_the_account(backend):
         assert ("compile_time_saved_s" in s.attrs) == (rec["cache"] == "hit")
         # the record lies inside its span, on the tracer's clock
         assert s.start <= rec["start"] < rec["end"] <= s.end
-    # a later dispatch of a warm bucket carries the two it always had
-    assert got[2].attrs == {"bucket": 32, "backend": "xla"}
+    # a later dispatch of a warm bucket carries the four it always has: the
+    # bucket, the lowering, and (PR 45) what was uploaded, in how many pieces
+    assert got[2].attrs == {"bucket": 32, "backend": "xla", "shards": 1, "upload_bytes": 128 * 32}
 
 
 def test_device_flush_is_marked_cold_only_when_it_was(backend):
