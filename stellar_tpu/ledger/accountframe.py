@@ -60,9 +60,12 @@ class AccountFrame(EntryFrame):
         super().__init__(entry)
 
     def _compute_key(self) -> LedgerKey:
-        return LedgerKey(
-            LedgerEntryType.ACCOUNT, LedgerKeyAccount(self.account.accountID)
-        )
+        aid = self.account.accountID
+        key = LedgerKey(LedgerEntryType.ACCOUNT, LedgerKeyAccount(aid))
+        # what key_bytes would pack, and what load_account keyed the cache
+        # with: a store hands these to the delta, the cache and the buffer
+        key._kb = _ACCT_KEY_PREFIX + aid.value
+        return key
 
     def _rebind_entry(self) -> None:
         self.account = self.entry.data.value
@@ -463,18 +466,6 @@ class AccountFrame(EntryFrame):
         s = entry.data.value.signers
         if len(s) > 1:
             s.sort(key=lambda sg: sg.pubKey.value)
-
-    def store_add(self, delta, db) -> None:
-        # guard BEFORE _normalize: its in-place signer sort would mutate a
-        # readonly frame's cache-shared entry, then raise — too late
-        self._assert_mutable()
-        self._normalize()
-        super().store_add(delta, db)
-
-    def store_change(self, delta, db) -> None:
-        self._assert_mutable()
-        self._normalize()
-        super().store_change(delta, db)
 
     @staticmethod
     def _sql_row(a, lastmod: int):

@@ -63,8 +63,11 @@ class LedgerDelta:
         the nested copy snapshots whatever the outer header holds at the
         nested delta's FIRST header access, not at construction.  No
         current call path interleaves outer/nested header mutation (ops
-        mutate only their own innermost delta's header); keep it that way
-        or make the copy eager again."""
+        mutate only their own innermost delta's header; the close's fee
+        pass, ``LedgerManager._process_fees_seq_nums``, raises ``feePool``
+        on the close's own delta, where it nests nothing and runs before
+        the apply loop opens its first nested delta); keep it that way or
+        make the copy eager again."""
         if self._header_local is None:
             self._header_local = _copy_header(self._previous_header)
         return self._header_local
@@ -257,18 +260,33 @@ def _copy_entry(e: LedgerEntry) -> LedgerEntry:
     return xdr_copy(e)  # codec-driven; no serialization round-trip
 
 
+_header_copies = 0
+
+
+def header_copies() -> int:
+    """Headers copied by any delta of this process so far; read it twice
+    and subtract."""
+    return _header_copies
+
+
 def _copy_header(h):
     """Field-sharing copy, made lazily on first mutable `header` access —
     a payment tx's nested APPLY deltas never touch the header, so those
-    pay zero copies, and the one remaining copy/tx (fee charging's
-    ``feePool +=``) shares every subobject instead of walking the codec:
+    pay zero copies, and the one remaining copy a close (the fee pass
+    raises ``feePool`` once, by the set's sum, on the close's own delta)
+    shares every subobject instead of walking the codec:
     scalars rebind, the hash fields are immutable bytes, and ``scpValue``
     is only ever whole-object ASSIGNED through a header (the herder
     composes values on its own objects; ledger/manager.py:322 assigns),
     so sharing it is safe — keep it that way.  Only the ``skipList``
     shell is copied, because bucket/manager.py writes its slots in
     place at close.  Measured ~1.9x faster than the C xdr_copy (which
-    must rebuild scpValue.upgrades and the list containers)."""
+    must rebuild scpValue.upgrades and the list containers).
+
+    Every copy is counted (``header_copies``): the fee pass reports the
+    copies made across its loop, so a header copied a transaction shows."""
+    global _header_copies
+    _header_copies += 1
     return LedgerHeader(
         h.ledgerVersion,
         h.previousLedgerHash,

@@ -80,13 +80,17 @@ class EntryCache:
 
     def put_owned(self, key: bytes, entry: Optional[LedgerEntry]):
         """Store without copying — the caller relinquishes ownership and
-        must not mutate `entry` afterwards."""
-        _retire(self._map.get(key))
-        self._map[key] = entry
-        self._map.move_to_end(key)
-        while len(self._map) > self.CAPACITY:
-            _retire(self._map.popitem(last=False)[1])
+        must not mutate `entry` afterwards.  -> what ``stored`` said of the
+        line before: the SHARED entry this one replaces, or None."""
+        m = self._map
+        prev = m.get(key)
+        _retire(prev)
+        m[key] = entry
+        m.move_to_end(key)
+        while len(m) > self.CAPACITY:
+            _retire(m.popitem(last=False)[1])
             self.evictions += 1
+        return prev
 
     def contains(self, key: bytes) -> bool:
         """Membership probe without touching hit/miss counters or LRU
@@ -95,8 +99,9 @@ class EntryCache:
 
     def stored(self, key: bytes) -> Optional[LedgerEntry]:
         """The line's SHARED entry, or None where there is no line or a
-        known-absent one — counters and LRU order untouched.  A store
-        asks this for the snapshot it is about to replace."""
+        known-absent one — counters and LRU order untouched.  A
+        write-through store asks this for the snapshot it is about to
+        replace (``_persist``); ``put_owned`` returns the same."""
         return self._map.get(key)
 
     def erase(self, key: bytes):
@@ -272,19 +277,26 @@ class EntryFrame:
                 " close is over); reload the entry to mutate"
             )
 
-    def store_add(self, delta, db) -> None:
-        self._assert_mutable()
-        self._stamp(delta)
-        if active_buffer(db) is None:
-            self._persist(db, insert=True)
-        self._record(delta, db, created=True)
+    def store_add(self, delta, db) -> LedgerEntry:
+        return self._store(delta, db, created=True)
 
-    def store_change(self, delta, db) -> None:
+    def store_change(self, delta, db) -> LedgerEntry:
+        return self._store(delta, db, created=False)
+
+    def _store(self, delta, db, *, created: bool) -> LedgerEntry:
+        """The one body of a store: guard, canonical form, stamp, the SQL
+        write where no buffer takes it, the record -> the snapshot the
+        store left in the delta, the entry cache and the store buffer
+        (``_record``): immutable from here on."""
+        # the guard BEFORE _normalize: an in-place sort would mutate a
+        # readonly frame's cache-shared entry, then raise — too late
         self._assert_mutable()
+        self._normalize()
         self._stamp(delta)
-        if active_buffer(db) is None:
-            self._persist(db, insert=False)
-        self._record(delta, db, created=False)
+        buf = active_buffer(db)
+        if buf is None:
+            self._persist(db, insert=created)
+        return self._record(delta, db, buf, created=created)
 
     def _persist(self, db, insert: bool) -> None:
         raise NotImplementedError
@@ -329,22 +341,35 @@ class EntryFrame:
         writes (``Bucket.apply``'s batches store without a frame).  Only
         an account has one: its signers' order (AccountFrame)."""
 
+    def _normalize(self) -> None:
+        """Put the frame's entry into the form every store writes, before
+        it is stamped and recorded (``canonicalize`` for an entry that a
+        frame holds).  Only an account has one (AccountFrame)."""
+
     def _stamp(self, delta) -> None:
         if delta.update_last_modified:
             self.last_modified = delta.header_ro().ledgerSeq
 
-    def _record(self, delta, db, *, created: bool) -> None:
+    def _record(self, delta, db, buf, *, created: bool) -> LedgerEntry:
         """After a (possibly buffered) write: record the entry in the delta,
-        the entry cache, and the active store buffer with ONE shared
-        immutable snapshot (all sides only read).
+        the entry cache, and ``buf`` — the active store buffer, None where
+        the write went through — with ONE shared immutable snapshot (all
+        sides only read) -> that snapshot.
 
         With seal-on-store (COW_ENTRY_SNAPSHOTS, default) that snapshot IS
         the frame's live entry: the frame seals itself and the copy is
         deferred to the next mutating access (touch()), which never comes
         for entries stored once per close.  CoW-off restores the eager
         per-store deep copy (the differential suite runs both modes and
-        compares hashes, SQL dumps, and history metas bit-exactly)."""
+        compares hashes, SQL dumps, and history metas bit-exactly).
+
+        The key's bytes are packed at most once a key object (``key_bytes``
+        memoizes them on it, and an account's key is born with the bytes
+        its load keyed the cache with: ``AccountFrame._compute_key``), so
+        the delta, the cache and the buffer share them; the cache gives
+        back the line it replaces as it takes the new one."""
         key = self.get_key()
+        kb = key_bytes(key)
         if getattr(db, "_cow_entry_snapshots", True):
             snap = self.entry
             self._sealed = True
@@ -355,14 +380,10 @@ class EntryFrame:
             delta.add_entry_snapshot(key, snap)
         else:
             delta.mod_entry_snapshot(key, snap)
-        kb = key_bytes(key)
-        cache = entry_cache_of(db)
-        # the line still holds the snapshot stored before this one: the
+        # the line still held the snapshot stored before this one: the
         # one place every store passes that can say what SQL (or the
         # overlay slot) has of this entry's signers
-        prev = cache.stored(kb)
-        cache.put_owned(kb, snap)
-        buf = active_buffer(db)
+        prev = entry_cache_of(db).put_owned(kb, snap)
         if buf is not None:
             buf.record(kb, key, snap, type(self), self.signers_differ(prev, snap))
         if self.entry_type == LedgerEntryType.ACCOUNT:
@@ -373,6 +394,7 @@ class EntryFrame:
             ctx = active_frame_context(db)
             if ctx is not None:
                 ctx.record_store(kb, self)
+        return snap
 
     @staticmethod
     def cache_of(db) -> EntryCache:

@@ -25,7 +25,7 @@ from ..xdr.ledger import TransactionMeta
 from ..database.database import UnrollbackableWrite
 from ..trace import NULL_TRACER
 from .accountframe import AccountFrame
-from .delta import LedgerDelta
+from .delta import LedgerDelta, header_copies
 from .headerframe import LedgerHeaderFrame
 
 log = xlog.logger("Ledger")
@@ -595,29 +595,50 @@ class LedgerManager:
         self.app.bucket_manager.forget_unreferenced_buckets()
 
     def _process_fees_seq_nums(self, txs, delta) -> None:
+        """Every fee of the set is charged before any transaction applies
+        (LedgerManagerImpl::processFeesSeqNums): one pass in apply order.
+        Each source account is stored straight into the close's delta
+        (``TransactionFrame.charge_fee_seq_num``: a charge changes that one
+        entry and a raise aborts the close, so nothing nests) and its
+        ``txfeehistory`` change list packed at once, while the snapshot is
+        hot; the header's ``feePool`` is raised once, by the set's sum; the
+        set's rows are encoded in one call."""
         from ..tx import history as tx_history
 
-        rows = []
         seq = self.current.header.ledgerSeq
         tracer = self.app.tracer
-        # fees.charge and fees.rows partition the pass: the first opens
-        # with the scope's savepoint, the second closes with its release
+        db = self.database
+        # fees.charge (the loop with its pack) and fees.rows (the encode
+        # call and the insert) partition the pass: the first opens with the
+        # scope's savepoint, the second closes with its release
         phase_sp = tracer.begin("fees.charge", txs=len(txs))
-        with self.database.transaction():
+        with db.transaction():
+            items = []
+            fees = 0
+            pack = tx_history.pack_fee_changes
+            copies_before = header_copies()
             for index, tx in enumerate(txs, start=1):
-                this_tx_delta = LedgerDelta(outer=delta)
-                tx.process_fee_seq_num(this_tx_delta, self)
-                rows.append(
-                    tx.fee_history_row(seq, index, this_tx_delta.get_changes())
+                fee, account = tx.charge_fee_seq_num(delta, db)
+                fees += fee
+                # the row holds the account as this transaction left it
+                items.append((index, tx.get_contents_hash(), pack(account)))
+            if fees:
+                delta.get_header().feePool += fees
+            if phase_sp is not None:
+                tracer.end(
+                    phase_sp,
+                    # distinct sources charged; headers any delta copied
+                    # during the pass (1 for a set with a fee, 0 for none)
+                    accounts=len({tx.source_bytes() for tx in txs}),
+                    header_copies=header_copies() - copies_before,
                 )
-                this_tx_delta.commit()
-            tracer.end(phase_sp)
-            phase_sp = tracer.begin("fees.rows", rows=len(rows))
+            phase_sp = tracer.begin("fees.rows", rows=len(items))
+            rows = tx_history.fee_rows(seq, items)
             # direct SQL write inside a (possibly savepoint-less) buffered
             # scope: give the scope a real savepoint first so a failure
             # after this point can still unwind the rows
-            self.database.materialize_savepoints()
-            tx_history.insert_fee_rows(self.database, rows)
+            db.materialize_savepoints()
+            tx_history.insert_fee_rows(db, rows)
         tracer.end(phase_sp)
 
     def _apply_transactions(self, txs, ledger_delta, tx_result_set) -> None:

@@ -240,14 +240,14 @@ class TrustFrame(EntryFrame):
         issuer = AccountFrame.load_account(asset.code_and_issuer()[1], db)
         return line, issuer
 
-    def store_add(self, delta, db) -> None:
+    def store_add(self, delta, db) -> LedgerEntry:
         assert not self.is_issuer, "issuer frames are never persisted"
-        super().store_add(delta, db)
+        return super().store_add(delta, db)
 
-    def store_change(self, delta, db) -> None:
+    def store_change(self, delta, db) -> Optional[LedgerEntry]:
         if self.is_issuer:
-            return  # synthetic line: nothing to persist
-        super().store_change(delta, db)
+            return None  # synthetic line: nothing to persist
+        return super().store_change(delta, db)
 
     def store_delete(self, delta, db) -> None:
         self._assert_mutable()

@@ -383,10 +383,11 @@ _applycore_tried = False
 
 
 def load_applycore():
-    """The compiled leg of the apply loop: a set's history rows in one
-    native call (``encode_history_rows(items)``), or None
-    (tx/history.transaction_rows then encodes per row with
-    ``base64``/``hex`` in Python — same bytes, slower)."""
+    """The compiled leg of the close's two passes over a set: its history
+    rows in one native call each (``encode_history_rows(items)`` from the
+    apply loop, ``encode_fee_rows(ledger_seq, items)`` from the fee pass),
+    or None (tx/history.transaction_rows and fee_rows then encode per row
+    with ``base64``/``hex`` in Python — same bytes, slower)."""
     global _applycore_mod, _applycore_tried
     with _applycore_lock:
         if _applycore_mod is not None or _applycore_tried:

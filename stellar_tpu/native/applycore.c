@@ -1,18 +1,24 @@
-/* applycore: the native leg of the apply loop
- * (ledger/manager.py _apply_transactions, via tx/history.transaction_rows):
- * the set's history rows in one native call.
+/* applycore: the native leg of the close's two passes over a set
+ * (ledger/manager.py _apply_transactions and _process_fees_seq_nums, via
+ * tx/history.transaction_rows and fee_rows): the set's history rows in
+ * one native call.
  *
- * One entry point:
+ * Two entry points over one body (encode_rows):
  *
  *   encode_history_rows(items) -> list
  *     items: sequence of (txid, body, result, meta) bytes 4-tuples
  *     returns [(txid_hex, body_b64, result_b64, meta_b64) str 4-tuples]
  *
- * The per-tx history row encode (hex + 3x base64) is the dominant
- * residual Python cost of the apply tail once the stores are buffered.
- * This leg gathers all input pointers under the GIL, then releases it
- * for the whole batch encode, so the close's other threads (bucket
- * merges, the verify pipeline) run meanwhile.
+ *   encode_fee_rows(ledger_seq, items) -> list
+ *     items: sequence of (index, txid, changes), the last two bytes
+ *     returns the rows of txfeehistory,
+ *     [(txid_hex, ledger_seq, index, changes_b64)]
+ *
+ * The per-tx history row encode (hex + 3x base64; hex + 1x for a fee
+ * row) is the dominant residual Python cost of the apply tail once the
+ * stores are buffered.  This leg gathers all input pointers under the
+ * GIL, then releases it for the whole batch encode, so the close's other
+ * threads (bucket merges, the verify pipeline) run meanwhile.
  *
  * Encoding contract matches tx/history.py exactly: lowercase hex for
  * the txid, standard base64 alphabet WITH '=' padding for the blobs.
@@ -64,10 +70,12 @@ static void b64_encode(const uint8_t *src, size_t n, char *dst) {
     }
 }
 
-static PyObject *encode_history_rows(PyObject *self, PyObject *arg) {
-    (void)self;
-    PyObject *fast =
-        PySequence_Fast(arg, "encode_history_rows expects a sequence");
+/* Rows of `width` bytes fields, the first to hex and the rest to base64.
+ * With `seq` an item carries its index ahead of them and comes out as the
+ * table's row, (hex, seq, index, base64...); without, as the strings. */
+static PyObject *encode_rows(PyObject *arg, Py_ssize_t width, PyObject *seq) {
+    Py_ssize_t lead = seq != NULL ? 1 : 0;
+    PyObject *fast = PySequence_Fast(arg, "expected a sequence of rows");
     if (fast == NULL)
         return NULL;
     Py_ssize_t n = PySequence_Fast_GET_SIZE(fast);
@@ -78,7 +86,7 @@ static PyObject *encode_history_rows(PyObject *self, PyObject *arg) {
     size_t *lens = NULL, *offs = NULL;
     char *slab = NULL;
     PyObject *out = NULL;
-    size_t nfields = (size_t)n * 4;
+    size_t nfields = (size_t)n * (size_t)width;
 
     if (n > 0) {
         ptrs = malloc(nfields * sizeof(*ptrs));
@@ -92,19 +100,20 @@ static PyObject *encode_history_rows(PyObject *self, PyObject *arg) {
     size_t total = 0;
     for (Py_ssize_t i = 0; i < n; i++) {
         PyObject *item = PySequence_Fast_GET_ITEM(fast, i);
-        if (!PyTuple_Check(item) || PyTuple_GET_SIZE(item) != 4) {
-            PyErr_SetString(PyExc_TypeError,
-                            "each item must be a (txid, body, result, meta) "
-                            "bytes 4-tuple");
+        if (!PyTuple_Check(item) || PyTuple_GET_SIZE(item) != lead + width) {
+            PyErr_Format(PyExc_TypeError,
+                         "each item must be a tuple of %zd bytes fields, "
+                         "the txid first%s",
+                         width, lead ? ", behind its index" : "");
             goto done;
         }
-        for (int f = 0; f < 4; f++) {
+        for (Py_ssize_t f = 0; f < width; f++) {
             char *buf;
             Py_ssize_t blen;
-            if (PyBytes_AsStringAndSize(PyTuple_GET_ITEM(item, f), &buf,
-                                        &blen) < 0)
+            if (PyBytes_AsStringAndSize(PyTuple_GET_ITEM(item, lead + f),
+                                        &buf, &blen) < 0)
                 goto done;
-            size_t slot = (size_t)i * 4 + (size_t)f;
+            size_t slot = (size_t)(i * width + f);
             ptrs[slot] = (const uint8_t *)buf;
             lens[slot] = (size_t)blen;
             offs[slot] = total;
@@ -120,7 +129,7 @@ static PyObject *encode_history_rows(PyObject *self, PyObject *arg) {
         }
         Py_BEGIN_ALLOW_THREADS
         for (size_t slot = 0; slot < nfields; slot++) {
-            if (slot % 4 == 0)
+            if (slot % (size_t)width == 0)
                 hex_encode(ptrs[slot], lens[slot], slab + offs[slot]);
             else
                 b64_encode(ptrs[slot], lens[slot], slab + offs[slot]);
@@ -132,13 +141,21 @@ static PyObject *encode_history_rows(PyObject *self, PyObject *arg) {
     if (out == NULL)
         goto done;
     for (Py_ssize_t i = 0; i < n; i++) {
-        PyObject *row = PyTuple_New(4);
+        PyObject *row = PyTuple_New(width + 2 * lead);
         if (row == NULL) {
             Py_CLEAR(out);
             goto done;
         }
-        for (int f = 0; f < 4; f++) {
-            size_t slot = (size_t)i * 4 + (size_t)f;
+        if (lead) {
+            PyObject *index =
+                PyTuple_GET_ITEM(PySequence_Fast_GET_ITEM(fast, i), 0);
+            Py_INCREF(seq);
+            PyTuple_SET_ITEM(row, 1, seq);
+            Py_INCREF(index);
+            PyTuple_SET_ITEM(row, 2, index);
+        }
+        for (Py_ssize_t f = 0; f < width; f++) {
+            size_t slot = (size_t)(i * width + f);
             PyObject *s = PyUnicode_FromStringAndSize(
                 slab + offs[slot], (Py_ssize_t)(offs[slot + 1] - offs[slot]));
             if (s == NULL) {
@@ -146,7 +163,8 @@ static PyObject *encode_history_rows(PyObject *self, PyObject *arg) {
                 Py_CLEAR(out);
                 goto done;
             }
-            PyTuple_SET_ITEM(row, f, s);
+            /* the hex leads the row; seq and index sit behind it */
+            PyTuple_SET_ITEM(row, f ? f + 2 * lead : 0, s);
         }
         PyList_SET_ITEM(out, i, row);
     }
@@ -160,16 +178,32 @@ done:
     return out;
 }
 
+static PyObject *encode_history_rows(PyObject *self, PyObject *arg) {
+    (void)self;
+    return encode_rows(arg, 4, NULL);
+}
+
+static PyObject *encode_fee_rows(PyObject *self, PyObject *args) {
+    (void)self;
+    PyObject *seq, *items;
+    if (!PyArg_ParseTuple(args, "OO", &seq, &items))
+        return NULL;
+    return encode_rows(items, 2, seq);
+}
+
 static PyMethodDef Methods[] = {
     {"encode_history_rows", encode_history_rows, METH_O,
      "Batch-encode (txid, body, result, meta) bytes rows to "
      "(hex, b64, b64, b64) str rows, releasing the GIL."},
+    {"encode_fee_rows", encode_fee_rows, METH_VARARGS,
+     "encode_fee_rows(ledger_seq, items): (index, txid, changes) items to "
+     "(hex, ledger_seq, index, b64) rows, releasing the GIL."},
     {NULL, NULL, 0, NULL},
 };
 
 static struct PyModuleDef moduledef = {
     PyModuleDef_HEAD_INIT, "_applycore",
-    "Parallel-apply host leg: GIL-released history-row encoding.", -1,
+    "The close's history rows, a set in one GIL-released call.", -1,
     Methods, NULL, NULL, NULL, NULL,
 };
 

@@ -40,7 +40,6 @@ from ..xdr.txs import (
     TransactionResultCode,
     TransactionResultResult,
 )
-from . import history as tx_history
 
 # Sampled transaction spans: the loop that applies a set
 # (``LedgerManager._apply_transactions``) hands the close's tracer to one
@@ -261,7 +260,7 @@ class TransactionFrame:
         """(Re)load the tx source into signing_account.  readonly skips
         the defensive cache copy — validation-path loads (check_valid /
         txset chain checks) only read; the apply path reloads mutable via
-        common_valid(applying=True) and process_fee_seq_num.
+        common_valid(applying=True) and charge_fee_seq_num.
 
         signing=True routes through the close's FrameContext identity map
         (ledger/framecontext.py): fee charging and validity-at-apply get
@@ -360,23 +359,48 @@ class TransactionFrame:
         return res
 
     # -- fee + sequence (TransactionFrame.cpp:314-348) ---------------------
-    def process_fee_seq_num(self, delta: LedgerDelta, lm) -> None:
+    def charge_fee_seq_num(self, delta: LedgerDelta, db):
+        """This transaction's share of the fee pass: signature tracker and
+        results reset, the source loaded through the close's frame context
+        (apply gets the same frame), the fee taken from its balance — all
+        it has where it has less, ``result.feeCharged`` rewritten — its
+        sequence number set, the account stamped and stored into ``delta``
+        (and the entry cache, the store buffer, the frame context:
+        ``EntryFrame._record``).
+        -> (the fee taken, the account as stored: an immutable snapshot).
+
+        Charging changes that one entry, so it is written straight into the
+        delta handed in and cannot fail halfway: a missing account or a
+        sequence number that does not follow raises, and the close aborts.
+        The fee is the caller's to add to the header's ``feePool``: the
+        close's pass (``LedgerManager._process_fees_seq_nums``) adds a
+        set's sum once."""
         self.reset_signature_tracker()
         self.reset_results()
-        if not self.load_account(lm.database):
+        source = self.load_account(db)
+        if not source:
             raise RuntimeError("Unexpected database state: missing source account")
+        account = source.mut()
         fee = self.result.feeCharged
         if fee > 0:
-            avail = self.signing_account.get_balance()
+            avail = account.balance
             if avail < fee:
                 fee = avail  # take all they have
                 self.result.feeCharged = fee
-            self.signing_account.mut().balance -= fee
-            delta.get_header().feePool += fee
-        if self.signing_account.get_seq_num() + 1 != self.envelope.tx.seqNum:
+            account.balance = avail - fee
+        seq_num = self.envelope.tx.seqNum
+        if account.seqNum + 1 != seq_num:
             raise RuntimeError("Unexpected account state: bad sequence")
-        self.signing_account.set_seq_num(self.envelope.tx.seqNum)
-        self.signing_account.store_change(delta, lm.database)
+        account.seqNum = seq_num
+        return fee, source.store_change(delta, db)
+
+    def process_fee_seq_num(self, delta: LedgerDelta, lm) -> None:
+        """One transaction's fee charged alone, ``feePool`` raised on
+        ``delta``'s header (the tests' direct applies; a close charges its
+        set through ``LedgerManager._process_fees_seq_nums``)."""
+        fee, _account = self.charge_fee_seq_num(delta, lm.database)
+        if fee > 0:
+            delta.get_header().feePool += fee
 
     # -- apply (TransactionFrame.cpp:439-495) ------------------------------
     def apply(
@@ -462,12 +486,6 @@ class TransactionFrame:
             meta.value.clear()
             self.mark_result_failed()
         return not error_encountered
-
-    # -- persistence (txhistory / txfeehistory) ----------------------------
-    def fee_history_row(self, ledger_seq: int, tx_index: int, changes):
-        return tx_history.fee_row(
-            self.get_contents_hash(), ledger_seq, tx_index, changes
-        )
 
     def to_stellar_message(self) -> StellarMessage:
         return StellarMessage(MessageType.TRANSACTION, self.envelope)

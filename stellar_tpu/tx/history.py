@@ -8,10 +8,13 @@ publish state machine reads them back out to build history checkpoint files.
 from __future__ import annotations
 
 import base64
+import struct
 from typing import List, Optional, Tuple
 
+from ..xdr.entries import LedgerEntry
 from ..xdr.ledger import (
     LEDGER_ENTRY_CHANGES,
+    LedgerEntryChangeType,
     TransactionHistoryEntry,
     TransactionHistoryResultEntry,
     TransactionMeta,
@@ -132,6 +135,38 @@ def fee_row(tx_id: bytes, ledger_seq: int, tx_index: int, changes) -> Tuple:
         tx_index,
         base64.b64encode(LEDGER_ENTRY_CHANGES.pack(changes)).decode(),
     )
+
+
+# what LEDGER_ENTRY_CHANGES packs ahead of the entry of a list that holds
+# one LEDGER_ENTRY_UPDATED: the list's length, the change's type
+_ONE_UPDATED = struct.pack(">II", 1, LedgerEntryChangeType.LEDGER_ENTRY_UPDATED)
+
+
+def pack_fee_changes(account: LedgerEntry) -> bytes:
+    """The packed change list of one fee charge, ``[LEDGER_ENTRY_UPDATED(
+    account)]``: charging a fee changes the source account and nothing
+    else.  The fee pass calls this right after each store, on the snapshot
+    the store left — the entry is in cache lines then; packed after the
+    loop, 5,000 snapshots later, a row cost 2-3x as much (PERF.md section
+    6, PR 46)."""
+    return _ONE_UPDATED + account.to_xdr()
+
+
+def fee_rows(ledger_seq: int, items: List[Tuple[int, bytes, bytes]]) -> List[Tuple]:
+    """[(tx_index, txid, packed changes)] -> the rows ``fee_row`` builds
+    one at a time, for a whole set in one call: the hex and the base-64
+    natively in `_applycore` (``encode_fee_rows``), or by the loop below
+    where the extension did not build.  Same bytes either way
+    (tests/test_txhistory_rows.py)."""
+    from ..native import load_applycore
+
+    mod = load_applycore()
+    if mod is not None:
+        return mod.encode_fee_rows(ledger_seq, items)
+    return [
+        (t.hex(), ledger_seq, index, base64.b64encode(c).decode())
+        for index, t, c in items
+    ]
 
 
 _TX_INSERT = f"INSERT INTO txhistory ({_TX_COLUMNS}) VALUES (?,?,?,?,?,?)"

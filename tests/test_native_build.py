@@ -261,6 +261,19 @@ for bad in ([(b"x",)], [("s", b"", b"", b"")], "nope"):
         pass
     else:
         raise SystemExit("applycore accepted a malformed item")
+# the fee pass's rows: (index, txid, changes) -> (hex, seq, index, base64)
+fee_items = [(i + 1, t, b) for i, (t, b, _r, _m) in enumerate(rows)]
+assert apl_mod.encode_fee_rows(7, fee_items) == [
+    (t.hex(), 7, i, base64.b64encode(b).decode()) for i, t, b in fee_items
+]
+assert apl_mod.encode_fee_rows(7, []) == []
+for bad in ([(1, b"x")], [(b"x", b"y")], [(1, "s", b"")], [(1, b"x", b"y", b"z")], "nope"):
+    try:
+        apl_mod.encode_fee_rows(7, bad)
+    except (TypeError, ValueError):
+        pass
+    else:
+        raise SystemExit("applycore accepted a malformed fee item")
 
 # -- sodium pool leg (skipped silently when libsodium is absent) -----------
 try:
@@ -408,3 +421,7 @@ def test_applycore_cold_build_encode_differential(cold_dir):
     assert got == want
     warm = native.load_applycore()
     assert warm.encode_history_rows(items) == want
+    fee_items = [(i + 1, t, b) for i, (t, b, _r, _m) in enumerate(items)]
+    fee_want = [(h, 3, i, b) for i, (h, b, _r, _m) in enumerate(want, start=1)]
+    assert cold.encode_fee_rows(3, fee_items) == fee_want
+    assert warm.encode_fee_rows(3, fee_items) == fee_want

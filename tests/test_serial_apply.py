@@ -422,10 +422,80 @@ def _payment_builds_no_path_payment_frame(app, monkeypatch):
     assert app.invariants.total_violations == 0, app.invariants.dump_info()
 
 
+def _missing_source(app, monkeypatch):
+    """A set that holds a transaction of an account that does not exist
+    aborts in the fee pass, after the transactions ahead of it were charged:
+    the node stays where it was, the entry cache is cleared, `txfeehistory`
+    holds no row of the ledger, and the next, valid, set closes."""
+    from stellar_tpu.ledger.entryframe import entry_cache_of
+
+    keys = [T.get_account("ms-%d" % i) for i in range(4)]
+    first = funded(app, keys)
+    lm = app.ledger_manager
+    nobody = pay(app, T.get_account("ms-nobody"), first + 1, keys[0], 5)
+    txs = [pay(app, k, first + 1, keys[i ^ 1], 5) for i, k in enumerate(keys)] + [nobody]
+    before = (lm.last_closed.hash, lm.last_closed.header.ledgerSeq, accounts_of(app))
+    with pytest.raises(RuntimeError, match="missing source account"):
+        close(app, txs)
+    assert (lm.last_closed.hash, lm.last_closed.header.ledgerSeq, accounts_of(app)) == before
+    assert not entry_cache_of(app.database)._map
+    assert app.database.query_all("SELECT COUNT(*) FROM txfeehistory WHERE ledgerseq=?", (before[1] + 1,)) == [(0,)]
+    good = [pay(app, k, first + 1, keys[i ^ 1], 5) for i, k in enumerate(keys)]
+    close(app, good)
+    assert codes_of(good) == ["txSUCCESS"] * 4
+    assert accounts_of(app)[keys[0].get_strkey_public()] == (START - FEE, first + 1)
+
+
+def _fee_of_one_equals_the_pass(app, monkeypatch):
+    """`process_fee_seq_num` for one transaction leaves what the close's
+    pass leaves for a set of that one: the delta's change, the header (its
+    fee pool raised by the fee), the fee charged, the account every later
+    load sees.  Over a fee the account can pay, one it cannot, and none."""
+    from stellar_tpu.ledger.delta import LedgerDelta
+    from stellar_tpu.xdr.ledger import LEDGER_ENTRY_CHANGES
+
+    keys = [T.get_account("fo-%d" % i) for i in range(3)]
+    first = funded(app, keys)
+    lm = app.ledger_manager
+    db = app.database
+
+    class Undo(Exception):
+        pass
+
+    def left_by(charge, fee):
+        tx = T.tx_from_ops(app, keys[0], first + 1, [T.payment_op(keys[1], 5)], fee=fee)
+        delta = LedgerDelta(lm.current.header, db)
+        pool = lm.current.header.feePool
+        try:
+            with db.transaction():
+                charge(tx, delta)
+                loaded = AccountFrame.load_account(keys[0].get_public_key(), db)
+                left = (
+                    LEDGER_ENTRY_CHANGES.pack(delta.get_changes()),
+                    delta.header_ro().to_xdr(),
+                    delta.header_ro().feePool - pool,
+                    tx.result.feeCharged,
+                    (loaded.get_balance(), loaded.get_seq_num()),
+                )
+                raise Undo
+        except Undo:
+            delta.rollback()
+        assert lm.current.header.feePool == pool
+        return left
+
+    for fee, taken in ((100, 100), (START + 5, START), (0, 0)):
+        alone = left_by(lambda tx, delta: tx.process_fee_seq_num(delta, lm), fee)
+        as_a_set = left_by(lambda tx, delta: lm._process_fees_seq_nums([tx], delta), fee)
+        assert alone == as_a_set
+        assert alone[2:] == (taken, taken, (START - taken, first + 1))
+
+
 OUTCOMES = {
     "failed-after-partners-payment": _failed_after_partners_payment,
     "bad-auth": _bad_auth,
     "bad-seq": _bad_seq,
+    "missing-source-aborts-the-close": _missing_source,
+    "fee-of-one-equals-the-pass-for-a-set-of-one": _fee_of_one_equals_the_pass,
     "underfunded": _underfunded,
     "op-raises": _op_raises,
     "unrollbackable-write": _unrollbackable_write,

@@ -569,6 +569,12 @@ class TestCloseFromInside:
             # the sample says which operation it timed
             assert {s.attrs["op"] for s in spans if s.name == "tx.apply"} == {"PAYMENT"}
             assert [s.attrs for s in spans if s.name == "apply.rows"] == [{"rows": CLOSE_TXS}]
+            # the fee pass: every transaction has a source of its own, the
+            # pool is raised on one copy of the header, a row a transaction
+            assert [s.attrs for s in spans if s.name == "fees.charge"] == [
+                {"txs": CLOSE_TXS, "accounts": CLOSE_TXS, "header_copies": 1}
+            ]
+            assert [s.attrs for s in spans if s.name == "fees.rows"] == [{"rows": CLOSE_TXS}]
             # one collection a close; every signature's hint finds one key
             assert [s.attrs for s in spans if s.name == "sig.collect"] == [{
                 "txs": CLOSE_TXS, "signatures": sigs * CLOSE_TXS,
@@ -578,6 +584,80 @@ class TestCloseFromInside:
             # the general signer loop where the account is held by signers
             valid = [s.attrs for s in spans if s.name == "tx.valid"]
             assert valid == [{"sigs": sigs, "keys": keys}] * 3
+
+    @pytest.mark.parametrize(
+        "fee_set", ["two-sources-of-five", "zero-fees", "empty-set", "nested-header-a-transaction"]
+    )
+    def test_fee_pass_counts_its_accounts_and_its_header_copies(self, clock, fee_set, monkeypatch):
+        """`fees.charge` carries `accounts`, the distinct sources charged,
+        and `header_copies`, the headers any delta copied during the pass —
+        one for a set that pays a fee however many transactions it holds,
+        none for a set that pays none, and one more a transaction where a
+        nested delta that copies its header is put back into the loop — and
+        `_copy_header` is called as often."""
+        from test_serial_apply import close, funded, pay
+
+        from stellar_tpu.ledger import delta as delta_module
+        from stellar_tpu.ledger.manager import LedgerManager
+        from stellar_tpu.main.application import Application
+        from stellar_tpu.tx import testutils as T
+        from stellar_tpu.tx.frame import TransactionFrame
+
+        app = Application(clock, T.get_test_config(177), new_db=True)
+        try:
+            keys = [T.get_account("fc-%d" % i) for i in range(4)]
+            first = funded(app, keys)
+            five = lambda: (  # noqa: E731
+                [pay(app, keys[0], first + n, keys[2], n) for n in (1, 2, 3)]
+                + [pay(app, keys[1], first + n, keys[3], n) for n in (1, 2)]
+            )
+            txs, accounts, copies = {
+                "two-sources-of-five": (five(), 2, 1),
+                "nested-header-a-transaction": (five(), 2, 6),
+                "zero-fees": (
+                    [T.tx_from_ops(app, k, first + 1, [T.payment_op(keys[3], 1)], fee=0) for k in keys[:3]], 3, 0,
+                ),
+                "empty-set": ([], 0, 0),
+            }[fee_set]
+            copied = []
+            real_copy = delta_module._copy_header
+            real_pass = LedgerManager._process_fees_seq_nums
+
+            def copy_header(h):
+                copied.append(h)
+                return real_copy(h)
+
+            def fee_pass(self, txs, delta):
+                # count the copies of the pass alone, not the apply loop's
+                monkeypatch.setattr(delta_module, "_copy_header", copy_header)
+                try:
+                    real_pass(self, txs, delta)
+                finally:
+                    monkeypatch.setattr(delta_module, "_copy_header", real_copy)
+
+            monkeypatch.setattr(LedgerManager, "_process_fees_seq_nums", fee_pass)
+            if fee_set == "nested-header-a-transaction":
+                real_charge = TransactionFrame.charge_fee_seq_num
+
+                def nesting_charge(self, delta, db):
+                    # what the loop did before PR 47: a delta a
+                    # transaction, its header copied for the fee
+                    nested = delta_module.LedgerDelta(outer=delta)
+                    nested.get_header()
+                    nested.rollback()
+                    return real_charge(self, delta, db)
+
+                monkeypatch.setattr(TransactionFrame, "charge_fee_seq_num", nesting_charge)
+            app.tracer.clear()
+            close(app, txs)
+            spans = app.tracer.spans()
+            assert [s.attrs for s in spans if s.name == "fees.charge"] == [
+                {"txs": len(txs), "accounts": accounts, "header_copies": copies}
+            ]
+            assert [s.attrs for s in spans if s.name == "fees.rows"] == [{"rows": len(txs)}]
+            assert len(copied) == copies
+        finally:
+            app.database.close()
 
     def test_every_span_of_a_close_carries_its_ledger(self, traced_closes):
         _traffic, closes = traced_closes
