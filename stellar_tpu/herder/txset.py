@@ -39,6 +39,9 @@ class TxSetFrame:
         # counted them: both dropped with the triples when the set changes
         self._account_ids_memo: Optional[set] = None
         self._warm_counted = False
+        # (ledger manager, its last closed hash) on which a full pass found
+        # every account's chain of this set valid; see ``_found_valid``
+        self._valid_on: Optional[tuple] = None
 
     @classmethod
     def from_xdr_set(cls, network_id: bytes, xdr_set: TransactionSet) -> "TxSetFrame":
@@ -49,7 +52,13 @@ class TxSetFrame:
 
     # -- canonical ordering & hash -----------------------------------------
     def sort_for_hash(self) -> None:
-        self.transactions.sort(key=lambda tx: tx.get_full_hash())
+        txs = self.transactions
+        ordered = sorted(txs, key=lambda tx: tx.get_full_hash())
+        if any(a is not b for a, b in zip(ordered, txs)):
+            # someone reordered the list in place: what else they did to it
+            # is unknown, so the verdict goes with the order
+            txs[:] = ordered
+            self._valid_on = None
         self._hash = None
 
     def get_contents_hash(self) -> bytes:
@@ -67,6 +76,7 @@ class TxSetFrame:
         self._triples_memo = None
         self._account_ids_memo = None
         self._warm_counted = False
+        self._valid_on = None
 
     def add_transaction(self, tx: TransactionFrame) -> None:
         self.transactions.append(tx)
@@ -265,10 +275,35 @@ class TxSetFrame:
                 return False, txs  # whole account group is bad
         return True, invalid
 
+    # -- the verdict, remembered -------------------------------------------
+    # SCP asks ``validate_value`` for one value at nomination and at every
+    # ballot step, and the herder trims and checks the same frame around
+    # them: ten passes a ledger over inputs that cannot have changed.  The
+    # set is these exact frames until ``_changed`` or a reorder; everything
+    # else a pass reads (header, accounts, signers, balances) changes only
+    # when this node closes a ledger, which changes its last closed hash.
+    # So a pass that found every chain valid is remembered with the ledger
+    # manager it asked and that hash.  Only ``True`` is remembered: an
+    # invalid set is walked again, and marks its meters again.  Another
+    # node in the process (another ledger manager) does its own pass.
+    def _found_valid(self, lm, lcl) -> bool:
+        return self._valid_on == (lm, lcl.hash)
+
+    def _walk_chains(self, app, lm):
+        """The full pass: one signature flush for the set, then every
+        account's chain.  Yields (txs, ok, invalid) an account."""
+        lm.txset_validations["full"] += 1
+        self._prewarm_signature_cache(app)
+        for txs in self._account_tx_map().values():
+            ok, invalid = self._check_account_chain(app, list(txs))
+            yield txs, ok, invalid
+
     def check_valid(self, app) -> bool:
         """TxSetFrame.cpp:247-330."""
-        with tracer_of(app).span("txset.validate", txs=len(self.transactions)):
-            lcl = app.ledger_manager.get_last_closed_ledger_header()
+        tracer = tracer_of(app)
+        with tracer.span("txset.validate", txs=len(self.transactions)) as sp:
+            lm = app.ledger_manager
+            lcl = lm.get_last_closed_ledger_header()
             if lcl.hash != self.previous_ledger_hash:
                 return False
             if len(self.transactions) > lcl.header.maxTxSetSize:
@@ -276,33 +311,37 @@ class TxSetFrame:
 
             last_hash = b"\x00" * 32
             for tx in self.transactions:
-                if tx.get_full_hash() < last_hash:
+                full_hash = tx.get_full_hash()
+                if full_hash < last_hash:
                     return False  # not in canonical order
-                last_hash = tx.get_full_hash()
+                last_hash = full_hash
 
-            self._prewarm_signature_cache(app)
+            if self._found_valid(lm, lcl):
+                lm.txset_validations["memo"] += 1
+                tracer.end(sp, memo=1)
+                return True
 
-            for txs in self._account_tx_map().values():
-                ok, invalid = self._check_account_chain(app, list(txs))
+            for _txs, ok, invalid in self._walk_chains(app, lm):
                 if not ok or invalid:
                     return False
+            self._valid_on = (lm, lcl.hash)
             return True
 
     def trim_invalid(self, app) -> List[TransactionFrame]:
         """Remove invalid txs; returns the trimmed ones (TxSetFrame.cpp:190)."""
         self.sort_for_hash()
-        self._prewarm_signature_cache(app)
+        lm = app.ledger_manager
+        lcl = lm.get_last_closed_ledger_header()
+        if self._found_valid(lm, lcl):
+            lm.txset_validations["trim_memo"] += 1
+            return []
         trimmed: List[TransactionFrame] = []
-        for txs in self._account_tx_map().values():
-            ok, invalid = self._check_account_chain(app, list(txs))
-            if not ok:
-                for tx in txs:
-                    trimmed.append(tx)
-                    self.remove_tx(tx)
-            else:
-                for tx in invalid:
-                    trimmed.append(tx)
-                    self.remove_tx(tx)
+        for txs, ok, invalid in self._walk_chains(app, lm):
+            for tx in invalid if ok else txs:
+                trimmed.append(tx)
+                self.remove_tx(tx)
+        if not trimmed:
+            self._valid_on = (lm, lcl.hash)
         return trimmed
 
     # -- surge pricing (TxSetFrame.cpp:156-186) ----------------------------

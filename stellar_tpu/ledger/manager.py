@@ -78,6 +78,10 @@ class LedgerManager:
             "book_rows": 0, "book_side_loads": 0, "txs_failed_at_apply": 0,
             "payments_applied": 0,
         }
+        # /info "txset_validations": the passes ``TxSetFrame.check_valid`` /
+        # ``trim_invalid`` walked in full on this node's state, and those a
+        # set answered from the verdict it remembers (herder/txset.py)
+        self.txset_validations = {"full": 0, "memo": 0, "trim_memo": 0}
         self._tx_apply_timer = app.metrics.new_timer(
             ("ledger", "transaction", "apply")
         )

@@ -233,6 +233,11 @@ class CommandHandler:
             # the order book's work and the transactions that failed at
             # apply, since the node started (monotonic)
             "exchange": dict(lm.exchange_stats),
+            # transaction-set validations since the node started: walked in
+            # full, and answered by ``check_valid`` / by ``trim_invalid``
+            # from the verdict a set remembers for this node's last closed
+            # ledger (monotonic)
+            "txset_validations": dict(lm.txset_validations),
             # catch-up since the node started: rounds, ledgers and
             # transactions replayed, triples prefetched, and the state of
             # the one in progress (a node replays one ledger a clock post

@@ -282,6 +282,227 @@ class TestTxSetValidity:
         app.graceful_stop()
 
 
+def count_chain_walks(monkeypatch):
+    """Count ``TxSetFrame._check_account_chain`` calls (one an account a
+    full pass); returns the list the calls land in."""
+    from stellar_tpu.herder.txset import TxSetFrame
+
+    walks = []
+    inner = TxSetFrame._check_account_chain
+
+    def counted(app, txs):
+        walks.append(len(txs))
+        return inner(app, txs)
+
+    monkeypatch.setattr(TxSetFrame, "_check_account_chain", staticmethod(counted))
+    return walks
+
+
+class TestTxSetVerdictMemo:
+    """A set found valid remembers the node and the last closed ledger it
+    was found valid on: the next ``check_valid`` / ``trim_invalid`` there
+    is the free gates and a comparison.  Anything that could change the
+    answer — the set, the ledger, the node — costs the full pass again, and
+    only ``True`` is ever remembered."""
+
+    _world = TestTxSetValidity._world
+
+    def _valid(self, clock):
+        app, ts, source, seq, payment = self._world(clock)
+        ts.sort_for_hash()
+        assert ts.check_valid(app)
+        assert app.ledger_manager.txset_validations == {"full": 1, "memo": 0, "trim_memo": 0}
+        return app, ts, source, seq, payment
+
+    def test_second_check_is_a_comparison(self, clock, monkeypatch):
+        app, ts, *_ = self._valid(clock)
+        results = [tx.result for tx in ts.transactions]
+        walks = count_chain_walks(monkeypatch)
+        app.tracer.clear()
+        for _ in range(3):
+            assert ts.check_valid(app)
+        assert walks == []
+        assert app.ledger_manager.txset_validations == {"full": 1, "memo": 3, "trim_memo": 0}
+        # the span stays, marked; the frames keep the pass's results
+        spans = [s for s in app.tracer.spans() if s.name == "txset.validate"]
+        assert [s.attrs.get("memo") for s in spans] == [1, 1, 1]
+        assert [tx.result for tx in ts.transactions] == results
+        assert all(tx.get_result_code().name == "txSUCCESS" for tx in ts.transactions)
+        info = app.command_handler.execute("info")["info"]
+        assert info["txset_validations"] == {"full": 1, "memo": 3, "trim_memo": 0}
+        app.graceful_stop()
+
+    def test_trim_after_valid_check_walks_nothing(self, clock, monkeypatch):
+        app, ts, *_ = self._valid(clock)
+        walks = count_chain_walks(monkeypatch)
+        before = list(ts.transactions)
+        assert ts.trim_invalid(app) == []
+        assert walks == [] and ts.transactions == before
+        assert app.ledger_manager.txset_validations == {"full": 1, "memo": 0, "trim_memo": 1}
+        app.graceful_stop()
+
+    def test_full_trim_that_removed_nothing_is_remembered(self, clock, monkeypatch):
+        app, ts, *_ = self._world(clock)
+        assert ts.trim_invalid(app) == []
+        walks = count_chain_walks(monkeypatch)
+        assert ts.check_valid(app) and ts.trim_invalid(app) == []
+        assert walks == []
+        assert app.ledger_manager.txset_validations == {"full": 1, "memo": 1, "trim_memo": 1}
+        app.graceful_stop()
+
+    def test_trim_that_removed_something_leaves_no_memo(self, clock, monkeypatch):
+        app, ts, source, seq, payment = self._world(clock)
+        ts.add_transaction(T.tx_from_ops(app, source, seq + 5, [T.payment_op(source, payment)]))
+        assert len(ts.trim_invalid(app)) == 1
+        assert ts._valid_on is None
+        walks = count_chain_walks(monkeypatch)
+        assert ts.check_valid(app)
+        assert walks == [10]  # one source account, its ten transactions
+        assert app.ledger_manager.txset_validations["full"] == 2
+        app.graceful_stop()
+
+    @pytest.mark.parametrize("how", ["add_transaction", "remove_tx", "reorder_then_sort"])
+    def test_a_changed_set_is_walked_again(self, clock, monkeypatch, how):
+        app, ts, source, seq, payment = self._valid(clock)
+        walks = count_chain_walks(monkeypatch)
+        if how == "add_transaction":
+            ts.add_transaction(T.tx_from_ops(app, source, seq + 1, [T.payment_op(source, 1)]))
+            ts.sort_for_hash()
+            want = False  # the eleventh payment takes the source below its reserve
+        elif how == "remove_tx":
+            ts.remove_tx(max(ts.transactions, key=lambda t: t.get_seq_num()))
+            want = True
+        else:
+            ts.transactions[0], ts.transactions[1] = ts.transactions[1], ts.transactions[0]
+            assert not ts.check_valid(app) and walks == []  # the order gate, before the memo
+            ts.sort_for_hash()
+            want = True
+        assert ts._valid_on is None
+        assert ts.check_valid(app) is want
+        assert len(walks) == 1
+        app.graceful_stop()
+
+    def test_sort_that_moves_nothing_keeps_the_memo(self, clock, monkeypatch):
+        app, ts, *_ = self._valid(clock)
+        walks = count_chain_walks(monkeypatch)
+        ts.sort_for_hash()
+        ts.get_contents_hash()
+        ts.to_xdr()
+        assert ts.check_valid(app) and walks == []
+        app.graceful_stop()
+
+    def test_a_close_ends_the_memo(self, clock, monkeypatch):
+        """After a close the set's ``previous_ledger_hash`` is not the last
+        closed hash: invalid at the first gate, memo or not.  The same
+        frames under the new hash are a new set and a full pass."""
+        from stellar_tpu.herder.txset import TxSetFrame
+
+        app, ts, *_ = self._valid(clock)
+        lm = app.ledger_manager
+        T.close_ledger_on(app, lm.last_closed.header.scpValue.closeTime + 5)
+        walks = count_chain_walks(monkeypatch)
+        assert not ts.check_valid(app) and walks == []
+        assert lm.txset_validations["memo"] == 0
+        # a caller that re-aims the frame in place still gets a full pass
+        ts.previous_ledger_hash = lm.last_closed.hash
+        assert ts.check_valid(app) and len(walks) == 1
+        again = TxSetFrame(lm.last_closed.hash, ts.transactions)
+        assert again.check_valid(app) and len(walks) == 2
+        app.graceful_stop()
+
+    def test_a_second_node_does_its_own_pass(self, clock, monkeypatch):
+        """Two nodes of one process on the same last closed hash (a
+        ``Simulation``): the verdict is the first node's alone."""
+        app, ts, *_ = self._valid(clock)
+        other, ts2, *_ = self._world(clock)
+        assert other.ledger_manager.last_closed.hash == app.ledger_manager.last_closed.hash
+        assert [t.get_full_hash() for t in sorted(ts2.transactions, key=lambda t: t.get_full_hash())] == [
+            t.get_full_hash() for t in ts.transactions
+        ]
+        walks = count_chain_walks(monkeypatch)
+        assert ts.check_valid(other) and len(walks) == 1
+        assert other.ledger_manager.txset_validations == {"full": 1, "memo": 0, "trim_memo": 0}
+        # ... and the verdict is now the second node's: the first walks again
+        assert ts.check_valid(app) and len(walks) == 2
+        assert ts.check_valid(app) and len(walks) == 2
+        other.graceful_stop()
+        app.graceful_stop()
+
+    @pytest.mark.parametrize("via", ["check_valid", "trim_then_check"])
+    def test_an_invalid_set_is_walked_and_metered_every_time(self, clock, monkeypatch, via):
+        app, ts, source, seq, payment = self._world(clock)
+        gap = T.tx_from_ops(app, source, seq + 5, [T.payment_op(source, payment)])
+        ts.add_transaction(gap)
+        ts.sort_for_hash()
+        meter = app.metrics.new_meter(("transaction", "invalid", "bad-seq"), "transaction")
+        walks = count_chain_walks(monkeypatch)
+        for n in (1, 2, 3):
+            assert not ts.check_valid(app)
+            assert (len(walks), meter.count) == (n, n)
+            assert gap.get_result_code().name == "txBAD_SEQ" and ts._valid_on is None
+        assert app.ledger_manager.txset_validations == {"full": 3, "memo": 0, "trim_memo": 0}
+        if via == "trim_then_check":
+            assert ts.trim_invalid(app) == [gap] and meter.count == 4
+            assert ts.check_valid(app) and len(walks) == 5
+        app.graceful_stop()
+
+
+class TestTriggeredLedgerValidatesOnce:
+    """One node, ``MANUAL_CLOSE``, a pending backlog of twice the set limit:
+    the triggered ledger is trimmed over the backlog, checked in full once
+    after the surge filter, and every later question about the set (SCP's
+    ``validate_value`` at nomination and each ballot step, the re-trim in
+    ``combine_candidates``) is answered from that verdict.  The ledger that
+    closes is the one that closes with every pass walked."""
+
+    LIMIT = 5
+
+    def _close_one(self, instance):
+        clock = VirtualClock(VIRTUAL_TIME)
+        cfg = T.get_test_config(instance)
+        cfg.MANUAL_CLOSE = True
+        app = Application.create(clock, cfg, new_db=True)
+        try:
+            app.start()
+            lm = app.ledger_manager
+            lm.current.header.maxTxSetSize = self.LIMIT
+            root = T.root_key_for(app)
+            seq = root_seq(app)
+            keys = [T.get_account(f"backlog-{i}") for i in range(2 * self.LIMIT)]
+            for i, k in enumerate(keys):
+                T.apply_tx(app, T.tx_from_ops(app, root, seq + 1 + i, [T.create_account_op(k, 10**9)]))
+            for i, k in enumerate(keys):
+                k_seq = AccountFrame.load_account(k.get_public_key(), app.database).get_seq_num()
+                tx = T.tx_from_ops(app, k, k_seq + 1, [T.payment_op(keys[i ^ 1], 1000 + i)])
+                assert app.herder.recv_transaction(tx) == TX_STATUS_PENDING
+            before = dict(lm.txset_validations)
+            start = lm.get_last_closed_ledger_num()
+            app.herder.trigger_next_ledger(lm.get_ledger_num())
+            assert clock.crank_until(lambda: lm.get_last_closed_ledger_num() > start, 30)
+            did = {k: v - before[k] for k, v in lm.txset_validations.items()}
+            applied = app.metrics.new_meter(("ledger", "transaction", "count"), "tx").count
+            return did, applied, lm.last_closed.hash, app.herder.num_pending_txs()
+        finally:
+            app.graceful_stop()
+            clock.shutdown()
+
+    def test_one_full_check_a_ledger_and_the_same_ledger(self, monkeypatch):
+        from stellar_tpu.herder.txset import TxSetFrame
+
+        did, applied, closed_hash, pending = self._close_one(77)
+        assert did["full"] <= 2 and did["memo"] + did["trim_memo"] >= 7, did
+        assert did["trim_memo"] >= 1
+        assert (applied, pending) == (self.LIMIT, self.LIMIT)
+
+        # the memo defeated: every pass walks, as before it existed
+        monkeypatch.setattr(TxSetFrame, "_found_valid", lambda self, lm, lcl: False)
+        walked, applied2, walked_hash, pending2 = self._close_one(77)
+        assert walked["memo"] == walked["trim_memo"] == 0
+        assert walked["full"] == did["full"] + did["memo"] + did["trim_memo"]
+        assert (applied2, pending2) == (applied, pending)
+        assert walked_hash == closed_hash
+
+
 class TestSurgePricing:
     """Ported from the reference's 'surge' case (HerderTests.cpp:320-490):
     DESIRED_MAX_TX_PER_LEDGER=5, competing accounts, the filter keeps the
