@@ -194,6 +194,16 @@ class Herder(SCPDriver):
         self.n_payload_encodes = 0
         self.scp_receive_s = 0.0
         self.scp_close_s = 0.0
+        # the transaction queue, monotonic since the node started (``/info``
+        # ``tx_queue``; counted with the tracer off too): transactions
+        # admitted behind a pending one of their account, transactions the
+        # trigger's trim and its surge filter took out of a proposed set;
+        # and the longest per-account chain of the set the last trigger
+        # proposed
+        self.n_chain_txs_admitted = 0
+        self.n_surge_cut = 0
+        self.n_trimmed = 0
+        self.last_set_longest_chain = 0
         # lazy-deletion max-heap (negated slots) over scp_slot_buckets:
         # the at-cap evict decision is O(log n) per envelope instead of a
         # max() scan over 1024 keys — the scan would sit on exactly the
@@ -754,6 +764,8 @@ class Herder(SCPDriver):
 
         self.received_transactions[0].setdefault(acc, TxMap()).add_tx(tx)
         self._pending_tx_ids.add(tx_id)
+        if agg[1]:
+            self.n_chain_txs_admitted += 1
         agg[0] += tx.get_fee()
         if tx.get_seq_num() > agg[1]:
             agg[1] = tx.get_seq_num()
@@ -779,6 +791,24 @@ class Herder(SCPDriver):
         """Queue depth across all generations (the ingest plane's surge
         high-water measure)."""
         return len(self._pending_tx_ids)
+
+    def tx_queue_stats(self) -> dict:
+        """``/info`` ``tx_queue``: the pending transactions now, by
+        generation and by account, the longest per-account chain of the set
+        the last trigger proposed, and what admission, the trim and the
+        surge filter did since the node started."""
+        gens = self.received_transactions
+        return {
+            "pending": len(self._pending_tx_ids),
+            "accounts_pending": len(set().union(*gens)),
+            "longest_chain": self.last_set_longest_chain,
+            "generations": [
+                sum(len(m.transactions) for m in gen.values()) for gen in gens
+            ],
+            "chain_txs_admitted": self.n_chain_txs_admitted,
+            "surge_cut": self.n_surge_cut,
+            "trimmed": self.n_trimmed,
+        }
 
     def get_max_seq_in_pending_txs(self, acc: PublicKey) -> int:
         high = 0
@@ -1124,14 +1154,25 @@ class Herder(SCPDriver):
                 for tx in txmap.transactions.values():
                     proposed.add_transaction(tx)
 
-        with tracer.span("herder.trim_invalid", txs=proposed.size()):
+        with tracer.span("herder.trim_invalid", txs=proposed.size()) as sp:
             removed = proposed.trim_invalid(self.app)
             self._remove_received_txs(removed)
-        with tracer.span("herder.surge"):
+            self.n_trimmed += len(removed)
+            if sp is not None and proposed.chain_shape is not None:
+                # the chains of everything pending, as the trim walked them
+                sp.attrs["accounts"], sp.attrs["longest_chain"] = proposed.chain_shape
+        with tracer.span("herder.surge") as sp:
+            offered = proposed.size()
             proposed.surge_pricing_filter(self.ledger_manager)
+            cut = offered - proposed.size()
+            self.n_surge_cut += cut
+            tracer.end(sp, cut=cut)
 
         if not proposed.check_valid(self.app):
             raise RuntimeError("wanting to emit an invalid txSet")
+        # this check walked the set as it will be proposed, or found the
+        # verdict of the trim's walk over the same transactions
+        self.last_set_longest_chain = proposed.chain_shape[1]
 
         tx_set_hash = proposed.get_contents_hash()
         self.pending_envelopes.recv_tx_set(tx_set_hash, proposed)

@@ -42,6 +42,9 @@ class TxSetFrame:
         # (ledger manager, its last closed hash) on which a full pass found
         # every account's chain of this set valid; see ``_found_valid``
         self._valid_on: Optional[tuple] = None
+        # (source accounts, transactions of the longest chain) of the set
+        # as the last full pass walked it; None until one has
+        self.chain_shape: Optional[Tuple[int, int]] = None
 
     @classmethod
     def from_xdr_set(cls, network_id: bytes, xdr_set: TransactionSet) -> "TxSetFrame":
@@ -99,7 +102,10 @@ class TxSetFrame:
         )
 
     # -- apply order (TxSetFrame.cpp:93-131) -------------------------------
-    def sort_for_apply(self) -> List[TransactionFrame]:
+    def sort_for_apply(self, tally: Optional[dict] = None) -> List[TransactionFrame]:
+        """Batch *d* holds every account's *d*-th transaction of the set;
+        inside a batch by full hash XOR the contents hash.  ``tally``, if
+        given, learns the set's ``accounts`` and ``batches``."""
         txs = sorted(self.transactions, key=lambda tx: tx.get_seq_num())
         batches: List[List[TransactionFrame]] = [[] for _ in range(4)]
         seen_count: Dict[bytes, int] = {}
@@ -120,6 +126,9 @@ class TxSetFrame:
                 key=lambda tx: int.from_bytes(tx.get_full_hash(), "big") ^ xh
             )
             out.extend(batch)
+        if tally is not None:
+            tally["accounts"] = len(seen_count)
+            tally["batches"] = max(seen_count.values(), default=0)
         return out
 
     def collect_account_ids(self) -> set:
@@ -294,7 +303,9 @@ class TxSetFrame:
         account's chain.  Yields (txs, ok, invalid) an account."""
         lm.txset_validations["full"] += 1
         self._prewarm_signature_cache(app)
-        for txs in self._account_tx_map().values():
+        chains = self._account_tx_map()
+        self.chain_shape = (len(chains), max(map(len, chains.values()), default=0))
+        for txs in chains.values():
             ok, invalid = self._check_account_chain(app, list(txs))
             yield txs, ok, invalid
 
@@ -321,11 +332,12 @@ class TxSetFrame:
                 tracer.end(sp, memo=1)
                 return True
 
-            for _txs, ok, invalid in self._walk_chains(app, lm):
-                if not ok or invalid:
-                    return False
-            self._valid_on = (lm, lcl.hash)
-            return True
+            valid = all(ok and not invalid for _txs, ok, invalid in self._walk_chains(app, lm))
+            if sp is not None:
+                sp.attrs["accounts"], sp.attrs["longest_chain"] = self.chain_shape
+            if valid:
+                self._valid_on = (lm, lcl.hash)
+            return valid
 
     def trim_invalid(self, app) -> List[TransactionFrame]:
         """Remove invalid txs; returns the trimmed ones (TxSetFrame.cpp:190)."""

@@ -438,7 +438,10 @@ class LedgerManager:
             )
             ledger_delta = LedgerDelta(self.current.header, self.database)
 
-            txs = ledger_data.tx_set.sort_for_apply()
+            with tracer.span("txset.sort_for_apply", txs=ledger_data.tx_set.size()) as sort_sp:
+                shape: dict = {}
+                txs = ledger_data.tx_set.sort_for_apply(shape)
+                tracer.end(sort_sp, **shape)
             # the set's accounts reach the entry cache in bulk (chunked IN()
             # selects) before fees, prewarm or apply read one of them.  A set
             # that was validated first was warmed where its triples were
@@ -695,6 +698,8 @@ class LedgerManager:
             rows = tx_history.transaction_rows(seq, blobs)
             stats["txs_failed_at_apply"] += failed
             if serial_sp is not None:
+                # distinct sources applied, as ``fees.charge`` counts them
+                serial_sp.attrs["accounts"] = len({tx.source_bytes() for tx in txs})
                 # fee kept, sequence number taken, effects unwound
                 serial_sp.attrs["failed"] = failed
                 # PAYMENT operations that went through credit / debit

@@ -258,6 +258,11 @@ class CommandHandler:
             # envelopes through the overlay's batch flush, the herder and
             # SCP, and what federated voting scanned for them (monotonic)
             info["scp"] = app.herder.scp_stats()
+            # the herder's transaction queue: what is pending now, by
+            # generation and account, the longest per-account chain of the
+            # last proposed set, and what admission, the trim and the surge
+            # filter did to chains since the node started (monotonic)
+            info["tx_queue"] = app.herder.tx_queue_stats()
         return {"info": info}
 
     def handle_metrics(self, q: dict) -> dict:

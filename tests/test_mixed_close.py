@@ -597,7 +597,7 @@ def test_spans_and_counters_of_a_mixed_close(world):
     assert exchange.attrs["rows"] == 6 and exchange.attrs["side_loads"] == 1
     (serial,) = by_name["apply.serial"]
     # E's payment reaches its body (and fails there); A's path payment has no PAYMENT
-    assert serial.attrs == {"txs": 3, "failed": 1, "payments": 1}
+    assert serial.attrs == {"txs": 3, "accounts": 3, "failed": 1, "payments": 1}
     (sampled,) = by_name["tx.apply"]  # index 0 of the set
     assert sampled.attrs["op"] in ("PATH_PAYMENT", "CHANGE_TRUST", "PAYMENT")
     (flush,) = by_name["commit.flush"]

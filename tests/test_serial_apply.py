@@ -369,7 +369,7 @@ def _payments_counted(app, monkeypatch):
     assert codes_of(txs) == ["txSUCCESS"] * 6 + ["txFAILED", "txBAD_AUTH"]
     assert T.inner_op_code(txs[6]).name == "PAYMENT_UNDERFUNDED"
     (serial,) = [s.attrs for s in app.tracer.spans() if s.name == "apply.serial"]
-    assert serial == {"txs": 8, "failed": 2, "payments": 4}
+    assert serial == {"txs": 8, "accounts": 8, "failed": 2, "payments": 4}
     after = info()
     assert after["payments_applied"] - before["payments_applied"] == 4
     assert after["txs_failed_at_apply"] - before["txs_failed_at_apply"] == 2
@@ -388,7 +388,7 @@ def _payments_counted(app, monkeypatch):
     app.tracer.clear()
     close(app, [T.tx_from_ops(app, keys[7], first + 2, [T.path_payment_op(keys[1], native, 3, native, 3)])])
     (serial,) = [s.attrs for s in app.tracer.spans() if s.name == "apply.serial"]
-    assert serial == {"txs": 1, "failed": 0, "payments": 0}
+    assert serial == {"txs": 1, "accounts": 1, "failed": 0, "payments": 0}
 
 
 def _payment_builds_no_path_payment_frame(app, monkeypatch):

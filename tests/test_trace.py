@@ -440,6 +440,8 @@ NEW_CLOSE_SPANS = {
     # PR 43: two a close that was not validated first (as here) — the
     # close's ask, which loads, and the collect's, which finds every line
     "accounts.warm",
+    # PR 49: the set laid out in the protocol's apply order, once a close
+    "txset.sort_for_apply",
 }
 CLOSE_TXS = 130
 # traffic -> (signatures an envelope, keys check_signature walks for it)
@@ -564,7 +566,7 @@ class TestCloseFromInside:
             # and how many PAYMENTs went through credit / debit (the failing too)
             failed = sum(1 for i in range(CLOSE_TXS) if _failing(traffic, i))
             assert [s.attrs for s in spans if s.name == "apply.serial"] == [
-                {"txs": CLOSE_TXS, "failed": failed, "payments": CLOSE_TXS}
+                {"txs": CLOSE_TXS, "accounts": CLOSE_TXS, "failed": failed, "payments": CLOSE_TXS}
             ]
             # the sample says which operation it timed
             assert {s.attrs["op"] for s in spans if s.name == "tx.apply"} == {"PAYMENT"}
@@ -709,12 +711,12 @@ class TestCloseFromInside:
             } | {s.name for s in spans if s.name.startswith("invariant.")}
             assert len(new) <= budget(CLOSE_TXS)
             fixed = len([s for s in new if not s.name.startswith("tx.")])
-            assert fixed <= 11
+            assert fixed <= 12
             assert [s.attrs["site"] for s in new if s.name == "accounts.warm"] == ["close", "collect"]
         # and at the widths the cells run: whole spans + 3 a sampled
         # transaction
         for txs in (1000, 5000):
-            worst = 11 + 3 * math.ceil(txs / TX_SAMPLE_STRIDE)
+            worst = 12 + 3 * math.ceil(txs / TX_SAMPLE_STRIDE)
             assert worst <= budget(txs), txs
         # a close that meets the order book adds one ``op.exchange`` a
         # conversion (tests/test_mixed_close.py counts them): at
