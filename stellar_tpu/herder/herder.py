@@ -1034,6 +1034,9 @@ class Herder(SCPDriver):
     def get_tx_set(self, ts_hash: bytes):
         return self.pending_envelopes.get_tx_set(ts_hash)
 
+    def get_tx_set_wire(self, ts_hash: bytes) -> Optional[bytes]:
+        return self.pending_envelopes.get_tx_set_wire(ts_hash)
+
     def process_scp_queue(self) -> None:
         # drain holdoff around the whole sweep: when several slots are
         # externalizable (a healed partition's replay run readied them in
@@ -1228,12 +1231,13 @@ class Herder(SCPDriver):
 
         from ..main.persistentstate import K_LAST_SCP_DATA
         from ..xdr.base import pack_var_array_of
-        from ..xdr.ledger import TransactionSet
 
         if slot_index is None:
             slot_index = self.ledger_manager.get_ledger_num()
         envs = self.scp.get_latest_messages_send(slot_index)
-        txsets: Dict[bytes, object] = {}
+        # the sets go into the blob as they go over the wire: the cache
+        # hands out each one's packed bytes, whichever form it keeps
+        txsets: Dict[bytes, bytes] = {}
         qsets: Dict[bytes, SCPQuorumSet] = {}
         for e in envs:
             for v in Slot.statement_values(e.statement):
@@ -1243,9 +1247,9 @@ class Herder(SCPDriver):
                     h = xdr_getfield(StellarValue, v, "txSetHash")
                 except Exception:
                     continue
-                ts = self.pending_envelopes.get_tx_set(h)
-                if ts is not None:
-                    txsets[h] = ts
+                wire = self.pending_envelopes.get_tx_set_wire(h)
+                if wire is not None:
+                    txsets[h] = wire
             qh = Slot.companion_qset_hash(e.statement)
             if qh is not None:
                 qs = self.pending_envelopes.get_qset(qh)
@@ -1254,7 +1258,8 @@ class Herder(SCPDriver):
 
         blob = (
             pack_var_array_of(SCPEnvelope, envs)
-            + pack_var_array_of(TransactionSet, [t.to_xdr() for t in txsets.values()])
+            + len(txsets).to_bytes(4, "big")
+            + b"".join(txsets.values())
             + pack_var_array_of(SCPQuorumSet, list(qsets.values()))
         )
         fs.kill_point(KP_SCP_PERSIST_PRE, ctx=self.app.database)

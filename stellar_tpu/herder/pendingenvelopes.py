@@ -12,12 +12,14 @@ from __future__ import annotations
 from collections import OrderedDict
 from typing import Dict, List, Optional
 
+from ..ledger.headerframe import LedgerHeaderFrame
 from ..trace import tracer_of
 from ..util import xlog
 from ..xdr.ledger import StellarValue
 from ..xdr.overlay import MessageType
 from ..xdr.scp import SCPEnvelope, SCPQuorumSet
 from ..scp.quorum import qset_hash as compute_qset_hash
+from .txset import TxSetFrame
 
 log = xlog.logger("Herder")
 
@@ -55,7 +57,18 @@ class PendingEnvelopes:
         self.fetching: Dict[int, Dict[bytes, SCPEnvelope]] = {}
         self.pending: Dict[int, List[SCPEnvelope]] = {}
         self.qset_cache = _LRU(QSET_CACHE_SIZE)
+        # hash -> the TxSetFrame as it was put, or, once its slot has closed
+        # on this node (``slot_closed``), the set's wire bytes alone: a
+        # closed 1,000-tx set is ~32,000 objects the collector's full
+        # passes would walk at every boundary for as long as it is kept,
+        # and ~196 KB of bytes it never looks at
         self.txset_cache = _LRU(TXSET_CACHE_SIZE)
+        # hashes of the ledgers this node closed before its last closed
+        # one (the newest of them), up to sequence ``_closed_through``
+        self._closed_ledgers = _LRU(TXSET_CACHE_SIZE)
+        self._closed_through: Optional[int] = None
+        self.txset_deflations = 0
+        self.txset_reinflations = 0
         self._recheck_posted = False
         self._shut_down = False
         self._size_counter = app.metrics.new_counter(
@@ -117,7 +130,22 @@ class PendingEnvelopes:
         return self.qset_cache.get(qs_hash)
 
     def get_tx_set(self, ts_hash: bytes):
-        return self.txset_cache.get(ts_hash)
+        """The frame that was put while the set's slot is open.  Of a set
+        kept as bytes, an equal frame built for this call — the cache keeps
+        the bytes: whoever asks about a closed slot again asks again."""
+        entry = self.txset_cache.get(ts_hash)
+        if isinstance(entry, bytes):
+            self.txset_reinflations += 1
+            return TxSetFrame.from_wire(self.app.network_id, entry)
+        return entry
+
+    def get_tx_set_wire(self, ts_hash: bytes) -> Optional[bytes]:
+        """The packed ``TransactionSet`` of a cached set in either form:
+        for who sends or stores the set and never looks inside it."""
+        entry = self.txset_cache.get(ts_hash)
+        if entry is None or isinstance(entry, bytes):
+            return entry
+        return entry.wire_bytes()
 
     def peer_doesnt_have(self, msg_type: MessageType, item_id: bytes, peer) -> None:
         om = self.app.overlay_manager
@@ -268,13 +296,65 @@ class PendingEnvelopes:
             del self.processed[s]
 
     def slot_closed(self, slot_index: int) -> None:
-        """Drop all state at or below the closed slot (keep newer)."""
+        """Drop all state at or below the closed slot (keep newer), and keep
+        the sets of closed slots as their bytes."""
         self.erase_below(slot_index + 1)
+        self._deflate_closed_tx_sets()
+
+    def _deflate_closed_tx_sets(self) -> None:
+        """A set is proposed, validated and externalized on top of the
+        ledger its ``previous_ledger_hash`` names.  Once this node has
+        closed a ledger on top of that one, no open slot can ask for the
+        frames again: the cache keeps the set, under its hash and in its
+        place in the LRU, as ``wire_bytes``.  A set built on the last
+        closed ledger is the open slot's; one whose previous ledger this
+        node has not closed (a node that is behind) may be a coming slot's:
+        both stay the frames that were put.  Whatever order sets and
+        closes arrive in, a set of a closed slot is found here at the next
+        boundary at the latest."""
+        lcl = self.app.ledger_manager.get_last_closed_ledger_header()
+        if not self._note_closed_ledgers(lcl):
+            return
+        closed = self._closed_ledgers  # never holds the last closed ledger
+        entries = self.txset_cache.d
+        for ts_hash, entry in entries.items():
+            if not isinstance(entry, bytes) and entry.previous_ledger_hash in closed:
+                entries[ts_hash] = entry.wire_bytes()
+                self.txset_deflations += 1
+
+    def _note_closed_ledgers(self, lcl) -> bool:
+        """Every ledger closed since the last boundary, by hash; False if
+        there is none (``ledger_closed`` runs more than once a close).  A
+        boundary that comes after several closes (a catch-up's buffered
+        ledgers are closed back to back and announced once) reads the
+        headers in between from the database."""
+        seq = lcl.header.ledgerSeq - 1
+        known = self._closed_through
+        if seq == known:
+            return False
+        closed = self._closed_ledgers
+        if known is not None and seq > known + 1:
+            first = max(known + 1, seq - closed.cap)
+            for frame in LedgerHeaderFrame.load_range(self.app.database, first, seq - 1):
+                closed.put(frame.get_hash(), None)
+        closed.put(lcl.header.previousLedgerHash, None)
+        self._closed_through = seq
+        return True
 
     def dump_info(self) -> dict:
+        deflated = [e for e in self.txset_cache.d.values() if isinstance(e, bytes)]
         return {
             "pending": {s: len(v) for s, v in self.pending.items()},
             "fetching": {s: len(v) for s, v in self.fetching.items()},
             "qsets": len(self.qset_cache.d),
+            # entries of either form; of them frames, and sets of closed
+            # slots kept as bytes (and how many bytes)
             "txsets": len(self.txset_cache.d),
+            "txsets_inflated": len(self.txset_cache.d) - len(deflated),
+            "txsets_deflated": len(deflated),
+            "txset_bytes": sum(map(len, deflated)),
+            # monotonic: sets turned into bytes at a ledger boundary, and
+            # ``get_tx_set`` calls that had to build frames from bytes
+            "txset_deflations": self.txset_deflations,
+            "txset_reinflations": self.txset_reinflations,
         }

@@ -34,6 +34,9 @@ class TxSetFrame:
         self.previous_ledger_hash = previous_ledger_hash
         self.transactions: List[TransactionFrame] = list(transactions or [])
         self._hash: Optional[bytes] = None
+        # the packed ``TransactionSet``, once someone asked for it; see
+        # ``wire_bytes``
+        self._wire: Optional[bytes] = None
         self._triples_memo: Optional[list] = None
         # the accounts the set can touch, and whether ``warm_asked`` has
         # counted them: both dropped with the triples when the set changes
@@ -53,6 +56,15 @@ class TxSetFrame:
         ]
         return cls(xdr_set.previousLedgerHash, txs)
 
+    @classmethod
+    def from_wire(cls, network_id: bytes, wire: bytes) -> "TxSetFrame":
+        """The set that ``wire_bytes`` packed: an equal frame (the same
+        transactions in the same canonical order, so the same contents
+        hash) that already carries those bytes."""
+        frame = cls.from_xdr_set(network_id, TransactionSet.from_xdr(wire))
+        frame._wire = wire
+        return frame
+
     # -- canonical ordering & hash -----------------------------------------
     def sort_for_hash(self) -> None:
         txs = self.transactions
@@ -62,6 +74,7 @@ class TxSetFrame:
             # is unknown, so the verdict goes with the order
             txs[:] = ordered
             self._valid_on = None
+            self._wire = None
         self._hash = None
 
     def get_contents_hash(self) -> bytes:
@@ -76,6 +89,7 @@ class TxSetFrame:
 
     def _changed(self) -> None:
         self._hash = None
+        self._wire = None
         self._triples_memo = None
         self._account_ids_memo = None
         self._warm_counted = False
@@ -100,6 +114,21 @@ class TxSetFrame:
         return TransactionSet(
             self.previous_ledger_hash, [tx.envelope for tx in self.transactions]
         )
+
+    def wire_bytes(self) -> bytes:
+        """``self.to_xdr().to_xdr()``, packed once: the bytes a ``TX_SET``
+        message and the persisted SCP state carry, and what the herder's
+        tx-set cache keeps of a set whose slot has closed.  The hash, a
+        count and each envelope's own memoized bytes; dropped with the
+        contents hash and the verdict when the set changes."""
+        if self._wire is None:
+            self.sort_for_hash()
+            txs = self.transactions
+            self._wire = b"".join(
+                [self.previous_ledger_hash, len(txs).to_bytes(4, "big")]
+                + [tx.env_xdr() for tx in txs]
+            )
+        return self._wire
 
     # -- apply order (TxSetFrame.cpp:93-131) -------------------------------
     def sort_for_apply(self, tally: Optional[dict] = None) -> List[TransactionFrame]:

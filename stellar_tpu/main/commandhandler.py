@@ -263,6 +263,11 @@ class CommandHandler:
             # last proposed set, and what admission, the trim and the surge
             # filter did to chains since the node started (monotonic)
             info["tx_queue"] = app.herder.tx_queue_stats()
+            # envelopes waiting for their items, and the item caches: the
+            # tx-set cache's entries, how many of them are frames and how
+            # many are sets of closed slots kept as their wire bytes
+            # (``txset_deflations`` / ``txset_reinflations`` monotonic)
+            info["pending_envelopes"] = app.herder.pending_envelopes.dump_info()
         return {"info": info}
 
     def handle_metrics(self, q: dict) -> dict:

@@ -64,6 +64,9 @@ class PeerState:
     CLOSING = 4
 
 
+# a packed TX_SET StellarMessage is its discriminant and the packed set
+_TX_SET_TAG = int(MessageType.TX_SET).to_bytes(4, "big")
+
 # hot-path dispatch table (resolved per-instance via getattr)
 _DISPATCH = {
     MessageType.ERROR_MSG: "recv_error",
@@ -482,9 +485,13 @@ class Peer:
                 log.warning("could not store peer %s:%d: %s", ip, addr.port, e)
 
     def recv_get_tx_set(self, msg: StellarMessage) -> None:
-        ts = self.app.herder.get_tx_set(msg.value)
-        if ts is not None:
-            self.send_message(StellarMessage(MessageType.TX_SET, ts.to_xdr()))
+        # the set's packed bytes as the cache holds or makes them: a set
+        # of a closed slot is not built into frames to be sent
+        wire = self.app.herder.get_tx_set_wire(msg.value)
+        if wire is not None:
+            self.send_message(
+                StellarMessage(MessageType.TX_SET), body=_TX_SET_TAG + wire
+            )
         else:
             self.send_dont_have(MessageType.TX_SET, msg.value)
 
