@@ -312,6 +312,8 @@ def _env_float(name: str, default: float) -> float:
 
 _pool = None
 _pool_lock = threading.Lock()
+# threads a large host batch fans out over (_sodium_verify_loop)
+_SODIUM_WORKERS = min(8, os.cpu_count() or 1)
 
 
 def _sodium_verify_native(items: Sequence[VerifyTriple]) -> Optional[List[bool]]:
@@ -358,10 +360,8 @@ def _sodium_verify_loop(items: Sequence[VerifyTriple]) -> List[bool]:
     it still scales, minus the per-chunk Python overhead).  Single-core
     hosts and small batches keep the plain serial loop — byte-identical
     to the reference, per the r09 satellite contract."""
-    import os
-
     n = len(items)
-    workers = min(8, os.cpu_count() or 1)
+    workers = _SODIUM_WORKERS
     if n < 256 or workers < 2:
         return [sodium.verify_detached(sig, msg, pk) for pk, msg, sig in items]
     native = _sodium_verify_native(items)
@@ -410,6 +410,13 @@ class TpuSigBackend(SigBackend):
     _tracer = NULL_TRACER
     n_device_flushes = 0
     n_caller_items = None
+    # libsodium's own seconds (stats() "host_verify"): batches, items and
+    # the time inside _sodium_verify_loop wherever this backend verifies on
+    # the host — taken inside the ``sig.host_verify`` span, so none of the
+    # tracer is in them (and counted with the tracer off too)
+    n_host_verify_calls = 0
+    n_host_verify_items = 0
+    host_verify_s = 0.0
 
     def __init__(
         self,
@@ -471,8 +478,9 @@ class TpuSigBackend(SigBackend):
         self._wedged_until: dict = {}  # analysis: locked-by _wedge_lock
         self.n_latch_flips: dict = {}
         # verify_batch is called concurrently (async signature prewarm
-        # worker + the SCP crank); the latch read/write go under one
-        # small lock so callers see consistent state
+        # worker + the SCP crank); the latch read/write (and the three
+        # host_verify counters) go under one small lock so callers see
+        # consistent state
         self._wedge_lock = threading.Lock()
 
     # A wedged device dispatch must never stall a caller indefinitely —
@@ -608,6 +616,21 @@ class TpuSigBackend(SigBackend):
             self._note_caller(caller, "device", -n)
             self._note_caller(caller, "host", n)
 
+    def _host_verify(self, items: Sequence[VerifyTriple]) -> List[bool]:
+        """libsodium over ``items``, its seconds counted: what every
+        ``sig.host_verify`` span holds (the cutover here, the latch and the
+        stall in ``_guarded``)."""
+        t0 = time.perf_counter()
+        oks = _sodium_verify_loop(items)
+        dt = time.perf_counter() - t0
+        # callers verify concurrently (the prewarm worker, the crank): the
+        # three move together or a window's s / items is off
+        with self._wedge_lock:
+            self.host_verify_s += dt
+            self.n_host_verify_calls += 1
+            self.n_host_verify_items += len(items)
+        return oks
+
     def verify_batch(
         self, items: Sequence[VerifyTriple], caller: str = CALLER_CLOSE
     ) -> List[bool]:
@@ -617,7 +640,7 @@ class TpuSigBackend(SigBackend):
             with self._tracer.span(
                 "sig.host_verify", items=len(items), reason="cutover"
             ):
-                return _sodium_verify_loop(items)
+                return self._host_verify(items)
         self._note_caller(caller, "device", len(items))
         return self._device_flush(
             "verify",
@@ -625,7 +648,7 @@ class TpuSigBackend(SigBackend):
             caller,
             True,
             lambda: self._verifier.verify(items),
-            lambda: _sodium_verify_loop(items),
+            lambda: self._host_verify(items),
         )
 
     def _device_flush(
@@ -707,6 +730,12 @@ class TpuSigBackend(SigBackend):
         # host-assist items (a share the verifier peels off for libsodium
         # while the device works; off as shipped) are counted "device" here
         s["caller_items"] = {k: dict(v) for k, v in (self.n_caller_items or {}).items()}
+        # s / items: what the host's verify costs a signature here
+        s["host_verify"] = {
+            "calls": self.n_host_verify_calls,
+            "items": self.n_host_verify_items,
+            "s": self.host_verify_s,
+        }
         return s
 
 

@@ -129,12 +129,14 @@ class Herder(SCPDriver):
         self.received_transactions: List[Dict[bytes, TxMap]] = [{} for _ in range(4)]
         # ingest-rate fast lane over the generations (ISSUE r20
         # satellite): every pending tx hash (duplicate checks go through
-        # ONE set instead of a per-generation probe) and a per-account
+        # ONE dict instead of a per-generation probe), each with the
+        # tracer's clock at its admission (the wait for its ledger:
+        # ``tx_queue_stats``), and a per-account
         # cache of (total fees, highest seq) summed ACROSS generations.
         # Aging only moves txs between generations — the cross-generation
         # aggregate is invariant under it — so the cache is dropped only
         # where txs actually leave the queue (_remove_received_txs).
-        self._pending_tx_ids: set = set()
+        self._pending_tx_ids: Dict[bytes, float] = {}
         self._acct_agg: Dict[bytes, List[int]] = {}
 
         self.tracking: Optional[ConsensusData] = None
@@ -199,11 +201,16 @@ class Herder(SCPDriver):
         # admitted behind a pending one of their account, transactions the
         # trigger's trim and its surge filter took out of a proposed set;
         # and the longest per-account chain of the set the last trigger
-        # proposed
+        # proposed; the transactions of externalized sets, and of those
+        # that were pending here the seconds from admission to their
+        # ledger's close, on the tracer's clock
         self.n_chain_txs_admitted = 0
         self.n_surge_cut = 0
         self.n_trimmed = 0
         self.last_set_longest_chain = 0
+        self.n_closed = 0
+        self.pending_wait_s = 0.0
+        self.pending_wait_max_s = 0.0
         # lazy-deletion max-heap (negated slots) over scp_slot_buckets:
         # the at-cap evict decision is O(log n) per envelope instead of a
         # max() scan over 1024 keys — the scan would sit on exactly the
@@ -243,6 +250,10 @@ class Herder(SCPDriver):
         # pre-r20 — under flood this is the cheapest reject in the node
         # and the meter is the only observable of re-flooded traffic
         self.m_tx_duplicate = m.new_meter(("herder", "tx", "duplicate"), "tx")
+        # admission to the closed ledger, milliseconds, one update a
+        # transaction that was pending here when its set externalized:
+        # ``/metrics`` carries p50 / p95 of what ``tx_queue`` sums
+        self.h_pending_wait = m.new_histogram(("herder", "tx", "pending-wait"))
         # stall-probe bookkeeping (see _note_quorum_ahead): last local
         # consensus progress and last probe, on the app clock; the
         # quorum-member set is cached keyed by local qset hash
@@ -668,7 +679,10 @@ class Herder(SCPDriver):
         finally:
             self.scp_close_s += time.perf_counter() - t0
 
-        self._remove_received_txs(externalized_set.transactions)
+        self._note_closed(
+            len(externalized_set.transactions),
+            self._remove_received_txs(externalized_set.transactions),
+        )
 
         # rebroadcast generation-1 leftovers in apply order
         om = self.app.overlay_manager
@@ -732,7 +746,19 @@ class Herder(SCPDriver):
     # ------------------------------------------------------------------
     # transaction queue
     # ------------------------------------------------------------------
-    def recv_transaction(self, tx) -> str:
+    def recv_transaction(self, tx, tracer=None) -> str:
+        """``tracer`` records ``herder.recv_transaction`` with
+        ``tx.check_valid`` under it: the ingest plane's own for a sampled
+        entry (``INGEST_SAMPLE_STRIDE``), and nobody's for the others —
+        not even the no-op's calls, at a rate of one a transaction."""
+        if tracer is None:
+            return self._recv_transaction(tx, None)
+        with tracer.span("herder.recv_transaction") as sp:
+            status = self._recv_transaction(tx, tracer)
+            tracer.end(sp, status=status)
+        return status
+
+    def _recv_transaction(self, tx, tracer) -> str:
         acc = tx.source_bytes()
         tx_id = tx.get_full_hash()
 
@@ -755,7 +781,12 @@ class Herder(SCPDriver):
             self._acct_agg[acc] = agg
         tot_fee = tx.get_fee() + agg[0]
 
-        if not tx.check_valid(self.app, agg[1]):
+        # (a span the caller's ends if the check raises)
+        valid_sp = None if tracer is None else tracer.begin("tx.check_valid")
+        valid = tx.check_valid(self.app, agg[1])
+        if valid_sp is not None:
+            tracer.end(valid_sp)
+        if not valid:
             return TX_STATUS_ERROR
 
         if tx.signing_account.get_balance_above_reserve(self.ledger_manager) < tot_fee:
@@ -763,7 +794,7 @@ class Herder(SCPDriver):
             return TX_STATUS_ERROR
 
         self.received_transactions[0].setdefault(acc, TxMap()).add_tx(tx)
-        self._pending_tx_ids.add(tx_id)
+        self._pending_tx_ids[tx_id] = self.app.tracer.now()
         if agg[1]:
             self.n_chain_txs_admitted += 1
         agg[0] += tx.get_fee()
@@ -795,8 +826,12 @@ class Herder(SCPDriver):
     def tx_queue_stats(self) -> dict:
         """``/info`` ``tx_queue``: the pending transactions now, by
         generation and by account, the longest per-account chain of the set
-        the last trigger proposed, and what admission, the trim and the
-        surge filter did since the node started."""
+        the last trigger proposed, what admission, the trim and the surge
+        filter did since the node started, and how long a transaction
+        waits for its ledger: ``closed`` transactions of externalized sets,
+        and of those that were pending here ``pending_wait_s`` /
+        ``pending_wait_max_s`` from admission to the close (the tracer's
+        clock; ``/metrics`` ``herder.tx.pending-wait`` has p50 / p95)."""
         gens = self.received_transactions
         return {
             "pending": len(self._pending_tx_ids),
@@ -808,6 +843,9 @@ class Herder(SCPDriver):
             "chain_txs_admitted": self.n_chain_txs_admitted,
             "surge_cut": self.n_surge_cut,
             "trimmed": self.n_trimmed,
+            "closed": self.n_closed,
+            "pending_wait_s": self.pending_wait_s,
+            "pending_wait_max_s": self.pending_wait_max_s,
         }
 
     def get_max_seq_in_pending_txs(self, acc: PublicKey) -> int:
@@ -818,7 +856,24 @@ class Herder(SCPDriver):
                 high = max(high, txmap.max_seq)
         return high
 
-    def _remove_received_txs(self, drop_txs) -> None:
+    def _note_closed(self, n_txs: int, stamps: List[float]) -> None:
+        """An externalized set of ``n_txs`` left the queue; ``stamps``: the
+        admission times of those that were pending here.  One that came
+        only inside a peer's set was never pending and adds no wait, and
+        neither does one the trim removed."""
+        self.n_closed += n_txs
+        now = self.app.tracer.now()
+        for at in stamps:
+            wait = now - at
+            self.pending_wait_s += wait
+            if wait > self.pending_wait_max_s:
+                self.pending_wait_max_s = wait
+            self.h_pending_wait.update(wait * 1000.0)
+
+    def _remove_received_txs(self, drop_txs) -> List[float]:
+        """-> the admission stamps of those of ``drop_txs`` that were
+        pending."""
+        stamps = []
         for gen in self.received_transactions:
             if not gen:
                 continue
@@ -829,7 +884,9 @@ class Herder(SCPDriver):
                 if txmap is None:
                     continue
                 if txmap.transactions.pop(tx.get_full_hash(), None) is not None:
-                    self._pending_tx_ids.discard(tx.get_full_hash())
+                    at = self._pending_tx_ids.pop(tx.get_full_hash(), None)
+                    if at is not None:
+                        stamps.append(at)
                     if not txmap.transactions:
                         del gen[acc]
                     else:
@@ -841,6 +898,7 @@ class Herder(SCPDriver):
         # recomputed lazily at the next submission from each account
         for tx in drop_txs:
             self._acct_agg.pop(tx.source_bytes(), None)
+        return stamps
 
     # ------------------------------------------------------------------
     # SCP envelope queue

@@ -63,20 +63,30 @@ from ..xdr.txs import TransactionResultCode
 # admission plane adds the reference's overload answer.
 INGEST_STATUS_TRY_AGAIN = "TRY_AGAIN_LATER"
 
+# Sampled admission spans: a flush hands the tracer to one entry in
+# INGEST_SAMPLE_STRIDE, chosen by its arrival index (no clock, no random
+# number: the same entries on every run), as the apply loop hands it to one
+# transaction in ``tx/frame.py`` ``TX_SAMPLE_STRIDE``; a sampled entry
+# records ``ingest.collect`` and, through the herder,
+# ``herder.recv_transaction`` with ``tx.check_valid`` under it.  A power of
+# two: the test is ``seq & (INGEST_SAMPLE_STRIDE - 1)``.
+INGEST_SAMPLE_STRIDE = 64
+
 
 class _Entry:
     """One queued submission: the tx plus its decision callback (the
     overlay floods / the HTTP handler answers only once the batch
     verdict lands)."""
 
-    __slots__ = ("tx", "on_status", "status", "fee_ratio", "seq")
+    __slots__ = ("tx", "on_status", "status", "fee_ratio", "seq", "at")
 
-    def __init__(self, tx, on_status, fee_ratio, seq):
+    def __init__(self, tx, on_status, fee_ratio, seq, at):
         self.tx = tx
         self.on_status = on_status
         self.status: Optional[str] = None
         self.fee_ratio = fee_ratio
         self.seq = seq  # arrival index: deterministic surge tie-break
+        self.at = at  # arrival on the tracer's clock: the wait for a flush
 
 
 class _TokenBucket:
@@ -118,6 +128,20 @@ class IngestPlane:
         self._arrivals = 0
         self.n_submitted = 0
         self.submit_s = 0.0
+        # where an admission's time goes, monotonic since the node started
+        # (``stats()``; counted with the tracer off too): entries taken by
+        # flushes, the seconds inside the gate (``_admit``), a flush's
+        # candidate triples, keys and cache peek, its verify of the misses
+        # with the latch, and its ``Herder.recv_transaction`` calls — all
+        # on ``time.perf_counter``, as ``submit_s`` — and the entries' wait
+        # from the gate to the flush that took them, on the tracer's clock
+        self.n_flushed = 0
+        self.gate_s = 0.0
+        self.collect_s = 0.0
+        self.verify_s = 0.0
+        self.herder_s = 0.0
+        self.queue_wait_s = 0.0
+        self.queue_wait_max_s = 0.0
         self._buckets: Dict[bytes, _TokenBucket] = {}
         self._timer = VirtualTimer(app.clock)
         self._timer_armed = False
@@ -131,7 +155,6 @@ class IngestPlane:
         self.m_reject_surge = m.new_meter(("ingest", "reject", "surge"), "tx")
         self.m_flush = m.new_meter(("ingest", "batch", "flush"), "batch")
         self.h_batch_size = m.new_histogram(("ingest", "batch", "size"))
-        self.h_occupancy = m.new_histogram(("ingest", "batch", "occupancy"))
         self.c_cache_hits = m.new_counter(("ingest", "verify", "cache-hits"))
         self.c_verified = m.new_counter(("ingest", "verify", "triples"))
 
@@ -148,7 +171,9 @@ class IngestPlane:
             if on_status is not None:
                 on_status(status)
             return status
+        t0 = time.perf_counter()
         entry = self._admit(tx, on_status)
+        self.gate_s += time.perf_counter() - t0
         if entry is None:
             return INGEST_STATUS_TRY_AGAIN
         if len(self._queue) >= self.batch_max:
@@ -161,14 +186,19 @@ class IngestPlane:
         """Queue + flush immediately (the ``/tx`` and LoadGenerator
         edges need a synchronous answer); everything already queued
         rides the same dispatch."""
-        # the admission edge is counted, not spanned (a span a
-        # transaction would fill the tracer's ring): stats()["submit_s"]
-        # over ["submitted"] is the edge's cost per transaction
+        # the edge itself is counted, not spanned: stats()["submit_s"]
+        # over ["submitted"] is its cost per transaction, and "phase_s"
+        # says where inside it that went.  What is spanned is the flush:
+        # one ``ingest.flush`` and, for its misses, one ``sig.host_verify``
+        # a flush — a transaction, at this edge, so a third and a fourth
+        # would fill the tracer's ring: the spans under the flush are
+        # sampled (INGEST_SAMPLE_STRIDE)
         t0 = time.perf_counter()
         try:
             if not self.enabled or self._shutting_down:
                 return self.app.herder.recv_transaction(tx)
             entry = self._admit(tx, None)
+            self.gate_s += time.perf_counter() - t0
             if entry is None:
                 return INGEST_STATUS_TRY_AGAIN
             if entry.status is None:
@@ -186,7 +216,7 @@ class IngestPlane:
             return [self.app.herder.recv_transaction(tx) for tx in txs]
         entries = []
         for tx in txs:
-            e = _Entry(tx, None, 0.0, self._arrivals)
+            e = _Entry(tx, None, 0.0, self._arrivals, self.app.tracer.now())
             self._arrivals += 1
             self._queue.append(e)
             entries.append(e)
@@ -215,7 +245,9 @@ class IngestPlane:
             if on_status is not None:
                 on_status(INGEST_STATUS_TRY_AGAIN)
             return None
-        entry = _Entry(tx, on_status, self._fee_ratio(tx), self._arrivals)
+        entry = _Entry(
+            tx, on_status, self._fee_ratio(tx), self._arrivals, self.app.tracer.now()
+        )
         self._arrivals += 1
         if self.surge_high_water > 0:
             backlog = self.app.herder.num_pending_txs() + len(self._queue)
@@ -284,8 +316,13 @@ class IngestPlane:
             return
         self.m_flush.mark()
         self.h_batch_size.update(len(batch))
-        self.h_occupancy.update(len(batch) / float(max(1, self.batch_max)))
-        with self.app.tracer.span("ingest.flush") as sp:
+        self.n_flushed += len(batch)
+        tracer = self.app.tracer
+        skip = INGEST_SAMPLE_STRIDE - 1
+        clock = time.perf_counter
+        with tracer.span("ingest.flush") as sp:
+            now = tracer.now()
+            t0 = clock()
             db = self.app.database
             cache = self._cache
             # per-entry candidate triples; triple-less txs pass through (the
@@ -294,6 +331,14 @@ class IngestPlane:
             keys: List[bytes] = []
             triples = []
             for e in batch:
+                wait = now - e.at
+                self.queue_wait_s += wait
+                if wait > self.queue_wait_max_s:
+                    self.queue_wait_max_s = wait
+                # one entry in INGEST_SAMPLE_STRIDE records its own share
+                # of the collect (a span the flush's end unwinds if the
+                # keys raise)
+                collect_sp = None if e.seq & skip else tracer.begin("ingest.collect")
                 try:
                     cand = e.tx.candidate_signature_pairs(db)
                 except Exception:
@@ -302,8 +347,12 @@ class IngestPlane:
                 triples.extend(cand)
                 keys.extend(cache.key_for(pk, sig, msg) for pk, msg, sig in cand)
                 slices.append((e, start, len(triples)))
+                if collect_sp is not None:
+                    tracer.end(collect_sp, triples=len(cand))
 
             cached = cache.peek_many(keys)
+            t1 = clock()
+            self.collect_s += t1 - t0
             miss_idx = [i for i, c in enumerate(cached) if c is None]
             self.c_cache_hits.inc(len(keys) - len(miss_idx))
             self.c_verified.inc(len(miss_idx))
@@ -320,6 +369,7 @@ class IngestPlane:
                 )
                 for i, ok in zip(miss_idx, fresh):
                     cached[i] = ok
+                self.verify_s += clock() - t1
 
             n_shed = 0
             herder = self.app.herder
@@ -334,14 +384,18 @@ class IngestPlane:
                 else:
                     if end == start:
                         self.m_passthrough.mark()
-                    e.status = herder.recv_transaction(e.tx)
+                    h0 = clock()
+                    # a sampled entry hands the herder the tracer
+                    if e.seq & skip:
+                        e.status = herder.recv_transaction(e.tx)
+                    else:
+                        e.status = herder.recv_transaction(e.tx, tracer)
+                    self.herder_s += clock() - h0
                     if e.status == "PENDING":
                         self.m_admit.mark()
                 if e.on_status is not None:
                     e.on_status(e.status)
-            self.app.tracer.end(
-                sp, batch=len(batch), triples=len(keys), shed=n_shed
-            )
+            tracer.end(sp, batch=len(batch), triples=len(keys), shed=n_shed)
 
     # ------------------------------------------------------------------
     # lifecycle / introspection
@@ -368,11 +422,27 @@ class IngestPlane:
             "flushes": flushes,
             "batch_size_mean": self.h_batch_size.mean,
             "batch_size_p95": self.h_batch_size.percentile(0.95),
-            "occupancy_mean": self.h_occupancy.mean,
+            "occupancy_mean": self.h_batch_size.mean / max(1, self.batch_max),
             "admitted": self.m_admit.count,
             # submit_sync calls and the seconds spent inside them
             "submitted": self.n_submitted,
             "submit_s": self.submit_s,
+            # entries taken by flushes, and where their time went: what
+            # submit_s holds beyond collect + verify + herder (and the gate)
+            # is the plane's own — meters, histograms, the timer, the span,
+            # status delivery
+            "flushed": self.n_flushed,
+            "phase_s": {
+                "gate": self.gate_s,
+                "collect": self.collect_s,
+                "verify": self.verify_s,
+                "herder": self.herder_s,
+            },
+            # from an entry's gate to the start of its flush, on the
+            # tracer's clock: ~0 at submit_sync, up to the deadline at
+            # the overlay edge
+            "queue_wait_s": self.queue_wait_s,
+            "queue_wait_max_s": self.queue_wait_max_s,
             "passthrough": self.m_passthrough.count,
             "rejects": {
                 "badsig": self.m_reject_badsig.count,

@@ -260,8 +260,9 @@ class CommandHandler:
             info["scp"] = app.herder.scp_stats()
             # the herder's transaction queue: what is pending now, by
             # generation and account, the longest per-account chain of the
-            # last proposed set, and what admission, the trim and the surge
-            # filter did to chains since the node started (monotonic)
+            # last proposed set, what admission, the trim and the surge
+            # filter did to chains since the node started, and how long the
+            # transactions of closed ledgers had been pending (monotonic)
             info["tx_queue"] = app.herder.tx_queue_stats()
             # envelopes waiting for their items, and the item caches: the
             # tx-set cache's entries, how many of them are frames and how
@@ -575,10 +576,17 @@ class CommandHandler:
         }
 
     def handle_ingest(self, q: dict) -> dict:
-        """The admission plane's counters (ingest/plane.py): batch-size /
-        occupancy histogram stats, per-reason shed counts (badsig /
-        ratelimit / surge), verify cache-hit split, rate-limiter
-        occupancy."""
+        """The admission plane's counters (ingest/plane.py): batch-size
+        histogram stats and ``occupancy_mean`` (the mean batch over
+        ``batch_max``), per-reason shed counts (badsig / ratelimit /
+        surge), verify cache-hit split, rate-limiter occupancy; and where
+        a submission's time goes on this node, monotonic since it started:
+        ``submitted`` / ``submit_s`` (the synchronous edge), ``flushed``
+        (entries taken by ``flushes``), ``phase_s`` (seconds in the
+        ``gate``, a flush's ``collect`` of triples and cached verdicts,
+        its ``verify`` of the misses, its ``herder`` calls) and
+        ``queue_wait_s`` / ``queue_wait_max_s`` (an entry's wait from the
+        gate to its flush, on the tracer's clock)."""
         ing = self.app.ingest
         if ing is None:
             return {"status": "not-built"}

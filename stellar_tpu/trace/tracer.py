@@ -180,11 +180,14 @@ class Tracer:
         # deterministic-test clock: only a VIRTUAL clock's now() is used
         # directly; REAL mode falls back to time.monotonic (wall time can
         # step backwards across NTP slews — a trace must not)
+        # ``now`` is the spans' clock, and is read with the tracer off too:
+        # a counter that sits beside the spans (a wait in a queue) is taken
+        # on it
         if clock is not None and getattr(clock, "mode", None) == "virtual":
-            self._now = clock.now
+            self.now = clock.now
             self.clock_name = "virtual"
         else:
-            self._now = time.monotonic
+            self.now = time.monotonic
             self.clock_name = "monotonic"
 
     # -- recording ----------------------------------------------------------
@@ -207,7 +210,7 @@ class Tracer:
             psid = None
         span = Span(
             name,
-            self._now(),
+            self.now(),
             threading.get_ident(),
             attrs or None,
             next(self._sids),
@@ -264,7 +267,7 @@ class Tracer:
         """Complete a span from ``begin`` (None-safe, double-end-safe)."""
         if span is None or span.end is not None:
             return
-        span.end = self._now()
+        span.end = self.now()
         _unwind(self._stack(), span)
         if attrs:
             if span.attrs:

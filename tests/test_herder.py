@@ -168,6 +168,98 @@ class TestTxQueueAging:
         assert load_or_none(app, dest) is None
 
 
+class TestPendingWait:
+    """``tx_queue`` ``closed`` / ``pending_wait_s`` / ``pending_wait_max_s``
+    (PR 51): a transaction is stamped where ``recv_transaction`` answers
+    PENDING, on the tracer's clock (the virtual one here), and its wait is
+    added where its externalized set leaves the queue."""
+
+    @pytest.mark.parametrize(
+        "case", ["closed", "closed-untraced", "two-arrivals", "trimmed", "never-pending"]
+    )
+    def test_wait_from_admission_to_the_closed_ledger(self, clock, case):
+        from stellar_tpu.herder.herder import TxMap
+
+        cfg = T.get_test_config(0)
+        cfg.MANUAL_CLOSE = False
+        cfg.TRACE_ENABLED = case != "closed-untraced"
+        app = Application(clock, cfg, new_db=True)
+        app.herder = h = Herder(app)
+        h.bootstrap()
+        lm = app.ledger_manager
+        # the first ledgers close empty: they add nothing
+        assert clock.crank_until(lambda: lm.get_last_closed_ledger_num() >= 2, 30)
+        s0 = h.tx_queue_stats()
+        assert (s0["closed"], s0["pending_wait_s"], s0["pending_wait_max_s"]) == (0, 0.0, 0.0)
+        hist = app.metrics.new_histogram(("herder", "tx", "pending-wait"))
+        assert hist.count == 0
+
+        root = T.root_key_for(app)
+        seq = root_seq(app)
+        dests = [T.get_account("wait-%d" % i) for i in range(2)]
+        txs = [
+            T.tx_from_ops(app, root, seq + 1 + i, [T.create_account_op(d, 10_000_000_000)])
+            for i, d in enumerate(dests)
+        ]
+        closed_at = []
+        externalized = h.value_externalized
+
+        def spy(slot, value):
+            externalized(slot, value)
+            closed_at.append(clock.now())
+
+        h.value_externalized = spy
+        start = lm.get_last_closed_ledger_num()
+        arrivals = []
+
+        def admit(tx):
+            arrivals.append(clock.now())
+            assert h.recv_transaction(tx) == TX_STATUS_PENDING
+
+        if case == "two-arrivals":
+            admit(txs[0])
+            clock.set_current_virtual_time(clock.now() + 0.25)
+            admit(txs[1])
+        elif case == "trimmed":
+            # the second no longer follows once the first is gone: the
+            # trigger's trim removes it, and it never reaches a ledger
+            admit(txs[0])
+            admit(txs[1])
+            h._remove_received_txs([txs[0]])
+        elif case == "never-pending":
+            # a transaction this node first sees inside the set that
+            # externalizes: in the queue's maps, never stamped
+            acc = txs[0].get_source_id().value
+            h.received_transactions[0].setdefault(acc, TxMap()).add_tx(txs[0])
+        else:
+            admit(txs[0])
+        assert clock.crank_until(lambda: lm.get_last_closed_ledger_num() > start, 30)
+        s = h.tx_queue_stats()
+        assert s["pending"] == 0
+
+        if case == "trimmed":
+            assert s["trimmed"] == 1 and load_or_none(app, dests[1]) is None
+            want_closed, waits = 0, []
+        elif case == "never-pending":
+            assert load_or_none(app, dests[0]) is not None
+            want_closed, waits = 1, []
+        else:
+            n = len(arrivals)
+            assert all(load_or_none(app, d) is not None for d in dests[:n])
+            want_closed, waits = n, [closed_at[0] - at for at in arrivals]
+            # a ledger's wait on this network: whole virtual seconds
+            assert waits[0] >= 1.0
+        assert s["closed"] == want_closed
+        assert s["pending_wait_s"] == sum(waits)
+        assert s["pending_wait_max_s"] == max(waits, default=0.0)
+        assert hist.count == len(waits)
+        if waits:
+            assert hist.max_value == max(waits) * 1000.0
+        # /info carries the block as the herder gives it
+        assert set(s0) == set(s) >= {"closed", "pending_wait_s", "pending_wait_max_s", "trimmed"}
+        assert bool(app.tracer.spans()) == cfg.TRACE_ENABLED
+
+
 class TestTxSetValidity:
     """Ported from the reference's 'txset' case (HerderTests.cpp:162-316):
     one funded source account, 2 destination chains x 5 txs; each section

@@ -78,7 +78,7 @@ def _payment(app, n, seq, fee=None, corrupt=False):
 def test_flush_on_size_trigger(clock):
     """INGEST_BATCH_MAX submissions close the batch synchronously: every
     queued submitter's callback fires with the herder's verdict, and the
-    occupancy histogram reads a full batch."""
+    occupancy reads a full batch."""
     app = make_app(
         clock, 60, INGEST_BATCH_MAX=4, INGEST_BATCH_DEADLINE_MS=60_000
     )
@@ -335,6 +335,113 @@ def test_replay_edge_skips_admission(clock):
         txs = [_payment(app, "rp-%d" % i, seq + 1 + i) for i in range(4)]
         assert app.ingest.submit_replay(txs) == [TX_STATUS_PENDING] * 4
         assert app.ingest.stats()["rejects"]["ratelimit"] == 0
+    finally:
+        app.graceful_stop()
+
+
+# -- admission measured from inside (PR 51) ---------------------------------
+
+
+def _phase_sum(s) -> float:
+    return sum(s["phase_s"].values())
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["traced", "untraced"])
+def test_phase_counters_over_a_mixed_run(clock, traced):
+    """``flushed`` is the sum of the batch sizes whatever edge filled the
+    batch, a phase's seconds are inside the edge that ran it (every flush
+    of the synchronous stretch runs inside ``submit_sync``, so the four
+    phases fit in ``submit_s`` there; ``submit`` and ``submit_replay`` move
+    the phases and not ``submit_s``), a shed entry never reaches the
+    herder's phase — and all of it counts with the tracer off."""
+    app = make_app(
+        clock, 160 + traced, INGEST_BATCH_MAX=4, INGEST_BATCH_DEADLINE_MS=60_000,
+        TRACE_ENABLED=traced,
+    )
+    try:
+        plane = app.ingest
+        seq = _root_seq(app)
+        PubKeyUtils.clear_verify_sig_cache()  # the other case's verdicts
+        s0 = plane.stats()
+        assert s0["flushed"] == 0 and _phase_sum(s0) == 0.0
+
+        # the synchronous edge: six flushes of one, the last of them shed
+        for i in range(5):
+            assert plane.submit_sync(_payment(app, "ph-%d" % i, seq + 1 + i)) == TX_STATUS_PENDING
+        s1 = plane.stats()
+        assert s1["flushed"] == 5 and s1["flushes"] == 5
+        assert all(v > 0.0 for v in s1["phase_s"].values()), s1["phase_s"]
+        assert _phase_sum(s1) <= s1["submit_s"]
+        assert plane.submit_sync(_payment(app, "ph-bad", seq + 6, corrupt=True)) == TX_STATUS_ERROR
+        s2 = plane.stats()
+        assert s2["flushed"] == 6 and s2["rejects"]["badsig"] == 1
+        assert s2["phase_s"]["herder"] == s1["phase_s"]["herder"]
+        assert s2["phase_s"]["collect"] > s1["phase_s"]["collect"]
+        assert s2["phase_s"]["verify"] > s1["phase_s"]["verify"]
+        assert _phase_sum(s2) <= s2["submit_s"]
+
+        # the overlay edge: a size-triggered batch of four, then three that
+        # a synchronous submission takes with it
+        for i in range(4):
+            plane.submit(_payment(app, "ph-o%d" % i, seq + 6 + i))
+        for i in range(3):
+            plane.submit(_payment(app, "ph-p%d" % i, seq + 10 + i))
+        assert plane.submit_sync(_payment(app, "ph-q", seq + 13)) == TX_STATUS_PENDING
+        # the replay edge: four and two
+        assert plane.submit_replay(
+            [_payment(app, "ph-r%d" % i, seq + 14 + i) for i in range(6)]
+        ) == [TX_STATUS_PENDING] * 6
+        s3 = plane.stats()
+        sizes = [1] * 6 + [4, 4, 4, 2]
+        assert s3["flushes"] == len(sizes) and s3["flushed"] == sum(sizes)
+        assert s3["batch_size_mean"] * s3["flushes"] == pytest.approx(s3["flushed"])
+        assert s3["occupancy_mean"] == pytest.approx(s3["batch_size_mean"] / 4)
+        assert s3["admitted"] == s3["flushed"] - 1
+        assert all(s3["phase_s"][k] > s2["phase_s"][k] for k in s3["phase_s"]), s3["phase_s"]
+        assert s3["submitted"] == 7
+        # no key of the block went away
+        assert set(s0) == set(s3)
+        spans = app.tracer.spans()
+        if traced:
+            assert [s.name for s in spans].count("ingest.flush") == len(sizes)
+        else:
+            assert spans == []
+    finally:
+        app.graceful_stop()
+
+
+@pytest.mark.parametrize("edge", ["deadline", "sync", "sync-takes-queued", "replay"])
+def test_queue_wait_is_read_on_the_virtual_clock(clock, edge):
+    """An entry left to the deadline waited the deadline, exactly; one the
+    synchronous edge flushed at once, or the replay edge, waited 0; queued
+    entries a synchronous submission takes with it waited since their own
+    arrival."""
+    app = make_app(clock, 164, INGEST_BATCH_DEADLINE_MS=50)
+    try:
+        plane = app.ingest
+        seq = _root_seq(app)
+        clock.crank_for(1.0)  # away from 0: a wait is a difference
+        at = clock.now()
+        if edge == "deadline":
+            assert plane.submit(_payment(app, "qw-0", seq + 1)) is None
+            clock.crank_for(0.2)
+            want = (at + plane.deadline_s) - at
+            assert want == pytest.approx(0.05)
+        elif edge == "sync":
+            assert plane.submit_sync(_payment(app, "qw-0", seq + 1)) == TX_STATUS_PENDING
+            want = 0.0
+        elif edge == "sync-takes-queued":
+            assert plane.submit(_payment(app, "qw-0", seq + 1)) is None
+            clock.set_current_virtual_time(at + 0.02)
+            assert plane.submit_sync(_payment(app, "qw-1", seq + 2)) == TX_STATUS_PENDING
+            want = clock.now() - at
+            assert 0.0 < want < plane.deadline_s
+        else:
+            assert plane.submit_replay([_payment(app, "qw-0", seq + 1)]) == [TX_STATUS_PENDING]
+            want = 0.0
+        s = plane.stats()
+        assert s["queued"] == 0 and s["flushes"] == 1
+        assert s["queue_wait_s"] == want and s["queue_wait_max_s"] == want
     finally:
         app.graceful_stop()
 

@@ -277,10 +277,17 @@ def attrs_of(led, name):
 
 
 def test_tx_queue_counters_against_the_stream(world):
-    chained = cut = 0
+    chained = cut = closed = 0
+    waited = 0.0
     for led in world.ledgers:
         chained += led.chained
         cut += len(led.at_trigger) - len(led.closed)
+        # PR 51: every closed transaction was pending here first, and each
+        # ledger's added their waits (the real clock: no two runs alike)
+        closed += len(led.closed)
+        assert led.stats["closed"] == closed
+        assert waited < led.stats["pending_wait_s"] and 0.0 < led.stats["pending_wait_max_s"] <= led.stats["pending_wait_s"]
+        waited = led.stats["pending_wait_s"]
         left = chains(led.left)
         assert led.stats["pending"] == len(led.left) == sum(led.stats["generations"])
         assert led.stats["accounts_pending"] == len(left)
@@ -292,6 +299,7 @@ def test_tx_queue_counters_against_the_stream(world):
     assert world.info["tx_queue"] == world.ledgers[-1].stats
     assert list(world.info["tx_queue"]) == [
         "pending", "accounts_pending", "longest_chain", "generations", "chain_txs_admitted", "surge_cut", "trimmed",
+        "closed", "pending_wait_s", "pending_wait_max_s",
     ]
 
 
@@ -389,7 +397,11 @@ def test_the_counters_count_with_the_tracer_off(world, tmp_path):
         for led in world.ledgers[:3]:
             wl.step(True)
             assert wl.drain_spans() == []
-            assert wl.herder.tx_queue_stats() == led.stats
+            stats = wl.herder.tx_queue_stats()
+            # the waits are seconds of this run's own clock
+            waits = {k: stats.pop(k) for k in ("pending_wait_s", "pending_wait_max_s")}
+            assert stats == {k: v for k, v in led.stats.items() if k not in waits}
+            assert all(v > 0.0 for v in waits.values())
     finally:
         wl.close()
 

@@ -727,6 +727,10 @@ class TestCloseFromInside:
         assert mixed <= budget(1000) + 200
 
 
+# what one entry in INGEST_SAMPLE_STRIDE records under its flush (PR 51)
+SAMPLED_ADMISSION = ("ingest.collect", "herder.recv_transaction", "tx.check_valid")
+
+
 class TestFrontDoorCounters:
     def test_submit_sync_counts_and_records_no_span_of_its_own(self, clock):
         from stellar_tpu.crypto.keys import SecretKey
@@ -755,11 +759,105 @@ class TestFrontDoorCounters:
             assert after["submitted"] - before["submitted"] == n
             assert after["submit_s"] > before["submit_s"]
             names = [s.name for s in app.tracer.spans()]
-            # what the edge recorded before this PR, and nothing else
-            assert set(names) <= {"ingest.flush", "sig.flush", "sig.host_verify"}, names
+            # what the edge recorded before this PR, and since PR 51 the
+            # three sampled spans of the one entry in 64 (the first here)
+            whole = {"ingest.flush", "sig.flush", "sig.host_verify"}
+            assert set(names) <= whole | set(SAMPLED_ADMISSION), names
             assert names.count("ingest.flush") == n
+            assert [names.count(k) for k in SAMPLED_ADMISSION] == [1, 1, 1]
         finally:
             app.graceful_stop()
+
+    @pytest.mark.parametrize("edge", ["sync", "batched", "replay"])
+    def test_one_entry_in_64_records_the_spans_under_its_flush(self, clock, edge):
+        """128 submissions: exactly two ``ingest.collect`` /
+        ``herder.recv_transaction`` / ``tx.check_valid`` triples (arrival
+        indices 0 and 64), each with its ``ingest.flush`` as ancestor, the
+        herder's span carrying the status — and every status what a node
+        with the tracer off (no entry sampled) answers."""
+        from stellar_tpu.crypto.keys import PubKeyUtils, SecretKey
+        from stellar_tpu.ingest.plane import INGEST_SAMPLE_STRIDE
+        from stellar_tpu.ledger.accountframe import AccountFrame
+        from stellar_tpu.main.application import Application
+        from stellar_tpu.tx import testutils as T
+
+        assert INGEST_SAMPLE_STRIDE == 64
+        n = 128
+
+        def drive(instance, traced):
+            cfg = T.get_test_config(instance)
+            cfg.HTTP_PORT = 0
+            cfg.MANUAL_CLOSE = True
+            cfg.TRACE_ENABLED = traced
+            cfg.INGEST_BATCH_MAX = 32
+            cfg.INGEST_BATCH_DEADLINE_MS = 60_000
+            app = Application.create(clock, cfg, new_db=True)
+            try:
+                app.start()
+                PubKeyUtils.clear_verify_sig_cache()
+                app.tracer.clear()
+                root = T.root_key_for(app)
+                seq = AccountFrame.load_account(root.get_public_key(), app.database).get_seq_num()
+                txs, at = [], seq
+                for i in range(n):
+                    # arrival 64, a sampled one, and 65 do not follow the
+                    # chain (ERROR); 66 is 63 again (DUPLICATE)
+                    if i in (64, 65):
+                        use = at + 7
+                    elif i == 66:
+                        txs.append(txs[63])
+                        continue
+                    else:
+                        at += 1
+                        use = at
+                    dest = SecretKey.pseudo_random_for_testing(9900 + i)
+                    txs.append(T.tx_from_ops(app, root, use, [T.create_account_op(dest, 10**9)]))
+                assert app.ingest._arrivals == 0
+                if edge == "sync":
+                    statuses = [app.ingest.submit_sync(tx) for tx in txs]
+                elif edge == "batched":
+                    statuses = []
+                    for tx in txs:
+                        app.ingest.submit(tx, on_status=statuses.append)
+                else:
+                    statuses = app.ingest.submit_replay(txs)
+                return statuses, app.tracer.spans(), app.ingest.stats()
+            finally:
+                app.graceful_stop()
+
+        plain, none, plain_stats = drive(179, False)
+        statuses, spans, stats = drive(180, True)
+        assert none == []
+        assert statuses == plain and len(statuses) == n
+        assert statuses[63:67] == ["PENDING", "ERROR", "ERROR", "DUPLICATE"]
+        assert stats["flushed"] == plain_stats["flushed"] == n
+        assert stats["flushes"] == (n if edge == "sync" else n // 32)
+
+        by = {s.sid: s for s in spans}
+
+        def ancestors(s):
+            while s.parent is not None:
+                s = by[s.parent]
+                yield s
+
+        sampled = {k: [s for s in spans if s.name == k] for k in SAMPLED_ADMISSION}
+        assert [len(v) for v in sampled.values()] == [2, 2, 2]
+        flushes = []
+        for collect, recv, valid in zip(*sampled.values()):
+            assert by[collect.parent].name == "ingest.flush"
+            assert recv.parent == collect.parent and valid.parent == recv.sid
+            assert "ingest.flush" in [a.name for a in ancestors(valid)]
+            assert collect.attrs == {"triples": 1}
+            flushes.append(collect.parent)
+        # the two are entries 0 and 64: of different flushes, and the
+        # second is the one whose sequence number does not follow
+        assert len(set(flushes)) == 2
+        assert [s.attrs["status"] for s in sampled["herder.recv_transaction"]] == ["PENDING", "ERROR"]
+        # selftime.py gives /trace the self time of each
+        from stellar_tpu.trace import self_p50_ms
+
+        selfs = self_p50_ms(spans)
+        assert all(selfs[k] >= 0.0 for k in SAMPLED_ADMISSION)
 
     def test_herder_trigger_and_its_children(self, clock):
         from test_herder import create_account_tx, load_or_none, make_scp_app

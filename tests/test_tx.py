@@ -783,6 +783,134 @@ def test_wedged_device_dispatch_falls_back_to_host_and_latches():
     assert be.n_latch_flips[CALLER_PIPELINE] == 2
 
 
+@pytest.mark.parametrize("site", ["cutover", "stall-then-latch", "torsion"])
+def test_host_verify_counts_libsodiums_own_seconds(site):
+    """stats() ``host_verify``: batches, items and the seconds inside
+    libsodium at every site that opens ``sig.host_verify`` — the cutover,
+    the device stall and the wedge latch — read inside the span, so the
+    spans' durations hold them; ``items`` is ``caller_items``' host column
+    summed over callers, and a host torsion batch adds nothing."""
+    import threading
+
+    from stellar_tpu.crypto.sigbackend import (
+        CALLER_CLOSE,
+        CALLER_INGEST,
+        CALLER_OVERLAY,
+        TpuSigBackend,
+    )
+    from stellar_tpu.trace import Tracer
+
+    be = TpuSigBackend.__new__(TpuSigBackend)  # skip JAX verifier init
+    be._tracer = Tracer(enabled=True)
+    be.cpu_cutover = 8 if site == "cutover" else 0
+    be.n_cutover_items = 0
+    be.n_cutover_torsion = 0
+    be.n_wedge_fallback_items = 0
+    be._wedged_until = {}
+    be.n_latch_flips = {}
+    be._wedge_lock = threading.Lock()
+    be.DEVICE_TIMEOUT = 0.2
+
+    class WedgedVerifier:
+        def cold_buckets(self, n, host_assist=True):
+            return 0
+
+        def chunk_count(self, n, host_assist=True):
+            return 1
+
+        def verify(self, items):
+            threading.Event().wait()  # wedged forever
+
+        verify_torsion = verify
+
+        def stats(self):
+            return {}
+
+    be._verifier = WedgedVerifier()
+    sks = [SecretKey.pseudo_random_for_testing(40 + i) for i in range(5)]
+    items = [(sk.public_raw, b"hv-%d" % i, sk.sign(b"hv-%d" % i)) for i, sk in enumerate(sks)]
+    bad = (items[0][0], b"another message", items[0][2])
+    assert be.stats()["host_verify"] == {"calls": 0, "items": 0, "s": 0.0}
+
+    if site == "cutover":
+        assert be.verify_batch(items[:3], caller=CALLER_INGEST) == [True] * 3
+        assert be.verify_batch([items[3], bad], caller=CALLER_CLOSE) == [True, False]
+        want_calls, want_items, reasons = 2, 5, ["cutover", "cutover"]
+    elif site == "stall-then-latch":
+        # the device outlasts its budget, then the class is latched
+        assert be.verify_batch(items[:3], caller=CALLER_INGEST) == [True] * 3
+        assert be.verify_batch([items[3], bad], caller=CALLER_INGEST) == [True, False]
+        assert be.n_latch_flips == {CALLER_INGEST: 1}
+        want_calls, want_items, reasons = 2, 5, ["device-stall", "wedge-latch"]
+    else:
+        encs = [sk.public_raw for sk in sks]
+        be.cpu_cutover = 8
+        assert be.torsion_check(encs, caller=CALLER_OVERLAY) == [True] * 5  # cutover
+        be.cpu_cutover = 0
+        assert be.torsion_check(encs, caller=CALLER_OVERLAY) == [True] * 5  # stall
+        assert be.torsion_check(encs, caller=CALLER_OVERLAY) == [True] * 5  # latched
+        assert be.n_wedge_fallback_items == 10 and be.n_cutover_torsion == 5
+        want_calls, want_items, reasons = 0, 0, []
+
+    s = be.stats()
+    hv = s["host_verify"]
+    assert (hv["calls"], hv["items"]) == (want_calls, want_items)
+    assert hv["items"] == sum(v["host"] for v in s["caller_items"].values())
+    assert hv["items"] == s["cpu_cutover_items"] + (
+        s["wedge_fallback_items"] if site == "stall-then-latch" else 0
+    )
+    spans = [sp for sp in be._tracer.spans() if sp.name == "sig.host_verify"]
+    assert [sp.attrs["reason"] for sp in spans] == reasons
+    assert sum(sp.attrs["items"] for sp in spans) == want_items
+    if want_items:
+        # both clocks are the machine's monotonic one: libsodium's seconds
+        # lie inside the spans
+        assert 0.0 < hv["s"] <= sum(sp.duration for sp in spans)
+    else:
+        assert hv["s"] == 0.0
+        assert len([sp for sp in be._tracer.spans() if sp.name == "sig.host_torsion"]) == 3
+
+
+def test_host_verify_counts_survive_concurrent_callers():
+    """More callers than cores, a short switch interval: no update of the
+    three ``host_verify`` counters is lost."""
+    import sys
+    import threading
+
+    from stellar_tpu.crypto.sigbackend import CALLER_CLOSE, TpuSigBackend
+
+    be = TpuSigBackend.__new__(TpuSigBackend)  # skip JAX verifier init
+    be.cpu_cutover = 8
+    be.n_cutover_items = 0
+    be._wedge_lock = threading.Lock()
+    sk = SecretKey.pseudo_random_for_testing(77)
+    items = [(sk.public_raw, b"hv", sk.sign(b"hv"))] * 3
+    threads, rounds = 16, 200
+    failed = []
+
+    def caller():
+        try:
+            for _ in range(rounds):
+                if be.verify_batch(items, caller=CALLER_CLOSE) != [True] * 3:
+                    failed.append("verdict")
+        except Exception as e:  # read in the assertion below
+            failed.append(repr(e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=caller) for _ in range(threads)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in ts) and not failed, failed
+    assert (be.n_host_verify_calls, be.n_host_verify_items) == (threads * rounds, 3 * threads * rounds)
+    assert be.host_verify_s > 0.0
+
+
 def test_first_dispatch_of_each_bucket_gets_the_compile_budget():
     """The compile-bearing budget follows the compiled SHAPE, not the
     surface: every bucket's first dispatch traces+lowers+compiles inside
